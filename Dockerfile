@@ -7,6 +7,9 @@ WORKDIR /opt/volcano-tpu
 COPY pyproject.toml README.md ./
 COPY volcano_tpu ./volcano_tpu
 RUN pip install --no-cache-dir . && mkdir -p /var/lib/vtpu
+# The XLA compile cache is placed from outside (scheduler.py sets no
+# directory where this is set); keep it on the state volume.
+ENV JAX_COMPILATION_CACHE_DIR=/var/lib/vtpu/xla_cache
 
 VOLUME /var/lib/vtpu
 EXPOSE 11250

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""Minimal repro for the 50k-node x 500k-pod + inter-pod-affinity TPU
-worker crash (BASELINE.md known limit).
+"""Minimal repro for the 50k-node x 500k-pod + inter-pod-affinity device
+failure of earlier rounds (never re-run on the current machine).
 
 Runs BASELINE config 5 FULL with affinity, logging every chunked solve
 (jobs, rows, active terms, padded count-tensor bytes) to an artifact

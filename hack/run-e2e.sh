@@ -9,8 +9,8 @@ hack/run-checks.sh
 # The pipelined-mode pass (tests/test_pipeline.py: double-buffered
 # sessions over the remote-solver split, overlap-correctness gate) runs
 # inside run-checks.sh's tier-1 leg above — not repeated here.
-# BENCH_MESH smoke (ISSUE 7): the mesh-native sharded solve A/B on a
-# forced 4-device virtual-CPU host at a small shape — asserts the mesh
+# BENCH_MESH smoke (ISSUE 7): the mesh-native sharded solve A/B on 4
+# virtual host devices (JAX_PLATFORMS=cpu) at a small shape — asserts the mesh
 # pass completes, pipelines, and emits its JSON tail (plain vs mesh,
 # lane splits, winner-reduce microbench).
 BENCH_MESH=4 BENCH_CONFIG=2 BENCH_NODES=256 BENCH_PODS=2048 \
@@ -193,8 +193,6 @@ assert t["evictions"] >= 1, t
 assert t["gang_bound"] >= t["gang"], f"serving gang did not bind: {t}"
 assert t["lost_pods"] == 0, f"pods lost: {t}"
 assert t["restored"] == t["evictions"], t
-# (%-formatting: a backslash inside an f-string expression is a
-# SyntaxError before Python 3.12.)
 print("BENCH_PREEMPT smoke OK (%s evictions, %s cycles to bind)"
       % (t["evictions"], t["converged_cycles"]))
 '

@@ -2,12 +2,14 @@
 
 Multi-chip sharding is validated on a virtual CPU mesh
 (xla_force_host_platform_device_count), matching how the driver dry-runs the
-multi-chip path; real-TPU benchmarking happens in bench.py.  The override
+multi-chip path; the run on real chips is chip_smoke.py.  The override
 logic is shared with __graft_entry__.dryrun_multichip via
 volcano_tpu.virtualcpu.
 """
 
 import os
+
+import pytest
 
 from volcano_tpu.virtualcpu import force_virtual_cpu_platform
 
@@ -27,3 +29,17 @@ os.environ.setdefault("VOLCANO_TPU_FALLBACK", "never")
 # monkeypatch.setenv("VOLCANO_TPU_EVICT_DEVICE", "1").  Outside tests
 # the device lane is the default.
 os.environ.setdefault("VOLCANO_TPU_EVICT_DEVICE", "0")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _drop_jit_caches_between_modules():
+    """One process runs ~850 tests and compiles thousands of XLA:CPU
+    executables; with all of them kept alive, jaxlib 0.9.0 segfaults
+    inside backend_compile_and_load a little past half way (compiling
+    ops/allocate.py's sequential solver in test_oracle_parity or
+    test_parallel).  Dropping the jit caches after each module keeps the
+    live set small, and the whole tier-1 line runs to the end."""
+    yield
+    import jax
+
+    jax.clear_caches()

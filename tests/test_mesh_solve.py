@@ -277,3 +277,35 @@ def test_mesh_pipelined_staleness_guard_drops_deleted(monkeypatch):
     assert key not in store.binder.binds
     assert len(store.binder.binds) == 31  # everyone else lands
     store.close()
+
+
+# ------------------------------------------- the asked-for mesh or nothing
+
+
+@needs_4
+def test_mesh_env_builds_the_mesh_once(monkeypatch):
+    from volcano_tpu.parallel.mesh import mesh_from_env
+
+    monkeypatch.setenv("VOLCANO_TPU_MESH", "4")
+    store = ClusterStore()
+    mesh = mesh_from_env(store)
+    assert mesh is not None and mesh.devices.size == 4
+    assert mesh_from_env(store) is mesh is store.solve_mesh
+    store.close()
+
+
+@pytest.mark.parametrize("raw", ["4096", "four"])
+def test_mesh_env_raises_when_the_mesh_cannot_be_built(monkeypatch, raw):
+    """More devices than the backend has, or not a number: the cycle
+    fails instead of carrying on on one device."""
+    from volcano_tpu.parallel.mesh import mesh_from_env
+
+    monkeypatch.setenv("VOLCANO_TPU_MESH", raw)
+    monkeypatch.setenv("VOLCANO_TPU_FALLBACK", "never")
+    store = synthetic_cluster(seed=1, n_nodes=8, n_pods=16, gang_size=2)
+    with pytest.raises(RuntimeError, match="VOLCANO_TPU_MESH"):
+        mesh_from_env(store)
+    with pytest.raises(RuntimeError, match="VOLCANO_TPU_MESH"):
+        Scheduler(store).run_once()
+    assert not store.binder.binds
+    store.close()

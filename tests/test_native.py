@@ -113,3 +113,37 @@ def test_encode_cluster_native_vs_fallback():
             continue
         for f1, f2 in zip(grp1, grp2):
             np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2))
+
+
+def test_stale_library_in_csrc_is_never_loaded(tmp_path, monkeypatch):
+    """The library that gets loaded is the one built from the sources:
+    a left-over ``libvcsnap.so`` of unknown origin (here: not even an
+    ELF file — loading it would fail loudly) and a library built from
+    OLDER sources are both ignored, and a changed source changes the
+    name that is loaded."""
+    import shutil
+
+    for name in native._BUILD_INPUTS:
+        shutil.copy(native._CSRC / name, tmp_path / name)
+    stale = tmp_path / "libvcsnap.so"
+    stale.write_bytes(b"not a shared object")
+    older = tmp_path / "libvcsnap-0123456789abcdef.so"
+    older.write_bytes(b"built from sources that are gone")
+    monkeypatch.setattr(native, "_CSRC", tmp_path)
+    monkeypatch.setattr(native, "_LIB", None)
+    monkeypatch.setattr(native, "_LIB_PATH", None)
+    monkeypatch.setattr(native, "_TRIED", False)
+    monkeypatch.delenv("VOLCANO_TPU_VCSNAP", raising=False)
+    monkeypatch.delenv("VOLCANO_TPU_NO_NATIVE", raising=False)
+
+    assert native.native_available()
+    loaded = native.loaded_path()
+    assert loaded == native.built_lib_path()
+    assert loaded.parent == tmp_path and loaded not in (stale, older)
+    assert stale.read_bytes() == b"not a shared object"  # untouched
+    assert not older.exists()  # swept by the build
+    assert native.lib_or_none().vcsnap_version() > 0
+
+    with open(tmp_path / "vcsnap.h", "a") as f:
+        f.write("\n// edited\n")
+    assert native.built_lib_path() != loaded

@@ -257,9 +257,11 @@ def test_node_relabel_mid_flight_drops_selector_rows():
 
 
 def test_fetch_device_crash_degrades_budget_and_replaces(monkeypatch):
-    """An execution-time device crash surfacing at the async fetch must
+    """Device memory exhaustion surfacing at the async fetch must
     route through the same chunk-budget degradation as a synchronous
     solve (not be swallowed), and the rows re-place."""
+    import jax
+
     from volcano_tpu import pipeline as pl
 
     store = _small(seed=29)
@@ -274,7 +276,8 @@ def test_fetch_device_crash_degrades_budget_and_replaces(monkeypatch):
     def crash_once(self):
         if calls["n"] == 0:
             calls["n"] += 1
-            raise RuntimeError("TPU worker process crashed mid-solve")
+            raise jax.errors.JaxRuntimeError(
+                "RESOURCE_EXHAUSTED: Error allocating device buffer")
         return real_fetch(self)
 
     monkeypatch.setattr(pl.InflightSolve, "fetch", crash_once)

@@ -59,7 +59,7 @@ def test_two_process_bind_loop(solver_proc):
     assert len(store.binder.binds) == 64
     assert client.requests >= 1
     assert client.ping()["solves"] >= 1  # the CHILD actually solved
-    # Overhead telemetry exists for BASELINE.md.
+    # Overhead telemetry is collected.
     assert client.bytes_out > 0 and client.bytes_in > 0
     store.close()
 

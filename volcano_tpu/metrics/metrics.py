@@ -213,8 +213,8 @@ class Metrics:
         )
         self.device_crash_recoveries = _Counter(
             f"{ns}_device_crash_recoveries_total",
-            "Mid-solve TPU runtime crashes recovered by degrading the "
-            "affinity chunk budget",
+            "Mid-solve device memory exhaustions recovered by degrading "
+            "the affinity chunk budget",
         )
         self.snapshot_transfer_bytes = _Gauge(
             f"{ns}_snapshot_transfer_bytes",

@@ -2,10 +2,17 @@
 
 The C++ library owns the hot marshalling loops of the snapshot encoder —
 CSR bitset packing, CSR resource-slot scatter, padded row gather, and the
-epsilon LessEqual row check (resource_info.go:286-320).  When the shared
-library is absent it is built on first use with g++ (cached), and if that
-fails every entry point falls back to a vectorized NumPy implementation
-with identical semantics (cross-checked by tests/test_native.py).
+epsilon LessEqual row check (resource_info.go:286-320).
+
+The library that gets loaded is built from the sources in the tree: its
+file name carries the content hash of ``csrc/vcsnap.cc``, ``vcsnap.h``
+and ``Makefile`` (``csrc/libvcsnap-<hash>.so``), it is built on first use
+with ``make -C csrc``, and a file of any other name lying in ``csrc/``
+(a hand-built or left-over ``libvcsnap.so``) is never loaded.  If the
+build fails every entry point falls back to a vectorized NumPy
+implementation with identical semantics (cross-checked by
+tests/test_native.py) and the failure is logged as an error; callers
+that must not run on the stand-in check ``native_available()``.
 
 Set VOLCANO_TPU_NO_NATIVE=1 to force the NumPy fallback;
 VOLCANO_TPU_VCSNAP=/path/to/libvcsnap.so to use a prebuilt library (e.g.
@@ -15,6 +22,7 @@ the ASAN build from `make -C csrc asan`).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -29,7 +37,11 @@ log = logging.getLogger(__name__)
 _CSRC = Path(__file__).resolve().parent.parent / "csrc"
 _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
+_LIB_PATH: Optional[Path] = None
 _TRIED = False
+
+# Everything the build rule reads; their bytes key the output name.
+_BUILD_INPUTS = ("vcsnap.cc", "vcsnap.h", "Makefile")
 
 _i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
 _i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
@@ -125,8 +137,31 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     return lib
 
 
+def built_lib_path() -> Path:
+    """``csrc/libvcsnap-<hash>.so`` for the sources as they are now."""
+    h = hashlib.sha256()
+    for name in _BUILD_INPUTS:
+        data = (_CSRC / name).read_bytes()
+        h.update(f"{name}:{len(data)}:".encode())
+        h.update(data)
+    return _CSRC / f"libvcsnap-{h.hexdigest()[:16]}.so"
+
+
+def _build(target: Path) -> None:
+    """``make`` the hash-named library (the rule writes to a temporary
+    name and renames, so a concurrent loader never maps a half-written
+    file), then drop libraries built from older sources."""
+    subprocess.run(
+        ["make", "-s", "-C", str(_CSRC), f"LIB={target.name}"],
+        check=True, capture_output=True, timeout=120,
+    )
+    for old in _CSRC.glob("libvcsnap-*.so"):
+        if old != target:
+            old.unlink(missing_ok=True)
+
+
 def _load() -> Optional[ctypes.CDLL]:
-    global _LIB, _TRIED
+    global _LIB, _LIB_PATH, _TRIED
     if _LIB is not None or _TRIED:
         return _LIB
     with _LOCK:
@@ -136,34 +171,36 @@ def _load() -> Optional[ctypes.CDLL]:
         if os.environ.get("VOLCANO_TPU_NO_NATIVE"):
             return None
         override = os.environ.get("VOLCANO_TPU_VCSNAP")
-        candidates = [Path(override)] if override else []
-        candidates.append(_CSRC / "libvcsnap.so")
-        for path in candidates:
-            if path.is_file():
-                try:
-                    _LIB = _bind(ctypes.CDLL(str(path)))
-                    return _LIB
-                except (OSError, AttributeError) as err:
-                    # AttributeError: stale prebuilt library missing a
-                    # newer symbol — fall through to the rebuild.
-                    _LIB = None
-                    log.warning("vcsnap load failed (%s): %s", path, err)
-        # Build on first use.
         try:
-            subprocess.run(
-                ["make", "-s", "-C", str(_CSRC)],
-                check=True, capture_output=True, timeout=120,
-            )
-            _LIB = _bind(ctypes.CDLL(str(_CSRC / "libvcsnap.so")))
-            log.info("built native vcsnap serializer")
+            if override:
+                path = Path(override)
+            else:
+                path = built_lib_path()
+                if not path.is_file():
+                    _build(path)
+                    log.info("built native vcsnap serializer %s",
+                             path.name)
+            _LIB = _bind(ctypes.CDLL(str(path)))
+            _LIB_PATH = path
         except (OSError, AttributeError, subprocess.SubprocessError) as err:
-            _LIB = None
-            log.warning("vcsnap build failed, using NumPy fallback: %s", err)
+            # AttributeError: an override library missing a newer symbol.
+            detail = getattr(err, "stderr", b"") or b""
+            log.error(
+                "native vcsnap library unavailable, using the NumPy "
+                "stand-in: %s %s", err,
+                detail.decode(errors="replace")[-2000:],
+            )
         return _LIB
 
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def loaded_path() -> Optional[Path]:
+    """File the bound library was loaded from (None: NumPy stand-in)."""
+    _load()
+    return _LIB_PATH
 
 
 def lib_or_none() -> Optional[ctypes.CDLL]:

@@ -49,12 +49,16 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..arrays.affinity import AffinityArgs
 from .resreq import less_equal
 from .scoring import ScoreWeights, node_score
 
-NEG = jnp.float32(-3.0e38)
+# A NumPy scalar, not jnp.float32(...): a jnp call at import time
+# initialises the backend, so merely importing the package would take
+# the chip (and a process that must stay off it could not say so first).
+NEG = np.float32(-3.0e38)
 
 
 class SolveNodes(NamedTuple):

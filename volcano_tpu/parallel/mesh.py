@@ -13,7 +13,7 @@ next to the [N, R] node state, and every chip needs the winner of each step
 anyway.
 
 ``dryrun_multichip`` in __graft_entry__.py drives this on a virtual CPU mesh;
-the same code runs unchanged on a real multi-chip TPU slice.
+``chip_smoke.py --chips 4`` drives it on the four chips of one TPU host.
 """
 
 from __future__ import annotations
@@ -37,9 +37,9 @@ def make_mesh(n_devices: Optional[int] = None, axis: str = NODES_AXIS,
 
     ``platform`` pins the backend explicitly ("cpu", "tpu"); default is
     jax's default backend.  Callers that need the virtual CPU mesh (the
-    multi-chip dryrun, the test suite) must force the platform first —
-    ``volcano_tpu.virtualcpu.force_virtual_cpu_platform`` — and pass
-    ``platform="cpu"``.
+    multi-chip dryrun, the test suite) force the platform first with
+    ``volcano_tpu.virtualcpu.force_virtual_cpu_platform``.  Raises when
+    the backend has fewer than ``n_devices`` devices.
     """
     devices = jax.devices(platform) if platform is not None else jax.devices()
     if n_devices is not None:
@@ -54,33 +54,24 @@ def make_mesh(n_devices: Optional[int] = None, axis: str = NODES_AXIS,
 def mesh_from_env(store) -> Optional[Mesh]:
     """The store's solve mesh, or one built from ``VOLCANO_TPU_MESH=<n>``
     (the deploy-time enable knob: ``store.solve_mesh`` set explicitly
-    always wins; unset/0/1 keeps the single-device path).  A backend
-    with fewer than n devices logs once and stays single-device instead
-    of failing the cycle — the knob must be safe to bake into a config
-    that also runs on one chip."""
+    always wins; unset/0/1 keeps the single-device path).  A value that
+    is not an integer, or a backend with fewer than n devices, raises:
+    a deployment that asked for n chips must not carry on on one."""
     mesh = getattr(store, "solve_mesh", None)
-    if mesh is not None:
+    if mesh is not None or getattr(store, "_mesh_env_checked", False):
         return mesh
-    if getattr(store, "_mesh_env_checked", False):
-        return None
-    store._mesh_env_checked = True
     raw = os.environ.get("VOLCANO_TPU_MESH", "")
     try:
-        n = int(raw)
+        n = int(raw) if raw else 0
     except ValueError:
-        if raw:
-            log.warning("VOLCANO_TPU_MESH=%r is not an integer; "
-                        "staying single-device", raw)
-        return None
-    if n < 2:
-        return None
-    try:
-        mesh = make_mesh(n)
-    except RuntimeError as e:
-        log.warning("VOLCANO_TPU_MESH=%s but %s; staying single-device",
-                    raw, e)
-        return None
-    store.solve_mesh = mesh
+        raise RuntimeError(
+            f"VOLCANO_TPU_MESH={raw!r} is not an integer") from None
+    if n >= 2:
+        try:
+            mesh = store.solve_mesh = make_mesh(n)
+        except RuntimeError as e:
+            raise RuntimeError(f"VOLCANO_TPU_MESH={raw}: {e}") from None
+    store._mesh_env_checked = True
     return mesh
 
 

@@ -31,33 +31,36 @@ log = logging.getLogger(__name__)
 
 _compile_cache_enabled = False
 
+# The persistent XLA cache when JAX_COMPILATION_CACHE_DIR does not place
+# it: one fixed directory in the checkout, next to csrc/ (resolved like
+# native._CSRC).  The directory's path is part of every cache key, so
+# it must not depend on $HOME, the pid or the time.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parent.parent / ".xla_cache"
+
 
 def enable_compilation_cache() -> None:
-    """Persist XLA executables across processes (wave-solver compiles run
-    multiple seconds; a restarted scheduler would otherwise pay them
-    again).  Opt out with VOLCANO_TPU_COMPILE_CACHE=0 or point the cache
-    elsewhere with VOLCANO_TPU_COMPILE_CACHE=<dir>."""
+    """Persist XLA executables across processes (wave-solver compiles
+    run many seconds; a restarted scheduler would otherwise pay them
+    again).  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX has already
+    taken the directory from it and none is set here."""
     global _compile_cache_enabled
     if _compile_cache_enabled:
         return
     _compile_cache_enabled = True
     import os
 
-    loc = os.environ.get("VOLCANO_TPU_COMPILE_CACHE", "")
-    if loc == "0":
-        return
-    if not loc:
-        loc = os.path.join(
-            os.path.expanduser("~"), ".cache", "volcano_tpu_xla"
-        )
-    try:
-        import jax
+    import jax
 
-        os.makedirs(loc, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", loc)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception as err:  # pragma: no cover - cache is best-effort
-        log.warning("compilation cache unavailable: %s", err)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        try:
+            COMPILE_CACHE_DIR.mkdir(exist_ok=True)
+        except OSError as err:  # read-only install: run uncached
+            log.warning("compilation cache unavailable (set "
+                        "JAX_COMPILATION_CACHE_DIR): %s", err)
+            return
+        jax.config.update("jax_compilation_cache_dir",
+                          str(COMPILE_CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
 import contextlib

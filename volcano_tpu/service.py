@@ -659,8 +659,10 @@ def main(argv=None) -> int:
                         "for a replica pool (solver_pool.py: hedged "
                         "dispatch, one-cycle failover, what-if offload; "
                         "VOLCANO_TPU_SOLVER_POOL=<n> pools n "
-                        "connections to a single address).  The "
-                        "scheduler then never touches an accelerator: "
+                        "connections to a single address).  This "
+                        "process then pins itself to the host CPU "
+                        "(its auxiliary kernels run there; the chip "
+                        "belongs to vtpu-solver): "
                         "each cycle's solver inputs ship as one "
                         "C++-packed snapshot frame and the assignment "
                         "vectors return — the north-star store<->solver "
@@ -674,6 +676,15 @@ def main(argv=None) -> int:
                         "reachable via VOLCANO_TPU_PIPELINE=1")
     args = p.parse_args(argv)
 
+    if args.remote_solver:
+        # The chip belongs to the vtpu-solver process.  This process
+        # still runs small kernels through JAX (gang_block_fit,
+        # frag_scores, victim scoring, the crash probe); left to JAX's
+        # default it would race the solver for the chip on a shared
+        # host, so it pins itself to the host CPU, loudly, up front.
+        from .device import keep_off_accelerator
+
+        keep_off_accelerator("vtpu-service --remote-solver")
     svc = Service(
         conf_path=args.scheduler_conf,
         schedule_period=args.schedule_period,

@@ -289,7 +289,7 @@ class _ShmReader:
             except (OSError, ValueError, TypeError) as e:
                 raise ShmUnavailable(f"cannot attach segment "
                                      f"{name!r}: {e}") from e
-            # py3.10 registers ATTACHED segments with the resource
+            # Python 3.12 registers ATTACHED segments with the resource
             # tracker too, which would unlink the client's live segment
             # when this process exits; the creator owns the unlink.
             # Skip when creator and reader share a process (in-process
@@ -698,7 +698,7 @@ class RemoteSolver:
         # Outstanding pipelined request (solve_async): the wire protocol
         # is strict request/reply, so at most one may be unread.
         self._pending: Optional["PendingSolve"] = None  # guarded-by: _lock
-        # Round-trip + payload telemetry for the BASELINE overhead table.
+        # Round-trip + payload telemetry (the split's overhead).
         self.requests = 0
         self.bytes_out = 0
         self.bytes_in = 0
@@ -1207,6 +1207,12 @@ def main(argv=None) -> None:
                              "(spawners parse this)")
     args = parser.parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    # This is the process that owns the chip: refuse to serve solves
+    # from a silent CPU fallback (no chip, or another process holds it).
+    from .device import device_info, require_accelerator
+
+    require_accelerator("vtpu-solver")
+    log.info("solver device: %s", device_info())
     server = SolverServer(host=args.host, port=args.port)
     if args.announce:
         print(f"SOLVER {server.port}", flush=True)

@@ -1,6 +1,6 @@
 """Synthetic cluster generators + solver-arg builder.
 
-Drives the BASELINE benchmark configurations (BASELINE.md: 1k x 10k binpack,
+Drives the BASELINE benchmark configurations (BASELINE.json: 1k x 10k binpack,
 5k DRF multi-queue, 10k preempt, 50k x 500k hyperscale) and the graft
 entry's example inputs.  This is the rebuild's equivalent of the reference's
 e2e fixture builders (test/e2e/util.go) at synthetic scale.
