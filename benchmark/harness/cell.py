@@ -1,0 +1,86 @@
+"""A cell, found by its name in ``BENCHMARK.json``: its configuration file,
+its traffic file and the per-layer metric files, all data.
+
+A later PR adds a configuration, a traffic mix, a cell or a per-layer metric
+by adding files and entries: ``configs/<config>.json``,
+``traffic/<mix>.json``, ``layer_metrics/<metric>.json`` under the benchmark's
+first path, found from the entries of ``BENCHMARK.json``.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+from dataclasses import dataclass
+from pathlib import Path
+from typing import List
+
+ROOT = Path(__file__).resolve().parents[2]
+
+
+def use_checkout_compile_cache() -> None:
+    """The one variable the harness sets, and only when it is unset: JAX's
+    persistent compile cache goes to the fixed ``.xla_cache/`` inside the
+    checkout that ``scheduler.py`` already uses.  Call before JAX is
+    imported."""
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", str(ROOT / ".xla_cache"))
+
+
+@dataclass
+class Cell:
+    name: str
+    chips: int
+    config_name: str
+    config: dict
+    traffic_name: str
+    traffic: dict
+    end_to_end: List[dict]      # BENCHMARK.json entries this cell reports
+    per_layer: List[dict]       # entry merged with its layer_metrics file
+
+    def sizes(self) -> dict:
+        """Resident set, batch and warm-up rounds in pods, from the traffic's
+        shares of the configuration's backlog."""
+        backlog = int(self.config["backlog_pods"])
+        t = self.traffic
+        return {
+            "resident_pods": int(round(backlog * float(t.get("resident_fraction", 0.0)))),
+            "batch_pods": max(1, int(round(backlog * float(t["batch_fraction"])))),
+            "warmup_rounds": int(t.get("warmup_rounds", 1)),
+            "max_cycles": int(t.get("max_cycles", 4)),
+            "bind_wait_s": float(t.get("bind_wait_s", 10.0)),
+            "profile_seconds": float(t.get("profile_seconds", 10.0)),
+        }
+
+
+def _read(path: Path) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def _applies(entry: dict, cell_name: str) -> bool:
+    return "workloads" not in entry or cell_name in entry["workloads"]
+
+
+def load_cell(workload: str, benchmark_file: Path = ROOT / "BENCHMARK.json") -> Cell:
+    benchmark_file = Path(benchmark_file)
+    base = benchmark_file.parent
+    bench = _read(benchmark_file)
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if workload not in cells:
+        raise SystemExit(f"no cell {workload!r} in {benchmark_file}; it has "
+                         f"{sorted(cells)}")
+    w = cells[workload]
+    configs = {c["name"]: c for c in bench["configs"]}
+    home = base / bench["paths"][0]
+    config = _read(base / configs[w["config"]]["file"])
+    traffic = _read(home / "traffic" / f"{w['traffic']}.json")
+    per_layer = []
+    for entry in bench["per_layer"]:
+        if _applies(entry, workload):
+            spec = _read(home / "layer_metrics" / f"{entry['name']}.json")
+            per_layer.append({**spec, **entry})
+    return Cell(
+        name=workload, chips=int(w["chips"]), config_name=w["config"],
+        config=config, traffic_name=w["traffic"], traffic=traffic,
+        end_to_end=[e for e in bench["end_to_end"] if _applies(e, workload)],
+        per_layer=per_layer)

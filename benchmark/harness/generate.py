@@ -1,0 +1,235 @@
+"""Traffic generation: one general generator, driven by a configuration file
+(node and pod shapes, gangs, queues) and a traffic file (how much, how often).
+
+The draw is a copy of ``volcano_tpu/synth.py``'s ``synthetic_cluster`` (nodes
+of one shape, zones round-robin, each gang one cpu and one memory size out of
+the configuration's choices, optional affinity mix), kept here because later
+PRs may change ``synth.py`` and may not change the yardstick.  Two departures,
+both so that every seed does the same work in another order:
+
+- gangs take the cpu x memory combinations in equal shares, dealt from a
+  deck the seed shuffles (synth draws them independently);
+- uids and creation timestamps are given (synth lets the API mint a uuid and
+  read the clock per pod), which also keeps the client's own cost small.
+
+A *plan* is plain data (names, integer requests, gang of each pod): the
+validator and the reference read plans and never the program's objects.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass, field
+from typing import List, Optional
+
+import numpy as np
+
+GI = 1 << 30
+NAMESPACE = "default"
+
+
+@dataclass
+class Plan:
+    """One batch of gangs as plain data.  Pod ``i`` of the batch has name
+    ``names[i]``, requests ``cpu_milli[i]`` / ``mem_bytes[i]`` and belongs to
+    gang ``gang[i]`` (an index into the gang arrays)."""
+
+    tag: str
+    names: List[str]
+    cpu_milli: np.ndarray
+    mem_bytes: np.ndarray
+    gang: np.ndarray
+    gang_names: List[str]
+    gang_min_member: np.ndarray
+    gang_queue: List[str]
+    gang_cpu: List[int] = field(default_factory=list)      # cores
+    gang_mem_gi: List[int] = field(default_factory=list)
+    gang_kind: List[str] = field(default_factory=list)     # "", affinity, ...
+
+    @property
+    def n_pods(self) -> int:
+        return len(self.names)
+
+    def keys(self) -> List[str]:
+        return [f"{NAMESPACE}/{n}" for n in self.names]
+
+
+def node_names(config) -> List[str]:
+    return [f"node-{i:06d}" for i in range(int(config["nodes"]["count"]))]
+
+
+def node_alloc(config) -> np.ndarray:
+    """[N, 3] int64: allocatable cpu (milli), memory (bytes), pods."""
+    n = config["nodes"]
+    row = [int(n["cpu"]) * 1000, int(n["memory_gi"]) * GI, int(n["pods"])]
+    return np.tile(np.array(row, dtype=np.int64), (int(n["count"]), 1))
+
+
+def queue_names(config) -> List[str]:
+    q = config.get("queues", {})
+    return ["default"] + [f"queue-{i}" for i in range(1, int(q.get("count", 1)))]
+
+
+class Generator:
+    """Batches of gangs for one run, all drawn from ``seed``."""
+
+    def __init__(self, config: dict, seed: int):
+        self.config = config
+        self.rng = np.random.default_rng(seed)
+        pods = config["pods"]
+        self.combos = [(int(c), int(m)) for c in pods["cpu_choices"]
+                       for m in pods["mem_gi_choices"]]
+        gang = config["gang"]
+        self.gang_sizes = [int(s) for s in gang.get("sizes", [gang.get("size", 1)])]
+        self.queues = queue_names(config)
+        mix = config.get("affinity_mix", {})
+        self.mix = (float(mix.get("affinity", 0.0)),
+                    float(mix.get("anti_affinity", 0.0)),
+                    float(mix.get("spread", 0.0)))
+        self.zones = int(config["nodes"].get("zones", 0))
+        self._gangs_made = 0
+        self._deck: List[int] = []
+
+    def plan(self, n_pods: int, tag: str, gang_size: Optional[int] = None) -> Plan:
+        """``n_pods`` pods in gangs of the configuration's size(s) (or of
+        ``gang_size``); the last gang is cut to what is left."""
+        sizes = []
+        left = int(n_pods)
+        while left > 0:
+            size = gang_size or int(self.gang_sizes[
+                int(self.rng.integers(len(self.gang_sizes)))
+                if len(self.gang_sizes) > 1 else 0])
+            size = min(size, left)
+            sizes.append(size)
+            left -= size
+        g_n = len(sizes)
+        combo_of = self._deal(g_n)
+        kinds = self._kinds(g_n)
+        names, cpu, mem, gang = [], [], [], []
+        gang_names, gang_queue, gang_cpu, gang_mem = [], [], [], []
+        for g, size in enumerate(sizes):
+            c, m = self.combos[int(combo_of[g])]
+            gname = f"{tag}-pg-{g:06d}"
+            gang_names.append(gname)
+            gang_queue.append(self.queues[(self._gangs_made + g) % len(self.queues)])
+            gang_cpu.append(c)
+            gang_mem.append(m)
+            for k in range(size):
+                names.append(f"{gname}-{k}")
+                cpu.append(c * 1000)
+                mem.append(m * GI)
+                gang.append(g)
+        self._gangs_made += g_n
+        return Plan(tag, names, np.array(cpu, np.int64), np.array(mem, np.int64),
+                    np.array(gang, np.int64), gang_names,
+                    np.array(sizes, np.int64), gang_queue, gang_cpu, gang_mem,
+                    kinds)
+
+    def _deal(self, g_n: int) -> np.ndarray:
+        """Combination of each of the next ``g_n`` gangs: dealt from a deck
+        of all cpu x memory combinations that is shuffled anew whenever it
+        runs out, so that any stretch of gangs holds every combination in
+        equal shares (to within one) whatever the seed."""
+        out = []
+        while len(out) < g_n:
+            if not len(self._deck):
+                self._deck = self.rng.permutation(len(self.combos)).tolist()
+            out.append(self._deck.pop())
+        return np.array(out, dtype=np.int64)
+
+    def _kinds(self, g_n: int) -> List[str]:
+        aff, anti, spread = self.mix
+        if aff + anti + spread <= 0.0:
+            return [""] * g_n
+        r = self.rng.random(g_n)
+        out = []
+        for x in r:
+            if self.zones > 0 and x < aff:
+                out.append("affinity")
+            elif aff <= x < aff + anti:
+                out.append("anti_affinity")
+            elif self.zones > 0 and aff + anti <= x < aff + anti + spread:
+                out.append("spread")
+            else:
+                out.append("")
+        return out
+
+
+# --- the only part of this file that touches the program's API types -------
+
+
+def to_nodes(config):
+    from volcano_tpu.api import Node
+
+    n = config["nodes"]
+    zones = int(n.get("zones", 0))
+    alloc = {"cpu": str(n["cpu"]), "memory": f"{n['memory_gi']}Gi",
+             "pods": int(n["pods"])}
+    out = []
+    for i, name in enumerate(node_names(config)):
+        labels = {"zone": f"zone-{i % zones}"} if zones > 0 else {}
+        out.append(Node(name=name, allocatable=dict(alloc), labels=labels))
+    return out
+
+
+def to_queues(config):
+    from volcano_tpu.api import Queue
+
+    q = config.get("queues", {})
+    weights = q.get("weights") or [1]
+    return [Queue(name=name, weight=int(weights[i % len(weights)]))
+            for i, name in enumerate(queue_names(config)) if i > 0]
+
+
+# Pod fields no gang of the benchmark sets: one empty list and one empty dict
+# stand in all of them, for every pod.  The store treats pod specs as
+# immutable, and a dozen fresh containers per pod would make the client's own
+# allocation (and the collector's passes over it) a tenth of a round.
+_NO_LIST: list = []
+_NO_DICT: dict = {}
+_UNSET = dict(
+    init_containers=_NO_LIST, node_selector=_NO_DICT, tolerations=_NO_LIST,
+    host_ports=_NO_LIST, affinity=_NO_LIST, anti_affinity=_NO_LIST,
+    preferred_node_affinity=_NO_LIST, required_node_affinity=_NO_LIST,
+    preferred_affinity=_NO_LIST, preferred_anti_affinity=_NO_LIST,
+    topology_spread=_NO_LIST, env=_NO_DICT, volumes=_NO_LIST)
+
+
+def to_objects(plan: Plan, stamps):
+    """The plan as API objects: one list of gangs, each ``(pod_group,
+    [pods])``.  ``stamps`` is an iterator of rising creation timestamps (the
+    run's own, so that job order does not depend on the clock).  Sub-objects
+    a gang's pods share (annotations, labels, containers) are shared by
+    reference, as ``synth.tier_cluster`` does: the store treats pod specs as
+    immutable."""
+    from volcano_tpu.api import (GROUP_NAME_ANNOTATION, AffinityTerm, Pod,
+                                 PodGroup)
+
+    gangs = []
+    start = 0
+    for g, gname in enumerate(plan.gang_names):
+        size = int(plan.gang_min_member[g])
+        pg = PodGroup(name=gname, min_member=size, queue=plan.gang_queue[g],
+                      creation_timestamp=float(next(stamps)))
+        anno = {GROUP_NAME_ANNOTATION: gname}
+        labels = {"app": gname}
+        containers = [{"cpu": str(plan.gang_cpu[g]),
+                       "memory": f"{plan.gang_mem_gi[g]}Gi"}]
+        kind = plan.gang_kind[g] if plan.gang_kind else ""
+        extra = dict(_UNSET)
+        if kind == "affinity":
+            extra["affinity"] = [AffinityTerm(match_labels=labels,
+                                              topology_key="zone")]
+        elif kind == "anti_affinity":
+            extra["anti_affinity"] = [AffinityTerm(
+                match_labels=labels, topology_key="kubernetes.io/hostname")]
+        elif kind == "spread":
+            extra["topology_spread"] = [("zone", 10)]
+        pods = []
+        for k in range(size):
+            name = plan.names[start + k]
+            pods.append(Pod(name=name, uid=f"bench-{name}", labels=labels,
+                            annotations=anno, containers=containers,
+                            creation_timestamp=float(next(stamps)), **extra))
+        start += size
+        gangs.append((pg, pods))
+    return gangs
