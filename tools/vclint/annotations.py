@@ -24,7 +24,8 @@ from typing import Dict, List, Optional, Set, Tuple
 # Files under the lock-discipline analysis (the concurrency surface of
 # the pipelined scheduler: shared store state, the mirror, the in-flight
 # solve handle, the remote-solver client, the flight-recorder ring the
-# HTTP debug handlers read cross-thread).  Runtime lockdep enforces the
+# HTTP debug handlers read cross-thread, the tracer's helper-thread
+# event buffer).  Runtime lockdep enforces the
 # same set: ``enable_lockdep`` wraps the guarded attributes of exactly
 # these files' classes.
 LOCK_FILES = [
@@ -41,6 +42,7 @@ LOCK_FILES = [
     "volcano_tpu/whatif.py",
     "volcano_tpu/ops/devsnap.py",
     "volcano_tpu/obs/recorder.py",
+    "volcano_tpu/obs/trace.py",
     "volcano_tpu/obs/audit.py",
     "volcano_tpu/obs/slo.py",
 ]

@@ -42,6 +42,9 @@ DEVICE_FNS = {
     # winner-reduction helper and the cycle's mesh dispatch both return
     # device values.
     "_topk_nodes", "_solve_mesh_dispatch",
+    # The synchronous dispatch of the allocate lane (ISSUE 25: the body
+    # of the device:dispatch span) returns the solve's device result.
+    "_solve_sync",
     # Device-incremental lane (ISSUE 9): the static-plane producer,
     # the warm-shortlist kernel, and the DeviceIncremental services
     # that return their cached device results.
@@ -100,6 +103,7 @@ HOT_REGISTRY: Dict[str, List[HotEntry]] = {
         # Mesh dispatch lane (ISSUE 7): wraps sharded_solve_wave_cycle
         # on the cycle thread for both the sync and pipelined paths.
         HotEntry("FastCycle._solve_mesh_dispatch"),
+        HotEntry("FastCycle._solve_sync"),
         HotEntry("FastCycle._commit_inflight"),
         HotEntry("FastCycle._commit"),
         HotEntry("FastCycle._solve_inputs"),

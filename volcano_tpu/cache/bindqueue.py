@@ -15,6 +15,11 @@ fast path:
   thread); each failure re-enters Pending with an exponential per-task
   backoff (``not_before``) during which the solver does not re-place it —
   the analog of the task sitting in the rate-limited errTasks queue.
+- The worker speaks to the store's tracer (obs/trace.py), per batch and
+  never per pod: ``bind:queue_wait`` (dispatch -> the worker picks the
+  batch up), ``bind:materialize``, ``bind:binder`` (the binder calls)
+  and ``bind:on_success``, as thread-safe events on the ``bind`` track
+  with ``args={"pods": n}``.  They drain with the next cycle's record.
 """
 
 from __future__ import annotations
@@ -23,6 +28,8 @@ import logging
 import threading
 import time
 from typing import Callable, List, Optional, Sequence, Tuple
+
+from ..obs.trace import null_tracer
 
 log = logging.getLogger(__name__)
 
@@ -39,14 +46,16 @@ class BindDispatcher:
     def __init__(self, binder,
                  on_failure: Callable[[List[Tuple[str, object]]], None],
                  on_success: Optional[Callable[[List[str], List[str]], None]] = None,
-                 materialize: Optional[Callable[[list], tuple]] = None):
+                 materialize: Optional[Callable[[list], tuple]] = None,
+                 tracer=None):
         self._binder = binder
+        self._tracer = tracer if tracer is not None else null_tracer()
         self._on_failure = on_failure
         self._on_success = on_success
         self._materialize = materialize
         self._cv = threading.Condition()
         # guarded-by: _cv
-        self._q: List[Tuple[Sequence[str], Sequence[str], Sequence[object]]] = []
+        self._q: List[tuple] = []  # (keys, hosts, pods, entry, t_ns)
         self._stopped = False  # guarded-by: _cv
         self._inflight = 0  # guarded-by: _cv
         # Runtime lockdep (obs/lockdep.py): created lazily, after the
@@ -68,7 +77,8 @@ class BindDispatcher:
         applies the pod.node_name record walk off the scheduling
         cycle's critical path."""
         with self._cv:
-            self._q.append((keys, hosts, pods, entry))
+            self._q.append((keys, hosts, pods, entry,
+                            time.perf_counter_ns()))
             self._inflight += 1
             self._cv.notify()
 
@@ -102,13 +112,22 @@ class BindDispatcher:
                     self._cv.wait()
                 if self._stopped and not self._q:
                     return
-                keys, hosts, pods, entry = self._q.pop(0)
+                keys, hosts, pods, entry, t_queued = self._q.pop(0)
+            now = time.perf_counter_ns
+            event = self._tracer.event
+            t0 = now()
+            args = {"pods": len(entry[0] if entry is not None else keys)}
+            event("bind:queue_wait", "bind", t_queued, t0 - t_queued,
+                  tid="bind", args=args)
             if entry is not None:
                 # Deferred record walk: tolist + setattr over the whole
                 # batch runs here, off the scheduling cycle (idempotent
                 # — a failure path may already have forced it through
                 # the store's apply_pending_bind_records).
                 keys, hosts, pods = self._materialize(entry)
+                event("bind:materialize", "bind", t0, now() - t0,
+                      tid="bind", args=args)
+            t0 = now()
             failed: List[str] = []
             bind_keys = getattr(self._binder, "bind_keys", None)
             batch_ok = False
@@ -140,6 +159,8 @@ class BindDispatcher:
                     except Exception:
                         log.exception("bind failed for %s", key)
                         failed.append(key)
+            event("bind:binder", "bind", t0, now() - t0, tid="bind",
+                  args=args)
             if failed:
                 try:
                     # Hand the pod objects back with the keys so the
@@ -161,10 +182,13 @@ class BindDispatcher:
                     )
                 else:
                     ok_pairs = (list(keys), list(hosts))
+                t0 = now()
                 try:
                     self._on_success(*ok_pairs)
                 except Exception:
                     log.exception("bind-success handler failed")
+                event("bind:on_success", "bind", t0, now() - t0,
+                      tid="bind", args=args)
             with self._cv:
                 self._inflight -= 1
                 self._cv.notify_all()

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import copy
 import threading
+import time
 from typing import Callable, Dict, List, Optional
 
 from ..api import (
@@ -443,6 +444,7 @@ class ClusterStore:
                 self.binder, self._on_bind_failures,
                 on_success=self._on_bind_success,
                 materialize=self._materialize_bind_entry,
+                tracer=self.tracer,
             )
         self._bind_dispatcher.dispatch(keys, hosts, pods, entry=entry)
 
@@ -587,6 +589,7 @@ class ClusterStore:
         with self._lock:
             if not self._objects_stale:
                 return
+            t0 = time.perf_counter_ns()
             self._objects_stale = False
             self._nodes = {}
             for row, name in enumerate(self.mirror.n_name):
@@ -618,6 +621,13 @@ class ClusterStore:
                     logging.getLogger(__name__).error(
                         "rebuild: failed to re-add task %s: %s", pod.uid, err
                     )
+            # Any thread that reads ``jobs``/``nodes`` after a commit
+            # pays this, in a cycle or between two: a parentless event
+            # on its own track, drained with the next cycle's record.
+            self.tracer.event(
+                "store:rebuild_objects", "store", t0,
+                time.perf_counter_ns() - t0, tid="store",
+                args={"pods": len(self.pods)})
 
     # ------------------------------------------------------------- watchers
 

@@ -40,7 +40,9 @@ import os
 import time
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .api import (
     PodGroupCondition,
@@ -107,6 +109,29 @@ _PHASE_STR_BY_CODE = np.array(
     ["", _PHASE_BY_CODE[1], _PHASE_BY_CODE[2], _PHASE_BY_CODE[3],
      _PHASE_BY_CODE[4], ""], object,
 )
+
+
+def _devsnap_counts(store) -> Tuple[int, int, int, int, int]:
+    """The running counters of the store's device snapshot
+    (ops/devsnap.py): full uploads, delta uploads, hits, host->device
+    puts and their bytes; zeros before the first solve builds it."""
+    snap = getattr(store, "device_snapshot", None)
+    if snap is None:
+        return (0, 0, 0, 0, 0)
+    return (snap.full_uploads, snap.delta_uploads, snap.hits,
+            snap.puts, snap.put_bytes)
+
+
+def _host_bytes(tree) -> Tuple[int, int]:
+    """(count, bytes) of the numpy leaves of ``tree``: what a jitted
+    call given ``tree`` transfers host->device (device-resident leaves
+    move nothing)."""
+    n = nbytes = 0
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if isinstance(leaf, np.ndarray):
+            n += 1
+            nbytes += leaf.nbytes
+    return n, nbytes
 
 
 def _pow2(n: int, minimum: int = 8) -> int:
@@ -225,8 +250,10 @@ class FastCycle:
         # Span tracer (obs/trace.py, ISSUE 3): the cycle's lanes, the
         # pipelined dispatch→fetch→commit chain, and the staleness
         # guard all record spans; a null tracer keeps bare test stores
-        # working.
-        self.tracer = tracer_of(store)
+        # working.  The tracer itself is stdlib-only: the profiler's
+        # annotation factory is handed in here (vc:<lane> annotations,
+        # inert unless a profiler trace runs).
+        self.tracer = tracer_of(store, annotate=TraceAnnotation)
 
     # --------------------------------------------------------- eligibility
 
@@ -731,15 +758,6 @@ class FastCycle:
         if not hasattr(store, "_phase_dirty_uids"):
             store._phase_dirty_uids = set()
         self._phase_dirty = store._phase_dirty_uids
-        # Per-lane wall-clock breakdown of this cycle (seconds),
-        # published as store.last_cycle_lanes for bench.py / operators:
-        # derive (mirror -> cycle arrays), order/pending (job ordering +
-        # row prep), encode (solver input build), device (solve dispatch
-        # + device->host fetch), commit, evict actions, close.  The
-        # trace spans (obs/trace.py) both record the span AND
-        # accumulate these lanes, so disabling tracing keeps the
-        # breakdown.
-        self.lanes: Dict[str, float] = {}
         # Cycle accounting for the flight recorder (obs/recorder.py).
         self.stats: Dict[str, object] = {
             "considered": 0, "bound": 0, "dropped": 0,
@@ -752,21 +770,30 @@ class FastCycle:
         # Clear immediately: a failed cycle (slow-path fallback) must not
         # leave a previous cycle's breakdown masquerading as its own.
         store.last_cycle_lanes = None
-        t_wall = time.time()
-        t_cycle = time.perf_counter()
-        err: Optional[BaseException] = None
-        try:
-            with self.tracer.span("cycle", cat="cycle",
-                                  args={"session": self.uid}):
+        # devsnap's running upload counters as the cycle finds them;
+        # the record's ``solve`` carries this cycle's deltas.
+        self._devsnap0 = _devsnap_counts(store)
+        # The cycle's frame (obs/trace.py CycleScope): the scheduler's
+        # when run_once() drives this cycle, else one of its own.  Its
+        # lane dict is the per-lane wall-clock breakdown of the cycle
+        # (seconds) — top-level spans only, the lanes rule — also
+        # published as store.last_cycle_lanes; the spans both record
+        # AND accumulate the lanes, so disabling tracing keeps the
+        # breakdown.
+        with self.tracer.cycle(getattr(store, "flight", None)) as scope:
+            scope.describe("cycle", {"session": self.uid})
+            self.lanes: Dict[str, float] = scope.lanes
+            err: Optional[BaseException] = None
+            try:
                 self._run_body()
-        except BaseException as e:
-            err = e
-            raise
-        finally:
-            # Failed cycles record too — a flight recorder that only
-            # remembers the good cycles answers no incident question.
-            self._record_cycle(t_wall, time.perf_counter() - t_cycle,
-                               err)
+            except BaseException as e:
+                err = e
+                raise
+            finally:
+                # Failed cycles record too — a flight recorder that
+                # only remembers the good cycles answers no incident
+                # question.
+                self._record_cycle(scope, err)
 
     def _run_body(self) -> None:
         store = self.store
@@ -892,8 +919,9 @@ class FastCycle:
         finally:
             # Committed binds dispatch even when close fails: binds are
             # idempotent and the commit bookkeeping already happened.
-            for keys, hosts, pods, entry in self._bind_batches:
-                store.dispatch_binds(keys, hosts, pods, entry=entry)
+            with tracer.span("bind_handoff", lanes=self.lanes):
+                for keys, hosts, pods, entry in self._bind_batches:
+                    store.dispatch_binds(keys, hosts, pods, entry=entry)
 
     # ------------------------------------------------------------- audit
 
@@ -988,53 +1016,102 @@ class FastCycle:
                     shard=shard, solve_id=solve_id, epoch=epoch,
                     detail=detail)
 
-    def _record_cycle(self, t_wall: float, duration_s: float,
-                      err: Optional[BaseException]) -> None:
-        """Run the cycle-end audits and seal this cycle into the
-        store's flight recorder."""
+    def _record_cycle(self, scope, err: Optional[BaseException]) -> None:
+        """Run the cycle-end audits and hand this cycle's record to its
+        scope, which completes it (duration, lanes, spans) and seals it
+        into the store's flight recorder when ``run_once()`` leaves."""
         from .obs.recorder import CycleRecord
 
-        st = self.stats
         # Runtime auditor (obs/audit.py, ISSUE 13): conservation
         # reconcile + sampled coherence audits + SLO feed.  Runs even
         # when no flight recorder is attached — the anomaly ring and
         # counters are the production surface; the CycleRecord copy is
-        # the forensic one.
+        # the forensic one.  The SLO's "cycle" observation is the time
+        # from entry to run_once() up to here: the audit, record and
+        # gc lanes of this cycle are still to come.
         anoms = []
         auditor = getattr(self.store, "auditor", None)
         if auditor is not None and auditor.enabled:
-            anoms = auditor.end_cycle(self, duration_s, err)
-        flight = getattr(self.store, "flight", None)
-        if flight is None:
-            self.tracer.drain()
-            return
-        seq = flight.record(CycleRecord(
-            session=self.uid, path="fast", t_wall=t_wall,
-            shard=None if self.shard is None else int(self.shard.index),
-            duration_s=duration_s, lanes=dict(self.lanes),
-            pods_considered=int(st["considered"]),
-            pods_bound=int(st["bound"]),
-            pods_dropped=int(st["dropped"]),
-            drop_reasons=dict(st["drop_reasons"]),
-            inflight_fetch_wait_ms=st["fetch_wait_ms"],
-            dispatched_solve_id=st["dispatched_solve_id"],
-            committed_solve_id=st["committed_solve_id"],
-            mutation_seq_at_dispatch=st["mut_at_dispatch"],
-            mutation_seq_at_commit=st["mut_at_commit"],
-            epoch_at_dispatch=st["epoch_at_dispatch"],
-            epoch_at_commit=st["epoch_at_commit"],
-            device_events=list(st["device_events"]),
-            error=type(err).__name__ if err is not None else None,
-            spans=self.tracer.drain(),
-            rebalance=st.get("rebalance"),
-            whatif=st.get("whatif"),
-            pool=st.get("pool"),
-            anomalies=[a.to_dict() for a in anoms],
-        ))
-        # Stamp the ring copies with the flight seq, so an operator can
-        # walk /debug/anomalies -> /debug/cycles/<seq> for forensics.
-        for a in anoms:
-            a.cycle_seq = seq
+            with scope.lane("audit"):
+                anoms = auditor.end_cycle(self, scope.elapsed_s(), err)
+        st = self.stats
+        with scope.lane("record"):
+            # ``stamp``: the ring copies get the flight seq at sealing,
+            # so an operator can walk /debug/anomalies ->
+            # /debug/cycles/<seq>.
+            scope.submit(CycleRecord(
+                session=self.uid, path="fast",
+                shard=(None if self.shard is None
+                       else int(self.shard.index)),
+                pods_considered=int(st["considered"]),
+                pods_bound=int(st["bound"]),
+                pods_dropped=int(st["dropped"]),
+                drop_reasons=dict(st["drop_reasons"]),
+                inflight_fetch_wait_ms=st["fetch_wait_ms"],
+                dispatched_solve_id=st["dispatched_solve_id"],
+                committed_solve_id=st["committed_solve_id"],
+                mutation_seq_at_dispatch=st["mut_at_dispatch"],
+                mutation_seq_at_commit=st["mut_at_commit"],
+                epoch_at_dispatch=st["epoch_at_dispatch"],
+                epoch_at_commit=st["epoch_at_commit"],
+                device_events=list(st["device_events"]),
+                error=type(err).__name__ if err is not None else None,
+                rebalance=st.get("rebalance"),
+                whatif=st.get("whatif"),
+                pool=st.get("pool"),
+                anomalies=[a.to_dict() for a in anoms],
+                solve=self._solve_record(),
+            ), stamp=anoms)
+
+    def _solve_counts(self) -> Dict[str, object]:
+        """This cycle's solve counts (``CycleRecord.solve``), created
+        by the first dispatch or in-flight fetch of the cycle: counts
+        at the boundaries the ``device`` spans time, never per pod."""
+        sc = self.stats.get("solve")
+        if sc is None:
+            sc = self.stats["solve"] = {
+                "dispatches": 0, "rows": 0, "nodes": int(self.Nn),
+                "devincr_mode": None, "dirty_nodes": None,
+                "arg_puts": 0, "arg_put_bytes": 0,
+                "fetches": 0, "fetch_bytes": 0,
+            }
+        return sc
+
+    def _count_dispatch(self, rows: int, args) -> None:
+        """One solve handed to the device: its rows and the numpy
+        leaves of ``args`` the jitted call uploads with it."""
+        sc = self._solve_counts()
+        sc["dispatches"] += 1
+        sc["rows"] += int(rows)
+        n, nbytes = _host_bytes(args)
+        sc["arg_puts"] += n
+        sc["arg_put_bytes"] += nbytes
+
+    def _count_fetch(self, fetched) -> None:
+        """One blocking device->host fetch and the bytes it returned."""
+        sc = self._solve_counts()
+        sc["fetches"] += 1
+        sc["fetch_bytes"] += _host_bytes(fetched)[1]
+
+    def _solve_record(self) -> Optional[Dict[str, object]]:
+        """``CycleRecord.solve``: the cycle's solve counts plus the
+        device snapshot's uploads of this cycle (deltas of devsnap's
+        running counters); None when no solve was dispatched or
+        fetched (null-delta skip, nothing pending)."""
+        sc = self.stats.get("solve")
+        if sc is None:
+            return None
+        dv = self.stats.get("devincr")
+        if dv:
+            sc["devincr_mode"] = dv.get("mode")
+            sc["devincr_static"] = dv.get("static")
+        now = _devsnap_counts(self.store)
+        full, delta, hits, puts, put_bytes = (
+            b - a for a, b in zip(self._devsnap0, now))
+        sc.update(devsnap_full=full, devsnap_delta=delta,
+                  devsnap_hits=hits, devsnap_puts=puts,
+                  devsnap_put_bytes=put_bytes)
+        return sc
 
     def _count_drops(self, reasons: Dict[str, int]) -> None:
         """Fold staleness-guard drop counts into the cycle stats and the
@@ -1094,13 +1171,12 @@ class FastCycle:
 
     def _record_twophase_lanes(self) -> None:
         """Fold the wave solver's coarse/fine dispatch timings into the
-        cycle's lane split (device_coarse / device_fine sub-lanes of the
-        device lane) and the trace event stream — these are the
-        host-side dispatch legs; the residual device wait stays on the
-        fetch that consumes the result.  Mesh dispatches annotate both
-        events (and the cycle stats) with the node-axis shard count, so
-        a trace distinguishes the per-shard sub-lanes from single-device
-        ones."""
+        cycle's lane split (device_coarse / device_fine: the one nested
+        pair the lanes rule allows, inside ``device``) — these are the
+        host-side dispatch legs, timed in ops/wave.py; the span that
+        stands around them is ``device:dispatch``, and the residual
+        device wait stays on the fetch that consumes the result.  Mesh
+        dispatches note the node-axis shard count in the cycle stats."""
         from .ops import wave as _wave_mod
 
         info = _wave_mod.LAST_TWOPHASE
@@ -1110,7 +1186,6 @@ class FastCycle:
         coarse = float(info.get("coarse_s", 0.0))
         fine = float(info.get("fine_s", 0.0))
         shards = int(info.get("mesh_shards", 1) or 1)
-        args = {"mesh_shards": shards} if shards > 1 else None
         if shards > 1:
             self.stats["mesh_shards"] = shards
         dvinfo = info.get("devincr")
@@ -1123,18 +1198,6 @@ class FastCycle:
                 metrics.device_incremental_solves.inc(mode=mode)
         lanes["device_coarse"] = lanes.get("device_coarse", 0.0) + coarse
         lanes["device_fine"] = lanes.get("device_fine", 0.0) + fine
-        now = time.perf_counter_ns()
-        if coarse > 0:
-            self.tracer.event(
-                "device_coarse", "device",
-                now - int((coarse + fine) * 1e9), int(coarse * 1e9),
-                tid="cycle", args=args,
-            )
-        if fine > 0:
-            self.tracer.event(
-                "device_fine", "device", now - int(fine * 1e9),
-                int(fine * 1e9), tid="cycle", args=args,
-            )
 
     def _evict_device_on(self) -> bool:
         """True when preempt/reclaim run the device-native
@@ -1485,11 +1548,14 @@ class FastCycle:
         # mirror version).
         from .ops import devincr as _dvm
 
+        # ``solve_prep`` (lane): everything of this action between the
+        # order / encode / journey / device / commit lanes.
         dv_store = None
         if solver == "wave" and _dvm.devincr_on():
             dv_store = _dvm.of_store(store)
             if not store.bind_backoff and dv_store.skip_token is not None:
-                tok = self._null_delta_token(solver, rounds)
+                with tracer.span("solve_prep", lanes=lanes):
+                    tok = self._null_delta_token(solver, rounds)
                 if dv_store.skip_token == tok:
                     dv_store.counts["skip"] += 1
                     metrics.device_incremental_solves.inc(mode="skip")
@@ -1518,8 +1584,9 @@ class FastCycle:
             # Require-contiguous gangs with no whole-gang fabric block
             # sit the solve out (exclusive drop reason
             # topology-infeasible) instead of scattering.
-            solve_jobs, task_rows = self._topology_pregate(
-                solve_jobs, task_rows)
+            with tracer.span("solve_prep", lanes=lanes):
+                solve_jobs, task_rows = self._topology_pregate(
+                    solve_jobs, task_rows)
             if not len(task_rows):
                 break
             # Distinct rows entering solves this cycle: retry rounds
@@ -1531,13 +1598,15 @@ class FastCycle:
             progress_any = False
             never_any = False
             try:
-                chunks = list(self._solve_chunks(solve_jobs, task_rows))
-                remote = self._remote_solver
-                from .parallel.mesh import mesh_from_env
+                with tracer.span("solve_prep", lanes=lanes):
+                    chunks = list(
+                        self._solve_chunks(solve_jobs, task_rows))
+                    remote = self._remote_solver
+                    from .parallel.mesh import mesh_from_env
 
-                # store.solve_mesh, or the VOLCANO_TPU_MESH deploy knob
-                # (docs/tuning.md); resolves once per store.
-                mesh = mesh_from_env(store)
+                    # store.solve_mesh, or the VOLCANO_TPU_MESH deploy
+                    # knob (docs/tuning.md); resolves once per store.
+                    mesh = mesh_from_env(store)
                 # Pipelined dispatch (ISSUE 1): a single-chunk wave
                 # solve is shipped WITHOUT blocking on the result; the
                 # commit lands at the top of the next cycle.  Chunked
@@ -1557,8 +1626,9 @@ class FastCycle:
                     # Device-incremental context (ISSUE 9): cache keys
                     # + dirty superset for this dispatch (a token dict
                     # for the remote child, which owns its planes).
-                    dv, dv_manifest = self._devincr_prepare(
-                        inputs, mesh, remote is not None)
+                    with tracer.span("solve_prep", lanes=lanes):
+                        dv, dv_manifest = self._devincr_prepare(
+                            inputs, mesh, remote is not None)
                     kind = "remote" if remote is not None else "local"
                     # The dispatch span opens the solve-id flow; the
                     # matching fetch/commit spans close it in cycle N+1.
@@ -1569,6 +1639,8 @@ class FastCycle:
                             lanes=lanes, lane="device",
                             args={"kind": kind, "rows": len(crows),
                                   "solve_id": solve_id}):
+                        self._count_dispatch(
+                            len(crows), (inputs, pid, profiles))
                         if remote is not None:
                             # The child process rebuilds node classes
                             # from the numpy frame itself; class planes
@@ -1616,90 +1688,85 @@ class FastCycle:
                             cjobs, crows, slim=(solver == "wave"))
                     # Journey: these rows entered a device solve
                     # (first-time rows record; repeats bulk-count).
-                    self._journey_rows(crows, "dispatched")
+                    with tracer.span("journey", lanes=lanes):
+                        self._journey_rows(crows, "dispatched")
                     # Device-incremental context: single-chunk wave
                     # solves only (chunked solves interleave commits,
                     # so each chunk would need its own proof).
                     dv = dv_manifest = None
                     if solver == "wave" and len(chunks) == 1:
-                        dv, dv_manifest = self._devincr_prepare(
-                            inputs, mesh, remote is not None)
-                        self._last_encode_token = (
-                            self._null_delta_token(solver, rounds)
-                            if dv_store is not None else None)
-                    t0 = time.perf_counter()
-                    if solver == "wave" and remote is not None:
-                        # Remote-solver split (BASELINE north-star
-                        # bridge): inputs cross to the device-owning
-                        # process as one C++-packed frame; assignment
-                        # vectors come back as numpy.  The child
-                        # rebuilds node classes from the frame itself.
-                        result = remote.solve(inputs, pid, profiles,
-                                              devincr=dv_manifest)
-                        if dv_manifest is not None:
-                            _dvm.of_store(store).anchor_dirty()
-                        mode = getattr(remote, "last_devincr_mode",
-                                       None)
-                        if mode in ("warm", "full"):
-                            metrics.device_incremental_solves.inc(
-                                mode=mode)
-                    elif solver == "wave" and mesh is not None:
-                        result = self._solve_mesh_dispatch(
-                            mesh, inputs, pid, profiles, ncls,
-                            devincr=dv)
-                    elif solver == "wave":
-                        result = solve_fn(*inputs, pid=pid,
-                                          profiles=profiles,
-                                          taint_any=self._taint_any,
-                                          node_classes=ncls, devincr=dv)
-                        self._record_twophase_lanes()
-                    else:
-                        result = solve_fn(*inputs)
-                    # One batched device->host fetch: every fetch is a
-                    # blocking round trip, so three sequential
-                    # np.asarray() calls would pay it three times.
-                    import jax
-
-                    for arr in (result.assigned, result.never_ready,
-                                result.fit_failed):
-                        try:
-                            arr.copy_to_host_async()
-                        except AttributeError:
-                            pass
-                    # Commit prep that doesn't need the assignments
-                    # overlaps the device solve + transfer wait.
-                    req_gather = self.m.c_req.gather(crows)
-                    self._obj_arrays()
-                    if solver == "wave":
-                        # The wave solver always carries the two-phase
-                        # fallback counters (zeros when disabled); ride
-                        # the same batched fetch.
-                        (assigned, never_ready, fit_failed, fb_ex,
-                         fb_aff) = jax.device_get(
-                            (result.assigned, result.never_ready,
-                             result.fit_failed, result.fb_exhausted,
-                             result.fb_affinity)
-                        )
-                        self._count_shortlist_fb(int(fb_ex), int(fb_aff))
-                    else:
-                        assigned, never_ready, fit_failed = (
-                            jax.device_get(
-                                (result.assigned, result.never_ready,
-                                 result.fit_failed)
-                            )
-                        )
-                    assigned = assigned[:len(crows)]
-                    # Fabric gate: require-contiguous gangs scattered
-                    # across blocks are vetoed before the commit.
-                    assigned = self._topology_gate(crows, assigned)
-                    dt_dev = time.perf_counter() - t0
-                    lanes["device"] = lanes.get("device", 0.0) + dt_dev
-                    metrics.device_solve_latency.observe(dt_dev * 1e3)
-                    tracer.event("device_solve", "device",
-                                 time.perf_counter_ns()
-                                 - int(dt_dev * 1e9),
-                                 int(dt_dev * 1e9), tid="cycle",
-                                 args={"rows": len(crows)})
+                        with tracer.span("solve_prep", lanes=lanes):
+                            dv, dv_manifest = self._devincr_prepare(
+                                inputs, mesh, remote is not None)
+                            self._last_encode_token = (
+                                self._null_delta_token(solver, rounds)
+                                if dv_store is not None else None)
+                    # The ``device`` lane: a host-clock span over
+                    # dispatch, the host work overlapped with the
+                    # solve, the blocking fetch and the fabric gate —
+                    # each a child span, none a lane.
+                    with tracer.span("device", cat="device",
+                                     lanes=lanes,
+                                     args={"rows": len(crows)}) as dev:
+                        with tracer.span("device:dispatch",
+                                         cat="device") as disp:
+                            self._count_dispatch(
+                                len(crows), (inputs, pid, profiles))
+                            result = self._solve_sync(
+                                solver, solve_fn, remote, mesh, inputs,
+                                pid, profiles, ncls, dv, dv_manifest)
+                            shards = self.stats.get("mesh_shards")
+                            if shards:
+                                disp.args = {"mesh_shards": shards}
+                            # One batched device->host fetch: every
+                            # fetch is a blocking round trip, so three
+                            # sequential np.asarray() calls would pay
+                            # it three times.
+                            for arr in (result.assigned,
+                                        result.never_ready,
+                                        result.fit_failed):
+                                try:
+                                    arr.copy_to_host_async()
+                                except AttributeError:
+                                    pass
+                        # Commit prep that doesn't need the assignments
+                        # overlaps the device solve + transfer wait.
+                        with tracer.span("device:host_prep",
+                                         cat="device"):
+                            req_gather = self.m.c_req.gather(crows)
+                            self._obj_arrays()
+                        # How long the host waited for the chip.
+                        with tracer.span("device:fetch", cat="device"):
+                            if solver == "wave":
+                                # The wave solver always carries the
+                                # two-phase fallback counters (zeros
+                                # when disabled); ride the same batched
+                                # fetch.
+                                fetched = jax.device_get(
+                                    (result.assigned,
+                                     result.never_ready,
+                                     result.fit_failed,
+                                     result.fb_exhausted,
+                                     result.fb_affinity))
+                            else:
+                                fetched = jax.device_get(
+                                    (result.assigned,
+                                     result.never_ready,
+                                     result.fit_failed))
+                        self._count_fetch(fetched)
+                        assigned, never_ready, fit_failed = fetched[:3]
+                        if solver == "wave":
+                            self._count_shortlist_fb(
+                                int(fetched[3]), int(fetched[4]))
+                        assigned = assigned[:len(crows)]
+                        # Fabric gate: require-contiguous gangs
+                        # scattered across blocks are vetoed before the
+                        # commit.
+                        with tracer.span("device:gate", cat="device"):
+                            assigned = self._topology_gate(
+                                crows, assigned)
+                    metrics.device_solve_latency.observe(
+                        dev.dur_ns / 1e6)
                     with tracer.span("commit", lanes=lanes):
                         progress = self._commit(
                             cjobs, crows, assigned, never_ready,
@@ -1738,11 +1805,43 @@ class FastCycle:
             # (a pipelined dispatch counts: its commit lands next cycle
             # and bumps the mutation counter if it binds, breaking the
             # proof before the next skip check reads it).
-            tok_now = (self._null_delta_token(solver, rounds)
-                       if self._last_encode_token is not None else None)
+            with tracer.span("solve_prep", lanes=lanes):
+                tok_now = (
+                    self._null_delta_token(solver, rounds)
+                    if self._last_encode_token is not None else None)
             dv_store.skip_token = (
                 tok_now if tok_now is not None
                 and tok_now == self._last_encode_token else None)
+
+    def _solve_sync(self, solver: str, solve_fn, remote, mesh, inputs,
+                    pid, profiles, ncls, dv, dv_manifest):
+        """Dispatch one synchronous solve (the body of the
+        ``device:dispatch`` span) and return its result handle."""
+        if solver != "wave":
+            return solve_fn(*inputs)
+        if remote is not None:
+            # Remote-solver split (BASELINE north-star bridge): inputs
+            # cross to the device-owning process as one C++-packed
+            # frame; assignment vectors come back as numpy.  The child
+            # rebuilds node classes from the frame itself.
+            from .ops import devincr as _dvm
+
+            result = remote.solve(inputs, pid, profiles,
+                                  devincr=dv_manifest)
+            if dv_manifest is not None:
+                _dvm.of_store(self.store).anchor_dirty()
+            mode = getattr(remote, "last_devincr_mode", None)
+            if mode in ("warm", "full"):
+                metrics.device_incremental_solves.inc(mode=mode)
+            return result
+        if mesh is not None:
+            return self._solve_mesh_dispatch(
+                mesh, inputs, pid, profiles, ncls, devincr=dv)
+        result = solve_fn(*inputs, pid=pid, profiles=profiles,
+                          taint_any=self._taint_any,
+                          node_classes=ncls, devincr=dv)
+        self._record_twophase_lanes()
+        return result
 
     # --------------------------------------- device-lane incrementality
 
@@ -1836,6 +1935,9 @@ class FastCycle:
         if dv is None:
             dv = _dvm.of_store(self.store)
         dirty = dv.take_dirty(self._dirty_nodes_now())
+        # None = tracking overflowed: the whole node axis re-ranks.
+        self._solve_counts()["dirty_nodes"] = (
+            None if dirty is None else int(len(dirty)))
         if remote:
             return None, {
                 "static_key": repr(static_key),
@@ -1893,8 +1995,10 @@ class FastCycle:
         # Commit prep that needs no assignment overlaps the round trip.
         req_gather = self.m.c_req.gather(crows)
         # Journey: these rows entered a device solve (first-time rows
-        # record with the flow's solve-id; repeats bulk-count).
-        self._journey_rows(crows, "dispatched", solve_id=solve_id)
+        # record with the flow's solve-id; repeats bulk-count).  Under
+        # the ``dispatch`` span here, so a child and no lane.
+        with self.tracer.span("dispatch:journey"):
+            self._journey_rows(crows, "dispatched", solve_id=solve_id)
         shard_idx = None if self.shard is None else self.shard.index
         shard_seq = None
         if self.shard is not None:
@@ -1938,30 +2042,10 @@ class FastCycle:
         self._record_twophase_lanes()
         return result
 
-    def _commit_inflight(self) -> None:
-        """Fetch + commit the previous cycle's dispatched solve (runs
-        first, before this cycle's actions).  A staleness guard drops
-        rows invalidated by store mutations that landed during the
-        overlap — pod deleted/bound/evicted, node gone, capacity taken
-        by the fast path — the same per-task semantics the async-bind
-        failure queue already has; everything else commits exactly as a
-        synchronous cycle would have."""
-        from .pipeline import take_inflight
-
-        inflight = take_inflight(
-            self.store,
-            None if self.shard is None else self.shard.index,
-        )
-        if inflight is None:
-            return
+    def _inflight_voided(self, inflight) -> bool:
+        """True when the parked solve predates a mirror compaction and
+        was dropped whole."""
         m = self.m
-        lanes = self.lanes
-        tracer = self.tracer
-        flow = inflight.solve_id or None
-        # committed_solve_id is set only once the fetch SUCCEEDS: a
-        # record showing a committed id with zero drops for a solve
-        # whose reply was lost would read as a clean commit — exactly
-        # the investigation the recorder exists for.
         if inflight.compact_gen != m.compact_gen:
             # Pod rows were renumbered while the solve was in flight;
             # the whole result is void (rows are otherwise stable for a
@@ -1982,7 +2066,39 @@ class FastCycle:
                 f"solve {inflight.solve_id} voided by mirror compaction"
             )
             inflight.abandon()
-            return
+            return True
+        return False
+
+    def _commit_inflight(self) -> None:
+        """Fetch + commit the previous cycle's dispatched solve (runs
+        first, before this cycle's actions).  A staleness guard drops
+        rows invalidated by store mutations that landed during the
+        overlap — pod deleted/bound/evicted, node gone, capacity taken
+        by the fast path — the same per-task semantics the async-bind
+        failure queue already has; everything else commits exactly as a
+        synchronous cycle would have."""
+        from .pipeline import take_inflight
+
+        m = self.m
+        lanes = self.lanes
+        tracer = self.tracer
+        # The ``inflight`` lane is the taking of the parked solve and
+        # its void check; the fetch below is ``device`` and the commit
+        # is ``commit``, each top-level (the lanes rule).
+        with tracer.span("inflight", lanes=lanes):
+            inflight = take_inflight(
+                self.store,
+                None if self.shard is None else self.shard.index,
+            )
+            if inflight is None:
+                return
+            if self._inflight_voided(inflight):
+                return
+        flow = inflight.solve_id or None
+        # committed_solve_id is set only once the fetch SUCCEEDS: a
+        # record showing a committed id with zero drops for a solve
+        # whose reply was lost would read as a clean commit — exactly
+        # the investigation the recorder exists for.
         fetch_span = tracer.span(
             "inflight_fetch", cat="pipeline", flow=flow, lanes=lanes,
             lane="device",
@@ -2056,6 +2172,7 @@ class FastCycle:
             # from a synchronous solve.
             raise
         self.store._remote_fetch_fails = 0
+        self._count_fetch(assigned)
         self.stats["committed_solve_id"] = inflight.solve_id or None
         self._count_shortlist_fb(*inflight.fallbacks)
         self._record_pool_fetch()
@@ -3705,10 +3822,15 @@ class FastCycle:
     def _commit(self, solve_jobs: List[int], task_rows: np.ndarray,
                 assigned: np.ndarray, never_ready: np.ndarray,
                 fit_failed: np.ndarray, req_gather=None) -> bool:
-        """Apply the assignment matrix in bulk (the vectorized _replay)."""
+        """Apply the assignment matrix in bulk (the vectorized _replay).
+
+        Runs under a ``commit`` lane span (``commit`` /
+        ``inflight_commit``); each block below is a child span of it
+        (``commit:guard`` .. ``commit:notify``), one per block and
+        never one per pod."""
         m = self.m
         store = self.store
-        jrank_never = never_ready[:len(solve_jobs)]
+        span = self.tracer.span
         committed = assigned >= 0
         if not committed.any():
             return False
@@ -3719,153 +3841,165 @@ class FastCycle:
         if stats is not None:
             stats["bound"] = int(stats["bound"]) + len(rows)
 
-        # Divergence guard (vectorized analog of the replay's re-check):
-        # charged capacity must not exceed allocatable.
-        if req_gather is not None:
-            # Subset the caller's full-task gather (prepared while the
-            # device solve ran) down to the committed rows — identity
-            # when everything committed (the steady north-star case).
-            er_all, si_all, v_all = req_gather
-            if committed.all():
-                er, si, v = er_all, si_all, v_all
+        with span("commit:guard"):
+            # Divergence guard (vectorized analog of the replay's
+            # re-check): charged capacity must not exceed allocatable.
+            if req_gather is not None:
+                # Subset the caller's full-task gather (prepared while
+                # the device solve ran) down to the committed rows —
+                # identity when everything committed (the steady
+                # north-star case).
+                er_all, si_all, v_all = req_gather
+                if committed.all():
+                    er, si, v = er_all, si_all, v_all
+                else:
+                    em = committed[er_all]
+                    new_idx = np.cumsum(committed) - 1
+                    er = new_idx[er_all[em]]
+                    si = si_all[em]
+                    v = v_all[em]
             else:
-                em = committed[er_all]
-                new_idx = np.cumsum(committed) - 1
-                er = new_idx[er_all[em]]
-                si = si_all[em]
-                v = v_all[em]
-        else:
-            er, si, v = m.c_req.gather(rows)
-        # bincount over flattened (node, slot) indices is several times
-        # faster than np.add.at for 200k+ scatter entries.
-        add = np.bincount(
-            nodes_c[er].astype(np.int64) * self.R + si,
-            weights=v, minlength=self.Nn * self.R,
-        ).reshape(self.Nn, self.R).astype(F)
-        new_used = self.n_used + add
-        over = new_used > self.n_alloc + self.eps[None, :]
-        if over.any() and bool((add[over.any(axis=1)] > 0).any()):
-            bad = np.flatnonzero(over.any(axis=1))
-            log.error(
-                "Device/host divergence: %d nodes oversubscribed; "
-                "falling back to object path this cycle", len(bad),
-            )
-            raise RuntimeError("fastpath divergence")
+                er, si, v = m.c_req.gather(rows)
+            # bincount over flattened (node, slot) indices is several
+            # times faster than np.add.at for 200k+ scatter entries.
+            add = np.bincount(
+                nodes_c[er].astype(np.int64) * self.R + si,
+                weights=v, minlength=self.Nn * self.R,
+            ).reshape(self.Nn, self.R).astype(F)
+            new_used = self.n_used + add
+            over = new_used > self.n_alloc + self.eps[None, :]
+            if over.any() and bool((add[over.any(axis=1)] > 0).any()):
+                bad = np.flatnonzero(over.any(axis=1))
+                log.error(
+                    "Device/host divergence: %d nodes oversubscribed; "
+                    "falling back to object path this cycle", len(bad),
+                )
+                raise RuntimeError("fastpath divergence")
 
-        # Array state updates.  The rows change dynamic state, so they
-        # enter the mirror's dirty set (the next derive's delta refresh
-        # reconciles the persistent aggregates) and the mutation counter
-        # moves with them — the dirty set and the staleness guard must
-        # agree on what "changed" means (commit runs before this cycle's
-        # dispatch captures its sequence, so the guard semantics are
-        # unchanged).
-        self._audit_flow_rows(rows, ST_BOUND, "commit-bind")
         # Journey: the placement landed (first-time rows record the
         # bind — and their time-to-bind — with the committing solve's
         # flow id; steady-state re-binds bulk-count).
-        self._journey_rows(
-            rows, "bound",
-            solve_id=int(self.stats.get("committed_solve_id") or 0))
-        m.p_status[rows] = ST_BOUND
-        m.p_node[rows] = nodes_c
-        m.mark_pods_dirty(rows)
-        m.mutation_seq += 1
-        if self.shard is not None:
-            # Cross-shard commit gate (shard.py, ISSUE 16): siblings
-            # whose overlapped solve raced these binds attribute their
-            # voids as cross-shard-conflict.
-            m.shard_commit_seq += 1
-        self.n_used = new_used
-        self.n_idle = self.n_idle - add
-        self.n_ntasks += np.bincount(
-            nodes_c, minlength=self.Nn
-        ).astype(I)
-        self.resident[rows] = True
+        with span("commit:journey"):
+            self._journey_rows(
+                rows, "bound",
+                solve_id=int(self.stats.get("committed_solve_id") or 0))
 
-        # Job counters (affects readiness for later rounds + close).
-        jr = self.jobr[rows]
-        bc = np.bincount(jr, minlength=self.Jn).astype(I)
-        self.j_cnt_alloc += bc
-        self.j_cnt_pending -= bc
-        self.j_ready_base = (
-            self.j_cnt_alloc + self.j_cnt_succ + self.j_cnt_empty_pending
-        )
-        # (er, si, v) reused from the divergence guard's gather above.
-        # The j_alloc_res/j_pending_res/q_alloc scatter updates are
-        # deferred (see _flush_aggr): later rounds and the evict
-        # machinery flush before reading.
-        if not hasattr(self, "_aggr_pending"):
-            self._aggr_pending = []
-        self._aggr_pending.append((jr[er], si, v, self.q_of_job[jr][er]))
+        with span("commit:state"):
+            # Array state updates.  The rows change dynamic state, so
+            # they enter the mirror's dirty set (the next derive's
+            # delta refresh reconciles the persistent aggregates) and
+            # the mutation counter moves with them — the dirty set and
+            # the staleness guard must agree on what "changed" means
+            # (commit runs before this cycle's dispatch captures its
+            # sequence, so the guard semantics are unchanged).
+            self._audit_flow_rows(rows, ST_BOUND, "commit-bind")
+            m.p_status[rows] = ST_BOUND
+            m.p_node[rows] = nodes_c
+            m.mark_pods_dirty(rows)
+            m.mutation_seq += 1
+            if self.shard is not None:
+                # Cross-shard commit gate (shard.py, ISSUE 16):
+                # siblings whose overlapped solve raced these binds
+                # attribute their voids as cross-shard-conflict.
+                m.shard_commit_seq += 1
+            self.n_used = new_used
+            self.n_idle = self.n_idle - add
+            self.n_ntasks += np.bincount(
+                nodes_c, minlength=self.Nn
+            ).astype(I)
+            self.resident[rows] = True
+
+            # Job counters (affects readiness for later rounds +
+            # close).
+            jr = self.jobr[rows]
+            bc = np.bincount(jr, minlength=self.Jn).astype(I)
+            self.j_cnt_alloc += bc
+            self.j_cnt_pending -= bc
+            self.j_ready_base = (
+                self.j_cnt_alloc + self.j_cnt_succ
+                + self.j_cnt_empty_pending
+            )
+            # (er, si, v) reused from the divergence guard's gather
+            # above.  The j_alloc_res/j_pending_res/q_alloc scatter
+            # updates are deferred (see _flush_aggr): later rounds and
+            # the evict machinery flush before reading.
+            if not hasattr(self, "_aggr_pending"):
+                self._aggr_pending = []
+            self._aggr_pending.append(
+                (jr[er], si, v, self.q_of_job[jr][er]))
 
         # Pod records + bind dispatch (async in the reference,
         # cache.go:536-552; here one batched dispatch).
         binder = store.binder
         bind_keys = getattr(binder, "bind_keys", None)
         notify = store._watchers
-        pod_a, key_a, name_a = self._obj_arrays()
-        # Bound hostnames land in the mirror as ONE batched column write
-        # (the vectorized replacement for the 100k pod-record setattr
-        # walk, which now only runs for record consumers — deferred to
-        # the bind dispatcher or the sync-bind path below).
-        m.p_node_name[rows] = name_a[nodes_c]
-        defer_records = (
-            getattr(store, "async_bind", False)
-            and not notify
-            and not store.n_volume_pods
-            and not m.p_pod_nones
-        )
-        if defer_records:
-            # The reference sets pod.NodeName via the API server on the
-            # async bind, observed later by informers — not inside the
-            # scheduling cycle (cache.go:536-552).  Register the object
-            # ARRAYS with the store and ship the entry to the bind
-            # dispatcher; its worker thread does the 100k-element tolist
-            # + node_name walk post-cycle (~45 ms off the commit lane at
-            # north-star scale).  Cycle-visible state (mirror arrays) is
-            # already updated above; any failure path about to read pod
-            # records forces the walk first (apply_pending_bind_records
-            # — registration at commit time covers prior cycles' not-
-            # yet-processed batches too).
-            entry = store.defer_bind_records(
-                key_a[rows], name_a[nodes_c], pod_a[rows]
+        with span("commit:records"):
+            pod_a, key_a, name_a = self._obj_arrays()
+            # Bound hostnames land in the mirror as ONE batched column
+            # write (the vectorized replacement for the 100k pod-record
+            # setattr walk, which now only runs for record consumers —
+            # deferred to the bind dispatcher or the sync-bind path
+            # below).
+            m.p_node_name[rows] = name_a[nodes_c]
+            defer_records = (
+                getattr(store, "async_bind", False)
+                and not notify
+                and not store.n_volume_pods
+                and not m.p_pod_nones
             )
-            self._bind_batches.append((None, None, None, entry))
-            store.mark_objects_stale()
-            return True
-        pod_l = pod_a[rows].tolist()
-        host_l = name_a[nodes_c].tolist()
-        # Tombstoned rows can't be committed in the common case; the
-        # mirror counts them so the 100k-element defensive None scan
-        # (identity, NOT `in`: `in` calls the dataclass __eq__) only
-        # runs when one exists.
-        if not m.p_pod_nones or not any(p is None for p in pod_l):
-            # Common case: every committed row has a live pod record.
-            # Object-array gathers + one zip setattr walk instead of
-            # four per-pod appends (this path covers 100k rows at
-            # north-star scale).
-            for pod, hostname in zip(pod_l, host_l):
-                pod.node_name = hostname
-            keys = key_a[rows].tolist()
-            hosts = host_l
-            bound_pods = pod_l
-            bound_rows = rows.tolist()
-        else:
-            keys = []
-            hosts = []
-            bound_pods = []
-            bound_rows = []
-            key_l = key_a[rows].tolist()
-            for row, pod, hostname, key in zip(
-                    rows.tolist(), pod_l, host_l, key_l):
-                if pod is None:
-                    continue
-                pod.node_name = hostname
-                keys.append(key)
-                hosts.append(hostname)
-                bound_pods.append(pod)
-                bound_rows.append(row)
-        from .cache.interface import BindFailure, VolumeBindFailure
+            if defer_records:
+                # The reference sets pod.NodeName via the API server on
+                # the async bind, observed later by informers — not
+                # inside the scheduling cycle (cache.go:536-552).
+                # Register the object ARRAYS with the store and ship
+                # the entry to the bind dispatcher; its worker thread
+                # does the 100k-element tolist + node_name walk
+                # post-cycle (~45 ms off the commit lane at north-star
+                # scale).  Cycle-visible state (mirror arrays) is
+                # already updated above; any failure path about to read
+                # pod records forces the walk first
+                # (apply_pending_bind_records — registration at commit
+                # time covers prior cycles' not-yet-processed batches
+                # too).
+                entry = store.defer_bind_records(
+                    key_a[rows], name_a[nodes_c], pod_a[rows]
+                )
+                self._bind_batches.append((None, None, None, entry))
+                store.mark_objects_stale()
+                return True
+            pod_l = pod_a[rows].tolist()
+            host_l = name_a[nodes_c].tolist()
+            # Tombstoned rows can't be committed in the common case;
+            # the mirror counts them so the 100k-element defensive None
+            # scan (identity, NOT `in`: `in` calls the dataclass
+            # __eq__) only runs when one exists.
+            if not m.p_pod_nones or not any(p is None for p in pod_l):
+                # Common case: every committed row has a live pod
+                # record.  Object-array gathers + one zip setattr walk
+                # instead of four per-pod appends (this path covers
+                # 100k rows at north-star scale).
+                for pod, hostname in zip(pod_l, host_l):
+                    pod.node_name = hostname
+                keys = key_a[rows].tolist()
+                hosts = host_l
+                bound_pods = pod_l
+                bound_rows = rows.tolist()
+            else:
+                keys = []
+                hosts = []
+                bound_pods = []
+                bound_rows = []
+                key_l = key_a[rows].tolist()
+                for row, pod, hostname, key in zip(
+                        rows.tolist(), pod_l, host_l, key_l):
+                    if pod is None:
+                        continue
+                    pod.node_name = hostname
+                    keys.append(key)
+                    hosts.append(hostname)
+                    bound_pods.append(pod)
+                    bound_rows.append(row)
+        from .cache.interface import BindFailure
 
         # Volume gate (statement.go allocate->AllocateVolumes, commit->
         # BindVolumes): pods carrying claims go through the volume binder
@@ -3873,67 +4007,86 @@ class FastCycle:
         # that pod to Pending.  Volume-free clusters skip on the store's
         # exact O(1) counter (the 100k-pod truthiness scan is not free,
         # and gating on store.pvcs would bypass custom volume binders).
-        if store.n_volume_pods and any(
-                pod.volumes for pod in bound_pods):
-            vb = store.volume_binder
-            vol_failed = []
-            for pod, hostname, key in zip(bound_pods, hosts, keys):
-                if not pod.volumes:
-                    continue
-                try:
-                    vb.allocate_volumes(pod, hostname)
-                    vb.bind_volumes(pod)
-                except VolumeBindFailure as e:
-                    store.record_event(f"Pod/{key}", "FailedScheduling",
-                                       str(e))
-                    vol_failed.append(key)
-            if vol_failed:
-                self._revert_failed_binds(vol_failed, keys, bound_rows,
-                                          bound_pods)
-                fset = set(vol_failed)
-                kept = [
-                    (k, h, p, r) for k, h, p, r
-                    in zip(keys, hosts, bound_pods, bound_rows)
-                    if k not in fset
-                ]
-                keys = [k for k, _, _, _ in kept]
-                hosts = [h for _, h, _, _ in kept]
-                bound_pods = [p for _, _, p, _ in kept]
-                bound_rows = [r for _, _, _, r in kept]
+        if store.n_volume_pods:
+            with span("commit:volumes"):
+                keys, hosts, bound_pods, bound_rows = (
+                    self._commit_volumes(keys, hosts, bound_pods,
+                                         bound_rows))
 
-        if getattr(store, "async_bind", False):
-            # Async dispatch (cache.go:536-552): the cycle only pays a
-            # list append (batches go to the dispatcher at cycle end —
-            # see run()); failures surface via drain_bind_failures at
-            # the next cycle's start and re-enter Pending with backoff.
-            self._bind_batches.append((keys, hosts, bound_pods, None))
-        else:
-            try:
-                if bind_keys is not None:
-                    bind_keys(keys, hosts)
-                else:
-                    failed = []
-                    for pod, hostname, key in zip(bound_pods, hosts, keys):
-                        try:
-                            binder.bind(pod, hostname)
-                        except BindFailure:
-                            failed.append(key)
-                    if failed:
-                        raise BindFailure(failed)
-            except BindFailure as bf:
-                self._revert_failed_binds(bf.failed, keys, bound_rows,
-                                          bound_pods)
-                failed = set(bf.failed)
-                bound_pods = [
-                    pod for pod, key in zip(bound_pods, keys)
-                    if key not in failed
-                ]
+        with span("commit:bind"):
+            if getattr(store, "async_bind", False):
+                # Async dispatch (cache.go:536-552): the cycle only
+                # pays a list append (batches go to the dispatcher at
+                # cycle end — see run()); failures surface via
+                # drain_bind_failures at the next cycle's start and
+                # re-enter Pending with backoff.
+                self._bind_batches.append(
+                    (keys, hosts, bound_pods, None))
+            else:
+                try:
+                    if bind_keys is not None:
+                        bind_keys(keys, hosts)
+                    else:
+                        failed = []
+                        for pod, hostname, key in zip(
+                                bound_pods, hosts, keys):
+                            try:
+                                binder.bind(pod, hostname)
+                            except BindFailure:
+                                failed.append(key)
+                        if failed:
+                            raise BindFailure(failed)
+                except BindFailure as bf:
+                    self._revert_failed_binds(bf.failed, keys,
+                                              bound_rows, bound_pods)
+                    failed = set(bf.failed)
+                    bound_pods = [
+                        pod for pod, key in zip(bound_pods, keys)
+                        if key not in failed
+                    ]
         if notify:
-            for pod in bound_pods:
-                store._notify("Pod", "bind", pod)
+            with span("commit:notify"):
+                for pod in bound_pods:
+                    store._notify("Pod", "bind", pod)
 
         store.mark_objects_stale()
         return True
+
+    def _commit_volumes(self, keys, hosts, bound_pods, bound_rows):
+        """The volume gate of ``_commit`` (the ``commit:volumes``
+        span): allocate + bind each claiming pod's volumes, revert the
+        pods whose claim failed, return the surviving lists."""
+        from .cache.interface import VolumeBindFailure
+
+        store = self.store
+        if not any(pod.volumes for pod in bound_pods):
+            return keys, hosts, bound_pods, bound_rows
+        vb = store.volume_binder
+        vol_failed = []
+        for pod, hostname, key in zip(bound_pods, hosts, keys):
+            if not pod.volumes:
+                continue
+            try:
+                vb.allocate_volumes(pod, hostname)
+                vb.bind_volumes(pod)
+            except VolumeBindFailure as e:
+                store.record_event(f"Pod/{key}", "FailedScheduling",
+                                   str(e))
+                vol_failed.append(key)
+        if vol_failed:
+            self._revert_failed_binds(vol_failed, keys, bound_rows,
+                                      bound_pods)
+            fset = set(vol_failed)
+            kept = [
+                (k, h, p, r) for k, h, p, r
+                in zip(keys, hosts, bound_pods, bound_rows)
+                if k not in fset
+            ]
+            keys = [k for k, _, _, _ in kept]
+            hosts = [h for _, h, _, _ in kept]
+            bound_pods = [p for _, _, p, _ in kept]
+            bound_rows = [r for _, _, _, r in kept]
+        return keys, hosts, bound_pods, bound_rows
 
     def _revert_failed_binds(self, failed_keys, keys: List[str],
                              bound_rows: List[int],
@@ -4856,11 +5009,19 @@ def run_cycle_fast(store, conf, shard=None) -> bool:
     plane (ISSUE 16) — cycles stay atomic under the store lock, so
     shards interleave at cycle granularity and only the PIPELINED
     overlap races across shards (the optimistic commit gate's domain)."""
-    cycle = FastCycle(store, conf, shard=shard)
-    if not cycle.eligible():
-        return False
-    with store._lock:
-        cycle.run()
+    tracer = tracer_of(store, annotate=TraceAnnotation)
+    with tracer.cycle(getattr(store, "flight", None)) as scope:
+        # Still the cycle's ``prologue`` lane (scheduler.py opens it):
+        # building the cycle, and the wait for the store lock.
+        with scope.lane("prologue"):
+            cycle = FastCycle(store, conf, shard=shard)
+            if not cycle.eligible():
+                return False
+            store._lock.acquire()
+        try:
+            cycle.run()
+        finally:
+            store._lock.release()
     if shard is not None:
         shard.cycles += 1
     return True

@@ -7,10 +7,13 @@ the HTTP service can wire them unconditionally:
 - ``trace``    — the low-overhead span API (``perf_counter_ns``; one
   small record appended per span, nothing else on the fast path) the
   cycle lanes, the pipelined dispatch→fetch→commit chain, the object
-  session's action/plugin boundaries, and the remote RPC clients all
-  record into.
+  session's action/plugin boundaries, the bind dispatcher and the
+  remote RPC clients all record into; the lanes rule (lanes partition
+  ``Scheduler.run_once()``) and the ``CycleScope`` that frames a
+  cycle's record live there.
 - ``recorder`` — the fixed-size ring buffer (default 256 cycles) of
-  per-cycle ``CycleRecord``s: lane breakdown, pods considered / bound /
+  per-cycle ``CycleRecord``s: lane breakdown and its residual, the
+  solve's counts, pods considered / bound /
   dropped, staleness-guard drop counts by reason, in-flight fetch wait,
   device crash events, mirror ``mutation_seq``/``epoch`` at dispatch vs
   commit, and the cycle's spans.

@@ -15,7 +15,10 @@ Produces the JSON object format (``{"traceEvents": [...]}``) that both
   budget-degradation) and per drop-reason tally, so "17 rows dropped:
   capacity-taken" is readable at the cycle where it happened;
 - metadata events name the process and the logical threads ("cycle",
-  "rpc", "bind");
+  "rpc", "bind" — the bind dispatcher's per-batch ``bind:*`` events —
+  and "store" — object-model rebuilds, from whichever thread paid);
+- a lane span says so (``args.lane``): lanes partition the cycle, the
+  spans nested under them (``commit:*``, ``device:*``) are children;
 - pod journeys (obs/journey.py, ISSUE 18) export as ASYNC tracks: one
   ``"ph": "b"``/``"e"`` pair per pod uid bracketing its timeline, with
   one ``"ph": "n"`` instant per journey event (kind / shard /
@@ -33,7 +36,7 @@ import json
 from typing import Dict, Iterable, List, Optional
 
 PID = 1
-_TID_ORDER = ("cycle", "rpc", "bind")
+_TID_ORDER = ("cycle", "rpc", "bind", "store")
 
 
 def _tid_of(name: str, table: Dict[str, int]) -> int:
@@ -60,6 +63,8 @@ def trace_events(records: Iterable,
             ts_us = span.ts_ns / 1e3
             args = dict(span.args) if span.args else {}
             args.setdefault("cycle_seq", rec.seq)
+            if span.lane is not None:
+                args["lane"] = span.lane
             ev = {
                 "name": span.name,
                 "cat": span.cat,

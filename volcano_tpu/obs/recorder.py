@@ -7,7 +7,9 @@ seconds only.  The flight recorder keeps the last N cycles (default
 256, ``VOLCANO_TPU_FLIGHT_CYCLES``) of everything a post-hoc "why did
 cycle 48231 drop 17 rows" investigation needs:
 
-- the lane breakdown (derive/feed/encode/device/order/commit/close),
+- the lane breakdown: top-level, disjoint spans that partition
+  ``Scheduler.run_once()`` (the lanes rule, obs/trace.py), and the
+  residual no lane names (``unattributed_ms``),
 - pods considered / bound / dropped, drop counts BY REASON (the
   staleness guard's deleted / competing-bind / capacity-taken /
   constraint-sensitive / node-epoch-churn, the topology gate's
@@ -18,6 +20,8 @@ cycle 48231 drop 17 rows" investigation needs:
 - mirror ``mutation_seq`` / node-table ``epoch`` at dispatch vs commit
   (how much the world moved during the overlap),
 - the dispatched and committed solve-ids (the cross-cycle link),
+- the counts of the cycle's solve (``solve``: rows, nodes, devincr
+  mode, devsnap uploads, host<->device transfers and their bytes),
 - the cycle's trace spans (``obs.trace``), and
 - the runtime auditor's anomalies for the cycle (``obs.audit``).
 
@@ -38,8 +42,10 @@ DEFAULT_CAPACITY = 256
 
 class CycleRecord:
     """One scheduling cycle's accounting.  Plain data; built by the
-    cycle thread, sealed by ``FlightRecorder.record`` (which assigns
-    ``seq``), then read-only."""
+    cycle thread, completed by its ``CycleScope`` (obs/trace.py:
+    ``t_wall``, ``duration_s``, ``lanes`` and ``spans`` are final when
+    ``run_once()`` leaves), sealed by ``FlightRecorder.record`` (which
+    assigns ``seq``), then read-only."""
 
     __slots__ = (
         "seq", "session", "path", "t_wall", "duration_s", "shard",
@@ -49,7 +55,7 @@ class CycleRecord:
         "committed_solve_id", "mutation_seq_at_dispatch",
         "mutation_seq_at_commit", "epoch_at_dispatch", "epoch_at_commit",
         "device_events", "error", "spans", "rebalance", "whatif",
-        "pool", "anomalies",
+        "pool", "anomalies", "solve",
     )
 
     def __init__(self, session: str = "", path: str = "fast",
@@ -72,7 +78,8 @@ class CycleRecord:
                  rebalance: Optional[dict] = None,
                  whatif: Optional[dict] = None,
                  pool: Optional[dict] = None,
-                 anomalies: Optional[List[dict]] = None):
+                 anomalies: Optional[List[dict]] = None,
+                 solve: Optional[dict] = None):
         self.seq = -1  # assigned by FlightRecorder.record
         self.session = session
         self.path = path
@@ -115,6 +122,23 @@ class CycleRecord:
         # Runtime-auditor findings for THIS cycle (ISSUE 13,
         # obs/audit.py Anomaly.to_dict): empty on a healthy cycle.
         self.anomalies = anomalies or []
+        # Counts of this cycle's solve, at the boundaries the spans
+        # time (ISSUE 25, FastCycle._solve_counts): dispatches, rows,
+        # nodes, devincr mode + dirty nodes, devsnap full/delta/hit
+        # uploads, blocking device->host fetches and host->device puts
+        # with their bytes.  None when no solve ran (null-delta skip,
+        # nothing pending, the object path).
+        self.solve = solve
+
+    @property
+    def unattributed_s(self) -> float:
+        """``duration_s`` minus every top-level lane: the time of
+        ``run_once()`` no lane names (the lanes rule, obs/trace.py)."""
+        from .trace import NESTED_LANES
+
+        return self.duration_s - sum(
+            s for name, s in self.lanes.items()
+            if name not in NESTED_LANES)
 
     def to_dict(self, include_spans: bool = False) -> dict:
         d = {
@@ -127,6 +151,7 @@ class CycleRecord:
             "lanes_ms": {
                 k: round(v * 1e3, 3) for k, v in self.lanes.items()
             },
+            "unattributed_ms": round(self.unattributed_s * 1e3, 3),
             "pods_considered": self.pods_considered,
             "pods_bound": self.pods_bound,
             "pods_dropped": self.pods_dropped,
@@ -147,6 +172,8 @@ class CycleRecord:
             "pool": (dict(self.pool)
                      if self.pool is not None else None),
             "anomalies": [dict(a) for a in self.anomalies],
+            "solve": (dict(self.solve)
+                      if self.solve is not None else None),
         }
         if include_spans:
             d["spans"] = [s.to_dict() for s in self.spans]

@@ -138,6 +138,11 @@ class DeviceSnapshot:
         self.hits = 0
         self.class_uploads = 0
         self.class_hits = 0
+        # Host->device puts this snapshot made, and their bytes (the
+        # nbytes of what was put; the cycle record's ``solve`` carries
+        # each cycle's deltas).
+        self.puts = 0
+        self.put_bytes = 0
         # Extra scatter passes taken because a delta exceeded the
         # per-scatter staging budget (see budget_bytes).
         self.delta_chunks = 0
@@ -147,6 +152,8 @@ class DeviceSnapshot:
     def _put_plane(self, a: np.ndarray):
         """Commit one full node plane: node-axis sharded on a mesh
         (when the axis divides), single default device otherwise."""
+        self.puts += 1
+        self.put_bytes += a.nbytes
         if self._node_shd is not None:
             n_dev = self.mesh.devices.size
             if a.ndim and a.shape[0] % n_dev == 0:
@@ -157,6 +164,8 @@ class DeviceSnapshot:
     def _put_delta(self, rows: np.ndarray, vals: np.ndarray):
         """Commit a padded delta (replicated on a mesh: every chip needs
         the row ids to decide ownership; the values are tiny)."""
+        self.puts += 2
+        self.put_bytes += rows.nbytes + vals.nbytes
         if self._rep_shd is not None:
             return (jax.device_put(rows, self._rep_shd),
                     jax.device_put(vals, self._rep_shd))
@@ -312,10 +321,10 @@ class DeviceSnapshot:
         # shard against the full table set).
         _put = (jax.device_put if self._rep_shd is None
                 else (lambda a: jax.device_put(a, self._rep_shd)))
-        self._cls_planes = {
-            name: _put(np.asarray(fn()))
-            for name, fn in build.items()
-        }
+        tables = {name: np.asarray(fn()) for name, fn in build.items()}
+        self.puts += len(tables)
+        self.put_bytes += sum(a.nbytes for a in tables.values())
+        self._cls_planes = {name: _put(a) for name, a in tables.items()}
         self._cls_key = key
         self.class_uploads += 1
         return self._cls_planes

@@ -15,6 +15,8 @@ import time
 from pathlib import Path
 from typing import List, Optional, Sequence
 
+from jax.profiler import TraceAnnotation
+
 from . import actions as _actions  # noqa: F401  (registers actions)
 from . import plugins as _plugins  # noqa: F401  (registers plugins)
 from .cache import ClusterStore
@@ -26,6 +28,7 @@ from .framework import (
     parse_scheduler_conf,
 )
 from .metrics import metrics
+from .obs.trace import tracer_of
 
 log = logging.getLogger(__name__)
 
@@ -174,37 +177,49 @@ class Scheduler:
         reclaimed."""
         import gc
 
-        gc_was_enabled = gc.isenabled()
-        if gc_was_enabled:
-            gc.disable()
-        try:
-            self._run_once_inner()
-        finally:
+        # The cycle's frame (obs/trace.py CycleScope): the record of
+        # this cycle covers entry to exit of run_once(), every lane of
+        # it is a top-level span, and the record is sealed when the
+        # scope closes — after the gc lane below.
+        tracer = tracer_of(self.store, annotate=TraceAnnotation)
+        with tracer.cycle(getattr(self.store, "flight", None)) as scope:
+            gc_was_enabled = gc.isenabled()
             if gc_was_enabled:
-                gc.enable()
-                gc.collect(0)
+                gc.disable()
+            try:
+                self._run_once_inner()
+            finally:
+                if gc_was_enabled:
+                    gc.enable()
+                    with scope.lane("gc"):
+                        gc.collect(0)
 
     def _run_once_inner(self) -> None:
-        conf = self._load_conf()
-        action_names = [
-            a.strip() for a in conf.actions.split(",") if a.strip()
-        ]
-        # Queued async-bind failures re-enter Pending (with backoff) before
-        # the cycle snapshots — on this thread, for BOTH the fast path and
-        # the object-session fallback (cache.go errTasks resync).
-        drain = getattr(self.store, "drain_bind_failures", None)
-        if drain is not None:
-            drain()
-        # Work stealing (shard.py, ISSUE 16): an idle shard claims the
-        # most-starved foreign queue BEFORE its cycle snapshots, so the
-        # stolen backlog is schedulable this very cycle.
-        if self.shard is not None:
-            self.shard.maybe_steal(self.store)
-        with metrics.e2e_timer(), _device_trace():
-            if self._fastpath_enabled() or self.shard is not None:
+        # The frame run_once() opened on this thread.
+        scope = tracer_of(self.store).cycle()
+        with scope.lane("prologue"):
+            conf = self._load_conf()
+            action_names = [
+                a.strip() for a in conf.actions.split(",") if a.strip()
+            ]
+            # Queued async-bind failures re-enter Pending (with backoff)
+            # before the cycle snapshots — on this thread, for BOTH the
+            # fast path and the object-session fallback (cache.go
+            # errTasks resync).
+            drain = getattr(self.store, "drain_bind_failures", None)
+            if drain is not None:
+                drain()
+            # Work stealing (shard.py, ISSUE 16): an idle shard claims
+            # the most-starved foreign queue BEFORE its cycle snapshots,
+            # so the stolen backlog is schedulable this very cycle.
+            if self.shard is not None:
+                self.shard.maybe_steal(self.store)
+            fast = self._fastpath_enabled() or self.shard is not None
+            if fast:
                 enable_compilation_cache()
                 from .fastpath import run_cycle_fast
-
+        with metrics.e2e_timer(), _device_trace():
+            if fast:
                 try:
                     if run_cycle_fast(self.store, conf, shard=self.shard):
                         return
@@ -230,6 +245,9 @@ class Scheduler:
                     log.exception(
                         "Fast path failed; falling back to object session"
                     )
+                    # The failed cycle's record ends here; the object
+                    # session's begins.
+                    scope.split()
             if self.shard is not None:
                 # Ineligible config (custom plugins / solver) under
                 # sharding: there is no shard-aware fallback.  Loud
@@ -239,78 +257,73 @@ class Scheduler:
                     "configuration (VOLCANO_TPU_SHARDS=1 restores the "
                     "object-session fallback)"
                 )
-            # An in-flight pipelined solve must not survive into the
-            # object session: its pods still read as Pending there and
-            # would double-schedule when the fast path later committed
-            # the stale assignment.  Abandoning is safe — the pods
-            # re-place on whichever path runs this cycle.
-            from .pipeline import abandon_inflight, abandon_inflight_plan
+            with scope.lane("prologue"):
+                # An in-flight pipelined solve must not survive into
+                # the object session: its pods still read as Pending
+                # there and would double-schedule when the fast path
+                # later committed the stale assignment.  Abandoning is
+                # safe — the pods re-place on whichever path runs this
+                # cycle.
+                from .pipeline import (
+                    abandon_inflight,
+                    abandon_inflight_plan,
+                )
 
-            abandon_inflight(self.store)
-            # A parked rebalance plan is also fast-path-only state; it
-            # mutates nothing until committed, so dropping it is free.
-            abandon_inflight_plan(self.store)
-            # The object session snapshots pod RECORDS as scheduling
-            # truth: force any deferred bind-record walks (node_name on
-            # committed pods, normally applied post-cycle by the bind
-            # dispatcher) before building it, or committed pods read as
-            # unbound and double-schedule.
-            apply_records = getattr(
-                self.store, "apply_pending_bind_records", None
-            )
-            if apply_records is not None:
-                apply_records()
-            self._run_object_session(conf, action_names)
+                abandon_inflight(self.store)
+                # A parked rebalance plan is also fast-path-only state;
+                # it mutates nothing until committed, so dropping it is
+                # free.
+                abandon_inflight_plan(self.store)
+                # The object session snapshots pod RECORDS as scheduling
+                # truth: force any deferred bind-record walks (node_name
+                # on committed pods, normally applied post-cycle by the
+                # bind dispatcher) before building it, or committed pods
+                # read as unbound and double-schedule.
+                apply_records = getattr(
+                    self.store, "apply_pending_bind_records", None
+                )
+                if apply_records is not None:
+                    apply_records()
+            self._run_object_session(conf, action_names, scope)
 
-    def _run_object_session(self, conf, action_names) -> None:
-        """One object-session cycle, traced + flight-recorded (the fast
-        path records its own cycles inside FastCycle.run)."""
-        import time as _time
-
+    def _run_object_session(self, conf, action_names, scope) -> None:
+        """One object-session cycle, traced + flight-recorded: the
+        session's lanes (open, one per action, close) join the cycle's
+        scope, which seals the record when run_once() leaves."""
         from .obs.recorder import CycleRecord
-        from .obs.trace import tracer_of
 
-        tracer = tracer_of(self.store)
-        lanes = {}
-        t_wall = _time.time()
-        t0 = _time.perf_counter()
+        tracer = scope.tracer
+        lanes = scope.lanes
+        scope.describe("object", None)
         ssn = None
         err = None
         try:
-            with tracer.span("cycle", cat="object"):
-                with tracer.span("open", lanes=lanes):
-                    ssn = open_session(
-                        self.store, conf.tiers, conf.configurations
-                    )
-                try:
-                    for name in action_names:
-                        action = get_action(name)
-                        if action is None:
-                            log.warning("Unknown action %s", name)
-                            continue
-                        with metrics.action_timer(name), tracer.span(
-                                f"action:{name}", cat="action",
-                                lanes=lanes, lane=name):
-                            action.execute(ssn)
-                finally:
-                    with tracer.span("close", lanes=lanes):
-                        close_session(ssn)
+            with tracer.span("open", lanes=lanes):
+                ssn = open_session(
+                    self.store, conf.tiers, conf.configurations
+                )
+            try:
+                for name in action_names:
+                    action = get_action(name)
+                    if action is None:
+                        log.warning("Unknown action %s", name)
+                        continue
+                    with metrics.action_timer(name), tracer.span(
+                            f"action:{name}", cat="action",
+                            lanes=lanes, lane=name):
+                        action.execute(ssn)
+            finally:
+                with tracer.span("close", lanes=lanes):
+                    close_session(ssn)
         except BaseException as e:
             err = e
             raise
         finally:
-            flight = getattr(self.store, "flight", None)
-            if flight is not None:
-                flight.record(CycleRecord(
+            with scope.lane("record"):
+                scope.submit(CycleRecord(
                     session=getattr(ssn, "uid", ""), path="object",
-                    t_wall=t_wall,
-                    duration_s=_time.perf_counter() - t0,
-                    lanes=lanes,
                     error=type(err).__name__ if err is not None else None,
-                    spans=tracer.drain(),
                 ))
-            else:
-                tracer.drain()
 
     @staticmethod
     def _fastpath_enabled() -> bool:

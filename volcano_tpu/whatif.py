@@ -332,7 +332,10 @@ def commit_inflight_plan(cyc) -> None:
     re-forms against fresh state)."""
     from .pipeline import take_inflight_plan
 
-    inflight = take_inflight_plan(cyc.store)
+    # Top of the cycle, outside every action span: the taking is the
+    # ``inflight`` lane, the landing a lane named after the action.
+    with cyc.tracer.span("inflight", lanes=cyc.lanes):
+        inflight = take_inflight_plan(cyc.store)
     if inflight is None:
         return
     m = cyc.m
