@@ -952,11 +952,10 @@ class FastCycle:
     def _journey_masks(self):
         """First-time row masks for the journey's steady-state bulk
         accounting (obs/journey.py): the feed re-pends and re-binds the
-        SAME backlog rows every cycle, and per-pod Python capture at
-        that scale would dwarf the cycle.  The masks remember which
-        rows already recorded their first consideration / first bind,
-        so per-pod work is paid once per pod and repeats fold into bulk
-        counters — journey cost stays churn-proportional.  Row indices
+        SAME backlog rows every cycle.  The masks remember which rows
+        already recorded their first consideration / first bind, so a
+        repeat costs neither the gather of its uid nor the journey's
+        uid lookup and folds into a bulk counter.  Row indices
         are stable for a pod's lifetime; a compaction renumbers them,
         so the masks are keyed on ``compact_gen`` and rebuilt on a
         bump (uid-keyed journey state survives; only the first-seen
@@ -986,35 +985,34 @@ class FastCycle:
                          solve_id=solve_id, detail=detail)
 
     def _journey_rows(self, rows, kind: str, *, solve_id: int = 0,
-                      epoch: int = -1, detail: str = "") -> None:
+                      epoch: int = -1, detail: str = ""
+                      ) -> Optional[dict]:
         """Bulk journey capture for the vectorized seams.  For the
         steady-state kinds (``dispatched``/``bound``/``unbound``) only
-        FIRST-time rows pay per-pod work (see ``_journey_masks``);
-        drops and voids are churn-sized, so every row records."""
+        FIRST-time rows are stamped (see ``_journey_masks``);
+        drops and voids are churn-sized, so every row records.
+        Returns the enclosing span's args: the rows given and how many
+        of them went to the journey's batch path."""
         jr = getattr(self.store, "journey", None)
-        if jr is None or not len(rows):
-            return
-        m = self.m
-        shard = self._journey_shard()
-        if kind in ("dispatched", "bound"):
+        n = len(rows)
+        if jr is None or not n:
+            return None
+        if kind == "unbound":
+            rows = rows[:0]
+        elif kind in ("dispatched", "bound"):
             gen, considered, bound_seen = self._journey_masks()
             mask = considered if kind == "dispatched" else bound_seen
-            fresh = ~mask[rows]
-            n_rep = int(len(rows) - np.count_nonzero(fresh))
-            if n_rep:
-                jr.repeat_rows(n_rep, kind)
-            if not fresh.any():
-                return
-            rows = rows[fresh]
+            rows = rows[~mask[rows]]
             mask[rows] = True
-        elif kind == "unbound":
-            # Re-pend loop: the pods' journeys already hold their
-            # first-bind latency; count in bulk only.
-            jr.repeat_rows(int(len(rows)), kind)
-            return
-        jr.pod_rows((m.p_uid[i] for i in rows.tolist()), kind,
-                    shard=shard, solve_id=solve_id, epoch=epoch,
-                    detail=detail)
+        if n > len(rows):
+            # Re-pend loop: the pods' journeys already hold their first
+            # consideration / first-bind latency; count in bulk only.
+            jr.repeat_rows(n - len(rows), kind)
+        if len(rows):
+            jr.pod_rows(map(self.m.p_uid.__getitem__, rows.tolist()),
+                        kind, shard=self._journey_shard(),
+                        solve_id=solve_id, epoch=epoch, detail=detail)
+        return {"rows": n, "fresh": len(rows)}
 
     def _record_cycle(self, scope, err: Optional[BaseException]) -> None:
         """Run the cycle-end audits and hand this cycle's record to its
@@ -1688,8 +1686,8 @@ class FastCycle:
                             cjobs, crows, slim=(solver == "wave"))
                     # Journey: these rows entered a device solve
                     # (first-time rows record; repeats bulk-count).
-                    with tracer.span("journey", lanes=lanes):
-                        self._journey_rows(crows, "dispatched")
+                    with tracer.span("journey", lanes=lanes) as sp:
+                        sp.args = self._journey_rows(crows, "dispatched")
                     # Device-incremental context: single-chunk wave
                     # solves only (chunked solves interleave commits,
                     # so each chunk would need its own proof).
@@ -1997,8 +1995,9 @@ class FastCycle:
         # Journey: these rows entered a device solve (first-time rows
         # record with the flow's solve-id; repeats bulk-count).  Under
         # the ``dispatch`` span here, so a child and no lane.
-        with self.tracer.span("dispatch:journey"):
-            self._journey_rows(crows, "dispatched", solve_id=solve_id)
+        with self.tracer.span("dispatch:journey") as sp:
+            sp.args = self._journey_rows(crows, "dispatched",
+                                         solve_id=solve_id)
         shard_idx = None if self.shard is None else self.shard.index
         shard_seq = None
         if self.shard is not None:
@@ -3879,8 +3878,8 @@ class FastCycle:
         # Journey: the placement landed (first-time rows record the
         # bind — and their time-to-bind — with the committing solve's
         # flow id; steady-state re-binds bulk-count).
-        with span("commit:journey"):
-            self._journey_rows(
+        with span("commit:journey") as sp:
+            sp.args = self._journey_rows(
                 rows, "bound",
                 solve_id=int(self.stats.get("committed_solve_id") or 0))
 

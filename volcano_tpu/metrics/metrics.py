@@ -59,6 +59,27 @@ class _Histogram:
             state[1] += value
             state[2] += 1
 
+    def observe_many(self, values, **labels):
+        """``observe`` for a batch under one lock acquisition: the same
+        bucket counts and count (``searchsorted`` left = ``bisect_left``);
+        the sum is equal up to float summation order."""
+        import numpy as np
+
+        vals = np.asarray(values, dtype=np.float64)
+        if not vals.size:
+            return
+        counts = np.bincount(
+            np.searchsorted(_DEFAULT_BUCKETS, vals, side="left"),
+            minlength=_N_BUCKETS + 1).tolist()
+        key = _labels_key(labels)
+        with self._lock:
+            state = self.data.get(key)
+            if state is None:
+                state = self.data[key] = [[0] * (_N_BUCKETS + 1), 0.0, 0]
+            state[0][:] = map(int.__add__, state[0], counts)
+            state[1] += float(vals.sum())
+            state[2] += int(vals.size)
+
 
 class _Gauge:
     def __init__(self, name: str, help_: str,

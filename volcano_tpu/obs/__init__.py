@@ -1,8 +1,9 @@
 """Observability layer: trace spans, cycle flight recorder, Perfetto
 export (ISSUE 3), runtime conservation auditor + SLO layer (ISSUE 13).
 
-Six stdlib-only modules, importable without jax/numpy so the store and
-the HTTP service can wire them unconditionally:
+Six modules, stdlib-only at module scope but for ``journey`` (numpy),
+importable without jax so the store and the HTTP service can wire them
+unconditionally:
 
 - ``trace``    — the low-overhead span API (``perf_counter_ns``; one
   small record appended per span, nothing else on the fast path) the

@@ -77,6 +77,21 @@ def _pct(vals: List[float], q: float) -> float:
     return vals[i]
 
 
+def _breach(b: Budget, ms: float, window: List[float], burn: float,
+            over: int) -> dict:
+    """A breach edge's detail: the sample that crossed, the window it
+    crossed in."""
+    return {
+        "lane": b.lane,
+        "target_ms": b.target_ms,
+        "observed_ms": round(ms, 3),
+        "window_p99_ms": round(_pct(window, 0.99), 3),
+        "burn_rate": round(burn, 2),
+        "over_in_window": over,
+        "window": len(window),
+    }
+
+
 class SLOTracker:
     """Per-lane sliding-window latency tracker with budget burn."""
 
@@ -132,6 +147,61 @@ class SLOTracker:
             self._feed_locked(lane, float(ms), breaches)
         return breaches
 
+    def observe_samples(self, lane: str, values) -> List[dict]:
+        """``observe_sample`` for a batch under one lock acquisition:
+        the window, counters, gauge and breach edges (in order) that
+        one call per value would leave."""
+        import numpy as np
+
+        vals = np.asarray(values, dtype=np.float64)
+        breaches: List[dict] = []
+        if not vals.size:
+            return breaches
+        with self._lock:
+            win = self._lanes.get(lane)
+            if win is None:
+                win = self._lanes[lane] = deque(maxlen=self.window)
+            b = self.budgets.get(lane)
+            if b is not None:
+                self._feed_many_locked(lane, b, win, vals, breaches)
+            win.extend(vals[-self.window:].tolist())
+            self.observations[lane] = (
+                self.observations.get(lane, 0) + int(vals.size))
+        return breaches
+
+    # holds: _lock
+    def _feed_many_locked(self, lane: str, b: Budget, win: deque, vals,
+                          breaches: List[dict]) -> None:
+        """The budget leg of ``_feed_locked`` for every value of a
+        batch, ``win`` being the window before it: the count over the
+        sliding window after each append is a difference of cumulative
+        sums over window + batch."""
+        import numpy as np
+
+        from ..metrics import metrics
+
+        old, n, size = len(win), len(vals), self.window
+        seq = np.concatenate([np.fromiter(win, np.float64, old), vals])
+        cum = np.concatenate([[0], np.cumsum(seq > b.target_ms)])
+        self.violations[lane] = (
+            self.violations.get(lane, 0) + int(cum[-1] - cum[old]))
+        end = np.arange(old + 1, old + n + 1)  # window end, per append
+        lo = np.maximum(end - size, 0)
+        first = int(np.searchsorted(end - lo, MIN_SAMPLES))
+        if first == n:
+            return
+        over = (cum[end] - cum[lo])[first:]
+        burn = (over / size) / b.allowed_frac
+        hot = burn >= 1.0
+        was = np.concatenate([[self._breached.get(lane, False)], hot[:-1]])
+        self._breached[lane] = bool(hot[-1])
+        metrics.slo_burn_rate.set(round(float(burn[-1]), 4), lane=lane)
+        for j in np.flatnonzero(hot & ~was).tolist():
+            i = first + j
+            breaches.append(_breach(b, float(vals[i]),
+                                    seq[lo[i]:end[i]].tolist(),
+                                    float(burn[j]), int(over[j])))
+
     # holds: _lock
     def _feed_locked(self, lane: str, ms: float,
                      breaches: List[dict]) -> None:
@@ -160,15 +230,7 @@ class SLOTracker:
         self._breached[lane] = now
         metrics.slo_burn_rate.set(round(burn, 4), lane=lane)
         if now and not was:
-            breaches.append({
-                "lane": lane,
-                "target_ms": b.target_ms,
-                "observed_ms": round(ms, 3),
-                "window_p99_ms": round(_pct(list(win), 0.99), 3),
-                "burn_rate": round(burn, 2),
-                "over_in_window": over,
-                "window": len(win),
-            })
+            breaches.append(_breach(b, ms, list(win), burn, over))
 
     # ------------------------------------------------------------- reads
 
