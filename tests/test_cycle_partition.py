@@ -289,6 +289,45 @@ def test_device_children_have_the_device_span_as_parent_and_fit_in_it():
         s.name for s in rec.spans}
 
 
+def test_derive_and_order_children_on_a_four_queue_cycle():
+    """ISSUE 27: four weighted queues and gangs in the same cycle: the
+    fair-share children lie inside ``derive`` / ``order``, the lanes
+    still partition ``run_once()``, and the overuse gate's count rode
+    the one fetch that was there."""
+    store = _store(seed=27, n_nodes=12, n_pods=96, gang_size=4, n_queues=4,
+                   queue_weights=(1, 2, 4, 8))
+    rec, outer_s = _cycle(store)
+    _assert_partitioned(rec)
+    _assert_lanes_rule(rec)
+    assert rec.duration_s <= outer_s
+    want = {"derive": ["derive:proportion"],
+            "order": ["order:shares", "order:queues", "order:jobs",
+                      "order:tasks"]}
+    for lane, names in want.items():
+        parents = [s for s in rec.spans if s.name == lane]
+        assert parents and all(s.lane == lane for s in parents)
+        for parent in parents:
+            kids = sorted(_children(rec, parent), key=lambda k: k.ts_ns)
+            assert [k.name for k in kids] == names
+            assert all(k.lane is None for k in kids)
+            assert sum(k.dur_ns for k in kids) <= parent.dur_ns
+            for k in kids:
+                assert parent.ts_ns <= k.ts_ns
+                assert (k.ts_ns + k.dur_ns
+                        <= parent.ts_ns + parent.dur_ns)
+    args = {s.name: s.args for s in rec.spans if ":" in s.name}
+    assert args["derive:proportion"]["queues"] == 4
+    assert len(args["derive:proportion"]["deserved_cpu"]) == 4
+    assert args["derive:proportion"]["iterations"] >= 1
+    assert args["order:queues"] == {"queues": 4, "overused": 0}
+    assert args["order:tasks"] == {"cache_hit": False}
+    solve = rec.solve
+    assert solve["queues"] == 4 and solve["jobs"] == 24
+    assert solve["gang_size_max"] == 4
+    assert solve["dispatches"] == solve["fetches"] == 1
+    assert solve["overuse_gated_jobs"] == 0
+
+
 # ---------------------------------------------------- bind thread and store
 
 

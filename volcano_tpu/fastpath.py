@@ -515,7 +515,8 @@ class FastCycle:
     def _proportion(self):
         """Water-fill deserved shares (proportion.go:117-173) over the
         queues that have session jobs.  Mirrors the plugin's Resource-level
-        loop exactly (queue counts are small)."""
+        loop exactly (queue counts are small).  Returns the args of the
+        ``derive:proportion`` span (None without the plugin)."""
         self._flush_aggr()
         q_alloc = np.zeros((self.Qn, self.R), F)
         q_req = np.zeros((self.Qn, self.R), F)
@@ -539,7 +540,7 @@ class FastCycle:
             self.q_deserved = np.full((self.Qn, self.R), 3.0e38, F)
             self.q_share = share_by_queue
             self.q_deserved_res = deserved_res
-            return
+            return None
 
         total = self._res(self.total_res)
         attrs = {}
@@ -555,7 +556,9 @@ class FastCycle:
 
         remaining = total.clone()
         meet = set()
+        iterations = 0
         while True:
+            iterations += 1
             total_weight = sum(
                 a["weight"] for qi, a in attrs.items() if qi not in meet
             )
@@ -598,6 +601,10 @@ class FastCycle:
             share_by_queue[self.queue_names[qi]] = a["share"]
         self.q_share = share_by_queue
         self.q_deserved_res = deserved_res
+        return {"queues": len(attrs), "iterations": iterations,
+                "met": len(meet),
+                "deserved_cpu": [float(a["deserved"].milli_cpu)
+                                 for a in attrs.values()]}
 
     def _slots_vec(self, r: Resource) -> np.ndarray:
         v = np.zeros((self.R,), F)
@@ -800,7 +807,8 @@ class FastCycle:
         tracer = self.tracer
         with tracer.span("derive", lanes=self.lanes):
             self.derive()
-            self._proportion()
+            with tracer.span("derive:proportion") as sp:
+                sp.args = self._proportion()
         self.new_conditions: Dict[int, PodGroupCondition] = {}
         self._evictor = None
         # Async bind batches commit collects; dispatched at cycle end so
@@ -1069,18 +1077,28 @@ class FastCycle:
         if sc is None:
             sc = self.stats["solve"] = {
                 "dispatches": 0, "rows": 0, "nodes": int(self.Nn),
+                "jobs": 0, "queues": 0, "gang_size_max": 0,
+                "overuse_gated_jobs": None,
                 "devincr_mode": None, "dirty_nodes": None,
                 "arg_puts": 0, "arg_put_bytes": 0,
                 "fetches": 0, "fetch_bytes": 0,
             }
         return sc
 
-    def _count_dispatch(self, rows: int, args) -> None:
-        """One solve handed to the device: its rows and the numpy
-        leaves of ``args`` the jitted call uploads with it."""
+    def _count_dispatch(self, rows: int, args, jobs) -> None:
+        """One solve handed to the device: its rows, its ``jobs`` (how
+        many, over how many queues, the largest gang's ``min_member``)
+        and the numpy leaves of ``args`` the jitted call uploads with
+        it."""
         sc = self._solve_counts()
         sc["dispatches"] += 1
         sc["rows"] += int(rows)
+        sj = np.asarray(jobs, np.int64)
+        sc["jobs"] += len(sj)
+        sc["queues"] = max(sc["queues"],
+                           len(np.unique(self.q_of_job[sj])))
+        sc["gang_size_max"] = max(sc["gang_size_max"],
+                                  int(self.m.j_minav[sj].max()))
         n, nbytes = _host_bytes(args)
         sc["arg_puts"] += n
         sc["arg_put_bytes"] += nbytes
@@ -1575,7 +1593,9 @@ class FastCycle:
             rnd += 1
             with tracer.span("order", lanes=lanes):
                 ordered = self._ordered_jobs()
-                prep = self._pending_rows(ordered)
+                with tracer.span("order:tasks") as sp:
+                    prep = self._pending_rows(ordered)
+                    sp.args = {"cache_hit": self._pending_cache_hit}
             if prep is None:
                 break
             solve_jobs, task_rows = prep
@@ -1638,7 +1658,7 @@ class FastCycle:
                             args={"kind": kind, "rows": len(crows),
                                   "solve_id": solve_id}):
                         self._count_dispatch(
-                            len(crows), (inputs, pid, profiles))
+                            len(crows), (inputs, pid, profiles), cjobs)
                         if remote is not None:
                             # The child process rebuilds node classes
                             # from the numpy frame itself; class planes
@@ -1709,7 +1729,8 @@ class FastCycle:
                         with tracer.span("device:dispatch",
                                          cat="device") as disp:
                             self._count_dispatch(
-                                len(crows), (inputs, pid, profiles))
+                                len(crows), (inputs, pid, profiles),
+                                cjobs)
                             result = self._solve_sync(
                                 solver, solve_fn, remote, mesh, inputs,
                                 pid, profiles, ncls, dv, dv_manifest)
@@ -1738,14 +1759,18 @@ class FastCycle:
                             if solver == "wave":
                                 # The wave solver always carries the
                                 # two-phase fallback counters (zeros
-                                # when disabled); ride the same batched
-                                # fetch.
+                                # when disabled) and, solved in this
+                                # process, the overuse gate's count;
+                                # ride the same batched fetch.
+                                gated = (
+                                    () if result.overuse_gated is None
+                                    else (result.overuse_gated,))
                                 fetched = jax.device_get(
                                     (result.assigned,
                                      result.never_ready,
                                      result.fit_failed,
                                      result.fb_exhausted,
-                                     result.fb_affinity))
+                                     result.fb_affinity) + gated)
                             else:
                                 fetched = jax.device_get(
                                     (result.assigned,
@@ -1756,6 +1781,11 @@ class FastCycle:
                         if solver == "wave":
                             self._count_shortlist_fb(
                                 int(fetched[3]), int(fetched[4]))
+                            if gated:
+                                sc = self._solve_counts()
+                                sc["overuse_gated_jobs"] = (
+                                    (sc["overuse_gated_jobs"] or 0)
+                                    + int(fetched[5]))
                         assigned = assigned[:len(crows)]
                         # Fabric gate: require-contiguous gangs
                         # scattered across blocks are vetoed before the
@@ -2759,49 +2789,60 @@ class FastCycle:
         path's PriorityQueues reduce to lexsorts over interned
         namespace/queue code columns; the final round-robin ("one job per
         namespace per round") is a second lexsort on (position-within-
-        namespace, namespace-rank)."""
+        namespace, namespace-rank).
+
+        Children of the ``order`` lane: ``order:shares``,
+        ``order:queues``, ``order:jobs``."""
         m = self.m
         rows = self._schedulable_rows()
         if not rows:
             return []
-        drf_share = self._drf_shares()
-        jkeys = self._job_keys(rows, drf_share)
-        ns_share = self._ns_shares(drf_share)
-        overused = self._overused_fn()
-        queue_order = self._queue_order_fn()
-        ns_order = self._namespace_order_fn(ns_share)
+        span = self.tracer.span
+        with span("order:shares"):
+            drf_share = self._drf_shares()
+            jkeys = self._job_keys(rows, drf_share)
+            ns_share = self._ns_shares(drf_share)
+        with span("order:queues") as sp:
+            overused = self._overused_fn()
+            queue_order = self._queue_order_fn()
+            ns_order = self._namespace_order_fn(ns_share)
 
-        rows_arr = np.asarray(rows, np.int64)
-        nsc = m.j_ns_code[rows_arr]
-        qc = m.j_queue_code[rows_arr]
-        qinfo = self.store.queues
+            rows_arr = np.asarray(rows, np.int64)
+            nsc = m.j_ns_code[rows_arr]
+            qc = m.j_queue_code[rows_arr]
+            qinfo = self.store.queues
 
-        # Rank the few distinct namespaces/queues with the comparator
-        # closures (the per-JOB work stays in numpy).
-        # First-appearance order feeds the stable sorts so comparator ties
-        # (if any plugin comparator were non-total) resolve exactly as the
-        # object path's insertion-ordered scans did.
-        ns_codes, ns_first = np.unique(nsc, return_index=True)
-        ns_codes = ns_codes[np.argsort(ns_first, kind="stable")]
-        ns_names = [m.ns_names.items[c] for c in ns_codes.tolist()]
-        ns_sorted = sorted(ns_names, key=_cmp_key(ns_order))
-        ns_rank_of = {n: i for i, n in enumerate(ns_sorted)}
-        ns_rank_by_code = np.full(int(ns_codes.max()) + 1, -1, np.int64)
-        for c, n in zip(ns_codes.tolist(), ns_names):
-            ns_rank_by_code[c] = ns_rank_of[n]
+            # Rank the few distinct namespaces/queues with the comparator
+            # closures (the per-JOB work stays in numpy).
+            # First-appearance order feeds the stable sorts so comparator
+            # ties (if any plugin comparator were non-total) resolve
+            # exactly as the object path's insertion-ordered scans did.
+            ns_codes, ns_first = np.unique(nsc, return_index=True)
+            ns_codes = ns_codes[np.argsort(ns_first, kind="stable")]
+            ns_names = [m.ns_names.items[c] for c in ns_codes.tolist()]
+            ns_sorted = sorted(ns_names, key=_cmp_key(ns_order))
+            ns_rank_of = {n: i for i, n in enumerate(ns_sorted)}
+            ns_rank_by_code = np.full(int(ns_codes.max()) + 1, -1, np.int64)
+            for c, n in zip(ns_codes.tolist(), ns_names):
+                ns_rank_by_code[c] = ns_rank_of[n]
 
-        q_codes, q_first = np.unique(qc, return_index=True)
-        q_codes = q_codes[np.argsort(q_first, kind="stable")]
-        q_names = [m.qnames.items[c] for c in q_codes.tolist()]
-        q_sorted = sorted(q_names,
-                          key=_cmp_key(lambda a, b: queue_order(qinfo[a],
-                                                                qinfo[b])))
-        q_rank_of = {n: i for i, n in enumerate(q_sorted)}
-        q_rank_by_code = np.full(int(q_codes.max()) + 1, -1, np.int64)
-        for c, n in zip(q_codes.tolist(), q_names):
-            # Overused queues drop out of this pass entirely
-            # (allocate.go:126-143).
-            q_rank_by_code[c] = -1 if overused(qinfo[n]) else q_rank_of[n]
+            q_codes, q_first = np.unique(qc, return_index=True)
+            q_codes = q_codes[np.argsort(q_first, kind="stable")]
+            q_names = [m.qnames.items[c] for c in q_codes.tolist()]
+            q_sorted = sorted(
+                q_names,
+                key=_cmp_key(lambda a, b: queue_order(qinfo[a], qinfo[b])))
+            q_rank_of = {n: i for i, n in enumerate(q_sorted)}
+            q_rank_by_code = np.full(int(q_codes.max()) + 1, -1, np.int64)
+            for c, n in zip(q_codes.tolist(), q_names):
+                # Overused queues drop out of this pass entirely
+                # (allocate.go:126-143).
+                q_rank_by_code[c] = (
+                    -1 if overused(qinfo[n]) else q_rank_of[n])
+            sp.args = {
+                "queues": len(q_codes),
+                "overused": int((q_rank_by_code[q_codes] < 0).sum()),
+            }
 
         ns_r = ns_rank_by_code[nsc]
         q_r = q_rank_by_code[qc]
@@ -2809,24 +2850,29 @@ class FastCycle:
         rows_arr = rows_arr[keep]
         if not len(rows_arr):
             return []
-        ns_r = ns_r[keep]
-        q_r = q_r[keep]
-        # Within a namespace: queues in queue order, jobs by job key.
-        order1 = np.lexsort((jkeys[rows_arr], q_r, ns_r))
-        seq = rows_arr[order1]
-        ns_s = ns_r[order1]
-        # Position within the namespace group (groups are contiguous now).
-        starts = np.concatenate(([True], ns_s[1:] != ns_s[:-1]))
-        group_start = np.maximum.accumulate(
-            np.where(starts, np.arange(len(seq)), 0)
-        )
-        k = np.arange(len(seq)) - group_start
-        # Round-robin: k-th jobs of every namespace, namespaces in order.
-        final = np.lexsort((ns_s, k))
-        return seq[final].tolist()
+        with span("order:jobs"):
+            ns_r = ns_r[keep]
+            q_r = q_r[keep]
+            # Within a namespace: queues in queue order, jobs by job key.
+            order1 = np.lexsort((jkeys[rows_arr], q_r, ns_r))
+            seq = rows_arr[order1]
+            ns_s = ns_r[order1]
+            # Position within the namespace group (groups are contiguous
+            # now).
+            starts = np.concatenate(([True], ns_s[1:] != ns_s[:-1]))
+            group_start = np.maximum.accumulate(
+                np.where(starts, np.arange(len(seq)), 0)
+            )
+            k = np.arange(len(seq)) - group_start
+            # Round-robin: k-th jobs of every namespace, namespaces in
+            # order.
+            final = np.lexsort((ns_s, k))
+            return seq[final].tolist()
 
     def _pending_rows(self, ordered: List[int]):
         """Pending task rows in processing order (job-contiguous)."""
+        # Read by the ``order:tasks`` span: did the cached order serve?
+        self._pending_cache_hit = False
         m = self.m
         Pn = self.Pn
         status = m.p_status[:Pn]
@@ -2882,6 +2928,7 @@ class FastCycle:
                 and np.array_equal(cache[1], rows_all)
                 and np.array_equal(cache[2], ranks)):
             kept_jobs, task_rows = cache[3]
+            self._pending_cache_hit = True
             return list(kept_jobs), task_rows
         # Task order within a job: priority desc, creation asc, uid asc
         # (priority plugin task_order + session default tie-break).

@@ -145,6 +145,9 @@ class AllocResult(NamedTuple):
     # shortlist was built on.  None from the sequential solver.
     fb_exhausted: jnp.ndarray = None  # [] int32
     fb_affinity: jnp.ndarray = None  # [] int32
+    # Wave solve only: jobs the queue-overuse gate refused (the count of
+    # ``job_overskip``), for the cycle record's ``solve`` counts.
+    overuse_gated: jnp.ndarray = None  # [] int32
 
 
 def _subset(bits_row, table):

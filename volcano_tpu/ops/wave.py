@@ -1052,6 +1052,12 @@ def _solve_wave(
         queue_l = jax.lax.dynamic_slice_in_dim(queue_p, jlo, W)
         onehot_ql = (queue_l[:, None] == jnp.arange(Q)[None, :]).astype(f32)
         onehot_jq = jnp.matmul(onehot_j, onehot_ql)  # [W_task, Q]
+        if has_overuse:
+            # [W, W]: task w' stands before task w in this wave and in
+            # the same queue (the overuse gate's in-wave prefix).
+            ahead_q = (
+                (jnp.matmul(onehot_jq, onehot_jq.T) > 0) & tril
+            ).astype(f32)
         onehot_u = (pid_l[:, None] == jnp.arange(UM)[None, :]).astype(f32)
         same_pid = pid_l[:, None] == pid_l[None, :]
         jsl = lambda a: jax.lax.dynamic_slice_in_dim(a, jlo, W, axis=0)
@@ -1418,25 +1424,46 @@ def _solve_wave(
              fb_r) = carry
             skip_l0 = skip_l
 
+            def of_job(flag_t):
+                """[W_task] flag -> [W_job]: set by any task of the job."""
+                return jnp.matmul(
+                    onehot_j.T, flag_t.astype(f32)[:, None])[:, 0] > 0
+
+            def to_tasks(flag_l):
+                """[W_job] flag -> [W_task]: each task reads its job's."""
+                return jnp.matmul(
+                    onehot_j, flag_l.astype(f32)[:, None])[:, 0] > 0
+
             if has_overuse:
-                # Queue-overuse gating at each job's first task (live q).
-                gate = is_first_w & ~done
-                q_tot_w = jnp.matmul(onehot_jq, s.q_alloc + s.q_pip)
+                # Queue-overuse gating at each job's first task, at the
+                # point in task order where the reference evaluates it:
+                # on what its queue holds once every earlier job is
+                # through.  A queue that is over on its live allocation
+                # refuses the job for good.  One that would be over once
+                # the wave's earlier unresolved tasks of the same queue
+                # (``ahead``) are placed holds the job back for this
+                # attempt only: some of those may find no node, and the
+                # next attempt reads their outcome from the live
+                # allocation.  (Without ``ahead`` every job of a wave
+                # passes on the allocation of the wave's start.)  A
+                # queue's earliest open task has nothing ahead, so every
+                # attempt keeps a candidate per queue.
+                gate = is_first_w & ~done & real_w
+                live_w = jnp.matmul(onehot_jq, s.q_alloc + s.q_pip)
                 des_w = jnp.matmul(onehot_jq, queues.deserved)
-                overused = ~less_equal(q_tot_w, des_w, eps, scalar_slot)
-                gate_over = gate & overused & real_w
-                gated = (
-                    jnp.matmul(
-                        onehot_j.T, gate_over.astype(f32)[:, None]
-                    )[:, 0] > 0
-                )
+                over_live = ~less_equal(live_w, des_w, eps, scalar_slot)
+                gated = of_job(gate & over_live)
                 skip_l = skip_l | gated
                 over_l = over_l | gated
 
-            skip_t = (
-                jnp.matmul(onehot_j, skip_l.astype(f32)[:, None])[:, 0] > 0
-            )
+            skip_t = to_tasks(skip_l)
             cand = ~done & ~skip_t
+
+            if has_overuse:
+                ahead = jnp.matmul(ahead_q, req_w * cand[:, None])
+                over_ahead = ~less_equal(
+                    live_w + ahead, des_w, eps, scalar_slot)
+                cand = cand & ~to_tasks(of_job(gate & cand & over_ahead))
 
             if two_phase:
                 (feas_sl, future_idle, walk_idle, aff_ok_c,
@@ -2289,6 +2316,7 @@ def _solve_wave(
         iters=state.iters,
         fb_exhausted=state.fb_exhausted,
         fb_affinity=state.fb_affinity,
+        overuse_gated=jnp.sum(state.job_overskip[:J], dtype=jnp.int32),
     )
 
 
