@@ -1,7 +1,10 @@
 """Async bind dispatch + rate-limited bind-failure backoff + event trail
 (the analog of cache.go:536-552 goroutine binds and 627-649 errTasks)."""
 
+import threading
 import time
+
+import pytest
 
 from volcano_tpu.cache.interface import BindFailure
 from volcano_tpu.scheduler import Scheduler
@@ -431,3 +434,247 @@ def test_materialize_bind_entry_removes_by_identity():
     assert not any(e is e1 for e in store._pending_record_walks)
     assert e1[3] is True
     store.close()
+
+
+# ------------------------------------- who holds a batch (ISSUE 28)
+#
+# The dispatcher owns a batch from dispatch() to the end of its delivery
+# and not a moment longer (cache/bindqueue.py, "Who holds a batch").
+
+
+class _Rec:
+    """Stand-in for a pod record: weakly referenceable, takes the
+    deferred walk's ``node_name``, and may log where it dies."""
+
+    __slots__ = ("node_name", "log", "binder", "__weakref__")
+
+    def __init__(self, log=None, binder=None):
+        self.node_name = None
+        self.log = log
+        self.binder = binder
+
+    def __del__(self):
+        if self.log is not None:
+            self.log.append((threading.current_thread().name,
+                             len(self.binder.calls)))
+
+
+class _RecordingBinder:
+    """Keeps what ``bind_keys`` was handed (the dispatcher's copies),
+    fails the second half of a batch when asked to, and notes how many
+    finalisers had run on the bind thread when each call came in."""
+
+    def __init__(self, fail_half=False, log=None):
+        self.calls = []
+        self.fail_half = fail_half
+        self.log = log
+        self.finalised_on_bind_thread_before = []
+
+    def bind_keys(self, keys, hosts):
+        if self.log is not None:
+            self.finalised_on_bind_thread_before.append(
+                sum(1 for t, _ in self.log if t == "vc-bind-dispatch"))
+        self.calls.append((keys, hosts))
+        if self.fail_half:
+            raise BindFailure(list(keys[len(keys) // 2:]))
+
+
+def _batch(n, tag, **rec_kw):
+    keys = [f"default/{tag}-{i}" for i in range(n)]
+    hosts = [f"n{i % 4}" for i in range(n)]
+    pods = [_Rec(**rec_kw) for _ in range(n)]
+    return keys, hosts, pods
+
+
+def _dispatcher(binder, store=None, tracer=None):
+    from volcano_tpu.cache.bindqueue import BindDispatcher
+
+    failures, successes = [], []
+    d = BindDispatcher(
+        binder, failures.extend,
+        on_success=lambda k, h: successes.append((k, h)),
+        materialize=(store._materialize_bind_entry
+                     if store is not None else None),
+        tracer=tracer)
+    return d, failures, successes
+
+
+@pytest.mark.parametrize("kind", ["plain", "deferred", "failed-keys"])
+def test_flush_means_the_dispatcher_holds_nothing_of_the_batch(kind):
+    """After dispatch() + flush(), with NO second batch dispatched, the
+    only holders of the batch are the test's own names: the lists'
+    reference counts are what they were before dispatch(), and a pod of
+    the batch dies the moment the test lets go of it."""
+    import gc
+    import sys
+    import weakref
+
+    import numpy as np
+
+    from volcano_tpu.cache import ClusterStore
+
+    n = 64
+    keys, hosts, pods = _batch(n, kind)
+    store = entry = None
+    binder = _RecordingBinder(fail_half=(kind == "failed-keys"))
+    if kind == "deferred":
+        store = ClusterStore()
+        entry = store.defer_bind_records(
+            np.array(keys, dtype=object), np.array(hosts, dtype=object),
+            np.array(pods, dtype=object))
+    d, failures, successes = _dispatcher(binder, store)
+    held = [entry] if entry is not None else [keys, hosts, pods]
+    before = [sys.getrefcount(x) for x in held]
+    if entry is not None:
+        before[0] -= 1      # the store's pending list lets go on delivery
+    gc.disable()    # reference counts alone must do it, not a collection
+    try:
+        if entry is not None:
+            d.dispatch(None, None, None, entry=entry)
+        else:
+            d.dispatch(keys, hosts, pods)
+        assert d.flush(timeout=30)
+        # Delivered as before: the binder and the hooks got copies.
+        assert binder.calls == [(keys, hosts)]
+        assert binder.calls[0][0] is not keys
+        half = n // 2 if kind == "failed-keys" else n
+        assert successes == [(keys[:half], hosts[:half])]
+        assert failures == list(zip(keys[half:], pods[half:]))
+        if kind == "deferred":
+            assert [p.node_name for p in pods] == hosts
+            assert store._pending_record_walks == []
+            # The materialized lists live in the entry; nobody else
+            # holds them.
+            assert [sys.getrefcount(entry[i]) for i in range(3)] == [2] * 3
+        assert [sys.getrefcount(x) for x in held] == before
+        first, last = weakref.ref(pods[0]), weakref.ref(pods[-1])
+        # The caller drops its own references, as complete() does when
+        # it deletes the pods from the store and the mirror.
+        del pods, held, entry
+        failures.clear()    # the failure hand-back holds (key, pod)
+        assert first() is None and last() is None
+    finally:
+        gc.enable()
+        d.stop()
+        if store is not None:
+            store.close()
+
+
+def test_a_delivered_batch_is_never_freed_on_the_bind_thread():
+    """The order backlog_to_bind_ms depends on, free of clocks: the pods
+    of batch A are freed where they are deleted (here, as in complete(),
+    on the caller's thread), not by the bind worker when it takes batch
+    B, in front of B's bind."""
+    import gc
+
+    log = []
+    binder = _RecordingBinder(log=log)
+    d, _failures, _successes = _dispatcher(binder)
+    gc.disable()
+    try:
+        keys, hosts, pods = _batch(256, "a", log=log, binder=binder)
+        d.dispatch(keys, hosts, pods)
+        assert d.flush(timeout=30)
+        assert log == []
+        del keys, hosts, pods       # complete(): the store lets go of A
+        here = threading.current_thread().name
+        assert log == [(here, 1)] * 256
+        d.dispatch(*_batch(8, "b"))
+        assert d.flush(timeout=30)
+    finally:
+        gc.enable()
+        d.stop()
+    assert len(binder.calls) == 2 and len(log) == 256
+    assert not [t for t, _ in log if t == "vc-bind-dispatch"]
+    # B's bind_keys was not preceded by a single finaliser on its thread.
+    assert binder.finalised_on_bind_thread_before == [0, 0]
+
+
+@pytest.mark.parametrize("kind", ["plain", "deferred", "failed-keys"])
+def test_bind_release_is_the_last_event_of_a_batch(kind):
+    """One ``bind:release`` a batch on the ``bind`` track with
+    ``args["pods"]``, after ``bind:on_success``; the events of a batch
+    are otherwise what they were."""
+    import numpy as np
+
+    from volcano_tpu.cache import ClusterStore
+    from volcano_tpu.obs.trace import Tracer
+
+    tracer = Tracer(enabled=True)
+    store = ClusterStore() if kind == "deferred" else None
+    binder = _RecordingBinder(fail_half=(kind == "failed-keys"))
+    d, _failures, _successes = _dispatcher(binder, store, tracer)
+    try:
+        for n in (8, 24):
+            keys, hosts, pods = _batch(n, kind)
+            if store is not None:
+                entry = store.defer_bind_records(
+                    np.array(keys, dtype=object),
+                    np.array(hosts, dtype=object),
+                    np.array(pods, dtype=object))
+                d.dispatch(None, None, None, entry=entry)
+            else:
+                d.dispatch(keys, hosts, pods)
+            assert d.flush(timeout=30)
+    finally:
+        d.stop()
+        if store is not None:
+            store.close()
+    events = tracer.drain()
+    names = ["bind:queue_wait", "bind:binder", "bind:on_success",
+             "bind:release"]
+    if kind == "deferred":
+        names.insert(1, "bind:materialize")
+    assert [e.name for e in events] == names * 2
+    for batch, n in ((events[:len(names)], 8), (events[len(names):], 24)):
+        for e in batch:
+            assert e.tid == "bind" and e.cat == "bind"
+            assert e.args == {"pods": n}
+            assert e.parent_id == 0 and e.dur_ns >= 0
+        ok, release = batch[-2], batch[-1]
+        assert release.ts_ns >= ok.ts_ns + ok.dur_ns
+    # flush() returned after the release, so it was all there to drain.
+    assert tracer.drain() == []
+
+
+def test_a_bound_pod_dies_when_the_store_deletes_it():
+    """The whole path, a store and its scheduler: once a batch is bound
+    and flushed, nothing of the program keeps its pod records alive
+    beyond ``delete_pod`` — not the dispatcher, not the commit path's
+    object-array cache on the store — so they are freed one by one
+    where they are deleted and never in one cascade inside the next
+    cycle.  Round two binds without deferral (round one's deletions
+    left tombstoned rows), as every counted round of the benchmark."""
+    import gc
+    import weakref
+
+    from volcano_tpu.api import GROUP_NAME_ANNOTATION, Pod, PodGroup
+
+    store = synthetic_cluster(n_nodes=8, n_pods=32, gang_size=4, seed=5)
+    store.async_bind = True
+    sched = Scheduler(store)
+    try:
+        for rnd in range(2):
+            sched.run_once()
+            assert store.flush_binds(timeout=30)
+            pods = list(store.pods.values())
+            assert len(pods) == 32 and all(p.node_name for p in pods)
+            refs = [weakref.ref(p) for p in pods]
+            gc.collect()
+            gc.disable()
+            try:
+                while pods:
+                    store.delete_pod(pods.pop())
+                assert [r() for r in refs] == [None] * 32
+            finally:
+                gc.enable()
+            for g in range(8):
+                store.add_pod_group(PodGroup(name=f"r{rnd}-{g}",
+                                             min_member=4))
+                for i in range(4):
+                    store.add_pod(Pod(
+                        name=f"r{rnd}-{g}-{i}",
+                        annotations={GROUP_NAME_ANNOTATION: f"r{rnd}-{g}"},
+                        containers=[{"cpu": "1", "memory": "1Gi"}]))
+    finally:
+        store.close()

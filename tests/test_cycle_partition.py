@@ -331,7 +331,7 @@ def test_derive_and_order_children_on_a_four_queue_cycle():
 # ---------------------------------------------------- bind thread and store
 
 
-def test_a_bind_batch_leaves_four_events_on_the_bind_track():
+def test_a_bind_batch_leaves_five_events_on_the_bind_track():
     store = _store(seed=31)
     _cycle(store)           # the batch's events drain with the next record
     rec, _ = _cycle(store)
@@ -339,13 +339,13 @@ def test_a_bind_batch_leaves_four_events_on_the_bind_track():
     bind = [s for s in spans if s.tid == "bind"]
     assert sorted(s.name for s in bind) == [
         "bind:binder", "bind:materialize", "bind:on_success",
-        "bind:queue_wait"]
+        "bind:queue_wait", "bind:release"]
     for s in bind:
         assert s.args == {"pods": 32} and s.parent_id == 0
         assert s.lane is None and s.dur_ns >= 0
     order = [s.name for s in sorted(bind, key=lambda s: s.ts_ns)]
     assert order == ["bind:queue_wait", "bind:materialize", "bind:binder",
-                     "bind:on_success"]
+                     "bind:on_success", "bind:release"]
     # Batches, not pods: nothing is recorded per pod anywhere.
     assert len(spans) < 3 * 60
 

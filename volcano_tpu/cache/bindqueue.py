@@ -17,9 +17,26 @@ fast path:
   the analog of the task sitting in the rate-limited errTasks queue.
 - The worker speaks to the store's tracer (obs/trace.py), per batch and
   never per pod: ``bind:queue_wait`` (dispatch -> the worker picks the
-  batch up), ``bind:materialize``, ``bind:binder`` (the binder calls)
-  and ``bind:on_success``, as thread-safe events on the ``bind`` track
-  with ``args={"pods": n}``.  They drain with the next cycle's record.
+  batch up), ``bind:materialize``, ``bind:binder`` (the binder calls),
+  ``bind:on_success`` and ``bind:release`` (the worker letting go of
+  the batch), as thread-safe events on the ``bind`` track with
+  ``args={"pods": n}``.  They drain with the next cycle's record.
+
+Who holds a batch.  The dispatcher owns a batch from ``dispatch()`` to
+the end of its delivery and not a moment longer: the queue holds it
+until the worker pops it, ``_deliver``'s frame holds it (and whatever
+the delivery makes: the materialized lists of a deferred entry, the
+copies handed to the binder and to ``on_success``, the failure map)
+until it returns, and nothing else of the dispatcher ever does — not
+the worker's loop, which outlives every batch and would otherwise keep
+the last one alive across its wait.  By the next cycle the round's
+completions have deleted those pods from the store, so a worker still
+holding them would be their last holder, and taking the next batch
+would free 100k pod records in one cascade that keeps the interpreter,
+in front of that batch's bind.  The release comes before ``_inflight``
+drops, so ``flush()`` returning means the dispatcher holds no reference
+to anything of the batches it was given; callers, the binder and the
+two hooks keep what they choose to keep.
 """
 
 from __future__ import annotations
@@ -75,7 +92,13 @@ class BindDispatcher:
         """Deferred batches pass ``entry`` (from the store's
         ``defer_bind_records``); the worker materializes lists and
         applies the pod.node_name record walk off the scheduling
-        cycle's critical path."""
+        cycle's critical path.
+
+        The dispatcher takes a reference to each argument, not a copy,
+        and holds it until the batch is delivered (module docstring,
+        "Who holds a batch"): the caller may drop its own at once, must
+        not mutate the lists before ``flush()``, and gets nothing kept
+        alive for it afterwards."""
         with self._cv:
             self._q.append((keys, hosts, pods, entry,
                             time.perf_counter_ns()))
@@ -83,7 +106,8 @@ class BindDispatcher:
             self._cv.notify()
 
     def flush(self, timeout: Optional[float] = None) -> bool:
-        """Block until every dispatched batch has been processed."""
+        """Block until every dispatched batch has been delivered and
+        let go of: on True the dispatcher references nothing of them."""
         deadline = None if timeout is None else time.time() + timeout
         with self._cv:
             while self._inflight > 0:
@@ -104,91 +128,115 @@ class BindDispatcher:
     # ------------------------------------------------------------- worker
 
     def _run(self) -> None:
-        from .interface import BindFailure
-
+        # This frame lives as long as the thread, so no local of it is
+        # ever a batch or a part of one (module docstring, "Who holds a
+        # batch"): the batch is popped, delivered and let go of inside
+        # _deliver's frame.
+        now = time.perf_counter_ns
         while True:
             with self._cv:
                 while not self._q and not self._stopped:
                     self._cv.wait()
                 if self._stopped and not self._q:
                     return
-                keys, hosts, pods, entry, t_queued = self._q.pop(0)
-            now = time.perf_counter_ns
-            event = self._tracer.event
-            t0 = now()
-            args = {"pods": len(entry[0] if entry is not None else keys)}
-            event("bind:queue_wait", "bind", t_queued, t0 - t_queued,
-                  tid="bind", args=args)
-            if entry is not None:
-                # Deferred record walk: tolist + setattr over the whole
-                # batch runs here, off the scheduling cycle (idempotent
-                # — a failure path may already have forced it through
-                # the store's apply_pending_bind_records).
-                keys, hosts, pods = self._materialize(entry)
-                event("bind:materialize", "bind", t0, now() - t0,
-                      tid="bind", args=args)
-            t0 = now()
-            failed: List[str] = []
-            bind_keys = getattr(self._binder, "bind_keys", None)
-            batch_ok = False
-            if bind_keys is not None:
-                try:
-                    bind_keys(list(keys), list(hosts))
-                    batch_ok = True
-                except BindFailure as bf:
-                    failed = list(bf.failed)
-                    batch_ok = True
-                except Exception:
-                    # Indeterminate: some binds may have taken effect.
-                    # Failing the whole batch would re-queue pods that
-                    # are already bound and later re-bind them — possibly
-                    # to a different node — with no unbind of the first
-                    # placement.  Re-drive per key instead: Bind is
-                    # idempotent (key -> node assignment), so repeating a
-                    # key that already landed is a no-op, and each key
-                    # gets a definite outcome.
-                    log.exception(
-                        "bind batch indeterminate; retrying per key"
-                    )
-            if not batch_ok:
-                for pod, host, key in zip(pods, hosts, keys):
-                    try:
-                        self._binder.bind(pod, host)
-                    except BindFailure:
-                        failed.append(key)
-                    except Exception:
-                        log.exception("bind failed for %s", key)
-                        failed.append(key)
-            event("bind:binder", "bind", t0, now() - t0, tid="bind",
-                  args=args)
-            if failed:
-                try:
-                    # Hand the pod objects back with the keys so the
-                    # store's drain never re-derives key->pod over the
-                    # whole pod table.
-                    by_key = {k: p for k, p in zip(keys, pods)}
-                    self._on_failure(
-                        [(k, by_key.get(k)) for k in failed]
-                    )
-                except Exception:
-                    log.exception("bind-failure handler failed")
-            if self._on_success is not None:
-                ok_pairs = None
-                if failed:
-                    fset = set(failed)
-                    ok_pairs = (
-                        [k for k in keys if k not in fset],
-                        [h for k, h in zip(keys, hosts) if k not in fset],
-                    )
-                else:
-                    ok_pairs = (list(keys), list(hosts))
-                t0 = now()
-                try:
-                    self._on_success(*ok_pairs)
-                except Exception:
-                    log.exception("bind-success handler failed")
-                event("bind:on_success", "bind", t0, now() - t0,
-                      tid="bind", args=args)
+            n_pods, t_done = self._deliver()
+            # _deliver's frame went between t_done and here.  While the
+            # store still holds the pods that frees three list shells
+            # and the delivery's copies; should the worker ever be a
+            # batch's last holder again, the cost reads here and not in
+            # the next batch's bind:queue_wait.
+            self._tracer.event("bind:release", "bind", t_done,
+                               now() - t_done, tid="bind",
+                               args={"pods": n_pods})
             with self._cv:
                 self._inflight -= 1
                 self._cv.notify_all()
+
+    def _deliver(self) -> Tuple[int, int]:
+        """Pop the head batch and deliver it: the binder calls, the
+        failure hand-back, ``on_success``, the batch's events.  Every
+        reference the dispatcher has to the batch, and to what the
+        delivery makes of it, is a local of this frame: returning is the
+        release.  Returns (pods in the batch, ``perf_counter_ns`` at the
+        end of the delivery)."""
+        from .interface import BindFailure
+
+        with self._cv:
+            keys, hosts, pods, entry, t_queued = self._q.pop(0)
+        now = time.perf_counter_ns
+        event = self._tracer.event
+        t0 = now()
+        args = {"pods": len(entry[0] if entry is not None else keys)}
+        event("bind:queue_wait", "bind", t_queued, t0 - t_queued,
+              tid="bind", args=args)
+        if entry is not None:
+            # Deferred record walk: tolist + setattr over the whole
+            # batch runs here, off the scheduling cycle (idempotent
+            # — a failure path may already have forced it through
+            # the store's apply_pending_bind_records).
+            keys, hosts, pods = self._materialize(entry)
+            event("bind:materialize", "bind", t0, now() - t0,
+                  tid="bind", args=args)
+        t0 = now()
+        failed: List[str] = []
+        bind_keys = getattr(self._binder, "bind_keys", None)
+        batch_ok = False
+        if bind_keys is not None:
+            try:
+                bind_keys(list(keys), list(hosts))
+                batch_ok = True
+            except BindFailure as bf:
+                failed = list(bf.failed)
+                batch_ok = True
+            except Exception:
+                # Indeterminate: some binds may have taken effect.
+                # Failing the whole batch would re-queue pods that
+                # are already bound and later re-bind them — possibly
+                # to a different node — with no unbind of the first
+                # placement.  Re-drive per key instead: Bind is
+                # idempotent (key -> node assignment), so repeating a
+                # key that already landed is a no-op, and each key
+                # gets a definite outcome.
+                log.exception(
+                    "bind batch indeterminate; retrying per key"
+                )
+        if not batch_ok:
+            for pod, host, key in zip(pods, hosts, keys):
+                try:
+                    self._binder.bind(pod, host)
+                except BindFailure:
+                    failed.append(key)
+                except Exception:
+                    log.exception("bind failed for %s", key)
+                    failed.append(key)
+        event("bind:binder", "bind", t0, now() - t0, tid="bind",
+              args=args)
+        if failed:
+            try:
+                # Hand the pod objects back with the keys so the
+                # store's drain never re-derives key->pod over the
+                # whole pod table.
+                by_key = {k: p for k, p in zip(keys, pods)}
+                self._on_failure(
+                    [(k, by_key.get(k)) for k in failed]
+                )
+            except Exception:
+                log.exception("bind-failure handler failed")
+        if self._on_success is not None:
+            ok_pairs = None
+            if failed:
+                fset = set(failed)
+                ok_pairs = (
+                    [k for k in keys if k not in fset],
+                    [h for k, h in zip(keys, hosts) if k not in fset],
+                )
+            else:
+                ok_pairs = (list(keys), list(hosts))
+            t0 = now()
+            try:
+                self._on_success(*ok_pairs)
+            except Exception:
+                log.exception("bind-success handler failed")
+            event("bind:on_success", "bind", t0, now() - t0,
+                  tid="bind", args=args)
+        return args["pods"], now()

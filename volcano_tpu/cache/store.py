@@ -726,6 +726,14 @@ class ClusterStore:
                 )
             gen0 = self.mirror.compact_gen
             self.mirror.remove_pod(pod.uid)
+            cached = self._objarr_cache
+            if cached is not None and cached[0][1] != self.mirror.pod_obj_gen:
+                # The removal moved pod_obj_gen, so the commit path's
+                # object arrays can never be served again; kept, they
+                # would be the last holder of every pod deleted before
+                # the next cycle, whose rebuild (fastpath._obj_arrays,
+                # under the device solve) would free them all at once.
+                self._objarr_cache = None
             self.mirror.maybe_compact()
             if self.mirror.compact_gen != gen0 and self._mesh_plane_cache:
                 # Compaction renumbers rows and voids in-flight device
