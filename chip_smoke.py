@@ -18,7 +18,7 @@ Phases (each prints one JSON line naming the device and the versions):
   service    a 3-replica gang Job through Service(simulate=True):
              admission -> controller -> scheduler thread -> bind -> Running
   north_sync one cold cycle (set-up seconds) then fresh-store cycles under
-             bench.CONF_BASE; each binds every pod
+             CONF_BASE; each binds every pod
   north_pipe one store, store.pipeline=True, re-pend feed: donated devsnap
              buffer under an in-flight solve, warm shortlists, a node
              relabel between cycles, no compile after warm-up
@@ -38,6 +38,23 @@ import os
 import sys
 import time
 from dataclasses import dataclass
+
+
+# The north star's scheduler conf (the benchmark's config files carry
+# the same text).
+CONF_BASE = """
+actions: "enqueue, allocate, backfill"
+tiers:
+- plugins:
+  - name: priority
+  - name: gang
+- plugins:
+  - name: drf
+  - name: predicates
+  - name: proportion
+  - name: nodeorder
+  - name: binpack
+"""
 
 
 @dataclass(frozen=True)
@@ -190,8 +207,8 @@ def phase_parity(env) -> None:
 
 
 def phase_service(env) -> None:
-    """bench.config_1's path: a 3-replica gang Job submitted through
-    admission reaches Running, scheduled by the service's own loop."""
+    """BASELINE.json configs[0]'s path: a 3-replica gang Job submitted
+    through admission reaches Running, scheduled by the service's own loop."""
     from volcano_tpu.api import Node
     from volcano_tpu.controllers.apis import Job, TaskSpec
     from volcano_tpu.service import Service
@@ -239,7 +256,7 @@ def _north_store(shape: Shape, seed: int, mesh):
     store = synthetic_cluster(
         n_nodes=shape.n_nodes, n_pods=shape.n_pods,
         gang_size=shape.gang_size, zones=shape.zones, seed=seed)
-    # Async bind dispatch, as in production and bench._cycle_bench.
+    # Async bind dispatch, as in production.
     store.async_bind = True
     if mesh is not None:
         store.solve_mesh = mesh
@@ -317,10 +334,9 @@ def _peak_bytes():
 
 
 def phase_north_sync(env, shape: Shape, seed: int, mesh=None) -> dict:
-    """Scheduler(store, conf_str=bench.CONF_BASE).run_once() on fresh
+    """Scheduler(store, conf_str=CONF_BASE).run_once() on fresh
     stores: one cold cycle, then ``shape.sync_cycles`` more.  Returns
     {seed: binds} for the mesh-vs-one-chip comparison."""
-    import bench
     from volcano_tpu.scheduler import Scheduler
 
     crashes0 = _crash_recoveries()
@@ -330,7 +346,7 @@ def phase_north_sync(env, shape: Shape, seed: int, mesh=None) -> dict:
     for i in range(1 + shape.sync_cycles):
         store = _north_store(shape, seed + i, mesh)
         t0 = time.perf_counter()
-        Scheduler(store, conf_str=bench.CONF_BASE).run_once()
+        Scheduler(store, conf_str=CONF_BASE).run_once()
         times.append(time.perf_counter() - t0)
         all_binds[seed + i] = _check_placement(store, shape)
         rec = store.flight.recent()[-1]
@@ -356,8 +372,8 @@ def phase_north_sync(env, shape: Shape, seed: int, mesh=None) -> dict:
 
 def phase_north_pipelined(env, shape: Shape, seed: int, compiles: _Compiles,
                           mesh=None) -> dict:
-    """One store, pipelined, with the re-pend feed of
-    bench._pipelined_bench: every cycle commits the previous cycle's
+    """One store, pipelined, with a feed that re-pends every bound
+    pod: every cycle commits the previous cycle's
     in-flight solve and dispatches the next.
 
     A few nodes' pods are held back from the re-pend for one cycle and
@@ -372,7 +388,6 @@ def phase_north_pipelined(env, shape: Shape, seed: int, compiles: _Compiles,
     """
     import numpy as np
 
-    import bench
     from volcano_tpu.api import Node, TaskStatus
     from volcano_tpu.scheduler import Scheduler
 
@@ -393,7 +408,7 @@ def phase_north_pipelined(env, shape: Shape, seed: int, compiles: _Compiles,
             fc._unbind_rows(rows)
 
     store.cycle_feed = feed
-    sched = Scheduler(store, conf_str=bench.CONF_BASE)
+    sched = Scheduler(store, conf_str=CONF_BASE)
     dv_modes = []
 
     def cycle():

@@ -17,8 +17,7 @@ mid-solve.  Knobs:
                               cycle completes; the historic worker crash
                               reproduces on the SECOND full-scale cycle
                               of the same process (cumulative device
-                              state), which is exactly what bench.py's
-                              warm+repeat loop does.
+                              state).
   REPRO_NODES / REPRO_PODS    override the 50000 x 500000 shape
 
 Artifact: hack/hyperscale_affinity_repro.jsonl (one line per chunk +

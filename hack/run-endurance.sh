@@ -23,8 +23,8 @@ export BENCH_ENDURANCE_CYCLES VOLCANO_TPU_AUDIT_SAMPLE
 # pool=1 shards=1 an exported BENCH_ENDURANCE_POOL>=2 or
 # BENCH_ENDURANCE_SHARDS>=2 would silently turn this into a second
 # pool/shard run and leave the single-connection path ungated.
-BENCH_ENDURANCE=1 BENCH_ENDURANCE_POOL=1 BENCH_ENDURANCE_SHARDS=1 \
-  python bench.py "$@" | tee /tmp/_vtpu_endurance_single.json
+BENCH_ENDURANCE_POOL=1 BENCH_ENDURANCE_SHARDS=1 \
+  python hack/endurance.py "$@" | tee /tmp/_vtpu_endurance_single.json
 echo "endurance gate OK (0 anomalies)"
 
 # Journey leg (ISSUE 18): the tail's journey block must prove the
@@ -65,9 +65,9 @@ PYEOF
 : "${BENCH_ENDURANCE_POOL:=2}"
 export BENCH_ENDURANCE_POOL
 if [ "${BENCH_ENDURANCE_POOL}" -gt 1 ]; then
-  BENCH_ENDURANCE=1 BENCH_ENDURANCE_SHARDS=1 \
+  BENCH_ENDURANCE_SHARDS=1 \
     BENCH_ENDURANCE_CYCLES=$(( BENCH_ENDURANCE_CYCLES / 2 > 150 \
-      ? BENCH_ENDURANCE_CYCLES / 2 : 150 )) python bench.py "$@"
+      ? BENCH_ENDURANCE_CYCLES / 2 : 150 )) python hack/endurance.py "$@"
   echo "endurance pool leg OK (0 anomalies, pool=${BENCH_ENDURANCE_POOL})"
 fi
 
@@ -83,9 +83,8 @@ export BENCH_ENDURANCE_SHARDS
 shard_secs=""
 if [ "${BENCH_ENDURANCE_SHARDS}" -gt 1 ]; then
   t0=$SECONDS
-  BENCH_ENDURANCE=1 \
-    BENCH_ENDURANCE_CYCLES=$(( BENCH_ENDURANCE_CYCLES / 2 > 150 \
-      ? BENCH_ENDURANCE_CYCLES / 2 : 150 )) python bench.py "$@"
+  BENCH_ENDURANCE_CYCLES=$(( BENCH_ENDURANCE_CYCLES / 2 > 150 \
+      ? BENCH_ENDURANCE_CYCLES / 2 : 150 )) python hack/endurance.py "$@"
   shard_secs=$(( SECONDS - t0 ))
   echo "endurance shard leg OK (0 anomalies, shards=${BENCH_ENDURANCE_SHARDS})"
 fi
@@ -102,9 +101,9 @@ fi
 : "${BENCH_ENDURANCE_LOCKDEP:=1}"
 if [ "${BENCH_ENDURANCE_LOCKDEP}" != "0" ]; then
   t0=$SECONDS
-  BENCH_ENDURANCE=1 VOLCANO_TPU_LOCKDEP=1 \
+  VOLCANO_TPU_LOCKDEP=1 \
     BENCH_ENDURANCE_CYCLES=$(( BENCH_ENDURANCE_CYCLES / 2 > 150 \
-      ? BENCH_ENDURANCE_CYCLES / 2 : 150 )) python bench.py "$@"
+      ? BENCH_ENDURANCE_CYCLES / 2 : 150 )) python hack/endurance.py "$@"
   lockdep_secs=$(( SECONDS - t0 ))
   if [ -n "${shard_secs}" ] && [ "${shard_secs}" -gt 0 ]; then
     echo "endurance lockdep leg OK (0 anomalies," \

@@ -107,7 +107,7 @@ def test_importing_the_package_takes_no_device():
     env = dict(os.environ, JAX_PLATFORMS="no_such_platform")
     proc = subprocess.run(
         [sys.executable, "-c",
-         "import bench, chip_smoke, volcano_tpu.service, "
+         "import chip_smoke, volcano_tpu.service, "
          "volcano_tpu.solver_service, volcano_tpu.fastpath, "
          "volcano_tpu.whatif, volcano_tpu.parallel"],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)

@@ -295,14 +295,25 @@ def test_invalidation_dirty_cap_overflow_falls_back(monkeypatch):
 # --------------------------------------------------- null-delta cycles
 
 
-def test_null_delta_skips_and_resumes(monkeypatch):
+@pytest.mark.parametrize("mode", ["on", "mesh", "off"])
+def test_null_delta_skips_and_resumes(monkeypatch, mode):
     """An idle pipelined loop records skip-cycles in the flight
     recorder, dispatches zero solves, and resumes an ordinary solve on
-    the first mutation."""
-    monkeypatch.setenv("VOLCANO_TPU_DEVINCR", "1")
+    the first mutation — under a mesh too.  With DEVINCR=0 the same
+    idle cycles each dispatch their solve."""
+    devincr = mode != "off"
+    monkeypatch.setenv("VOLCANO_TPU_DEVINCR", "1" if devincr else "0")
     _reset_uid_counters()
     store = synthetic_cluster(n_nodes=8, n_pods=24, gang_size=4, seed=5)
     store.pipeline = True
+    if mode == "mesh":
+        import jax
+
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 virtual devices")
+        from volcano_tpu.parallel import make_mesh
+
+        store.solve_mesh = make_mesh(4)
     sched = Scheduler(store)
     for _ in range(2):
         sched.run_once()
@@ -315,8 +326,17 @@ def test_null_delta_skips_and_resumes(monkeypatch):
     ))
     sched.run_once()   # dispatches the (failing) solve
     sched.run_once()   # commits the empty result
-    dv = store._devincr_cache
     seq0 = store._solve_seq
+    if not devincr:
+        for _ in range(3):
+            sched.run_once()
+        assert store._solve_seq == seq0 + 3, "an idle cycle skipped"
+        assert not any("null-delta" in e
+                       for r in store.flight.recent()[-3:]
+                       for e in r.device_events)
+        store.close()
+        return
+    dv = store._devincr_cache
     skips0 = dv.counts["skip"]
     for _ in range(3):
         sched.run_once()

@@ -288,14 +288,15 @@ def test_fastpath_volume_gate_and_revert():
 
 
 def test_cycle_lane_breakdown_published():
-    """Each fast cycle publishes its per-lane wall-clock split
-    (store.last_cycle_lanes) — the bench/operator visibility surface."""
+    """Each fast cycle publishes its per-lane wall-clock split on its
+    flight record (CycleRecord.lanes) — what the benchmark's lane
+    reader and /debug/cycles show."""
     from volcano_tpu.scheduler import Scheduler
     from volcano_tpu.synth import synthetic_cluster
 
     store = synthetic_cluster(n_nodes=8, n_pods=24, gang_size=2)
     Scheduler(store).run_once()
-    lanes = store.last_cycle_lanes
+    lanes = store.flight.recent()[-1].lanes
     for key in ("derive", "order", "encode", "device", "commit",
                 "close", "enqueue"):
         assert key in lanes and lanes[key] >= 0.0, (key, lanes)

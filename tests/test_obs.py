@@ -10,7 +10,7 @@ Pins the acceptance contracts:
   churn) produce per-reason drop counters that sum exactly to the
   dropped rows, with ``/debug/cycles`` returning the matching record;
 - the ring buffer is bounded; lane breakdowns survive tracing being
-  disabled (bench compatibility).
+  disabled.
 
 All CPU-only (conftest pins JAX_PLATFORMS=cpu); tier-1.
 """
@@ -341,16 +341,15 @@ def test_flight_recorder_ring_is_bounded():
 
 def test_lanes_survive_tracing_disabled(monkeypatch):
     """VOLCANO_TPU_TRACE=0 drops span records but keeps the lane
-    breakdown (bench.py compatibility)."""
+    breakdown on the flight record."""
     monkeypatch.setenv("VOLCANO_TPU_TRACE", "0")
     store = _small(seed=13)
     Scheduler(store).run_once()
     store.flush_binds()
-    assert store.last_cycle_lanes
-    assert "derive" in store.last_cycle_lanes
     rec = store.flight.recent()[-1]
     assert rec.spans == []
     assert rec.lanes
+    assert "derive" in rec.lanes
 
 
 def test_object_session_cycles_are_recorded(monkeypatch):

@@ -259,25 +259,30 @@ def test_wire_delta_churn_parity_two_process(solver_proc, monkeypatch):
 
 def test_wire_kill_switch_full_frames(solver_proc, monkeypatch):
     """VOLCANO_TPU_WIRE=0: classic v1 frames only (no delta machinery),
-    same binds."""
+    same binds; a delta frame is the smaller one."""
     monkeypatch.setenv("VOLCANO_TPU_WIRE", "0")
+    off = RemoteSolver(f"127.0.0.1:{solver_proc}")
     binds_off, states_off, kinds, counts, fallbacks = _wire_loop(
-        solver_proc, cycles=6)
+        solver_proc, cycles=6, client=off)
     assert counts["delta"] == 0 and counts["full"] >= 6
     assert set(kinds) == {"full"}
     assert fallbacks == {}
     monkeypatch.setenv("VOLCANO_TPU_WIRE", "1")
+    on = RemoteSolver(f"127.0.0.1:{solver_proc}")
     binds_on, states_on, _k, counts_on, _fb = _wire_loop(
-        solver_proc, cycles=6)
+        solver_proc, cycles=6, client=on)
     assert counts_on["delta"] >= 1
     assert binds_on and binds_on == binds_off
     assert states_on == states_off
+    per_full = off.frame_bytes["full"] / counts["full"]
+    per_delta = on.frame_bytes["delta"] / counts_on["delta"]
+    assert per_delta < per_full / 2, (per_delta, per_full)
 
 
 def test_wire_forced_fallback_lever(solver_proc, monkeypatch):
     """VOLCANO_TPU_WIRE=fallback: the v2 machinery runs but every frame
-    ships full through the fallback path, counted reason=forced — the
-    bench A/B lever — with identical binds."""
+    ships full through the fallback path, counted reason=forced, with
+    identical binds."""
     monkeypatch.setenv("VOLCANO_TPU_WIRE", "fallback")
     binds_fb, states_fb, kinds, counts, fallbacks = _wire_loop(
         solver_proc, cycles=6)

@@ -217,10 +217,19 @@ def test_fallback_cap_limits_rescores(monkeypatch):
 
 def test_fallback_counter_exported_via_scheduler(monkeypatch):
     """Driving the full fast path: the per-reason counter series and the
-    per-store accumulator pick up the kernel's fallback counts."""
+    cycle's stats pick up the kernel's fallback counts."""
+    from volcano_tpu.fastpath import FastCycle
     from volcano_tpu.scheduler import Scheduler
 
     _pin(monkeypatch, 4, twophase=True)
+    cycles = {}
+    count = FastCycle._count_shortlist_fb
+
+    def spy(self, exhausted, affinity):
+        count(self, exhausted, affinity)
+        cycles[id(self)] = self
+
+    monkeypatch.setattr(FastCycle, "_count_shortlist_fb", spy)
 
     def series_total():
         data = metrics.solve_shortlist_fallback.data
@@ -232,9 +241,9 @@ def test_fallback_counter_exported_via_scheduler(monkeypatch):
     store.flush_binds()
     assert all(p.node_name for p in store.pods.values())
     delta = series_total() - before
-    acc = getattr(store, "_shortlist_fb", {})
     assert delta > 0
-    assert sum(acc.values()) == delta
+    assert sum(c.stats["shortlist_fallbacks"]
+               for c in cycles.values()) == delta
 
 
 # --------------------------------------------- devsnap class planes

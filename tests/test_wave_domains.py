@@ -138,8 +138,7 @@ def test_conflict_compaction_overflow_parity(monkeypatch):
 
     # Env guard: the overflow precondition (300 givers in ONE wave,
     # > GCAP = min(256, W)) requires the default wave size; a smaller
-    # VOLCANO_TPU_WAVE would make this test silently cover only the
-    # compact branch.
+    # one would make this test silently cover only the compact branch.
     assert wave_mod.DEFAULT_WAVE >= 300, wave_mod.DEFAULT_WAVE
 
     def build():

@@ -227,7 +227,7 @@ class StoreMirror:
         self.p_pod: List[Optional[Pod]] = []
         # Count of None entries in p_pod (tombstoned rows): lets the
         # commit path skip its defensive 100k-element None scan when no
-        # pod has ever been removed (the common bench/steady case).
+        # pod has ever been removed (the common steady case).
         self.p_pod_nones = 0
         self.p_feat: List[Optional[_PodFeat]] = []
         self.p_row: Dict[str, int] = {}

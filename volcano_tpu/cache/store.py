@@ -122,7 +122,7 @@ class ClusterStore:
         # Async bind dispatch + rate-limited bind-failure resync
         # (cache.go:536-552 goroutine binds; 627-649 errTasks).  Sync by
         # default so tests observe binds immediately after a cycle;
-        # production service/bench enable async.
+        # the production service enables async.
         self.async_bind = False
         self._bind_dispatcher = None
         self._bind_fail_lock = threading.Lock()
@@ -229,7 +229,7 @@ class ClusterStore:
         # Remote-solver client: a solver_service.RemoteSolver (single
         # connection) or a solver_pool.SolverPool (N replicas with
         # hedged dispatch / failover / what-if offload, ISSUE 15) —
-        # attached by Service/bench/tests, None for local-solve stores.
+        # attached by Service and tests, None for local-solve stores.
         # Dispatch and fetch run only on the cycle thread; both client
         # types synchronize their own internals (each holds its own
         # lock, never the store's), so the slot needs no store-lock
@@ -456,7 +456,7 @@ class ClusterStore:
     def close(self) -> None:
         """Stop background machinery (the bind dispatcher thread).  The
         dispatcher's callbacks pin this store, so long-lived processes
-        creating many stores (benchmarks) must close them."""
+        creating many stores must close them."""
         from ..pipeline import abandon_inflight, abandon_inflight_plan
 
         # A parked pipelined solve holds device buffers (or a remote

@@ -6,9 +6,9 @@ unset and JAX has not recognised a TPU VM (where it sets
 ``jax_platforms`` to ``tpu,cpu`` itself and fails loudly), the process
 carries on on the host CPU with an INFO log line.  For a scheduler whose
 hot path was built for the chip that is a silent change of system, so
-``bench.py`` and ``vtpu-solver`` call ``require_accelerator`` before
-they build anything, and the results ``bench.py`` and ``chip_smoke.py``
-print carry ``device_info()``.
+``vtpu-solver`` and ``hack/endurance.py`` call ``require_accelerator``
+before they build anything, and the results ``chip_smoke.py`` and
+``hack/endurance.py`` print carry ``device_info()``.
 
 A chip belongs to one process.  Under ``vtpu-service --remote-solver``
 the solver child owns it; the service process still runs its small

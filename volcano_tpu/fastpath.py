@@ -225,7 +225,7 @@ class FastCycle:
         self.uid = (f"ssn-{n}" if shard is None
                     else f"ssn-{n}@s{shard.index}")
         # Per-shard solver client override: each shard may own its own
-        # device lane (bench A/B, service wiring); falls back to the
+        # device lane (service wiring); falls back to the
         # store-wide client.  Resolved once per cycle — both slots are
         # cycle-thread-owned, so no lock is needed beyond ownership.
         self._remote_solver = getattr(store, "remote_solver", None)
@@ -242,7 +242,7 @@ class FastCycle:
         # Pipelined sessions (ISSUE 1): the device solve is dispatched
         # without blocking and committed at the top of the NEXT cycle,
         # hiding the device round trip behind the host lanes.  Opt in
-        # per store (bench, service flag) or globally via env.
+        # per store (service flag) or globally via env.
         flag = getattr(store, "pipeline", None)
         if flag is None:
             flag = os.environ.get("VOLCANO_TPU_PIPELINE", "0") == "1"
@@ -345,8 +345,7 @@ class FastCycle:
         self.aggr = aggr
         # One env read per cycle: VOLCANO_TPU_INCREMENTAL=0 kills the
         # whole incremental host-lane machinery — the aggregate delta
-        # refresh AND the order/encode/commit/close caches below — so
-        # the bench A/B (BENCH_HOST=1) measures the full surface.
+        # refresh AND the order/encode/commit/close caches below.
         self._incr = incremental_on()
         self.derive_mode = aggr.refresh(m, Pn, Nn, R, self.n_alive)
         # Sampled coherence audit of the refreshed planes (ISSUE 13):
@@ -774,18 +773,15 @@ class FastCycle:
             "epoch_at_dispatch": None, "epoch_at_commit": None,
             "device_events": [],
         }
-        # Clear immediately: a failed cycle (slow-path fallback) must not
-        # leave a previous cycle's breakdown masquerading as its own.
-        store.last_cycle_lanes = None
         # devsnap's running upload counters as the cycle finds them;
         # the record's ``solve`` carries this cycle's deltas.
         self._devsnap0 = _devsnap_counts(store)
         # The cycle's frame (obs/trace.py CycleScope): the scheduler's
         # when run_once() drives this cycle, else one of its own.  Its
         # lane dict is the per-lane wall-clock breakdown of the cycle
-        # (seconds) — top-level spans only, the lanes rule — also
-        # published as store.last_cycle_lanes; the spans both record
-        # AND accumulate the lanes, so disabling tracing keeps the
+        # (seconds) — top-level spans only, the lanes rule — and ends up
+        # as the flight record's ``lanes``; the spans both record AND
+        # accumulate the lanes, so disabling tracing keeps the
         # breakdown.
         with self.tracer.cycle(getattr(store, "flight", None)) as scope:
             scope.describe("cycle", {"session": self.uid})
@@ -827,7 +823,7 @@ class FastCycle:
                 # voids) right after the solve, against the freshest
                 # state this cycle will see (actions/rebalance.py).
                 self._commit_inflight_plan()
-                # Workload-injection seam (bench.py steady state, loop
+                # Workload-injection seam (hack/endurance.py's churn, loop
                 # tests): new work "arrives" after the commit and before
                 # this cycle's actions, so every pipelined cycle both
                 # commits session N-1 and dispatches session N.
@@ -914,7 +910,6 @@ class FastCycle:
                 self._evictor.st.flush()
             with tracer.span("close", lanes=self.lanes):
                 self._close()
-            store.last_cycle_lanes = dict(self.lanes)
         except BaseException:
             # Failures AFTER the action loop (evictor flush, close) must
             # also land the deferred node_name walks before the caller
@@ -1144,22 +1139,15 @@ class FastCycle:
 
     def _count_shortlist_fb(self, exhausted: int, affinity: int) -> None:
         """Fold the two-phase solve's shortlist-fallback rescore counts
-        into the per-reason counter series, the cycle stats, and a
-        per-store accumulator bench.py resets between A/B passes."""
+        into the per-reason counter series and the cycle stats."""
         if exhausted <= 0 and affinity <= 0:
             return
-        acc = getattr(self.store, "_shortlist_fb", None)
-        if acc is None:
-            acc = self.store._shortlist_fb = {}
         if exhausted > 0:
             metrics.solve_shortlist_fallback.inc(
                 exhausted, reason="exhausted")
-            acc["exhausted"] = acc.get("exhausted", 0) + exhausted
         if affinity > 0:
             metrics.solve_shortlist_fallback.inc(
                 affinity, reason="affinity-required")
-            acc["affinity-required"] = (
-                acc.get("affinity-required", 0) + affinity)
         self.stats["shortlist_fallbacks"] = (
             int(self.stats.get("shortlist_fallbacks", 0))
             + exhausted + affinity)
@@ -1899,23 +1887,8 @@ class FastCycle:
     # solve; warm shortlists simply disable (full re-rank — today's
     # behavior) there.  8 MB ≈ 8 ms of blake2b worst case on the cycle
     # thread, a bounded fraction of the warm win; beyond it the hash
-    # itself would eat the saving.  Env-overridable
-    # (VOLCANO_TPU_DEVINCR_CNT0_HASH_MAX, bytes): at the 100k-node
-    # tier the [E, D] pair outgrows 8 MB while the warm win ALSO grows
-    # with N, so deployments whose device lane dwarfs the hash cost
-    # raise the cap instead of silently losing warm shortlists at the
-    # exact scale they matter most.
+    # itself would eat the saving.
     _DEVINCR_CNT0_HASH_MAX = 8_000_000
-
-    @staticmethod
-    def _devincr_cnt0_hash_max() -> int:
-        raw = os.environ.get("VOLCANO_TPU_DEVINCR_CNT0_HASH_MAX")
-        if raw:
-            try:
-                return max(0, int(raw))
-            except ValueError:
-                pass
-        return FastCycle._DEVINCR_CNT0_HASH_MAX
 
     def _devincr_prepare(self, inputs, mesh, remote: bool):
         """Assemble the device-incremental cache keys + dirty superset
@@ -1948,7 +1921,7 @@ class FastCycle:
         aff = inputs[7]
         cnt0 = np.asarray(aff.cnt0)
         warm_key = None
-        if cnt0.nbytes <= self._devincr_cnt0_hash_max():
+        if cnt0.nbytes <= self._DEVINCR_CNT0_HASH_MAX:
             if cnt0.any():
                 h = hashlib.blake2b(digest_size=16)
                 h.update(repr(cnt0.shape).encode())
@@ -3154,13 +3127,12 @@ class FastCycle:
     def _device_snapshot(self):
         """The store's persistent device-resident snapshot, or None on
         paths that ship numpy (remote solver frames — the child process
-        owns its device state) or when disabled (VOLCANO_TPU_DEVSNAP=0).
+        owns its device state).
         A mesh store gets the mesh-sharded snapshot: node planes commit
         with the node-axis NamedSharding and delta scatters stay
         shard-local (ops/devsnap.py), so the mesh path no longer
         re-ships numpy planes every cycle."""
-        if (self._remote_solver is not None
-                or os.environ.get("VOLCANO_TPU_DEVSNAP", "1") == "0"):
+        if self._remote_solver is not None:
             return None
         from .ops.devsnap import for_store
 
@@ -3248,10 +3220,7 @@ class FastCycle:
         cls_sig = ""
         from .ops import wave as _wave_mod
 
-        use_classes = (
-            slim and N and _wave_mod._two_phase_on()
-            and _wave_mod._nodeclass_on()
-        )
+        use_classes = slim and N and _wave_mod._two_phase_on()
         if use_classes:
             def _build_classes():
                 from .ops.nodeclass import build_node_classes

@@ -19,7 +19,7 @@ device side already is (``ops/devsnap.py`` delta scatters):
   **subtract-old / add-new delta scatters** over only the dirty rows.
   The shadow columns snapshot the dynamic state as of the last derive,
   so "old" contributions are recomputed exactly, and rows whose shadow
-  equals their live state (the steady-state bench's bind-then-re-pend
+  equals their live state (a steady state's bind-then-re-pend
   churn) contribute nothing and cost nothing beyond a vector compare.
 - A **proven full-rebuild fallback** covers everything the delta path
   cannot: node-table epoch churn (node liveness participates in the

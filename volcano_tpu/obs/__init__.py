@@ -41,10 +41,10 @@ unconditionally:
 
 Consumers: ``service.py`` exposes ``/debug/cycles``,
 ``/debug/cycles/<seq>``, ``/debug/trace?cycles=K``, ``/debug/health``
-and ``/debug/anomalies``; ``bench.py`` writes one trace file per
-config and folds drop-reason totals, per-lane p50/p95, and the audit
-overhead block into its machine-readable JSON tail.  docs/tracing.md
-and docs/observability.md document all of it.
+and ``/debug/anomalies``; the benchmark (``benchmark/run.py``) reads
+the flight records' lanes; ``hack/endurance.py`` gates on the auditor's
+verdict and prints the audit and journey blocks in its JSON tail.
+docs/tracing.md and docs/observability.md document all of it.
 """
 
 from .audit import Anomaly, Auditor

@@ -227,7 +227,7 @@ class Auditor:
         # generation, which must re-anchor, not read as a
         # regression.  # guarded-by: _lock
         self._wire_client: Dict[str, int] = {}
-        # Accounting for the bench audit tails / /debug/health.
+        # Accounting for audit_stats() / /debug/health.
         self.cycles = 0  # guarded-by: _lock
         self.sampled_cycles = 0  # guarded-by: _lock
         self.reconciles = 0  # guarded-by: _lock
@@ -329,7 +329,8 @@ class Auditor:
             self._reanchor_reason = why
 
     def set_enabled(self, flag: bool) -> None:
-        """Flip the auditor at runtime (the bench overhead A/B).
+        """Flip the auditor at runtime (the endurance harness's overhead
+        A/B).
         Re-enabling re-anchors: mutations while disabled recorded no
         flows, so the first reconcile back must not compare."""
         flag = bool(flag)
@@ -643,7 +644,8 @@ class Auditor:
             return sum(self.anomaly_counts.values())
 
     def audit_stats(self) -> dict:
-        """Bench tail block: sampled cycles + measured overhead."""
+        """Sampled cycles + measured overhead (the endurance harness's
+        ``audit`` tail block, chip_smoke.py)."""
         with self._lock:
             return {
                 "enabled": self.enabled,

@@ -32,7 +32,6 @@ the stable subset above is emitted.
 
 from __future__ import annotations
 
-import json
 from typing import Dict, Iterable, List, Optional
 
 PID = 1
@@ -177,11 +176,3 @@ def perfetto_trace(records: Iterable,
         "displayTimeUnit": "ms",
     }
 
-
-def write_trace(path: str, records: Iterable,
-                journey: Optional[Iterable[dict]] = None) -> str:
-    """Dump records to ``path`` as Perfetto-loadable JSON; returns the
-    path."""
-    with open(path, "w") as f:
-        json.dump(perfetto_trace(records, journey=journey), f)
-    return path

@@ -92,7 +92,7 @@ _EDGE_KINDS = ("enqueued", "status-sync", "removed")
 
 # Per-pod drop-chain depth (why-pending evidence window).
 _DROP_CHAIN = 8
-# Bench-percentile sample windows.
+# Latency-percentile sample windows.
 _TTB_WINDOW = 4096
 _GANG_WINDOW = 1024
 _QUEUE_WINDOW = 256
@@ -145,8 +145,7 @@ def _pct(vals: List[float], q: float) -> Optional[float]:
 class JourneyLog:
     """Bounded columnar per-pod event timeline + per-pod summaries.
 
-    Writers call under the store lock (mirror writers / fast path) or
-    from bench teardown; readers are the /debug HTTP threads.  All
+    Writers call under the store lock (mirror writers / fast path); readers are the /debug HTTP threads.  All
     shared state is guarded by the journey's own ``_lock`` — never
     taken around store state, so a /debug/pods scrape cannot block the
     cycle thread on store work.
@@ -207,7 +206,7 @@ class JourneyLog:
         # idiom): nanoseconds spent inside the capture entry points,
         # two perf_counter reads per CALL (not per event).
         self.capture_ns = 0
-        # Latency sample windows for the bench tail / queue rollup.
+        # Latency sample windows for stats() / the queue rollup.
         self._ttb_ms: deque = deque(maxlen=_TTB_WINDOW)
         self._ttfc_ms: deque = deque(maxlen=_TTB_WINDOW)
         self._gang_ttfb_ms: deque = deque(maxlen=_GANG_WINDOW)
@@ -726,7 +725,8 @@ class JourneyLog:
             }
 
     def stats(self) -> dict:
-        """The bench JSON-tail journey block."""
+        """Event counts and time-to-bind / gang full-bind percentiles
+        (the endurance harness's ``journey`` tail block)."""
         with self._lock:
             self._flush_kind_counts()
             ttb = list(self._ttb_ms)

@@ -1,9 +1,8 @@
 """Cycle flight recorder: a fixed-size ring of per-cycle records.
 
 The scheduler's interesting behavior spans TWO cycles since the
-pipelined sessions landed (dispatch in N, commit in N+1), and the only
-prior visibility was ``store.last_cycle_lanes`` — last cycle only, lane
-seconds only.  The flight recorder keeps the last N cycles (default
+pipelined sessions landed (dispatch in N, commit in N+1).  The flight
+recorder keeps the last N cycles (default
 256, ``VOLCANO_TPU_FLIGHT_CYCLES``) of everything a post-hoc "why did
 cycle 48231 drop 17 rows" investigation needs:
 
@@ -27,7 +26,7 @@ cycle 48231 drop 17 rows" investigation needs:
 
 Concurrency: the cycle thread records (holding the store lock — the
 ring lock nests strictly inside it and is never taken around store
-state); the HTTP ``/debug`` handlers and bench read from their own
+state); the HTTP ``/debug`` handlers read from their own
 threads.  Everything shared is guarded by ``_lock`` (vclint-checked).
 """
 

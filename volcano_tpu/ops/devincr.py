@@ -55,8 +55,8 @@ log = logging.getLogger(__name__)
 
 
 def devincr_on() -> bool:
-    """The device-incremental kill switch (read per call so bench.py
-    can A/B inside one process)."""
+    """The device-incremental kill switch (read per call, so a test can
+    flip it between two solves of one process)."""
     return os.environ.get("VOLCANO_TPU_DEVINCR", "1") != "0"
 
 
@@ -64,31 +64,17 @@ def warm_blocks() -> int:
     """Node-axis block count of the warm-shortlist candidate retention
     (pow2; clamped to the padded node axis and raised to the mesh shard
     count by the caller)."""
-    try:
-        b = int(os.environ.get("VOLCANO_TPU_WARM_BLOCKS", 16))
-    except ValueError:
-        b = 16
-    p = 1
-    while p * 2 <= max(1, b):
-        p *= 2
-    return p
+    return 16
 
 
 def warm_block_rows() -> int:
     """Upper bound on node rows per warm block (pow2).  At the 100k-node
-    tier the fixed default block count would leave 8k+ rows per block —
-    one dirty node then re-ranks 8k rows; bounding rows/block instead
+    tier the fixed block count would leave 8k+ rows per block — one
+    dirty node then re-ranks 8k rows; bounding rows/block instead
     keeps the warm re-rank cost proportional to churn, and the
     block->shard->global merge (ops.wave._merge_block_cands) keeps the
     extra blocks' reduce shard-local."""
-    try:
-        r = int(os.environ.get("VOLCANO_TPU_WARM_BLOCK_ROWS", 8192))
-    except ValueError:
-        r = 8192
-    p = 1
-    while p * 2 <= max(1, r):
-        p *= 2
-    return p
+    return 8192
 
 
 # Past this fraction of blocks dirty, a full re-rank beats the gather +

@@ -132,7 +132,8 @@ class DeviceSnapshot:
         # Two-phase class tables ([C, *], tiny), content-addressed.
         self._cls_planes: Dict[str, object] = {}
         self._cls_key: Optional[Tuple] = None
-        # Telemetry for tests/bench: full vs delta vs hit counts.
+        # Telemetry (the flight record's ``solve`` counts, tests): full vs
+        # delta vs hit counts.
         self.full_uploads = 0
         self.delta_uploads = 0
         self.hits = 0

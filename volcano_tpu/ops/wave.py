@@ -96,13 +96,13 @@ def _env_int(name: str, default: int) -> int:
         return default
 
 
-DEFAULT_WAVE = _env_int("VOLCANO_TPU_WAVE", 2048)
+DEFAULT_WAVE = 2048
 # cnt0 tables above this element count ship as sparse entries and are
 # scattered on device (tests lower it to force the sparse path).
 CNT0_SPARSE_MIN = 4_000_000
 # Same for each profile-term table ([U, Ep]): past this element count
 # the four tables ship as one sparse entry list.
-PROF_SPARSE_MIN = _env_int("VOLCANO_TPU_PROF_SPARSE_MIN", 1_000_000)
+PROF_SPARSE_MIN = 1_000_000
 # diversification breadth: k-th contender takes its k-th best node
 TOPK = _env_int("VOLCANO_TPU_TOPK", 256)
 # In-attempt re-walk rounds for conflict losers.  Default 4: measured
@@ -110,20 +110,7 @@ TOPK = _env_int("VOLCANO_TPU_TOPK", 256)
 # per-attempt sub-round machinery than the attempt-count reduction it
 # buys; acceptance stays exact either way — sub-rounds only change how
 # much conflict retry happens inside one ranking).
-SUBROUNDS = _env_int("VOLCANO_TPU_SUBROUNDS", 4)
-# live affinity steering inside sub-rounds ([UM,EW]x[EW,N] matmuls per
-# dirty sub-round).  Default OFF: at the north-star affinity shape
-# (10k nodes x 100k pods, 5/5/10% affinity mix) the steering cost more
-# per attempt than it saved in attempt count when it was last measured
-# (an earlier round, not on the current machine) — placements are
-# identical either way.
-# Re-enable with VOLCANO_TPU_AFF_STEER=1 for term-heavy small clusters.
-AFF_STEER = _env_int("VOLCANO_TPU_AFF_STEER", 0)
-# Attempt-level cache of the inter-pod affinity planes (required/anti
-# feasibility + soft score): recompute only on term-count changes
-# instead of every attempt.  Exact (same values); knob exists for A/B
-# measurement.
-AFF_ACACHE = _env_int("VOLCANO_TPU_AFF_ACACHE", 1)
+SUBROUNDS = 4
 # Flattened (term x domain) scatter keys index an [EW * D + 1] buffer
 # with int32 device arithmetic (jax's default index width).  At the
 # 100k-node x 1M-pod tier the PRODUCT crosses 2^31 while each axis
@@ -147,7 +134,7 @@ def _keyspace_max() -> int:
 # zero outside a term's own key's domains, so each output element picks
 # up exactly one product, and f32 represents the integer counts
 # exactly).  Above it (hyperscale D ~ 50k) the gather path remains.
-DOM_MM_MAX_MB = _env_int("VOLCANO_TPU_DOM_MM_MB", 1024)
+DOM_MM_MAX_MB = 1024
 
 # ---- two-phase device solve (node-class compaction + shortlists) -----
 # Phase 1 (coarse) collapses the node table into node classes and
@@ -159,14 +146,10 @@ DOM_MM_MAX_MB = _env_int("VOLCANO_TPU_DOM_MM_MB", 1024)
 # feasible candidate falls back to a full-N rescore for that attempt
 # (counted per reason), so binding is never lost to pruning — the
 # TPU-native analog of the reference's percentageOfNodesToFind sampling
-# (scheduler_helper.go:37-62).  Knobs are read per call so bench.py can
-# A/B both modes inside one process.
+# (scheduler_helper.go:37-62).  The knobs are read per call, so a test
+# can flip them between two solves of one process.
 def _two_phase_on() -> bool:
     return _os.environ.get("VOLCANO_TPU_TWOPHASE", "1") != "0"
-
-
-def _nodeclass_on() -> bool:
-    return _os.environ.get("VOLCANO_TPU_NODECLASS", "1") != "0"
 
 
 def _fallback_cap() -> int:
@@ -197,7 +180,7 @@ def shortlist_size(n: int) -> int:
 # broadcast (the only [*, N, R] tensor of the coarse pass) so hyperscale
 # profile counts stream through lax.map instead of materializing
 # [U, N, R] at once.
-COARSE_CHUNK = _env_int("VOLCANO_TPU_COARSE_CHUNK", 256)
+COARSE_CHUNK = 256
 
 # Telemetry of the most recent two-phase solve on this host (the cycle
 # driver folds it into the device_coarse/device_fine sub-lanes and the
@@ -444,7 +427,7 @@ def _hier_blocks(n: int, k: int, n_shards: int = 1,
 # _hier_blocks): below TOPK_HIER_MIN nodes a single top_k wins; above,
 # blocks aim at TOPK_BLOCK_ROWS rows each.
 TOPK_HIER_MIN = _env_int("VOLCANO_TPU_TOPK_HIER_MIN", 65536)
-TOPK_BLOCK_ROWS = _env_int("VOLCANO_TPU_TOPK_BLOCK_ROWS", 8192)
+TOPK_BLOCK_ROWS = 8192
 
 
 def _merge_block_cands(cand_s, cand_i, k: int, n_shards: int = 1):
@@ -1269,12 +1252,9 @@ def _solve_wave(
 
                 # Cache init is (all-true, zeros) and aff_dirty_a starts
                 # at wave_live, so term-free waves never enter the
-                # compute branch (the old _aff_skip case).  With the
-                # cache disabled, every attempt of a live wave
-                # recomputes (the pre-cache behavior).
-                gate = aff_dirty_a if AFF_ACACHE else wave_live
+                # compute branch.
                 aff_ok, aff_soft = jax.lax.cond(
-                    gate, _aff_parts,
+                    aff_dirty_a, _aff_parts,
                     lambda cnt: (aff_ok_c, aff_soft_c), cw_a + cw_p
                 )
                 p_feasible &= aff_ok
@@ -1371,9 +1351,8 @@ def _solve_wave(
                         (aff_viol < 0.5) & (anti_viol < 0.5), soft
                     )
 
-                gate = aff_dirty_a if AFF_ACACHE else wave_live
                 aff_ok, aff_soft = jax.lax.cond(
-                    gate, _aff_parts_sl,
+                    aff_dirty_a, _aff_parts_sl,
                     lambda cnt: (aff_ok_c, aff_soft_c), cw_a + cw_p
                 )
                 feas &= aff_ok
@@ -1612,75 +1591,10 @@ def _solve_wave(
                 )
 
             def sub_body(sc):
-                (s_, cw_a_, cw_p_, feas_k_c, aff_dirty, done_sub, alloc_l_,
+                (s_, cw_a_, cw_p_, feas_k, _dirty, done_sub, alloc_l_,
                  assigned_w_, pipelined_w_, si, _progressed,
                  cnt_changed) = sc
                 cand_s = cand & ~done_sub & ~aborted
-
-                if has_aff:
-                    # Live affinity steering: after an affinity-relevant
-                    # acceptance, recompute the profile-level required-
-                    # (anti)affinity feasibility against the sub-round
-                    # count window, so once a sibling claims a domain the
-                    # rest of the gang walks only nodes of that domain
-                    # instead of re-discovering it one attempt at a time.
-                    # Gated on a dirty flag: waves without affinity
-                    # activity skip the [N, EW] work entirely.
-                    def steer(_):
-                        cnt_live_n = cw_a_ + cw_p_  # [EW, D]
-                        total_live_n = jnp.sum(cnt_live_n, axis=-1)
-                        selfok_p = (
-                            (total_live_n == 0)[None, :] & p_t_matches
-                        )  # [UM, EW]
-                        # bf16 indicator matmuls: see _aff_parts.
-                        bf_ = jnp.bfloat16
-                        need_l = (p_t_req_aff & ~selfok_p).astype(bf_)
-                        if two_phase:
-                            # Steer directly at the ranked candidates:
-                            # [UM, K, EW] window instead of [UM, N].
-                            dw_r = node_dom_t[ranked]  # [UM, K, EW]
-                            cval_r = cnt_live_n[
-                                term_arange[None, None, :],
-                                jnp.maximum(dw_r, 0),
-                            ]
-                            cval_r = jnp.where(dw_r >= 0, cval_r, 0)
-                            aff_viol_l = jnp.einsum(
-                                "ue,uke->uk", need_l,
-                                (cval_r == 0).astype(bf_),
-                            )
-                            anti_viol_l = jnp.einsum(
-                                "ue,uke->uk", p_t_req_anti.astype(bf_),
-                                (cval_r > 0).astype(bf_),
-                            )
-                            return feas_k_att & (aff_viol_l < 0.5) & (
-                                anti_viol_l < 0.5
-                            )
-                        cval_live = cnt_live_n[
-                            term_arange[None, :], jnp.maximum(node_dom_t, 0)
-                        ]
-                        cval_live = jnp.where(node_dom_t >= 0, cval_live, 0)
-                        aff_viol_l = jnp.matmul(
-                            need_l, (cval_live == 0).astype(bf_).T
-                        )
-                        anti_viol_l = jnp.matmul(
-                            p_t_req_anti.astype(bf_),
-                            (cval_live > 0).astype(bf_).T,
-                        )
-                        p_feas_sub = p_feasible & (aff_viol_l < 0.5) & (
-                            anti_viol_l < 0.5
-                        )
-                        return jnp.take_along_axis(
-                            p_feas_sub, ranked, axis=1
-                        )
-
-                    if AFF_STEER:
-                        feas_k = jax.lax.cond(
-                            aff_dirty, steer, lambda _: feas_k_c, None
-                        )
-                    else:
-                        feas_k = feas_k_c
-                else:
-                    feas_k = feas_k_c
 
                 # Live capacity walk (copies of the profile per ranked node).
                 if has_future:
@@ -2157,6 +2071,9 @@ def _solve_wave(
                 assigned_w_ = jnp.where(acc_alloc, choice, assigned_w_)
                 pipelined_w_ = jnp.where(acc_pipe, choice, pipelined_w_)
                 resolved = acc_alloc | acc_pipe
+                # dirty_next has no reader in the loop: the slot stays so
+                # that the traced program is the one the cells were
+                # measured with (ROADMAP, named debts).
                 if has_aff:
                     giver_rel = jnp.any(
                         t_matches_w & term_req_w[None, :], axis=1
@@ -2983,7 +2900,7 @@ def solve_wave(
     # ---- two-phase solve prep (node classes + shortlists) ------------
     N_in = int(nodes.idle.shape[0])
     two_phase = _two_phase_on() and N_in > 0
-    if two_phase and node_classes is None and _nodeclass_on() \
+    if two_phase and node_classes is None \
             and isinstance(nodes.label_bits, np.ndarray):
         node_classes = _host_node_classes(nodes)
     cls_identity = node_classes is None

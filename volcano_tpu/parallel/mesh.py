@@ -151,8 +151,8 @@ def sharded_solve_wave(mesh: Mesh, solve_args: Sequence,
 # committed mesh-sharded arrays from the sharded devsnap
 # (ops/devsnap.py — per-shard resident planes with shard-local delta
 # scatters) and pass straight through; the plane cache below remains
-# the fallback for direct callers and VOLCANO_TPU_DEVSNAP=0, where it
-# still skips the per-cycle device_put on an epoch hit.
+# the fallback for callers that ship numpy planes, where it still
+# skips the per-cycle device_put on an epoch hit.
 _EPOCH_STABLE_NODE_FIELDS = frozenset(
     {"allocatable", "max_tasks", "ready", "label_bits", "taint_bits"}
 )

@@ -289,7 +289,7 @@ class ShardedScheduler:
     front-end ``service.make_scheduler`` returns when
     ``VOLCANO_TPU_SHARDS`` > 1.  Mirrors the single ``Scheduler``'s
     lifecycle surface (run / run_once / stop / healthy) so Service and
-    bench drive either interchangeably."""
+    the endurance harness drive either interchangeably."""
 
     def __init__(self, store, conf_path: Optional[str] = None,
                  conf_str: Optional[str] = None,
@@ -322,8 +322,8 @@ class ShardedScheduler:
             s.run()
 
     def run_once(self) -> None:
-        """One synchronous cycle per shard, in shard order (tests and
-        bench drive this for determinism; the optimistic commit
+        """One synchronous cycle per shard, in shard order (tests and the
+        endurance harness drive this for determinism; the optimistic commit
         protocol engages all the same, because each shard's pipelined
         dispatch from call K commits during call K+1, AFTER its
         siblings' intervening commits)."""

@@ -11,7 +11,7 @@ frame (reconnect -> full frame -> deltas is already the healed path the
 endurance gate proves).
 
 ``SolverPool`` duck-types the ``RemoteSolver`` client surface the fast
-path, bench, and auditor consume (``solve`` / ``solve_async`` / ``ping``
+path, the endurance harness, and the auditor consume (``solve`` / ``solve_async`` / ``ping``
 / ``close`` / telemetry counters), so ``store.remote_solver`` may hold
 either and the dispatch seams stay unchanged.  Three perf behaviors,
 all kill-switched by ``VOLCANO_TPU_SOLVER_POOL`` (default 1 = exactly
@@ -209,7 +209,7 @@ class SolverPool:
         # tokens are only valid for it (any other replica's child
         # missed the dirty supersets since ITS last frame).
         self._devincr_owner: Optional[int] = None  # guarded-by: _lock
-        # Telemetry (bench pool tails + flight recorder).
+        # Telemetry (health_snapshot() + flight recorder).
         self.hedge_dispatches = 0  # guarded-by: _lock
         self.hedge_wins = 0        # guarded-by: _lock
         self.failovers = 0         # guarded-by: _lock
@@ -279,8 +279,7 @@ class SolverPool:
                 r.busy = False
             r.client.close()
 
-    # Aggregated telemetry: the bench wire tails and BASELINE overhead
-    # table read these off whatever store.remote_solver holds.
+    # Aggregated telemetry, read off whatever store.remote_solver holds.
     @property
     def requests(self) -> int:
         return sum(r.client.requests for r in self.replicas)
@@ -318,8 +317,8 @@ class SolverPool:
         return out
 
     def per_replica_frames(self) -> List[Dict[str, int]]:
-        """Per-replica frame counters (the bench pool tail's proof that
-        deltas re-engaged on each member)."""
+        """Per-replica frame counters (proof that deltas re-engaged on
+        each member)."""
         return [dict(r.client.frame_counts) for r in self.replicas]
 
     def health_snapshot(self) -> dict:

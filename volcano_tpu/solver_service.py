@@ -107,12 +107,11 @@ def _registry():
 
 def wire_mode() -> str:
     """The delta-frame lane switch (docs/tuning.md "Remote wire"), read
-    per frame so bench.py can A/B inside one process: ``"on"`` (delta
+    per frame so a test can flip it inside one process: ``"on"`` (delta
     frames when the wire cache holds, the default), ``"off"`` (classic
     v1 full frames, no wire section at all — the kill switch), or
     ``"fallback"`` (the v2 machinery runs but every frame deliberately
-    voids the cache first, exercising the full-frame fallback path —
-    the bench A/B's forced-fallback lever)."""
+    voids the cache first, exercising the full-frame fallback path)."""
     v = os.environ.get("VOLCANO_TPU_WIRE", "1").strip().lower()
     if v in ("0", "off", "no"):
         return "off"
@@ -192,7 +191,7 @@ class ShmUnavailable(RuntimeError):
 
 
 # Segment names embed the pid plus a PROCESS-GLOBAL sequence: two live
-# clients in one process (two stores, a bench A/B) must never both
+# clients in one process (two stores, a pool) must never both
 # create "vtpu_wire_<pid>_1".
 _SHM_SEQ = itertools.count(1)
 
@@ -293,7 +292,7 @@ class _ShmReader:
             # tracker too, which would unlink the client's live segment
             # when this process exits; the creator owns the unlink.
             # Skip when creator and reader share a process (in-process
-            # bench server): attach and create then share ONE tracker
+            # server thread): attach and create then share ONE tracker
             # entry, and unregistering here would delete the creator's.
             try:
                 creator_pid = int(str(name).split("_")[2])
@@ -444,7 +443,7 @@ class SolverServer:
         self.host = host
         self._stop = threading.Event()
         self.solves = 0
-        # Fault-injection hook (bench.py BENCH_POOL straggler schedule,
+        # Fault-injection hook (hack/endurance.py's pool-leg straggler,
         # tests/test_solver_pool.py): called with the running solve
         # count; a positive return sleeps that many seconds before the
         # reply ships — a reply-side straggler, exactly the tail the
@@ -718,7 +717,7 @@ class RemoteSolver:
         # dropping every reply (like the shm lane's self-disable).
         self._wire_v1_child = False
         self._shm = _ShmLane() if shm_on() else None
-        # Frame telemetry for the metrics counters + bench wire tails.
+        # Frame telemetry for the metrics counters + the endurance tail.
         self.frame_counts = {"full": 0, "delta": 0}
         self.frame_bytes = {"full": 0, "delta": 0}
         self.wire_fallbacks: Dict[str, int] = {}
