@@ -353,15 +353,23 @@ def test_a_bind_batch_leaves_five_events_on_the_bind_track():
 def test_a_delete_after_a_commit_leaves_one_rebuild_event():
     store = _store(seed=37)
     _cycle(store)
-    victim = next(iter(store.pods.values()))
-    store.delete_pod(victim)        # reads store.jobs: pays the rebuild
-    store.delete_pod(next(iter(store.pods.values())))  # already rebuilt
+    store.delete_pod(next(iter(store.pods.values())))
+    store.delete_pod(next(iter(store.pods.values())))
+    rec, _ = _cycle(store)
+    # The deletes alone leave the stale model alone: nobody read it.
+    assert not [s for s in rec.spans if s.name == "store:rebuild_objects"]
+    assert rec.object_model == {"stale": 1, "stale_events": 2}
+    assert len(store.jobs) == 8     # a reader: pays the rebuild
+    store.delete_pod(next(iter(store.pods.values())))   # kept up, fresh
     rec, _ = _cycle(store)
     rebuilds = [s for s in rec.spans if s.name == "store:rebuild_objects"]
     assert len(rebuilds) == 1
     assert rebuilds[0].tid == "store" and rebuilds[0].parent_id == 0
-    assert rebuilds[0].args == {"pods": 31}     # the victim is gone
+    # The two victims are gone, and their deletes were taken stale.
+    assert rebuilds[0].args == {"pods": 30, "stale_events": 2}
     assert rebuilds[0].dur_ns > 0
+    assert rec.object_model == {"stale": 0, "stale_events": 0}
+    assert rec.to_dict()["object_model"] == rec.object_model
 
 
 # --------------------------------------------------------------- the counts
@@ -615,6 +623,10 @@ def test_the_export_gives_bind_and_store_their_tracks_and_lanes_their_name():
     store = _store(seed=79)
     _cycle(store)
     store.delete_pod(next(iter(store.pods.values())))
+    _cycle(store)
+    names = {ev["name"] for ev in export.trace_events(store.flight.recent())}
+    assert "store:rebuild_objects" not in names     # the delete read nothing
+    assert store.nodes      # a reader of the model after a commit
     _cycle(store)
     events = export.trace_events(store.flight.recent())
     tracks = {ev["args"]["name"]: ev["tid"] for ev in events

@@ -136,3 +136,40 @@ def test_object_path_status_writes_refresh_mirror_columns():
     assert m.j_cond_sig[row] == (
         hash(("NotEnoughResources", "0/2 ready")) & 0x7FFFFFFFFFFFFFFF
     )
+
+
+def test_job_uid_rank_extends_its_uid_array_and_stays_the_full_sort():
+    """The rank is a strict monotone map of the uid strings whether the
+    string array was made in one go or extended round by round, with
+    uids of growing width, and across a pod-table compaction (which
+    carries the job table, and the cached array, over)."""
+    store = ClusterStore()
+    m = store.mirror
+    assert len(m.job_uid_rank()) == 0
+    rng = np.random.default_rng(5)
+
+    def check():
+        rank = m.job_uid_rank()
+        uids = list(m.j_uid)
+        want = np.empty(len(uids), np.int64)
+        want[np.argsort(np.array(uids), kind="stable")] = np.arange(len(uids))
+        np.testing.assert_array_equal(rank, want)
+        assert [uids[i] for i in np.argsort(rank)] == sorted(uids)
+
+    for step in range(12):
+        for _ in range(int(rng.integers(1, 40))):
+            name = "g" + "x" * step + str(int(rng.integers(0, 10 ** 6)))
+            store.add_pod_group(PodGroup(name=name, min_member=1))
+        check()
+        assert len(m._j_uid_arr) == len(m.j_uid)
+    first = m._j_uid_arr
+    pods = [Pod(name=f"tmp-{i}", containers=[{"cpu": "100m"}])
+            for i in range(4200)]
+    for p in pods:
+        store.add_pod(p)
+    gen = m.compact_gen
+    for p in pods:
+        store.delete_pod(p)
+    assert m.compact_gen > gen and m._j_uid_arr is first
+    store.add_pod_group(PodGroup(name="after", min_member=1))
+    check()

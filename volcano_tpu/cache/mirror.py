@@ -1060,13 +1060,25 @@ class StoreMirror:
         rank = self._j_uid_rank
         Jn = len(self.j_uid)
         if rank is None or len(rank) != Jn:
-            order = np.argsort(np.array(self.j_uid[:Jn]), kind="stable")
+            # Job rows are append-only and a row keeps its uid, so the
+            # uids as a numpy string array are extended by the new rows
+            # alone: making that array from Jn Python strings costs
+            # several times the sort, and a new row comes every round.
+            uids = self._j_uid_arr
+            if uids is None or not 0 < len(uids) <= Jn:
+                uids = np.array(self.j_uid[:Jn])
+            elif len(uids) < Jn:
+                uids = np.concatenate(
+                    [uids, np.array(self.j_uid[len(uids):Jn])])
+            self._j_uid_arr = uids
+            order = np.argsort(uids, kind="stable")
             rank = np.empty(Jn, np.int64)
             rank[order] = np.arange(Jn)
             self._j_uid_rank = rank
         return rank
 
     _j_uid_rank: Optional[np.ndarray] = None
+    _j_uid_arr: Optional[np.ndarray] = None
 
     def upsert_pod_group(self, pg, priority: int) -> None:
         row = self.job_row(pg.uid)

@@ -71,8 +71,15 @@ class ClusterStore:
         self._nodes: Dict[str, NodeInfo] = {}
         # The fast path (volcano_tpu.fastpath) commits directly to the pod
         # records + array mirror and marks the derived JobInfo/NodeInfo
-        # object model stale; it is lazily rebuilt from pods on next access.
+        # object model stale; it stays stale, untouched by the event
+        # handlers below, until somebody reads ``jobs`` / ``nodes``.
         self._objects_stale = False
+        # Handler calls that skipped the object model: since it went
+        # stale (``store:rebuild_objects`` ``args.stale_events``), and
+        # since a cycle record last took them (``CycleRecord.
+        # object_model``).
+        self._stale_events = 0  # guarded-by: _lock
+        self._stale_events_cycle = 0  # guarded-by: _lock
         self.queues: Dict[str, QueueInfo] = {}
         self.priority_classes: Dict[str, PriorityClass] = {}
         self.namespace_weights: Dict[str, int] = {}
@@ -576,9 +583,47 @@ class ClusterStore:
         return self._nodes
 
     def mark_objects_stale(self) -> None:
-        """Called by the fast path after a bulk commit: JobInfo/NodeInfo
-        accounting will be rebuilt from the pod records on next read."""
-        self._objects_stale = True
+        """Called by the fast path after a bulk commit: the JobInfo /
+        NodeInfo object model is void, and stays so until a reader asks
+        for ``jobs`` / ``nodes`` (``snapshot()`` included), which
+        rebuilds it from the pod records.
+
+        While it is stale the event handlers keep only what
+        ``_rebuild_objects`` reads (the pod and pod-group tables, the
+        priority classes, the mirror's node and job rows) and leave the
+        model alone: no TaskInfo per event, and no rebuild on the first
+        event after a commit.  An ``add_pod`` / ``update_pod`` that
+        would raise out of ``_add_task`` on a fresh model
+        (``NodeInfo.add_task`` on an over-subscribed or NotReady node)
+        is taken without error while stale; the rebuild logs the same
+        divergence when somebody reads.
+
+        Going stale lets go of the model: kept, it would be the last
+        holder of every pod record it knew, deleted ones included, for
+        as long as nobody reads."""
+        with self._lock:
+            if not self._objects_stale:
+                self._objects_stale = True
+                self._jobs = {}
+                self._nodes = {}
+
+    # holds: _lock
+    def _skip_objects(self) -> bool:
+        """An event handler's question before it touches the object
+        model: true, and counted, while the model is stale."""
+        if not self._objects_stale:
+            return False
+        self._stale_events += 1
+        self._stale_events_cycle += 1
+        return True
+
+    def take_object_model_counts(self) -> Dict[str, int]:
+        """``CycleRecord.object_model``, taken at a cycle's start: is
+        the model stale, and how many handler calls skipped it since
+        the last cycle took the count."""
+        with self._lock:
+            n, self._stale_events_cycle = self._stale_events_cycle, 0
+            return {"stale": int(self._objects_stale), "stale_events": n}
 
     def _rebuild_objects(self) -> None:
         """Recompute the JobInfo/NodeInfo object model from pods + pod
@@ -591,6 +636,7 @@ class ClusterStore:
                 return
             t0 = time.perf_counter_ns()
             self._objects_stale = False
+            stale_events, self._stale_events = self._stale_events, 0
             self._nodes = {}
             for row, name in enumerate(self.mirror.n_name):
                 if name is not None and self.mirror.n_alive[row]:
@@ -602,13 +648,9 @@ class ClusterStore:
                     continue
                 job = JobInfo(uid)
                 job.set_pod_group(pg)
-                if (
-                    pg.priority_class
-                    and pg.priority_class in self.priority_classes
-                ):
-                    job.priority = self.priority_classes[
-                        pg.priority_class
-                    ].value
+                pc = self._priority_class_of(pg)
+                if pc is not None:
+                    job.priority = pc.value
                 self._jobs[uid] = job
             for pod in self.pods.values():
                 try:
@@ -621,13 +663,19 @@ class ClusterStore:
                     logging.getLogger(__name__).error(
                         "rebuild: failed to re-add task %s: %s", pod.uid, err
                     )
-            # Any thread that reads ``jobs``/``nodes`` after a commit
-            # pays this, in a cycle or between two: a parentless event
-            # on its own track, drained with the next cycle's record.
+            # Only a reader of ``jobs``/``nodes`` pays this, on its own
+            # thread, in a cycle or between two: a parentless event on
+            # its own track, drained with the next cycle's record.
             self.tracer.event(
                 "store:rebuild_objects", "store", t0,
                 time.perf_counter_ns() - t0, tid="store",
-                args={"pods": len(self.pods)})
+                args={"pods": len(self.pods),
+                      "stale_events": stale_events})
+
+    def _priority_class_of(self, pg: PodGroup) -> Optional[PriorityClass]:
+        if not pg.priority_class:
+            return None
+        return self.priority_classes.get(pg.priority_class)
 
     # ------------------------------------------------------------- watchers
 
@@ -694,29 +742,35 @@ class ClusterStore:
             self.pods[pod.uid] = pod
             if pod.volumes:
                 self.n_volume_pods += 1
-            self._add_task(pod)
+            if not self._skip_objects():
+                self._add_task(pod)
             self.mirror.upsert_pod(pod, self.mirror.job_row)
             self._notify("Pod", "add", pod)
 
     def update_pod(self, pod: Pod) -> None:
         with self._lock:
             old = self.pods.get(pod.uid)
+            fresh = not self._skip_objects()
             if old is not None:
-                self._remove_task(old)
+                if fresh:
+                    self._remove_task(old)
                 if old.volumes:
                     self.n_volume_pods -= 1
             self.pods[pod.uid] = pod
             if pod.volumes:
                 self.n_volume_pods += 1
-            self._add_task(pod)
+            if fresh:
+                self._add_task(pod)
             self.mirror.upsert_pod(pod, self.mirror.job_row)
             self._notify("Pod", "update", pod)
 
     def delete_pod(self, pod: Pod) -> None:
         with self._lock:
             old = self.pods.pop(pod.uid, None)
+            fresh = not self._skip_objects()
             if old is not None:
-                self._remove_task(old)
+                if fresh:
+                    self._remove_task(old)
                 if old.volumes:
                     self.n_volume_pods -= 1
             if self.bind_backoff:
@@ -749,27 +803,30 @@ class ClusterStore:
 
     def add_node(self, node: Node) -> None:
         with self._lock:
-            existing = self.nodes.get(node.name)
-            if existing is not None:
-                existing.set_node(node)
-            else:
-                self.nodes[node.name] = NodeInfo(node)
+            self._set_node(node)
             self.mirror.upsert_node(node)
             self._notify("Node", "add", node)
 
     def update_node(self, node: Node) -> None:
         with self._lock:
-            existing = self.nodes.get(node.name)
-            if existing is None:
-                self.nodes[node.name] = NodeInfo(node)
-            else:
-                existing.set_node(node)
+            self._set_node(node)
             self.mirror.upsert_node(node)
             self._notify("Node", "update", node)
 
+    # holds: _lock
+    def _set_node(self, node: Node) -> None:
+        if self._skip_objects():
+            return
+        existing = self.nodes.get(node.name)
+        if existing is None:
+            self.nodes[node.name] = NodeInfo(node)
+        else:
+            existing.set_node(node)
+
     def delete_node(self, name: str) -> None:
         with self._lock:
-            self.nodes.pop(name, None)
+            if not self._skip_objects():
+                self.nodes.pop(name, None)
             self.mirror.remove_node(name)
             self._notify("Node", "delete", name)
 
@@ -777,28 +834,33 @@ class ClusterStore:
 
     def add_pod_group(self, pg: PodGroup) -> None:
         with self._lock:
-            self.pod_groups[pg.uid] = pg
-            job = self._get_or_create_job(pg.uid)
-            job.set_pod_group(pg)
-            if pg.priority_class and pg.priority_class in self.priority_classes:
-                job.priority = self.priority_classes[pg.priority_class].value
-            self.mirror.upsert_pod_group(pg, job.priority)
+            self._set_pod_group(pg)
             self._notify("PodGroup", "add", pg)
 
     def update_pod_group(self, pg: PodGroup) -> None:
         with self._lock:
-            self.pod_groups[pg.uid] = pg
+            self._set_pod_group(pg)
+            self._notify("PodGroup", "update", pg)
+
+    # holds: _lock
+    def _set_pod_group(self, pg: PodGroup) -> None:
+        self.pod_groups[pg.uid] = pg
+        pc = self._priority_class_of(pg)
+        if self._skip_objects():
+            # What the rebuild will give the job.
+            priority = pc.value if pc is not None else 0
+        else:
             job = self._get_or_create_job(pg.uid)
             job.set_pod_group(pg)
-            if pg.priority_class and pg.priority_class in self.priority_classes:
-                job.priority = self.priority_classes[pg.priority_class].value
-            self.mirror.upsert_pod_group(pg, job.priority)
-            self._notify("PodGroup", "update", pg)
+            if pc is not None:
+                job.priority = pc.value
+            priority = job.priority
+        self.mirror.upsert_pod_group(pg, priority)
 
     def delete_pod_group(self, uid: str) -> None:
         with self._lock:
             self.pod_groups.pop(uid, None)
-            job = self.jobs.get(uid)
+            job = None if self._skip_objects() else self.jobs.get(uid)
             if job is not None:
                 job.unset_pod_group()
                 if not job.tasks:
@@ -997,12 +1059,15 @@ class ClusterStore:
         never mutated, so snapshot TaskInfos holding the old Pod keep
         their point-in-time view.  Re-indexes the job task sets and the
         mirror; returns the new record.  Caller holds the lock."""
-        self._remove_task(pod)
+        fresh = not self._skip_objects()
+        if fresh:
+            self._remove_task(pod)
         pod = copy.copy(pod)
         for name, value in mutations.items():
             setattr(pod, name, value)
         self.pods[pod.uid] = pod
-        self._add_task(pod)
+        if fresh:
+            self._add_task(pod)
         self.mirror.upsert_pod(pod, self.mirror.job_row)
         return pod
 

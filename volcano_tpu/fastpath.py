@@ -772,6 +772,7 @@ class FastCycle:
             "mut_at_dispatch": None, "mut_at_commit": None,
             "epoch_at_dispatch": None, "epoch_at_commit": None,
             "device_events": [],
+            "object_model": store.take_object_model_counts(),
         }
         # devsnap's running upload counters as the cycle finds them;
         # the record's ``solve`` carries this cycle's deltas.
@@ -1062,6 +1063,7 @@ class FastCycle:
                 pool=st.get("pool"),
                 anomalies=[a.to_dict() for a in anoms],
                 solve=self._solve_record(),
+                object_model=st["object_model"],
             ), stamp=anoms)
 
     def _solve_counts(self) -> Dict[str, object]:

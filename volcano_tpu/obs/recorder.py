@@ -54,7 +54,7 @@ class CycleRecord:
         "committed_solve_id", "mutation_seq_at_dispatch",
         "mutation_seq_at_commit", "epoch_at_dispatch", "epoch_at_commit",
         "device_events", "error", "spans", "rebalance", "whatif",
-        "pool", "anomalies", "solve",
+        "pool", "anomalies", "solve", "object_model",
     )
 
     def __init__(self, session: str = "", path: str = "fast",
@@ -78,7 +78,8 @@ class CycleRecord:
                  whatif: Optional[dict] = None,
                  pool: Optional[dict] = None,
                  anomalies: Optional[List[dict]] = None,
-                 solve: Optional[dict] = None):
+                 solve: Optional[dict] = None,
+                 object_model: Optional[Dict[str, int]] = None):
         self.seq = -1  # assigned by FlightRecorder.record
         self.session = session
         self.path = path
@@ -128,6 +129,13 @@ class CycleRecord:
         # with their bytes.  None when no solve ran (null-delta skip,
         # nothing pending, the object path).
         self.solve = solve
+        # The store's JobInfo/NodeInfo object model as this cycle found
+        # it (``ClusterStore.take_object_model_counts``): ``stale`` 1
+        # when no reader had rebuilt it since the last commit, and
+        # ``stale_events``, the store events since the previous cycle
+        # that therefore left it alone.  None on the object path, which
+        # reads the model.
+        self.object_model = object_model
 
     @property
     def unattributed_s(self) -> float:
@@ -173,6 +181,8 @@ class CycleRecord:
             "anomalies": [dict(a) for a in self.anomalies],
             "solve": (dict(self.solve)
                       if self.solve is not None else None),
+            "object_model": (dict(self.object_model)
+                             if self.object_model is not None else None),
         }
         if include_spans:
             d["spans"] = [s.to_dict() for s in self.spans]
