@@ -205,6 +205,10 @@ class StoreMirror:
         # Total memberships across terms: an O(1) content version for
         # the encode cache (memberships only grow between compactions).
         self.term_members_total = 0
+        # Terms with a member row in the pod table (a tombstoned row
+        # counts until the next compaction); ``len(terms)`` against it
+        # is what the append-only term tables hold of dead gangs.
+        self.terms_live = 0
         self._terms_by_pair: Dict[Tuple[str, str], List[int]] = {}
         self._terms_by_job: Dict[str, List[int]] = {}
         self._terms_all: List[int] = []  # empty-selector terms
@@ -540,7 +544,7 @@ class StoreMirror:
         before = len(self.terms)
         e = self.terms.intern(key)
         if len(self.terms) != before:
-            self.topo_keys.intern(term.topology_key)
+            self._intern_topo_key(term.topology_key)
             sel = dict(term.match_labels)
             self.term_info.append((sel, term.topology_key, set(ns)))
             self.term_members.append([])
@@ -550,20 +554,27 @@ class StoreMirror:
             else:
                 self._terms_all.append(e)
             self._backfill_term(e)
-            self._node_dom_dirty = True
         return e
+
+    def _intern_topo_key(self, topo_key: str) -> None:
+        """The node-domain table has a column per topology key, so only
+        a key it has not seen voids it: a term on a known key (every
+        constrained gang brings one of its own) leaves it alone."""
+        before = len(self.topo_keys)
+        self.topo_keys.intern(topo_key)
+        if len(self.topo_keys) != before:
+            self._node_dom_dirty = True
 
     def _intern_job_term(self, job_id: str, topo_key: str) -> int:
         key = (((JOB_SELECTOR, job_id),), topo_key, None)
         before = len(self.terms)
         e = self.terms.intern(key)
         if len(self.terms) != before:
-            self.topo_keys.intern(topo_key)
+            self._intern_topo_key(topo_key)
             self.term_info.append(({JOB_SELECTOR: job_id}, topo_key, None))
             self.term_members.append([])
             self._terms_by_job.setdefault(job_id, []).append(e)
             self._backfill_term(e)
-            self._node_dom_dirty = True
         return e
 
     def _term_matches(self, e: int, namespace: str, labels: Dict[str, str],
@@ -590,6 +601,7 @@ class StoreMirror:
             )
             members.extend(int(r) for r in rows)
             self.term_members_total += len(rows)
+            self.terms_live += bool(len(rows))
             return
         if sel:
             # Candidates: rows carrying the rarest selector pair.
@@ -610,6 +622,7 @@ class StoreMirror:
             jrow = self.p_job[row]
             juid = self.j_uid[jrow] if jrow >= 0 else ""
             if self._term_matches(e, pod.namespace, pod.labels, juid or ""):
+                self.terms_live += not members
                 members.append(row)
                 self.term_members_total += 1
 
@@ -745,7 +758,9 @@ class StoreMirror:
                 cand.update(self._terms_by_pair.get(kv, ()))
             for e in cand:
                 if self._term_matches(e, pod.namespace, pod.labels, juid):
-                    self.term_members[e].append(row)
+                    members = self.term_members[e]
+                    self.terms_live += not members
+                    members.append(row)
                     self.term_members_total += 1
 
     # holds: _lock
@@ -876,6 +891,7 @@ class StoreMirror:
             if self.n_alive[row]:
                 self.node_liveness_gen += 1
             self.n_alive[row] = False
+            self._node_dom_dirty = True
             # Pods pointing at this node keep their row; their node col is
             # fixed up by the per-cycle liveness mask (n_alive).
             self.epoch += 1
@@ -991,15 +1007,22 @@ class StoreMirror:
         self._pod_dirty_overflow = False
         return rows
 
+    def node_dom_dirty(self) -> bool:
+        """Whether the next ``node_dom()`` rebuilds the table."""
+        return (
+            self._node_dom_dirty
+            or self._node_dom is None
+            or self._node_dom.shape
+            != (len(self.n_name), max(1, len(self.topo_keys)))
+        )
+
     def node_dom(self) -> np.ndarray:
-        """[Nrows, K] topology domain ids (interned, append-only)."""
+        """[Nrows, K] topology domain ids (interned, append-only).
+        A function of the node rows and the topology keys alone: node
+        events and a new key void it, terms and pods do not."""
         K = max(1, len(self.topo_keys))
         N = len(self.n_name)
-        if (
-            not self._node_dom_dirty
-            and self._node_dom is not None
-            and self._node_dom.shape == (N, K)
-        ):
+        if not self.node_dom_dirty():
             return self._node_dom
         dom = np.full((N, K), -1, I)
         for k, key in enumerate(self.topo_keys.items):
@@ -1181,9 +1204,11 @@ class StoreMirror:
                      "j_event_key", "j_topo",
                      "_fabric_vals", "_fabric_blocks",
                      "j_alive", "_pods_ref", "_orphans", "epoch",
-                     "node_liveness_gen"):
+                     "node_liveness_gen",
+                     # the node-domain table is of the nodes and keys
+                     # above, which a pod-table compaction leaves alone
+                     "_node_dom", "_node_dom_dirty"):
             setattr(fresh, attr, getattr(old, attr))
-        fresh._node_dom_dirty = True
         if hasattr(old, "_node_csr_row"):
             fresh._node_csr_row = old._node_csr_row
         remap = np.full(total, -1, I)
@@ -1242,6 +1267,9 @@ class StoreMirror:
         ]
         fresh.term_members_total = sum(
             len(members) for members in fresh.term_members
+        )
+        fresh.terms_live = sum(
+            1 for members in fresh.term_members if members
         )
         fresh._pods_by_pair = {
             kv: [int(remap[r]) for r in rows if remap[r] >= 0]

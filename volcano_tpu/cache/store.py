@@ -227,6 +227,14 @@ class ClusterStore:
         # (class-table sig, profile generation, cnt0 hash) assembled by
         # FastCycle._devincr_prepare.  Cycle-thread only, under _lock.
         self._devincr_cache = None  # guarded-by: _lock (any-receiver)
+        # High-water marks of the solve's data-dependent shape buckets
+        # (ops/wave.settle: padded terms, profile rows, profiles and
+        # terms per wave, sparse entry lists), so that the shapes a
+        # round's terms give the jitted programs do not move between
+        # rounds.  Cycle-thread only, under _lock; for the store's
+        # life, but for a device memory exhaustion, which drops them
+        # with the chunk budget (FastCycle._on_device_crash).
+        self._solve_shape_marks: Dict[str, int] = {}  # guarded-by: _lock (any-receiver)
 
         # Migration ledger (actions/rebalance.py MigrationLedger),
         # attached by the rebalance lane's first committed plan; the
@@ -623,7 +631,15 @@ class ClusterStore:
         the last cycle took the count."""
         with self._lock:
             n, self._stale_events_cycle = self._stale_events_cycle, 0
-            return {"stale": int(self._objects_stale), "stale_events": n}
+            out = {"stale": int(self._objects_stale), "stale_events": n}
+            m = self.mirror
+            if len(m.terms):
+                # The mirror's inter-pod term tables are append-only:
+                # every term ever interned against those that still
+                # have a member row (absent while none was interned).
+                out["terms_interned"] = len(m.terms)
+                out["terms_live"] = int(m.terms_live)
+            return out
 
     def _rebuild_objects(self) -> None:
         """Recompute the JobInfo/NodeInfo object model from pods + pod

@@ -35,6 +35,7 @@ via ``actions/allocate.py``).
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import time
@@ -1079,8 +1080,38 @@ class FastCycle:
                 "devincr_mode": None, "dirty_nodes": None,
                 "arg_puts": 0, "arg_put_bytes": 0,
                 "fetches": 0, "fetch_bytes": 0,
+                "aff_rows": 0, "aff_terms": 0, "aff_terms_padded": 0,
+                "aff_domains": 0, "aff_chunks": 0, "aff_device_bytes": 0,
+                "shortlist_fb_affinity": 0, "shortlist_fb_exhausted": 0,
             }
         return sc
+
+    def _count_affinity(self, task_rows: np.ndarray, E: int, Ep: int,
+                        Np: int) -> None:
+        """What one solve's inter-pod terms give the device (the
+        ``aff_*`` solve counts; all 0 in a cycle without terms): the
+        pending rows that carry a term, the active terms and their
+        padded bucket, the topology domains, and the bytes the
+        ``has_aff`` branch of ``_solve_wave`` holds for them, reckoned
+        from shapes: the two ``[Ep + 1, D]`` int32 count tensors and,
+        where it is on (``DOM_MM_MAX_MB``), the ``[N, D]`` float32
+        domain one-hot.  The largest solve of the cycle is kept."""
+        from .ops.wave import DOM_MM_MAX_MB
+
+        if getattr(self, "stats", None) is None:
+            return  # a bare FastCycle outside run() (tests) records nothing
+        m = self.m
+        D = max(1, len(m.domains))
+        nbytes = 2 * (Ep + 1) * D * 4
+        if D * Np * 4 <= DOM_MM_MAX_MB * 1_000_000:
+            nbytes += Np * D * 4
+        sc = self._solve_counts()
+        sc["aff_rows"] = max(sc["aff_rows"], int(
+            np.count_nonzero(m.p_has_ip[task_rows])))
+        sc["aff_terms"] = max(sc["aff_terms"], int(E))
+        sc["aff_terms_padded"] = max(sc["aff_terms_padded"], int(Ep))
+        sc["aff_domains"] = max(sc["aff_domains"], D)
+        sc["aff_device_bytes"] = max(sc["aff_device_bytes"], nbytes)
 
     def _count_dispatch(self, rows: int, args, jobs) -> None:
         """One solve handed to the device: its rows, its ``jobs`` (how
@@ -1153,6 +1184,9 @@ class FastCycle:
         self.stats["shortlist_fallbacks"] = (
             int(self.stats.get("shortlist_fallbacks", 0))
             + exhausted + affinity)
+        sc = self._solve_counts()
+        sc["shortlist_fb_exhausted"] += int(exhausted)
+        sc["shortlist_fb_affinity"] += int(affinity)
 
     def _record_pool_fetch(self) -> None:
         """Fold the solver pool's last-fetch info (winning replica,
@@ -1507,6 +1541,10 @@ class FastCycle:
         dvc = getattr(store, "_devincr_cache", None)
         if dvc is not None:
             dvc.invalidate()
+        # The shape buckets' high-water marks would hold the retry's
+        # smaller chunks to the tensors that did not fit.
+        store._solve_shape_marks.clear()
+        store._encode_cache = None
         log.error(
             "device memory exhausted mid-solve (%s); halving affinity "
             "chunk budget to %.3gx and resuming the cycle", e, scale,
@@ -1607,8 +1645,16 @@ class FastCycle:
             never_any = False
             try:
                 with tracer.span("solve_prep", lanes=lanes):
-                    chunks = list(
-                        self._solve_chunks(solve_jobs, task_rows))
+                    # ``solve_prep:chunks`` (child): sizing the count
+                    # tensors against the budget; only a store that has
+                    # interned an inter-pod term records it.
+                    with (tracer.span("solve_prep:chunks") if len(self.m.terms)
+                          else contextlib.nullcontext()) as sp:
+                        chunks = list(
+                            self._solve_chunks(solve_jobs, task_rows))
+                        if self._chunks_had_terms:
+                            self._solve_counts()["aff_chunks"] += len(chunks)
+                            sp.args = {"chunks": len(chunks)}
                     remote = self._remote_solver
                     from .parallel.mesh import mesh_from_env
 
@@ -1672,7 +1718,8 @@ class FastCycle:
                                 payload = solve_fn(
                                     *inputs, pid=pid, profiles=profiles,
                                     taint_any=self._taint_any,
-                                    node_classes=ncls, devincr=dv)
+                                    node_classes=ncls, devincr=dv,
+                                    shape_marks=store._solve_shape_marks)
                                 self._record_twophase_lanes()
                             # Start the device->host transfer now; the
                             # fetch at the next cycle's top only waits
@@ -1857,7 +1904,8 @@ class FastCycle:
                 mesh, inputs, pid, profiles, ncls, devincr=dv)
         result = solve_fn(*inputs, pid=pid, profiles=profiles,
                           taint_any=self._taint_any,
-                          node_classes=ncls, devincr=dv)
+                          node_classes=ncls, devincr=dv,
+                          shape_marks=self.store._solve_shape_marks)
         self._record_twophase_lanes()
         return result
 
@@ -2682,7 +2730,16 @@ class FastCycle:
         # chunks — shipping an [E, D~N] int32 pair (6.5 GB at
         # 50k x 500k) that exhausts a 16 GB chip's memory.
         if E:
-            m.node_dom()
+            # ``solve_prep:node_dom`` (child): the rebuild of a dirty
+            # node-domain table, the cycle's first reader of which is
+            # this call (a Python walk over every node and key).
+            with (self.tracer.span("solve_prep:node_dom")
+                  if m.node_dom_dirty() else contextlib.nullcontext()) as sp:
+                dom = m.node_dom()
+                if sp is not None:
+                    sp.args = {"nodes": int(dom.shape[0]),
+                               "keys": int(dom.shape[1]),
+                               "domains": len(m.domains)}
         D = max(1, len(m.domains))
         # Two int32 [Ep, D] tensors; budget against the solver's actual
         # padded bucket (headroom + pow2 round-up reaches 2.5x raw).
@@ -3392,9 +3449,13 @@ class FastCycle:
         q_alloc[:self.Qn] = self.q_alloc
         queues = SolveQueues(deserved=deserved, allocated=q_alloc)
 
-        aff, pid, profiles = self._affinity_and_profiles(
-            task_rows, None if slim else tasks, Np
-        )
+        # ``encode:affinity`` (child of ``encode``): only a store that
+        # has interned an inter-pod term records it.
+        with (self.tracer.span("encode:affinity") if len(m.terms)
+              else contextlib.nullcontext()):
+            aff, pid, profiles = self._affinity_and_profiles(
+                task_rows, None if slim else tasks, Np
+            )
         weights = self._score_weights()
         # Device-incremental key inputs (ISSUE 9): the class-table
         # content signature (or the identity marker — epoch-keyed) and
@@ -3506,6 +3567,7 @@ class FastCycle:
                             cached["profiles"])
                 term_key = cached["term_key"]
                 Ep = cached["Ep"]
+                self._count_affinity(task_rows, E, Ep, Np)
                 cnt0 = self._term_cnt0(cached["members"], term_key, Ep)
                 node_dom_raw = m.node_dom()
                 node_dom = np.full((Np, K), -1, I)
@@ -3569,9 +3631,13 @@ class FastCycle:
 
         term_local = np.full(len(m.terms), -1, np.int64)
         term_local[active] = np.arange(E)
-        from .ops.wave import bucket_pow2
+        from .ops.wave import settled_pow2
 
-        Ep = bucket_pow2(E, floor=1)
+        # The padded term count keeps its high-water mark: a round's
+        # active terms are a draw, and a bucket taken anew from each
+        # draw flips at a power of two and lowers the solve again.
+        Ep = settled_pow2(self.store._solve_shape_marks, "Ep", E, floor=1)
+        self._count_affinity(task_rows, E, Ep, Np)
 
         # ---- sparse membership hash + per-term local membership ---------
         rng = np.random.RandomState(0x7A5E)

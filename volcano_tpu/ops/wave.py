@@ -85,6 +85,7 @@ from .nodeclass import NodeClasses
 from .resreq import less_equal
 from .scoring import ScoreWeights, node_score
 
+import math as _math
 import os as _os
 import time as _time
 
@@ -1083,8 +1084,8 @@ def _solve_wave(
             p_t_soft = esl(prof.t_soft[pids])
             t_matches_w = p_t_matches[pid_l]  # [W, EW]
             # Terms some wave profile REQUIRES (affinity or anti): the
-            # conflict machinery and the dirty tracking both key off
-            # this set (soft-only spread terms never feed either).
+            # conflict machinery keys off this set (soft-only spread
+            # terms never feed it).
             term_req_w = jnp.any(p_t_req_aff | p_t_req_anti, axis=0)
 
 
@@ -1584,14 +1585,14 @@ def _solve_wave(
             # feasibility depends on count state that live_parts refreshes
             # per attempt.
             def sub_cond(sc):
-                (_s, _cwa, _cwp, _fk, _dirty, done_sub, _al, _aw, _pw, si,
+                (_s, _cwa, _cwp, _fk, done_sub, _al, _aw, _pw, si,
                  progressed, _cch) = sc
                 return progressed & (si < SUBROUNDS) & jnp.any(
                     cand & ~done_sub & ~aborted
                 )
 
             def sub_body(sc):
-                (s_, cw_a_, cw_p_, feas_k, _dirty, done_sub, alloc_l_,
+                (s_, cw_a_, cw_p_, feas_k, done_sub, alloc_l_,
                  assigned_w_, pipelined_w_, si, _progressed,
                  cnt_changed) = sc
                 cand_s = cand & ~done_sub & ~aborted
@@ -2071,30 +2072,18 @@ def _solve_wave(
                 assigned_w_ = jnp.where(acc_alloc, choice, assigned_w_)
                 pipelined_w_ = jnp.where(acc_pipe, choice, pipelined_w_)
                 resolved = acc_alloc | acc_pipe
-                # dirty_next has no reader in the loop: the slot stays so
-                # that the traced program is the one the cells were
-                # measured with (ROADMAP, named debts).
-                if has_aff:
-                    giver_rel = jnp.any(
-                        t_matches_w & term_req_w[None, :], axis=1
-                    )
-                    dirty_next = jnp.any(
-                        resolved & (involved_any_t | giver_rel)
-                    )
-                else:
-                    dirty_next = jnp.bool_(False)
                 return (
-                    s_, cw_a_, cw_p_, feas_k, dirty_next,
+                    s_, cw_a_, cw_p_, feas_k,
                     done_sub | resolved, alloc_l_,
                     assigned_w_, pipelined_w_, si + 1, jnp.any(resolved),
                     cnt_changed,
                 )
 
-            (s, cw_a, cw_p, _fk, _dirty, done_sub, alloc_l, assigned_w,
+            (s, cw_a, cw_p, _fk, done_sub, alloc_l, assigned_w,
              pipelined_w, subs, _prog, cnt_changed_out) = (
                 jax.lax.while_loop(
                     sub_cond, sub_body,
-                    (s, cw_a, cw_p, feas_k_att, jnp.bool_(False), done,
+                    (s, cw_a, cw_p, feas_k_att, done,
                      alloc_l, assigned_w, pipelined_w, jnp.int32(0),
                      jnp.bool_(True), jnp.bool_(False)),
                 )
@@ -2395,19 +2384,56 @@ def _profiles_from_pid(tasks: SolveTasks, aff: AffinityArgs,
     return profiles, pid
 
 
-def bucket_pow2(n: int, floor: int, min_pad: int = 8) -> int:
+def bucket_pow2(n: int, floor: int, min_pad: int = 8, room: int = 0) -> int:
     """Anti-recompile shape bucket: next power of two >= n plus 25%
     headroom (raw counts clustering at a power of two must not flip
     buckets cycle-to-cycle — each flip is a multi-second XLA recompile).
-    ``floor`` bounds the smallest bucket per axis."""
-    target = n + max(n // 4, min_pad)
+    ``floor`` bounds the smallest bucket per axis; ``room`` asks for
+    more headroom than the quarter where that is larger."""
+    target = n + max(n // 4, min_pad, room)
     b = max(floor, 1)
     while b < target:
         b *= 2
     return b
 
 
-def _pad_profiles_rows(profiles: SolveProfiles) -> SolveProfiles:
+def draw_headroom(n: int) -> int:
+    """Room for the next draw of a count that is one: a round's terms
+    (and with them its constrained profiles) are a binomial draw of its
+    gangs, so two rounds of the same traffic differ by a few sqrt(n).
+    Five of them cover a window of rounds against the warm-up round's
+    draw; past n = 400 ``bucket_pow2``'s own quarter is the larger."""
+    return 5 * _math.isqrt(max(int(n), 0))
+
+
+def settle(marks: Optional[dict], axis: str, n: int, fresh: int) -> int:
+    """Shape bucket of ``axis`` kept at its high-water mark in ``marks``
+    (one dict per store, for the store's life; None: no memory, the
+    bucket is ``fresh``).  A count that fits the bucket it once got
+    keeps it, so the shapes a round's terms give the jitted programs
+    do not move between rounds of the same traffic: a bucket taken anew
+    from every round's draw crosses a power of two at random, and each
+    crossing lowers ``_static_planes``, ``_coarse_shortlist`` and
+    ``_solve_wave`` again.  ``fresh`` is the bucket ``n`` would get on
+    its own (headroom included); it is taken only when ``n`` outgrows
+    the mark."""
+    if marks is None:
+        return fresh
+    have = marks.get(axis)
+    if have is None or n > have:
+        marks[axis] = have = fresh
+    return have
+
+
+def settled_pow2(marks: Optional[dict], axis: str, n: int, floor: int,
+                 min_pad: int = 8) -> int:
+    """``bucket_pow2`` of a count that is a draw (``draw_headroom``),
+    kept at its high-water mark (``settle``)."""
+    return settle(marks, axis, n, bucket_pow2(
+        n, floor, min_pad, room=draw_headroom(n)))
+
+
+def _pad_profiles_rows(profiles: SolveProfiles, marks=None) -> SolveProfiles:
     """Pad the profile table's row axis to a power of two (min 64) with
     inert zero rows.  The row count is data-dependent (distinct task
     profiles this cycle); unpadded it changes shape almost every cycle
@@ -2415,7 +2441,7 @@ def _pad_profiles_rows(profiles: SolveProfiles) -> SolveProfiles:
     dwarfing the solve itself.  Padded rows are never referenced: pid and
     wave_prof only index real rows."""
     U = int(_np(profiles.req).shape[0])
-    pad = bucket_pow2(U, floor=64) - U
+    pad = settled_pow2(marks, "U", U, floor=64) - U
     if pad == 0:
         return profiles
     def z(a):
@@ -2429,7 +2455,8 @@ def _pad_profiles_rows(profiles: SolveProfiles) -> SolveProfiles:
 
 def _term_windows(profiles: SolveProfiles, aff: AffinityArgs,
                   pid: np.ndarray, wave_prof: np.ndarray, n_waves: int,
-                  skip_cnt0: bool = False, skip_prof: bool = False):
+                  skip_cnt0: bool = False, skip_prof: bool = False,
+                  marks=None):
     """Per-wave lists of the affinity terms the wave's profiles reference.
 
     Every [*, E] tensor in the kernel is gathered down to the wave's term
@@ -2486,7 +2513,7 @@ def _term_windows(profiles: SolveProfiles, aff: AffinityArgs,
         terms = np.flatnonzero(iom[pids].any(axis=0))
         term_lists.append(terms)
         ew = max(ew, len(terms))
-    EW = bucket_pow2(ew, floor=16, min_pad=4)
+    EW = settled_pow2(marks, "EW", ew, floor=16, min_pad=4)
     wave_terms = np.full((n_waves, EW), E, np.int32)  # pad = dummy row
     for w, terms in enumerate(term_lists):
         wave_terms[w, :len(terms)] = terms
@@ -2508,7 +2535,8 @@ def _term_windows(profiles: SolveProfiles, aff: AffinityArgs,
     return profiles, aff, wave_terms, int(EW), iom, terms_disjoint
 
 
-def _wave_profiles(pid: np.ndarray, n_waves: int, wave: int):
+def _wave_profiles(pid: np.ndarray, n_waves: int, wave: int, marks=None,
+                   has_terms: bool = False):
     """Per-wave lists of the profiles actually PRESENT in each wave.
 
     Shared profiles recur across the whole task list, so id *ranges* per
@@ -2527,9 +2555,13 @@ def _wave_profiles(pid: np.ndarray, n_waves: int, wave: int):
         u = np.unique(seg[w])
         lists.append(u)
         um = max(um, len(u))
-    UM = 1
-    while UM < um:
-        UM *= 2
+    # Under terms every constrained gang is a profile of its own, so
+    # the count is a draw like the terms' (draw_headroom); without
+    # them it is the cluster's few request shapes.
+    fresh = 1
+    while fresh < um + (draw_headroom(um) if has_terms else 0):
+        fresh *= 2
+    UM = settle(marks, "UM", um, fresh)
     wave_prof = np.zeros((n_waves, UM), np.int32)
     for w, u in enumerate(lists):
         wave_prof[w, :len(u)] = u
@@ -2634,6 +2666,7 @@ def solve_wave(
     node_classes: NodeClasses = None,
     mesh_shards: int = 1,
     devincr=None,
+    shape_marks: Optional[dict] = None,
 ) -> AllocResult:
     """Wave-batched solve; same signature/result as ``allocate.solve``.
 
@@ -2676,6 +2709,11 @@ def solve_wave(
     Results are bit-for-bit equal to ``devincr=None``; custom-plugin
     solves (``extra_ok``/``extra_score``) and non-two-phase solves
     ignore the context.
+
+    ``shape_marks`` (optional dict, the caller's for its store's life)
+    keeps every data-dependent shape bucket of this call (profile rows,
+    profiles and terms per wave, sparse entry lists) at its high-water
+    mark (``settle``); padding is inert, so results do not depend on it.
     """
     P = int(tasks.job.shape[0])
     if (extra_ok is not None or extra_score is not None) and (
@@ -2725,7 +2763,11 @@ def solve_wave(
             tasks, aff, extra_ok, extra_score
         )
     u_before = int(_np(profiles.req).shape[0])
-    profiles = _pad_profiles_rows(profiles)
+    has_terms = bool(
+        _np(profiles.t_req_aff).any() or _np(profiles.t_req_anti).any()
+        or _np(profiles.t_soft).any()
+    )
+    profiles = _pad_profiles_rows(profiles, shape_marks)
     u_pad = int(_np(profiles.req).shape[0]) - u_before
     if extra_ok is not None:
         if u_pad:
@@ -2742,7 +2784,7 @@ def solve_wave(
             ])
     else:
         score_prof = np.zeros((1, 1), np.float32)
-    wave_prof = _wave_profiles(pid, n_waves, wave)
+    wave_prof = _wave_profiles(pid, n_waves, wave, shape_marks, has_terms)
     # Input diet for the device call: the kernel reads only job/real
     # per-task (req/init_req come from profile gathers), so every other
     # per-task field ships as a [1, ...] dummy, and the three [P] id
@@ -2788,12 +2830,7 @@ def solve_wave(
         cnt0_any = bool(cnt0_host.any())
     features = (
         bool(_np(profiles.ports).any()),
-        bool(
-            _np(profiles.t_req_aff).any()
-            or _np(profiles.t_req_anti).any()
-            or _np(profiles.t_soft).any()
-            or cnt0_any
-        ),
+        has_terms or cnt0_any,
         # Device-resident callers (ops/devsnap.py, the mesh plane cache)
         # pass the taint feature as a host-computed hint — fetching a
         # persistent device plane back just to .any() it would put a
@@ -2814,6 +2851,7 @@ def solve_wave(
         _term_windows(
             profiles, aff, pid, wave_prof, n_waves,
             skip_cnt0=cnt0_sparse, skip_prof=prof_sparse,
+            marks=shape_marks,
         )
     )
     # Profile-term tables ([U, Ep] bool x3 + f32) reach ~75 MB at the
@@ -2837,7 +2875,7 @@ def solve_wave(
             | (t_mat_h[ur, ec].astype(np.int8) << 2)
         )
         soft_vals = t_soft_h[ur, ec].astype(np.float32)
-        k = bucket_pow2(len(ur), floor=16)
+        k = settled_pow2(shape_marks, "prof_entries", len(ur), floor=16)
         ppad = k - len(ur)
         if ppad:
             ur = np.concatenate([ur, np.zeros(ppad, np.int64)])
@@ -2879,7 +2917,8 @@ def solve_wave(
         # scatter them on device — into the dummy-row-extended shape —
         # instead of uploading (and host-copying) the dense zeros.
         vals_nz = cnt0_host[rows_nz, cols_nz].astype(np.int32)
-        k = bucket_pow2(len(rows_nz), floor=16)
+        k = settled_pow2(shape_marks, "cnt0_entries", len(rows_nz),
+                         floor=16)
         cpad = k - len(rows_nz)
         if cpad:
             # Padded entries add 0 to cell (0, 0): a no-op.
