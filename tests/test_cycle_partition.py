@@ -23,7 +23,7 @@ from volcano_tpu.synth import synthetic_cluster
 
 pytestmark = pytest.mark.tier1
 
-NEW_LANES = {"prologue", "inflight", "solve_prep", "journey",
+NEW_LANES = {"prologue", "inflight", "solve_prep",
              "bind_handoff", "audit", "record", "gc"}
 OLD_LANES = {"derive", "order", "encode", "device", "commit", "close"}
 
@@ -107,6 +107,10 @@ def test_every_new_lane_is_present_beside_the_old_ones():
     assert NEW_LANES <= set(rec.lanes)
     assert OLD_LANES <= set(rec.lanes)
     assert rec.pods_bound == 32
+    # The ``dispatched`` stamp is no lane (ISSUE 32): it runs inside
+    # ``device``, in the wait for the solve.
+    assert "journey" not in rec.lanes
+    assert not [s for s in rec.spans if s.name == "journey"]
 
 
 def test_lanes_sum_to_at_most_the_duration_and_the_record_states_the_rest():
@@ -271,12 +275,22 @@ def test_device_children_have_the_device_span_as_parent_and_fit_in_it():
     assert devices and all(s.lane == "device" for s in devices)
     for dev in devices:
         kids = _children(rec, dev)
-        assert [k.name for k in sorted(kids, key=lambda k: k.ts_ns)] == [
-            "device:dispatch", "device:host_prep", "device:fetch",
-            "device:gate"]
+        kids = sorted(kids, key=lambda k: k.ts_ns)
+        assert [k.name for k in kids] == [
+            "device:dispatch", "device:journey", "device:host_prep",
+            "device:fetch", "device:gate"]
         assert all(k.lane is None for k in kids)
         assert sum(k.dur_ns for k in kids) <= dev.dur_ns
         assert dev.args["rows"] > 0
+        # The ``dispatched`` stamp (ISSUE 32): after the programs are
+        # enqueued, before the host waits for them, inside the lane.
+        stamp = kids[1]
+        assert stamp.args == {"rows": dev.args["rows"],
+                              "fresh": dev.args["rows"]}
+        assert kids[0].ts_ns + kids[0].dur_ns <= stamp.ts_ns
+        assert stamp.ts_ns + stamp.dur_ns <= kids[3].ts_ns
+        assert dev.ts_ns <= stamp.ts_ns
+        assert stamp.ts_ns + stamp.dur_ns <= dev.ts_ns + dev.dur_ns
     # The dispatch legs are lanes of their own inside ``device`` (the one
     # nested pair), timed in ops/wave.py, and fit in the dispatch span.
     dispatch_s = sum(s.dur_ns for s in rec.spans

@@ -522,7 +522,27 @@ def _sums(h):
     return {k: v[1] for k, v in h.data.items()}
 
 
-def _drive(monkeypatch, name, budget):
+def _stamp_by_slot(jr, column, uids, kind, kw):
+    """A batch as the fast cycle stamps its rows (ISSUE 32,
+    ``fastpath._journey_rows``): the ``dispatched`` stamp leaves each
+    pod's slot in a column, the ``bound`` stamp reads the column and
+    looks up no uid but those the column does not know.  ``column`` is
+    keyed by uid here, where the cycle's is by mirror row; a row without
+    a uid (tombstoned) drops out before either."""
+    live = [u for u in uids if u]
+    if kind == "dispatched":
+        column.update(zip(live, jr.pod_rows(live, kind, **kw).tolist()))
+    elif kind == "bound":
+        sl = np.fromiter((column.get(u, -1) for u in live), np.int64,
+                         len(live))
+        jr.pod_slots(sl, live[len(live) - min(len(live), jr.capacity):],
+                     kind, miss_uids=[u for u, s in zip(live, sl) if s < 0],
+                     **kw)
+    else:
+        jr.pod_rows(uids, kind, **kw)
+
+
+def _drive(monkeypatch, name, budget, by_slot=False):
     build, cap = SCENARIOS[name]
     ops = build(random.Random(f"{name}/{budget}"))
     clock = Clock()
@@ -536,6 +556,7 @@ def _drive(monkeypatch, name, budget):
     jr._metrics = reg = Metrics()
     ref = RefJourney(cap, jr._anchor_ns, ref_slo)
     uids = set()
+    column = {}
     for op in ops:
         now = clock.t - jr._anchor_ns
         if op[0] == "tick":
@@ -544,6 +565,13 @@ def _drive(monkeypatch, name, budget):
             jr.pod_event(op[1], op[2], **op[3])
             ref.apply(op[1], op[2], now, **op[3])
             uids.add(op[1])
+            if op[2] == "removed":  # the mirror tombstones the row
+                column.pop(op[1], None)
+        elif op[0] == "rows" and by_slot:
+            _stamp_by_slot(jr, column, op[1], op[2], op[3])
+            for u in op[1]:
+                ref.apply(u, op[2], now, **op[3])
+            uids.update(u for u in op[1] if u)
         elif op[0] == "rows":
             jr.pod_rows(op[1], op[2], **op[3])
             for u in op[1]:
@@ -565,11 +593,17 @@ def _drive(monkeypatch, name, budget):
     return jr, ref, reg, auditor, sorted(uids), ops
 
 
+@pytest.mark.parametrize("by_slot", [False, True], ids=["uids", "slots"])
 @pytest.mark.parametrize("budget", [False, True],
                          ids=["no-budget", "ttb-budget"])
 @pytest.mark.parametrize("name", list(SCENARIOS))
-def test_batch_path_equals_per_event_reference(monkeypatch, name, budget):
-    jr, ref, reg, auditor, uids, ops = _drive(monkeypatch, name, budget)
+def test_batch_path_equals_per_event_reference(monkeypatch, name, budget,
+                                               by_slot):
+    """``slots``: every ``bound`` batch goes through ``pod_slots`` with
+    the slots its pods' ``dispatched`` stamps returned (ISSUE 32), and
+    leaves what the uid path and the per-event log leave."""
+    jr, ref, reg, auditor, uids, ops = _drive(monkeypatch, name, budget,
+                                              by_slot)
     assert uids and any(op[0] == "rows" for op in ops)
 
     for uid in uids + ["never-seen"]:
@@ -579,6 +613,7 @@ def test_batch_path_equals_per_event_reference(monkeypatch, name, budget):
     got = jr.stats()
     for key in ("capture_ms", "bulk_calls", "bulk_events", "scalar_events"):
         got.pop(key)
+    slot_hits = got.pop("slot_hits")
     assert got == ref.stats()
     assert jr.trace_rows() == list(ref.ring)
     assert [(a.reason, a.detail) for a in jr.conservation_check(
@@ -603,6 +638,12 @@ def test_batch_path_equals_per_event_reference(monkeypatch, name, budget):
     assert st["bulk_events"] + st["scalar_events"] == st["events"]
     assert st["bulk_events"] == sum(
         sum(1 for u in op[1] if u) for op in ops if op[0] == "rows")
+    # Only a ``bound`` batch by slots counts hits, and at most its pods.
+    bound_events = sum(sum(1 for u in op[1] if u) for op in ops
+                       if op[0] == "rows" and op[2] == "bound")
+    assert 0 <= slot_hits <= (bound_events if by_slot else 0)
+    if by_slot and name in ("queues-and-gangs", "batch-larger-than-ring"):
+        assert slot_hits == bound_events    # all dispatched just before
 
 
 def test_edge_kinds_are_not_batch_kinds():
@@ -749,10 +790,11 @@ def test_bound_batch_takes_no_per_event_routine(monkeypatch):
 
 
 def test_cycle_spans_say_how_many_rows_took_the_batch_path():
-    """The ``journey`` lane's span and ``commit:journey`` carry
-    ``args = {"rows", "fresh"}``; ``stats()`` counts the batches.  A
-    re-pend feed makes the second cycle's rows repeats: they fold into
-    bulk counters and never reach the log."""
+    """``device:journey`` carries ``args = {"rows", "fresh"}`` and
+    ``commit:journey`` beside them ``slot_hits``, the rows whose slot
+    the ``dispatched`` stamp had left; ``stats()`` counts the batches.
+    A re-pend feed makes the second cycle's rows repeats: they fold
+    into bulk counters and never reach the log."""
     from volcano_tpu.api import TaskStatus
     from volcano_tpu.scheduler import Scheduler
     from volcano_tpu.synth import synthetic_cluster
@@ -763,15 +805,18 @@ def test_cycle_spans_say_how_many_rows_took_the_batch_path():
     def stamps():
         rec = store.flight.recent()[-1]
         return {s.name: s.args for s in rec.spans
-                if s.name in ("journey", "commit:journey")}
+                if s.name in ("journey", "device:journey",
+                              "commit:journey")}
 
     sched.run_once()
     store.flush_binds()
-    assert stamps() == {"journey": {"rows": 32, "fresh": 32},
-                        "commit:journey": {"rows": 32, "fresh": 32}}
+    assert stamps() == {"device:journey": {"rows": 32, "fresh": 32},
+                        "commit:journey": {"rows": 32, "fresh": 32,
+                                           "slot_hits": 32}}
     st = store.journey.stats()
     assert (st["bulk_calls"], st["bulk_events"]) == (2, 64)
     assert st["scalar_events"] == 32 and st["bound"] == 32
+    assert st["slot_hits"] == 32
 
     def feed(fc):
         m = fc.m
@@ -783,9 +828,382 @@ def test_cycle_spans_say_how_many_rows_took_the_batch_path():
     store.cycle_feed = feed
     sched.run_once()
     store.flush_binds()
-    assert stamps() == {"journey": {"rows": 8, "fresh": 0},
-                        "commit:journey": {"rows": 8, "fresh": 0}}
+    assert stamps() == {"device:journey": {"rows": 8, "fresh": 0},
+                        "commit:journey": {"rows": 8, "fresh": 0,
+                                           "slot_hits": 0}}
     st = store.journey.stats()
     assert (st["bulk_calls"], st["bulk_events"]) == (2, 64)
     assert (st["rebinds"], st["reconsiders"]) == (8, 8)
+    assert st["slot_hits"] == 32
+    store.close()
+
+
+# ------------------------------------- rows keep their slots (ISSUE 32)
+
+
+def test_a_bound_batch_by_slots_looks_no_uid_up(monkeypatch):
+    """20,000 first binds from the slots the ``dispatched`` stamp
+    returned, with the uid -> slot dict refusing every lookup and every
+    one-at-a-time routine patched to raise; only the ring's 1,024 uids
+    are handed over."""
+    n = 20_000
+    jr = JourneyLog(capacity=1024, slo=SLOTracker(),
+                    auditor=Auditor(enabled=False))
+    jr._metrics = Metrics()
+    uids = [f"pod-{i}" for i in range(n)]
+    for i, u in enumerate(uids):
+        jr.pod_event(u, "enqueued", status=ST_PENDING, queue=f"q{i % 3}",
+                     gang=f"g{i // 8}")
+    sl = jr.pod_rows(uids, "dispatched", solve_id=1)
+    assert sl.dtype == np.int64 and len(sl) == n
+    assert sl.tolist() == [jr._slot[u] for u in uids]
+
+    class NoLookups(dict):
+        def get(self, *a):
+            raise AssertionError("uid looked up on the slot path")
+
+        __getitem__ = get
+
+    def boom(*a, **kw):
+        raise AssertionError("per-event routine on the slot path")
+
+    jr._slot = NoLookups(jr._slot)
+    for routine in ("_apply", "_sync_status", "_new_pod", "_resolve"):
+        monkeypatch.setattr(JourneyLog, routine, boom)
+    jr.pod_slots(sl, uids[-1024:], "bound", solve_id=1)
+
+    jr._slot = dict(jr._slot)
+    st = jr.stats()
+    assert st["slot_hits"] == n and st["bound"] == n
+    assert (st["bulk_calls"], st["bulk_events"]) == (2, 2 * n)
+    assert [r["uid"] for r in jr.trace_rows()] == uids[-1024:]
+    assert {r["kind"] for r in jr.trace_rows()} == {"bound"}
+    assert jr.conservation_check(uids) == []
+    with pytest.raises(ValueError):
+        jr.pod_slots(sl, uids, "removed")
+
+
+def test_a_stamp_carries_the_instant_it_is_handed(monkeypatch):
+    """``pod_rows(now=)``: the events, the first consideration and the
+    latency carry the instant taken earlier, not the call's; an instant
+    before the pod's last event marks it as a clock step would."""
+    clock = Clock()
+    monkeypatch.setattr(journey_mod, "time", clock)
+    jr = JourneyLog(capacity=64)
+    jr._metrics = Metrics()
+    for u in ("a", "b"):
+        jr.pod_event(u, "enqueued", status=ST_PENDING, queue="q")
+    clock.t += 7_000_000
+    entered = jr.now()
+    assert entered == clock.t - jr._anchor_ns
+    clock.t += 590_000_000          # the dispatch, and the stamp after it
+    jr.pod_rows(["a"], "dispatched", now=entered)
+    jr.pod_rows(["b"], "dispatched")
+    a, b = jr.timeline("a"), jr.timeline("b")
+    assert a["time_to_first_consider_ms"] == 7.0
+    assert b["time_to_first_consider_ms"] == 597.0
+    assert a["events"][-1]["ts_us"] == round(
+        (jr._anchor_ns + entered) / 1e3, 1)
+    assert b["events"][-1]["ts_us"] - a["events"][-1]["ts_us"] == 590_000.0
+    assert a["monotone"] and b["monotone"]
+    jr.pod_rows(["b"], "evicted", now=entered)     # before b's last event
+    assert jr.timeline("b")["monotone"] is False
+
+
+def _cycle_over(store):
+    from volcano_tpu.fastpath import FastCycle
+    from volcano_tpu.framework import (DEFAULT_SCHEDULER_CONF,
+                                       parse_scheduler_conf)
+
+    return FastCycle(store, parse_scheduler_conf(DEFAULT_SCHEDULER_CONF))
+
+
+def _uid_path_rows(cyc, rows, kind, **kw):
+    """``FastCycle._journey_rows`` as it was before ISSUE 32: first-time
+    rows by the masks, their uids gathered, one ``pod_rows``."""
+    jr = cyc.store.journey
+    n = len(rows)
+    _, considered, bound_seen, _ = cyc._journey_masks()
+    mask = considered if kind == "dispatched" else bound_seen
+    rows = rows[~mask[rows]]
+    mask[rows] = True
+    if n > len(rows):
+        jr.repeat_rows(n - len(rows), kind)
+    if len(rows):
+        jr.pod_rows(map(cyc.m.p_uid.__getitem__, rows.tolist()), kind,
+                    shard=cyc._journey_shard(), **kw)
+
+
+def _seeded_store(monkeypatch, clock):
+    """1,200 pods in gangs of 4 over two queues on a 1,024-event ring;
+    four more pods enter while the journey is detached (adopted
+    mid-life at their first stamp); one pod is deleted (its row a
+    tombstone)."""
+    import itertools
+
+    import volcano_tpu.api.spec as spec
+    from volcano_tpu.api import GROUP_NAME_ANNOTATION, Pod, PodGroup
+    from volcano_tpu.synth import synthetic_cluster
+
+    monkeypatch.setattr(spec, "_uid_counter", itertools.count(1))
+    monkeypatch.setattr(spec, "_ts_counter", itertools.count(1))
+    monkeypatch.setenv("VOLCANO_TPU_JOURNEY_EVENTS", "1024")
+    clock.t = 1_000_000_000
+    store = synthetic_cluster(n_nodes=8, n_pods=1200, gang_size=4,
+                              n_queues=2, seed=32)
+    jr = store.journey
+    assert jr.capacity == 1024
+    store.journey = store.mirror.journey = None
+    store.add_pod_group(PodGroup(name="late", min_member=4))
+    for k in range(4):
+        store.add_pod(Pod(name=f"late-{k}",
+                          annotations={GROUP_NAME_ANNOTATION: "late"},
+                          containers=[{"cpu": "1", "memory": "1Gi"}]))
+    store.journey = store.mirror.journey = jr
+    victim = store.pods[store.mirror.p_uid[17]]
+    store.delete_pod(victim)
+    return store, victim.uid
+
+
+def _journey_facts(store, uids):
+    jr = store.journey
+    stats = jr.stats()
+    for key in ("capture_ms", "slot_hits"):
+        stats.pop(key)
+    return {"timelines": {u: jr.timeline(u) for u in uids},
+            "stats": stats, "rollup": jr.queue_rollup(),
+            "ring": jr.trace_rows()}
+
+
+def test_slot_path_and_uid_path_leave_the_same_journey(monkeypatch):
+    """(a) One seeded batch through ``_journey_rows`` on one store and
+    through the uid path on its twin, on one clock: a row twice, a
+    tombstoned row, pods adopted mid-life, a row the ``dispatched``
+    stamp never saw, a batch larger than the ring.  Field for field."""
+    clock = Clock()
+    monkeypatch.setattr(journey_mod, "time", clock)
+    facts = []
+    for slot_path in (True, False):
+        store, dead_uid = _seeded_store(monkeypatch, clock)
+        m = store.mirror
+        total = len(m.p_uid)
+        assert total == 1204 and m.p_uid[17] is None
+        late = [r for r, u in enumerate(m.p_uid)
+                if u and store.pods[u].name.startswith("late-")]
+        assert len(late) == 4
+        order = np.random.default_rng(32).permutation(total)
+        unseen = int(order[100])
+        assert unseen not in (5, 9, 17) and unseen not in late
+        # Dispatched: every row but one, the tombstone in, row 5 twice.
+        disp = np.concatenate([order[order != unseen], [5]])
+        # Committed: most rows, in another order, and then row 9, the
+        # tombstone and the row no stamp saw (once more, if drawn).
+        bound = np.concatenate([
+            np.random.default_rng(7).permutation(total)[:1150],
+            late, [9, 17, unseen]])
+        stamp = (_cycle_over(store)._journey_rows if slot_path
+                 else lambda *a, **kw: _uid_path_rows(
+                     _cycle_over(store), *a, **kw))
+        with store._lock:
+            clock.t += 3_000_000
+            stamp(disp, "dispatched", solve_id=3)
+            clock.t += 90_000_000
+            args = stamp(bound, "bound", solve_id=3)
+        uids = [u for u in m.p_uid if u] + [dead_uid, "never-seen"]
+        facts.append(_journey_facts(store, uids))
+        if slot_path:
+            # Every live row but the unseen one came with its slot; the
+            # tombstone has no event on either path.
+            live = int(m.p_alive[bound].sum())
+            assert live == len(bound) - int((bound == 17).sum())
+            hits = live - int((bound == unseen).sum())
+            assert args == {"rows": len(bound), "fresh": len(bound),
+                            "slot_hits": hits}
+            assert store.journey.stats()["slot_hits"] == hits
+            # Adopted mid-life: synthetic roots, bound all the same.
+            for r in late:
+                assert facts[0]["timelines"][m.p_uid[r]][
+                    "time_to_bind_ms"] == 90.0
+        store.close()
+    assert facts[0]["ring"] == facts[1]["ring"]
+    assert len(facts[0]["ring"]) == 1024
+    assert facts[0]["stats"] == facts[1]["stats"]
+    assert facts[0]["rollup"] == facts[1]["rollup"]
+    assert facts[0]["timelines"] == facts[1]["timelines"]
+    assert facts[0]["timelines"]["never-seen"] is None
+    # The tombstoned row was not adopted anew: its pod's last events
+    # have left the ring and no state is left of it.
+    assert facts[0]["timelines"][dead_uid] is None
+    assert facts[0]["stats"]["bound"] > 1100
+
+
+def test_a_reused_slot_is_never_bound_through_a_dead_rows_entry(monkeypatch):
+    """(b) A pod deleted after its ``dispatched`` stamp frees its
+    journey slot; the next pod takes that slot, and a new row.  The
+    column still names the slot at the dead row: a ``bound`` stamp over
+    that row stamps nobody, and the newcomer binds through its own row,
+    by uid, as a pod the column does not know."""
+    clock = Clock()
+    monkeypatch.setattr(journey_mod, "time", clock)
+    from volcano_tpu.api import GROUP_NAME_ANNOTATION, Pod, PodGroup
+    from volcano_tpu.synth import synthetic_cluster
+
+    store = synthetic_cluster(n_nodes=8, n_pods=32, gang_size=4, seed=32)
+    jr, m = store.journey, store.mirror
+    rows = np.arange(32)
+    with store._lock:
+        _cycle_over(store)._journey_rows(rows, "dispatched", solve_id=1)
+        column = store._journey_masks[3]
+    victim = store.pods[m.p_uid[11]]
+    slot = jr._slot[victim.uid]
+    assert column[11] == slot
+    clock.t += 1_000_000
+    store.delete_pod(victim)
+    store.add_pod_group(PodGroup(name="next", min_member=1))
+    newcomer = Pod(name="next-0", annotations={GROUP_NAME_ANNOTATION: "next"},
+                   containers=[{"cpu": "1", "memory": "1Gi"}])
+    store.add_pod(newcomer)
+    row = m.p_row[newcomer.uid]
+    assert jr._slot[newcomer.uid] == slot and row == 32
+    assert not m.p_alive[11] and m.p_uid[11] is None
+    with store._lock:
+        column = _cycle_over(store)._journey_masks()[3]
+        assert column[11] == slot and column[row] == -1  # stale, unknown
+        clock.t += 50_000_000
+        args = _cycle_over(store)._journey_rows(rows, "bound", solve_id=1)
+    assert args == {"rows": 32, "fresh": 32, "slot_hits": 31}
+    late = jr.timeline(newcomer.uid)
+    assert late["time_to_bind_ms"] is None and late["status"] == ST_PENDING
+    assert late["last_kind"] == "enqueued"
+    assert [e["kind"] for e in late["events"]] == ["enqueued"]
+    assert jr.stats()["bound"] == 31
+    assert jr.why_pending(newcomer.uid) == "never considered (queue backlog)"
+    with store._lock:
+        clock.t += 2_000_000
+        args = _cycle_over(store)._journey_rows(
+            np.asarray([row, 11]), "bound", solve_id=2)
+    # Row 11 was seen bound above, so only the newcomer is first-time.
+    assert args == {"rows": 2, "fresh": 1, "slot_hits": 0}
+    late = jr.timeline(newcomer.uid)
+    assert late["time_to_bind_ms"] is not None and late["status"] == ST_BOUND
+    assert jr.stats()["bound"] == 32 and jr.stats()["slot_hits"] == 31
+    store.close()
+
+
+def test_another_journey_on_the_store_drops_the_column():
+    """The slots are the attached journey's own: a store handed another
+    ``JourneyLog`` starts the column (and the masks) afresh."""
+    from volcano_tpu.synth import synthetic_cluster
+
+    store = synthetic_cluster(n_nodes=8, n_pods=32, gang_size=4, seed=33)
+    rows = np.arange(32)
+    with store._lock:
+        _cycle_over(store)._journey_rows(rows, "dispatched")
+        assert (store._journey_masks[3] >= 0).all()
+    other = JourneyLog(capacity=64)
+    store.journey = store.mirror.journey = other
+    with store._lock:
+        mk = _cycle_over(store)._journey_masks()
+        assert (mk[3] == -1).all() and not mk[1].any()
+        args = _cycle_over(store)._journey_rows(rows, "bound")
+    assert args == {"rows": 32, "fresh": 32, "slot_hits": 0}
+    assert other.stats()["bound"] == 32 and other.stats()["pods"] == 32
+    store.close()
+
+
+def test_a_compaction_between_two_cycles_drops_the_column():
+    """(c) The column lives and dies with the first-time masks: a
+    compaction renumbers the rows, the next cycle starts both afresh
+    and its own ``dispatched`` stamp fills the column for its rows."""
+    from volcano_tpu.api import GROUP_NAME_ANNOTATION, Pod, PodGroup
+    from volcano_tpu.scheduler import Scheduler
+    from volcano_tpu.synth import synthetic_cluster
+
+    store = synthetic_cluster(n_nodes=8, n_pods=32, gang_size=4, seed=34)
+    sched, m, jr = Scheduler(store), store.mirror, store.journey
+    sched.run_once()
+    store.flush_binds()
+    key, _, bound_seen, column = store._journey_masks
+    assert key == (m.compact_gen, jr) and len(column) == 32
+    assert bound_seen.all() and (column >= 0).all()
+    assert column.tolist() == [jr._slot[u] for u in m.p_uid]
+
+    gen = m.compact_gen
+    store.add_pod_group(PodGroup(name="churn", min_member=1))
+    for k in range(4400):
+        pod = Pod(name=f"churn-{k}",
+                  annotations={GROUP_NAME_ANNOTATION: "churn"},
+                  containers=[{"cpu": "1", "memory": "1Gi"}])
+        store.add_pod(pod)
+        store.delete_pod(pod)
+    assert m.compact_gen > gen and len(m.p_uid) < 4096
+    store.add_pod_group(PodGroup(name="late", min_member=4))
+    for k in range(4):
+        store.add_pod(Pod(name=f"late-{k}",
+                          annotations={GROUP_NAME_ANNOTATION: "late"},
+                          containers=[{"cpu": "1", "memory": "1Gi"}]))
+    sched.run_once()
+    store.flush_binds()
+    rec = store.flight.recent()[-1]
+    stamps = {s.name: s.args for s in rec.spans
+              if s.name in ("device:journey", "commit:journey")}
+    assert stamps == {"device:journey": {"rows": 4, "fresh": 4},
+                      "commit:journey": {"rows": 4, "fresh": 4,
+                                         "slot_hits": 4}}
+    key, considered, bound_seen, column = store._journey_masks
+    assert key == (m.compact_gen, jr)
+    assert len(column) == len(considered) == len(m.p_uid)
+    known = np.flatnonzero(column >= 0)
+    assert sorted(store.pods[m.p_uid[r]].name for r in known) == [
+        f"late-{k}" for k in range(4)]
+    assert column[known].tolist() == [jr._slot[m.p_uid[r]] for r in known]
+    assert considered.sum() == bound_seen.sum() == 4
+    store.close()
+
+
+def test_dispatched_events_carry_the_instant_before_the_dispatch(
+        monkeypatch):
+    """(d) The stamp runs after the programs are enqueued; its events
+    carry the instant the rows entered the solve, taken before."""
+    import time
+
+    from volcano_tpu.fastpath import FastCycle
+    from volcano_tpu.scheduler import Scheduler
+    from volcano_tpu.synth import synthetic_cluster
+
+    store = synthetic_cluster(n_nodes=8, n_pods=32, gang_size=4, seed=35)
+    jr = store.journey
+    seen = {}
+    solve_sync = FastCycle._solve_sync
+
+    def slow_dispatch(self, *a, **kw):
+        seen["before"] = jr.now()
+        time.sleep(0.05)
+        seen["stamped"] = jr.stats()["bulk_events"]
+        try:
+            return solve_sync(self, *a, **kw)
+        finally:
+            seen["after"] = jr.now()
+
+    monkeypatch.setattr(FastCycle, "_solve_sync", slow_dispatch)
+    Scheduler(store).run_once()
+    store.flush_binds()
+    assert seen["stamped"] == 0 and seen["after"] - seen["before"] >= 50e6
+    events = [r for r in jr.trace_rows() if r["kind"] == "dispatched"]
+    assert len(events) == 32 and len({r["ts_us"] for r in events}) == 1
+    at_us = events[0]["ts_us"]
+    assert at_us <= round((jr._anchor_ns + seen["before"]) / 1e3, 1)
+    rec = store.flight.recent()[-1]
+    spans = {s.name: s for s in rec.spans}
+    stamp, dispatch = spans["device:journey"], spans["device:dispatch"]
+    assert dispatch.dur_ns >= 50e6
+    assert stamp.ts_ns >= dispatch.ts_ns + dispatch.dur_ns
+    # On the tracer's clock the events lie before the dispatch span ends
+    # by at least the dispatch, though the stamp began after it.
+    assert at_us * 1e3 <= stamp.ts_ns - 50e6 + 1e6
+    for uid in list(store.pods)[:4]:
+        t = jr.timeline(uid)
+        assert t["monotone"] is True
+        assert [e["kind"] for e in t["events"]] == [
+            "enqueued", "dispatched", "bound"]
     store.close()

@@ -255,6 +255,9 @@ class FastCycle:
         # annotation factory is handed in here (vc:<lane> annotations,
         # inert unless a profiler trace runs).
         self.tracer = tracer_of(store, annotate=TraceAnnotation)
+        # The last ``dispatched`` stamp's (rows, uids), for the commit
+        # of the same rows (``_journey_bound``).
+        self._journey_uids = None
 
     # --------------------------------------------------------- eligibility
 
@@ -956,26 +959,34 @@ class FastCycle:
 
     def _journey_masks(self):
         """First-time row masks for the journey's steady-state bulk
-        accounting (obs/journey.py): the feed re-pends and re-binds the
+        accounting (obs/journey.py), and beside them the journey slot of
+        each row, as its ``dispatched`` stamp resolved it (-1 = not
+        known): the feed re-pends and re-binds the
         SAME backlog rows every cycle.  The masks remember which rows
         already recorded their first consideration / first bind, so a
         repeat costs neither the gather of its uid nor the journey's
-        uid lookup and folds into a bulk counter.  Row indices
-        are stable for a pod's lifetime; a compaction renumbers them,
-        so the masks are keyed on ``compact_gen`` and rebuilt on a
+        uid lookup and folds into a bulk counter; the slots let the
+        ``bound`` stamp skip both for the rows it does record.  Row
+        indices are stable for a pod's lifetime; a compaction renumbers
+        them, so all three are keyed on ``compact_gen`` and rebuilt on a
         bump (uid-keyed journey state survives; only the first-seen
-        memo resets, costing one re-record per live pod)."""
+        memo resets, costing one re-record per live pod).  Slots are the
+        attached journey's own, so another journey on the store drops
+        them too."""
         m = self.m
         n = len(m.p_uid)
+        key = (m.compact_gen, self.store.journey)
         mk = getattr(self.store, "_journey_masks", None)
-        if mk is None or mk[0] != m.compact_gen:
+        if mk is None or mk[0] != key:
             mk = self.store._journey_masks = (
-                m.compact_gen, np.zeros(n, bool), np.zeros(n, bool))
+                key, np.zeros(n, bool), np.zeros(n, bool),
+                np.full(n, -1, np.int64))
         elif len(mk[1]) < n:
-            grow = lambda a: np.concatenate(
-                [a, np.zeros(n - len(a), bool)])
+            grow = lambda a, fill: np.concatenate(
+                [a, np.full(n - len(a), fill, a.dtype)])
             mk = self.store._journey_masks = (
-                mk[0], grow(mk[1]), grow(mk[2]))
+                mk[0], grow(mk[1], False), grow(mk[2], False),
+                grow(mk[3], -1))
         return mk
 
     def _journey_event(self, row: int, kind: str, *,
@@ -990,14 +1001,19 @@ class FastCycle:
                          solve_id=solve_id, detail=detail)
 
     def _journey_rows(self, rows, kind: str, *, solve_id: int = 0,
-                      epoch: int = -1, detail: str = ""
-                      ) -> Optional[dict]:
+                      epoch: int = -1, detail: str = "",
+                      now: Optional[int] = None) -> Optional[dict]:
         """Bulk journey capture for the vectorized seams.  For the
         steady-state kinds (``dispatched``/``bound``/``unbound``) only
         FIRST-time rows are stamped (see ``_journey_masks``);
         drops and voids are churn-sized, so every row records.
-        Returns the enclosing span's args: the rows given and how many
-        of them went to the journey's batch path."""
+        A ``dispatched`` stamp leaves each row's journey slot in the
+        masks' third column and the ``bound`` stamp starts from there
+        (``_journey_bound``).  ``now`` is the instant the events carry
+        when the stamp runs later (``JourneyLog.now()``).
+        Returns the enclosing span's args: the rows given, how many of
+        them went to the journey's batch path and, of a ``bound`` stamp,
+        how many of those came with their slot."""
         jr = getattr(self.store, "journey", None)
         n = len(rows)
         if jr is None or not n:
@@ -1005,7 +1021,7 @@ class FastCycle:
         if kind == "unbound":
             rows = rows[:0]
         elif kind in ("dispatched", "bound"):
-            gen, considered, bound_seen = self._journey_masks()
+            _, considered, bound_seen, slot_of_row = self._journey_masks()
             mask = considered if kind == "dispatched" else bound_seen
             rows = rows[~mask[rows]]
             mask[rows] = True
@@ -1013,11 +1029,49 @@ class FastCycle:
             # Re-pend loop: the pods' journeys already hold their first
             # consideration / first-bind latency; count in bulk only.
             jr.repeat_rows(n - len(rows), kind)
-        if len(rows):
-            jr.pod_rows(map(self.m.p_uid.__getitem__, rows.tolist()),
-                        kind, shard=self._journey_shard(),
-                        solve_id=solve_id, epoch=epoch, detail=detail)
-        return {"rows": n, "fresh": len(rows)}
+        args = {"rows": n, "fresh": len(rows)}
+        common = dict(shard=self._journey_shard(), solve_id=solve_id,
+                      epoch=epoch, detail=detail)
+        if kind == "bound":
+            args["slot_hits"] = (
+                self._journey_bound(jr, rows, slot_of_row, common)
+                if len(rows) else 0)
+        elif len(rows):
+            # A tombstoned row has no uid: it drops out here, in array
+            # form, so that the slots that come back line up with rows.
+            rows = rows[self.m.p_alive[rows]]
+            uids = list(map(self.m.p_uid.__getitem__, rows.tolist()))
+            sl = jr.pod_rows(uids, kind, now=now, **common)
+            if kind == "dispatched" and len(sl) == len(rows):
+                slot_of_row[rows] = sl
+                # The commit of these rows wants their uids once more,
+                # for the event ring: strings only, gone with the cycle.
+                self._journey_uids = (rows, uids)
+        return args
+
+    def _journey_bound(self, jr, rows, slot_of_row, common: dict) -> int:
+        """The ``bound`` stamp of first-time ``rows``, from the slots
+        their ``dispatched`` stamp left; the rows that came with one.
+        A slot is its pod's own while the row lives: only the pod's
+        removal frees it, and that tombstones the row for good (new pods
+        take new rows; a compaction drops the column).  A row that is
+        not alive has no uid and no event, as on the uid path."""
+        m = self.m
+        rows = rows[m.p_alive[rows]]
+        sl = slot_of_row[rows]
+        miss_uids = [m.p_uid[r] for r in rows[sl < 0].tolist()]
+        # The ring's uids: the dispatched stamp's own list when the
+        # commit is of those very rows (a burst), else gathered for the
+        # events the ring will hold.
+        kept, self._journey_uids = self._journey_uids, None
+        if kept is not None and np.array_equal(kept[0], rows):
+            tail = kept[1]
+        else:
+            k = min(len(rows), jr.capacity)
+            tail = list(map(m.p_uid.__getitem__,
+                            rows[len(rows) - k:].tolist()))
+        jr.pod_slots(sl, tail, "bound", miss_uids=miss_uids, **common)
+        return len(rows) - len(miss_uids)
 
     def _record_cycle(self, scope, err: Optional[BaseException]) -> None:
         """Run the cycle-end audits and hand this cycle's record to its
@@ -1593,7 +1647,7 @@ class FastCycle:
         from .ops import devincr as _dvm
 
         # ``solve_prep`` (lane): everything of this action between the
-        # order / encode / journey / device / commit lanes.
+        # order / encode / device / commit lanes.
         dv_store = None
         if solver == "wave" and _dvm.devincr_on():
             dv_store = _dvm.of_store(store)
@@ -1741,10 +1795,6 @@ class FastCycle:
                     with tracer.span("encode", lanes=lanes):
                         inputs, pid, profiles, ncls = self._solve_inputs(
                             cjobs, crows, slim=(solver == "wave"))
-                    # Journey: these rows entered a device solve
-                    # (first-time rows record; repeats bulk-count).
-                    with tracer.span("journey", lanes=lanes) as sp:
-                        sp.args = self._journey_rows(crows, "dispatched")
                     # Device-incremental context: single-chunk wave
                     # solves only (chunked solves interleave commits,
                     # so each chunk would need its own proof).
@@ -1763,6 +1813,11 @@ class FastCycle:
                     with tracer.span("device", cat="device",
                                      lanes=lanes,
                                      args={"rows": len(crows)}) as dev:
+                        # The instant these rows entered the solve: what
+                        # their ``dispatched`` events carry, stamped
+                        # below while the chip works.
+                        jr = getattr(store, "journey", None)
+                        entered = None if jr is None else jr.now()
                         with tracer.span("device:dispatch",
                                          cat="device") as disp:
                             self._count_dispatch(
@@ -1785,6 +1840,14 @@ class FastCycle:
                                     arr.copy_to_host_async()
                                 except AttributeError:
                                     pass
+                        # Journey: these rows entered a device solve
+                        # (first-time rows record; repeats bulk-count).
+                        # It needs nothing from the solve, so it runs
+                        # in the wait for it, as the commit prep does.
+                        with tracer.span("device:journey",
+                                         cat="device") as sp:
+                            sp.args = self._journey_rows(
+                                crows, "dispatched", now=entered)
                         # Commit prep that doesn't need the assignments
                         # overlaps the device solve + transfer wait.
                         with tracer.span("device:host_prep",

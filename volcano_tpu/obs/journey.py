@@ -30,7 +30,10 @@ Cost discipline: a batch of rows (``pod_rows``: the fast path's
 ``dispatched`` / ``bound`` / ``dropped`` seams) is stamped with a fixed
 number of Python operations whatever its size — one uid -> slot pass,
 scatters over the per-pod columns, a ``bincount`` for each histogram
-and for the gangs, slice writes into the ring.  In a burst every row
+and for the gangs, slice writes into the ring.  The pass is made once
+per solve: ``pod_rows`` returns the slots it resolved, the fast cycle
+keeps them by mirror row, and the ``bound`` stamp of the same rows
+(``pod_slots``) starts from them (ISSUE 32).  In a burst every row
 is first-time, so the batch is the whole backlog (100,000 rows twice a
 cycle at the north star): on the chip's host such a stamp takes 47-49
 ms, where one pod at a time it took 310 (``dispatched``) and 648
@@ -196,6 +199,8 @@ class JourneyLog:
         self.bulk_calls = 0
         self.bulk_events = 0
         self.scalar_events = 0
+        # Events of ``pod_slots`` batches that came with their slot.
+        self.slot_hits = 0
         # Per-kind event counts on their way to the registry counter,
         # folded every _FLUSH_EVERY events: inc() takes the registry-wide
         # metrics lock and builds a sorted tuple.  guarded-by: _lock
@@ -232,23 +237,71 @@ class JourneyLog:
                         solve_id, epoch, detail)
             self.capture_ns += time.perf_counter_ns() - t0
 
+    @property
+    def capacity(self) -> int:
+        """Events the ring holds."""
+        return self._cap
+
+    def now(self) -> int:
+        """The instant a stamp taken now would carry, for a caller that
+        stamps later (``pod_rows(..., now=)``)."""
+        return time.time_ns() - self._anchor_ns
+
     def pod_rows(self, uids: Iterable[Optional[str]], kind: str, *,
                  shard: int = -1, solve_id: int = 0, epoch: int = -1,
-                 detail: str = "") -> None:
+                 detail: str = "", now: Optional[int] = None
+                 ) -> "np.ndarray":
         """Stamp one event on every pod of a batch (the fast path's
         vectorized writers): one timestamp, one lock acquisition and a
         fixed number of Python operations whatever the batch's size —
-        the same facts ``pod_event`` called once per uid would leave."""
+        the same facts ``pod_event`` called once per uid would leave.
+        ``now`` is the instant the events carry, when that is earlier
+        than the call (``self.now()`` then).  Returns the pods' slots,
+        one for each uid that is not None or empty, in batch order: a
+        caller that keeps them hands them to ``pod_slots`` and the
+        next stamp skips the uid -> slot pass."""
+        if kind in _EDGE_KINDS:
+            raise ValueError(f"{kind!r} carries a per-pod payload: "
+                             "pod_event")
+        t0 = time.perf_counter_ns()
+        if now is None:
+            now = time.time_ns() - self._anchor_ns
+        uids = list(filter(None, uids))
+        with self._lock:
+            sl = self._resolve(uids, now)
+            if uids:
+                self._stamp(sl, uids, kind, now, shard, solve_id, epoch,
+                            detail)
+            self.capture_ns += time.perf_counter_ns() - t0
+        return sl
+
+    def pod_slots(self, slots: "np.ndarray", tail_uids: List[str],
+                  kind: str, *, miss_uids: Iterable[str] = (),
+                  shard: int = -1, solve_id: int = 0, epoch: int = -1,
+                  detail: str = "") -> None:
+        """``pod_rows`` for a batch whose slots the caller kept from an
+        earlier stamp's return: no uid of the batch is looked up.
+        ``slots[i] < 0`` is a pod whose slot the caller does not know;
+        ``miss_uids`` are the uids of those, in batch order.  The event
+        ring holds uids, of a batch's last ``capacity`` events at most:
+        ``tail_uids`` ends with them (the last ``min(len(slots),
+        capacity)`` uids of the batch; more in front does no harm).
+        The caller answers for each slot being its pod's own: a slot is
+        reused once its pod is ``removed``."""
         if kind in _EDGE_KINDS:
             raise ValueError(f"{kind!r} carries a per-pod payload: "
                              "pod_event")
         t0 = time.perf_counter_ns()
         now = time.time_ns() - self._anchor_ns
-        uids = list(filter(None, uids))
         with self._lock:
-            if uids:
-                self._stamp(uids, kind, now, shard, solve_id, epoch,
-                            detail)
+            miss = np.flatnonzero(slots < 0)
+            if len(miss):
+                slots = slots.copy()
+                slots[miss] = self._resolve(list(miss_uids), now)
+            if len(slots):
+                self.slot_hits += len(slots) - len(miss)
+                self._stamp(slots, tail_uids, kind, now, shard, solve_id,
+                            epoch, detail)
             self.capture_ns += time.perf_counter_ns() - t0
 
     def repeat_rows(self, n: int, kind: str) -> None:
@@ -488,11 +541,10 @@ class JourneyLog:
         col = getattr(self, name)
         return np.frombuffer(col, col.typecode)
 
-    def _stamp(self, uids: List[str], kind: str, now: int, shard: int,
-               solve_id: int, epoch: int, detail: str) -> None:
-        """``_apply`` over a batch, as array work.  Every pod of the
-        batch shares ``now``, so a uid that comes twice is first-time
-        once, at its first occurrence."""
+    def _resolve(self, uids: List[str], now: int) -> "np.ndarray":
+        """The slots of ``uids``, one dict lookup each; a pod the
+        journey never saw enqueue (adopted mid-life) gets its synthetic
+        root here, at its first occurrence."""
         n = len(uids)
         sl = np.fromiter(map(self._slot.get, uids, repeat(-1)),
                          np.int64, n)
@@ -500,6 +552,16 @@ class JourneyLog:
             s = self._slot.get(uids[i])
             sl[i] = (self._new_pod(uids[i], now, "", -1, _SYNTHETIC)
                      if s is None else s)
+        return sl
+
+    def _stamp(self, sl: "np.ndarray", tail_uids: List[str], kind: str,
+               now: int, shard: int, solve_id: int, epoch: int,
+               detail: str) -> None:
+        """``_apply`` over a batch of slots, as array work.  Every pod
+        of the batch shares ``now``, so a slot that comes twice is
+        first-time once, at its first occurrence.  ``tail_uids`` ends
+        with the uids of the batch's last ``min(n, cap)`` events."""
+        n = len(sl)
         last = self._view("_p_last")
         stale = last[sl] > now
         if stale.any():
@@ -531,9 +593,10 @@ class JourneyLog:
         k = min(n, cap)
         h = (self._head + n - k) % cap
         cut = min(k, cap - h)
-        for a, b, lo in ((h, h + cut, n - k), (0, k - cut, n - k + cut)):
+        t = len(tail_uids) - k  # where the batch's last k uids begin
+        for a, b, lo in ((h, h + cut, t), (0, k - cut, t + cut)):
             if b > a:
-                self._ev_uid[a:b] = uids[lo:lo + b - a]
+                self._ev_uid[a:b] = tail_uids[lo:lo + b - a]
                 self._ev_detail[a:b] = repeat(detail or None, b - a)
                 for (name, _), v in zip(_EV_COLS, (code, shard, solve_id,
                                                    epoch, now)):
@@ -741,6 +804,7 @@ class JourneyLog:
                 "bulk_calls": self.bulk_calls,
                 "bulk_events": self.bulk_events,
                 "scalar_events": self.scalar_events,
+                "slot_hits": self.slot_hits,
                 "rebinds": self.rebinds,
                 "reconsiders": self.reconsiders,
                 "ttfc_p50_ms": _pct(ttfc, 0.50),
