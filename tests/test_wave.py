@@ -12,6 +12,7 @@ device work; these tests pin down what it must still guarantee:
   big to fit) behave like the sequential solver's.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -213,6 +214,12 @@ def test_sparse_cnt0_path_matches_dense(monkeypatch):
     sparse = np.asarray(wave.solve_wave(*args2).assigned)
     assert np.array_equal(dense, sparse)
     assert (sparse >= 0).sum() == 3
+    # A table that arrives committed to ONE device (no mesh behind its
+    # sharding) is rebuilt where it was placed.
+    args3 = list(solve_args_from_store(build())[0])
+    args3[7] = args3[7]._replace(cnt0=jax.device_put(np.asarray(args3[7].cnt0)))
+    placed = np.asarray(wave.solve_wave(*args3).assigned)
+    assert np.array_equal(dense, placed)
 
 
 def test_sparse_profile_tables_match_dense(monkeypatch):

@@ -1148,16 +1148,20 @@ class FastCycle:
         padded bucket, the topology domains, and the bytes the
         ``has_aff`` branch of ``_solve_wave`` holds for them, reckoned
         from shapes: the two ``[Ep + 1, D]`` int32 count tensors and,
-        where it is on (``DOM_MM_MAX_MB``), the ``[N, D]`` float32
-        domain one-hot.  The largest solve of the cycle is kept."""
-        from .ops.wave import DOM_MM_MAX_MB
+        where it is on (``ops/wave.dom_mm_on``), the ``[N, D]`` float32
+        domain one-hot.  The largest solve of the cycle is kept; on a
+        mesh, a chip's share of it beside the whole."""
+        from .ops.wave import dom_mm_on
 
         if getattr(self, "stats", None) is None:
             return  # a bare FastCycle outside run() (tests) records nothing
         m = self.m
         D = max(1, len(m.domains))
+        shards = self._mesh_shards()
+        if Np % shards:
+            shards = 1  # solve_wave's own rule: the global form
         nbytes = 2 * (Ep + 1) * D * 4
-        if D * Np * 4 <= DOM_MM_MAX_MB * 1_000_000:
+        if dom_mm_on(D, Np):
             nbytes += Np * D * 4
         sc = self._solve_counts()
         sc["aff_rows"] = max(sc["aff_rows"], int(
@@ -1166,6 +1170,12 @@ class FastCycle:
         sc["aff_terms_padded"] = max(sc["aff_terms_padded"], int(Ep))
         sc["aff_domains"] = max(sc["aff_domains"], D)
         sc["aff_device_bytes"] = max(sc["aff_device_bytes"], nbytes)
+        if shards > 1:
+            # On a mesh the count pair shards on the domain axis and the
+            # one-hot on the node axis: a chip's share (absent on one
+            # device, where aff_device_bytes is the chip's).
+            sc["aff_device_bytes_chip"] = max(
+                sc.get("aff_device_bytes_chip", 0), -(-nbytes // shards))
 
     def _count_dispatch(self, rows: int, args, jobs) -> None:
         """One solve handed to the device: its rows, its ``jobs`` (how
@@ -1282,6 +1292,10 @@ class FastCycle:
         shards = int(info.get("mesh_shards", 1) or 1)
         if shards > 1:
             self.stats["mesh_shards"] = shards
+            # Into the record's ``solve`` block too, with the side of the
+            # one-hot's gate the dispatched program took (``dom_mm_on``).
+            self._solve_counts().update(
+                mesh_shards=shards, aff_dom_mm=int(info.get("dom_mm", 0)))
         dvinfo = info.get("devincr")
         if dvinfo:
             # Device-incremental decision of this dispatch (ISSUE 9):
@@ -1699,6 +1713,12 @@ class FastCycle:
             never_any = False
             try:
                 with tracer.span("solve_prep", lanes=lanes):
+                    # The mesh the solve runs on, if any (store.solve_mesh,
+                    # the conf's ``mesh`` argument of this action, or the
+                    # VOLCANO_TPU_MESH deploy knob: docs/tuning.md), built
+                    # once per store and value; the sizing below reckons
+                    # with a chip's share on it.
+                    mesh = self._solve_mesh(args)
                     # ``solve_prep:chunks`` (child): sizing the count
                     # tensors against the budget; only a store that has
                     # interned an inter-pod term records it.
@@ -1710,11 +1730,6 @@ class FastCycle:
                             self._solve_counts()["aff_chunks"] += len(chunks)
                             sp.args = {"chunks": len(chunks)}
                     remote = self._remote_solver
-                    from .parallel.mesh import mesh_from_env
-
-                    # store.solve_mesh, or the VOLCANO_TPU_MESH deploy
-                    # knob (docs/tuning.md); resolves once per store.
-                    mesh = mesh_from_env(store)
                 # Pipelined dispatch (ISSUE 1): a single-chunk wave
                 # solve is shipped WITHOUT blocking on the result; the
                 # commit lands at the top of the next cycle.  Chunked
@@ -2133,6 +2148,23 @@ class FastCycle:
         else:
             self.store._shard_inflight[self.shard.index] = inflight
 
+    def _solve_mesh(self, args: Optional[Arguments] = None):
+        """The mesh this store's solves run on, or None for one device
+        (``parallel/mesh.mesh_from_env``): an embedder's
+        ``store.solve_mesh``, then the ``mesh`` argument of the conf's
+        ``allocate`` action (``args``; looked up when not handed in),
+        then ``VOLCANO_TPU_MESH``."""
+        from .parallel.mesh import mesh_from_env
+
+        if args is None:
+            args = get_action_args(self.conf.configurations, "allocate")
+        return mesh_from_env(self.store, args.get("mesh") if args else None)
+
+    def _mesh_shards(self) -> int:
+        """Devices of the mesh ``_solve_mesh`` last resolved (1: none)."""
+        mesh = getattr(self.store, "solve_mesh", None)
+        return 1 if mesh is None else int(mesh.devices.size)
+
     def _solve_mesh_dispatch(self, mesh, inputs, pid, profiles, ncls,
                              devincr=None):
         """Dispatch the wave solve over the device mesh: node axis +
@@ -2143,9 +2175,11 @@ class FastCycle:
         straight through committed; the remaining epoch-stable plane
         (aff.node_dom) rides the store's declared mesh plane cache
         (cleared on close()/compaction, guarded by the store lock this
-        cycle already holds)."""
+        cycle already holds).  The shape buckets are the store's, as on
+        one device (``store._solve_shape_marks``)."""
         from .parallel.mesh import sharded_solve_wave_cycle
 
+        placed: Dict[str, int] = {}
         result = sharded_solve_wave_cycle(
             mesh, inputs, pid, profiles,
             plane_cache=self.store._mesh_plane_cache,
@@ -2153,7 +2187,18 @@ class FastCycle:
             taint_any=self._taint_any,
             node_classes=ncls,
             devincr=devincr,
+            shape_marks=self.store._solve_shape_marks,
+            # ``device:shard`` (child of ``device``; of ``dispatch`` or
+            # ``whatif_solve`` on those paths): the host -> mesh
+            # placement alone.
+            shard_span=lambda: self.tracer.span("device:shard",
+                                                cat="device"),
+            placed=placed,
         )
+        sc = self._solve_counts()
+        sc["mesh_put_bytes"] = sc.get("mesh_put_bytes", 0) + placed["bytes"]
+        sc["mesh_resident_bytes"] = (sc.get("mesh_resident_bytes", 0)
+                                     + placed["resident_bytes"])
         self._record_twophase_lanes()
         return result
 
@@ -2805,8 +2850,12 @@ class FastCycle:
                                "domains": len(m.domains)}
         D = max(1, len(m.domains))
         # Two int32 [Ep, D] tensors; budget against the solver's actual
-        # padded bucket (headroom + pow2 round-up reaches 2.5x raw).
-        cost = float(bucket_pow2(E, floor=1)) * D * 8.0 if E else 0.0
+        # padded bucket (headroom + pow2 round-up reaches 2.5x raw).  On
+        # a mesh the pair shards on the domain axis
+        # (parallel/mesh.shard_wave_inputs), so what meets the budget is
+        # one chip's share of it.
+        per_cell = 8.0 / self._mesh_shards()
+        cost = float(bucket_pow2(E, floor=1)) * D * per_cell if E else 0.0
         if cost <= budget or len(solve_jobs) <= 1:
             if cost > budget:
                 log.warning(
@@ -2835,7 +2884,8 @@ class FastCycle:
             i0, i1 = np.searchsorted(refs_row, [lo, hi])
             e_chunk = len(np.unique(refs_term[i0:i1]))
             padded = (
-                bucket_pow2(e_chunk, floor=1) * D * 8.0 if e_chunk else 0.0
+                bucket_pow2(e_chunk, floor=1) * D * per_cell
+                if e_chunk else 0.0
             )
             if padded > budget:
                 log.warning(

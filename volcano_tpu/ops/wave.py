@@ -85,6 +85,7 @@ from .nodeclass import NodeClasses
 from .resreq import less_equal
 from .scoring import ScoreWeights, node_score
 
+import functools as _functools
 import math as _math
 import os as _os
 import time as _time
@@ -136,6 +137,15 @@ def _keyspace_max() -> int:
 # up exactly one product, and f32 represents the integer counts
 # exactly).  Above it (hyperscale D ~ 50k) the gather path remains.
 DOM_MM_MAX_MB = 1024
+
+
+def dom_mm_on(D: int, N: int) -> bool:
+    """Whether a ``has_aff`` solve over ``N`` (padded) nodes and ``D``
+    domains takes the one-hot matmul side of the gate above: the whole
+    ``[N, D]`` float32 one-hot against ``DOM_MM_MAX_MB``, on a mesh too
+    (the one mesh reading there is, hyper-50k.burst with the gate
+    forced open, had the matmul side 35 % slower: PERF.md)."""
+    return D * N * 4 <= DOM_MM_MAX_MB * 1_000_000
 
 # ---- two-phase device solve (node-class compaction + shortlists) -----
 # Phase 1 (coarse) collapses the node table into node classes and
@@ -189,7 +199,8 @@ COARSE_CHUNK = 256
 # coarse_s, fine_s, shortlist ((U, S) or None), n_nodes,
 # compacted_classes (bool: real class planes vs per-node identity),
 # mesh_shards (effective node-axis shard count of the rankings; 1 off
-# a mesh).
+# a mesh), dom_mm (the has_aff program took the one-hot matmul side of
+# dom_mm_on's gate).
 LAST_TWOPHASE: dict = {"enabled": False}
 
 
@@ -992,7 +1003,7 @@ def _solve_wave(
     # term's own key's domains, so cnt @ dom_oh picks up exactly
     # cnt[e, node_dom[n, key(e)]] — the per-attempt gather as one MXU
     # pass.  Built once per solve; trace-static size gate.
-    dom_mm = has_aff and (D * N * 4 <= DOM_MM_MAX_MB * 1_000_000)
+    dom_mm = has_aff and dom_mm_on(D, N)
     if dom_mm:
         # Stored [N, D] (node-major): contractions read it transposed
         # for free via dot_general, while the sub-round filter can
@@ -2231,6 +2242,23 @@ def _scatter_cnt0(rows, cols, vals, e, d):
     return jnp.zeros((e, d), jnp.int32).at[rows, cols].add(vals)
 
 
+@_functools.lru_cache(maxsize=4)
+def _scatter_cnt0_onto(sharding):
+    """``_scatter_cnt0`` with its result born under ``sharding`` (a mesh
+    caller's domain-axis sharding): every chip fills its own shard, and
+    the dense table never stands whole on one of them."""
+    return jax.jit(_scatter_cnt0.__wrapped__, static_argnames=("e", "d"),
+                   out_shardings=sharding)
+
+
+def _mesh_pad(d: int, sharding) -> int:
+    """Columns to add to a ``d``-wide domain axis so that ``sharding``
+    (a mesh caller's, or None) splits it evenly; domain ids only ever
+    index the original range."""
+    mesh = getattr(sharding, "mesh", None)
+    return 0 if mesh is None else (-d) % mesh.devices.size
+
+
 @partial(jax.jit, static_argnames=("u", "e"))
 def _scatter_profile_tables(rows, cols, flags, soft, u, e):
     """Rebuild the dense [U, E] profile-term tables from their sparse
@@ -2667,6 +2695,7 @@ def solve_wave(
     mesh_shards: int = 1,
     devincr=None,
     shape_marks: Optional[dict] = None,
+    cnt0_sharding=None,
 ) -> AllocResult:
     """Wave-batched solve; same signature/result as ``allocate.solve``.
 
@@ -2714,6 +2743,14 @@ def solve_wave(
     keeps every data-dependent shape bucket of this call (profile rows,
     profiles and terms per wave, sparse entry lists) at its high-water
     mark (``settle``); padding is inert, so results do not depend on it.
+
+    ``cnt0_sharding`` (mesh callers, ``parallel/mesh.shard_wave_inputs``:
+    the domain-axis sharding of the count tensors) is where ``aff.cnt0``
+    goes; the caller hands the table over as the HOST array it is and
+    places nothing.  One that ships sparse has its entries go up, and
+    the dense ``[Ep + 1, D]`` table exists only as the mesh's shards;
+    a small one is placed dense.  Either way the domain axis is
+    zero-padded to a multiple of the mesh.
     """
     P = int(tasks.job.shape[0])
     if (extra_ok is not None or extra_score is not None) and (
@@ -2821,6 +2858,12 @@ def solve_wave(
     cnt0_in = aff.cnt0
     cnt0_host = _np(cnt0_in)
     cnt0_sparse = cnt0_host.size > CNT0_SPARSE_MIN
+    # Where the count table, and whatever is rebuilt on the device
+    # beside it, goes: the sharding a mesh caller named, else the
+    # placement of a table that arrived committed.
+    in_sharding = (cnt0_sharding if cnt0_sharding is not None
+                   else None if isinstance(cnt0_in, np.ndarray)
+                   else getattr(cnt0_in, "sharding", None))
     if cnt0_sparse:
         # One scan serves both the feature bit and the sparse extraction
         # (cnt0 is the largest host array on this path).
@@ -2888,20 +2931,19 @@ def solve_wave(
             ur.astype(np.int32), ec.astype(np.int32), flags, soft_vals,
             t_aff_h.shape[0], t_aff_h.shape[1] + 1,
         )
-        in_sh = getattr(cnt0_in, "sharding", None)
-        if in_sh is not None and not isinstance(cnt0_in, np.ndarray):
+        if in_sharding is not None:
             try:
                 d_aff, d_anti, d_mat, d_soft = tuple(
-                    jax.device_put(x, in_sh)
+                    jax.device_put(x, in_sharding)
                     for x in (d_aff, d_anti, d_mat, d_soft)
                 )
             except ValueError:
-                # A partitioned in_sh whose axis does not divide the
+                # A partitioned sharding whose axis does not divide the
                 # rebuilt [U, Ep+1] tables (mesh callers sharding the
                 # term axis): replicate them instead — the [E, D] count
                 # pair is the memory wall, not these.
                 rep = jax.sharding.NamedSharding(
-                    in_sh.mesh, jax.sharding.PartitionSpec()
+                    in_sharding.mesh, jax.sharding.PartitionSpec()
                 )
                 d_aff, d_anti, d_mat, d_soft = tuple(
                     jax.device_put(x, rep)
@@ -2925,17 +2967,26 @@ def solve_wave(
             rows_nz = np.concatenate([rows_nz, np.zeros(cpad, np.int64)])
             cols_nz = np.concatenate([cols_nz, np.zeros(cpad, np.int64)])
             vals_nz = np.concatenate([vals_nz, np.zeros(cpad, np.int32)])
-        cnt0_dev = _scatter_cnt0(
+        # Mesh callers: the rebuilt table is born under the placement
+        # the caller named for (or gave) cnt0, or the jit below sees
+        # committed arrays on incompatible device sets.
+        scatter = (_scatter_cnt0 if in_sharding is None
+                   else _scatter_cnt0_onto(in_sharding))
+        aff = aff._replace(cnt0=scatter(
             rows_nz.astype(np.int32), cols_nz.astype(np.int32), vals_nz,
-            cnt0_host.shape[0] + 1, cnt0_host.shape[1],
-        )
-        in_sharding = getattr(cnt0_in, "sharding", None)
-        if in_sharding is not None and not isinstance(cnt0_in, np.ndarray):
-            # Mesh callers pass cnt0 replicated over their devices; the
-            # rebuilt table must match, or the jit below sees committed
-            # arrays on incompatible device sets.
-            cnt0_dev = jax.device_put(cnt0_dev, in_sharding)
-        aff = aff._replace(cnt0=cnt0_dev)
+            cnt0_host.shape[0] + 1,
+            cnt0_host.shape[1] + _mesh_pad(cnt0_host.shape[1], cnt0_sharding),
+        ))
+    elif cnt0_sharding is not None:
+        # A table small enough to go up dense: _term_windows left it a
+        # host array with its dummy row; place it as the mesh caller
+        # asked.
+        dense = _np(aff.cnt0)
+        pad = _mesh_pad(dense.shape[1], cnt0_sharding)
+        if pad:
+            dense = np.concatenate(
+                [dense, np.zeros((dense.shape[0], pad), dense.dtype)], axis=1)
+        aff = aff._replace(cnt0=jax.device_put(dense, cnt0_sharding))
     # ---- two-phase solve prep (node classes + shortlists) ------------
     N_in = int(nodes.idle.shape[0])
     two_phase = _two_phase_on() and N_in > 0
@@ -2975,7 +3026,8 @@ def solve_wave(
     # [EW, D] (term x domain) scatters — ``ew`` and the domain width
     # are exactly the kernel's EW and D.
     hier_pin = _hier_pin()
-    flat_keys = (ew * int(cnt0_host.shape[1]) + 1) <= _keyspace_max()
+    D_dev = int(aff.cnt0.shape[1])
+    flat_keys = (ew * D_dev + 1) <= _keyspace_max()
     # Device-incremental context (ISSUE 9): only the two-phase slim
     # path qualifies — custom-plugin solves carry per-solve [U, N]
     # planes the cache keys cannot cover.
@@ -3048,6 +3100,7 @@ def solve_wave(
         "n_nodes": N_in,
         "compacted_classes": two_phase and not cls_identity,
         "mesh_shards": n_sh,
+        "dom_mm": features[1] and dom_mm_on(D_dev, N_in),
         "devincr": dv.solve_info() if dv is not None else None,
     })
     if dv is not None:

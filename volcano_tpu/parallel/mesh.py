@@ -12,12 +12,19 @@ Task/job/queue state stays replicated — it is tiny (O(P + J + Q) scalars)
 next to the [N, R] node state, and every chip needs the winner of each step
 anyway.
 
+A deployment switches it on in its own ``volcano-scheduler.conf``: the
+``allocate`` action's argument ``mesh: <n>`` (``mesh_from_env`` below; the
+environment variable ``VOLCANO_TPU_MESH`` is the deploy-time override for
+a conf that names none, an embedder's ``store.solve_mesh`` wins over both).
+
 ``dryrun_multichip`` in __graft_entry__.py drives this on a virtual CPU mesh;
-``chip_smoke.py --chips 4`` drives it on the four chips of one TPU host.
+``chip_smoke.py --chips 4`` and the benchmark's ``hyper-50k.burst`` (through
+the scheduler's normal path) drive it on the four chips of one TPU host.
 """
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 from typing import Optional, Sequence
@@ -51,27 +58,59 @@ def make_mesh(n_devices: Optional[int] = None, axis: str = NODES_AXIS,
     return Mesh(np.array(devices), (axis,))
 
 
-def mesh_from_env(store) -> Optional[Mesh]:
-    """The store's solve mesh, or one built from ``VOLCANO_TPU_MESH=<n>``
-    (the deploy-time enable knob: ``store.solve_mesh`` set explicitly
-    always wins; unset/0/1 keeps the single-device path).  A value that
-    is not an integer, or a backend with fewer than n devices, raises:
-    a deployment that asked for n chips must not carry on on one."""
+def mesh_from_env(store, conf_mesh: Optional[str] = None) -> Optional[Mesh]:
+    """The mesh a store's solves run on, or None for one device.
+
+    Three places may name it, the first that does wins: an embedder's
+    ``store.solve_mesh`` (a prebuilt ``Mesh``); the deployment's own
+    ``volcano-scheduler.conf``, ``conf_mesh`` being the ``mesh``
+    argument of its ``allocate`` action (``configurations: [{name:
+    allocate, arguments: {mesh: 4}}]``; ``0`` / ``1`` say one device);
+    and, where the conf names none, ``VOLCANO_TPU_MESH=<n>`` from the
+    environment (unset/0/1: one device).  A value that is not an
+    integer, or a backend with fewer than n devices, raises: a
+    deployment that asked for n chips must not carry on on one.
+
+    The mesh is built once per store and value.  When a conf reload
+    (or the environment) changes the value, the mesh the resolver had
+    built is replaced and the mesh plane cache dropped with it;
+    devincr's placement token and the device snapshot follow the mesh's
+    identity by themselves (``DeviceIncremental.set_mesh``,
+    ``devsnap.for_store``)."""
+    # An embedder's mesh is known from the resolver's own by identity
+    # (JAX interns equal meshes: one set after the resolver built an
+    # equal one reads as the resolver's, and follows the conf).
     mesh = getattr(store, "solve_mesh", None)
-    if mesh is not None or getattr(store, "_mesh_env_checked", False):
-        return mesh
-    raw = os.environ.get("VOLCANO_TPU_MESH", "")
+    resolved = getattr(store, "_mesh_resolved", None)  # (asked, mesh built)
+    if mesh is not None and (resolved is None or mesh is not resolved[1]):
+        return mesh  # the embedder's
+    conf_mesh = "" if conf_mesh is None else str(conf_mesh).strip()
+    asked = (conf_mesh, "" if conf_mesh
+             else os.environ.get("VOLCANO_TPU_MESH", ""))
+    if resolved is not None and resolved[0] == asked:
+        store.solve_mesh = resolved[1]
+        return resolved[1]
+    raw = asked[0] or asked[1]
+    said = (f"allocate argument mesh: {raw}" if asked[0]
+            else f"VOLCANO_TPU_MESH={raw}")
     try:
         n = int(raw) if raw else 0
     except ValueError:
-        raise RuntimeError(
-            f"VOLCANO_TPU_MESH={raw!r} is not an integer") from None
+        raise RuntimeError(f"{said} is not an integer") from None
+    mesh = None
     if n >= 2:
         try:
-            mesh = store.solve_mesh = make_mesh(n)
+            mesh = make_mesh(n)
         except RuntimeError as e:
-            raise RuntimeError(f"VOLCANO_TPU_MESH={raw}: {e}") from None
-    store._mesh_env_checked = True
+            raise RuntimeError(f"{said}: {e}") from None
+    if resolved is not None:
+        # The value moved under a live store: whatever was placed for
+        # the old mesh (or for one device) must not meet the new one.
+        cache = getattr(store, "_mesh_plane_cache", None)
+        if cache:
+            cache.clear()
+    store.solve_mesh = mesh
+    store._mesh_resolved = (asked, mesh)
     return mesh
 
 
@@ -162,7 +201,8 @@ def shard_wave_inputs(mesh: Mesh, solve_args: Sequence, pid, profiles,
                       axis: str = NODES_AXIS,
                       plane_cache: Optional[dict] = None,
                       epoch: Optional[int] = None,
-                      node_classes=None):
+                      node_classes=None,
+                      placed: Optional[dict] = None):
     """Mesh placement for the fast path's pre-profiled wave inputs.
 
     Beyond the node-axis sharding of ``shard_solve_args``, the affinity
@@ -172,11 +212,17 @@ def shard_wave_inputs(mesh: Mesh, solve_args: Sequence, pid, profiles,
     cluster size one chip can hold regardless of mesh width:
 
     - ``aff.cnt0`` [E, D] shards on the DOMAIN axis (hostname domains
-      are per-node, so D scales with N; XLA pads uneven shards),
-    - the profile term tables (``t_req_aff``/``t_req_anti``/
-      ``t_matches``/``t_soft`` [U, E]) shard on the TERM axis,
-    - ``pid`` and the remaining profile rows are replicated (profile
-      counts are tiny next to [*, N] and [E, D] state).
+      are per-node, so D scales with N).  It is not placed here: it
+      stays the host array it is, and ``solve_wave`` reads it (feature
+      bit, term windows, sparse entries) and then places or rebuilds
+      it on the mesh under the domain-axis sharding this function
+      returns (``solve_wave``'s ``cnt0_sharding``).  A placement here
+      was fetched straight back: 820 MB of zeros at 50,000 nodes;
+    - ``pid`` and the profile rows stay host arrays as well:
+      ``solve_wave`` pads, windows and (past ``PROF_SPARSE_MIN``)
+      sparsifies them on the host before the jit sees them, so a
+      placement here was fetched straight back; the jit replicates
+      them (profile counts are tiny next to [*, N] and [E, D] state).
 
     The kernel's count-window contraction (cnt @ dom_ohT over D) then
     runs as partial products with an XLA-inserted reduce over ICI.
@@ -185,10 +231,27 @@ def shard_wave_inputs(mesh: Mesh, solve_args: Sequence, pid, profiles,
     and ``aff.node_dom`` resident on the mesh across cycles: a hit skips
     their host->device transfer entirely (pass the same dict every
     cycle; the fast path parks one on the store).
+
+    ``placed`` (optional dict) receives what this call moved: ``arrays``
+    / ``bytes`` handed to ``jax.device_put``, ``cache_hits`` of the
+    plane cache, and ``resident_bytes`` found on the mesh already (those
+    hits and the committed planes of the sharded devsnap).
+
+    Returns ``(args, pid, profiles, node_classes, cnt0_sharding)``.
     """
     node_sharded = NamedSharding(mesh, P(axis))
     replicated = NamedSharding(mesh, P())
     col_sharded = NamedSharding(mesh, P(None, axis))
+    tally = {"arrays": 0, "bytes": 0, "cache_hits": 0, "resident_bytes": 0}
+
+    def put(a, sharding):
+        tally["arrays"] += 1
+        tally["bytes"] += int(a.nbytes)
+        return jax.device_put(a, sharding)
+
+    def resident(x):
+        tally["resident_bytes"] += int(x.nbytes)
+        return x
 
     # The slim fast path appends a 9th element (the [N] f32 topology
     # node-order bias, ops/topology.contig_bias) only when a fabric
@@ -208,7 +271,7 @@ def shard_wave_inputs(mesh: Mesh, solve_args: Sequence, pid, profiles,
         # device->host->device round trip of every plane every cycle,
         # exactly the re-shipping this path exists to remove.
         if isinstance(x, jax.Array) and not isinstance(x, np.ndarray):
-            return x
+            return resident(x)
         # The slim fast path ships [1, R] broadcast dummies for
         # releasing/pipelined; those replicate (a 1-row axis cannot
         # shard over the mesh).
@@ -216,13 +279,13 @@ def shard_wave_inputs(mesh: Mesh, solve_args: Sequence, pid, profiles,
         sh = node_sharded if (a.ndim and a.shape[0] == n_nodes
                               and a.shape[0] % mesh.devices.size == 0) \
             else replicated
-        return jax.device_put(a, sh)
+        return put(a, sh)
 
     def put_node_cached(name, x):
         # Committed mesh arrays (sharded devsnap) ARE the persistent
         # per-device planes — no cache entry needed.
         if isinstance(x, jax.Array) and not isinstance(x, np.ndarray):
-            return x
+            return resident(x)
         # Persistent per-device plane: re-ship only when the node table
         # (epoch) or the padded shape moved.  The mesh IDENTITY is part
         # of the key (not just its size): a store whose solve_mesh is
@@ -235,28 +298,15 @@ def shard_wave_inputs(mesh: Mesh, solve_args: Sequence, pid, profiles,
         key = (epoch, a.shape, a.dtype.str, mesh.devices.size, id(mesh))
         hit = plane_cache.get(name)
         if hit is not None and hit[0] == key:
-            return hit[1]
+            tally["cache_hits"] += 1
+            return resident(hit[1])
         arr = put_node(a)
         plane_cache[name] = (key, arr)
         return arr
 
-    n_mesh = mesh.devices.size
-
-    def put_cols(x):
-        # Shard axis 1, zero-padding it up to a mesh multiple (padded
-        # domain/term columns are inert: domain ids and term windows
-        # only ever index the original range).  Tables too small to
-        # split stay replicated.
-        a = np.asarray(x)
-        if a.ndim < 2 or a.shape[1] < n_mesh:
-            return jax.device_put(a, replicated)
-        pad = (-a.shape[1]) % n_mesh
-        if pad:
-            a = np.concatenate(
-                [a, np.zeros((a.shape[0], pad, *a.shape[2:]), a.dtype)],
-                axis=1,
-            )
-        return jax.device_put(a, col_sharded)
+    def rep(tree):
+        return jax.tree_util.tree_map(
+            lambda x: put(np.asarray(x), replicated), tree)
 
     nodes = type(nodes)(*[
         put_node_cached(name, x)
@@ -265,42 +315,21 @@ def shard_wave_inputs(mesh: Mesh, solve_args: Sequence, pid, profiles,
     ])
     aff = type(aff)(
         node_dom=put_node_cached("node_dom", aff.node_dom),
-        term_key=jax.device_put(np.asarray(aff.term_key), replicated),
-        cnt0=put_cols(aff.cnt0),
-        t_req_aff=jax.device_put(np.asarray(aff.t_req_aff), replicated),
-        t_req_anti=jax.device_put(np.asarray(aff.t_req_anti), replicated),
-        t_matches=jax.device_put(np.asarray(aff.t_matches), replicated),
-        t_soft=jax.device_put(np.asarray(aff.t_soft), replicated),
-    )
-    rep = lambda tree: jax.tree_util.tree_map(
-        lambda x: jax.device_put(np.asarray(x), replicated), tree
-    )
-    profiles = type(profiles)(
-        req=jax.device_put(np.asarray(profiles.req), replicated),
-        init_req=jax.device_put(np.asarray(profiles.init_req), replicated),
-        ports=jax.device_put(np.asarray(profiles.ports), replicated),
-        sel_bits=jax.device_put(np.asarray(profiles.sel_bits), replicated),
-        aff_bits=jax.device_put(np.asarray(profiles.aff_bits), replicated),
-        aff_terms=jax.device_put(np.asarray(profiles.aff_terms),
-                                 replicated),
-        tol_bits=jax.device_put(np.asarray(profiles.tol_bits), replicated),
-        pref_bits=jax.device_put(np.asarray(profiles.pref_bits),
-                                 replicated),
-        pref_w=jax.device_put(np.asarray(profiles.pref_w), replicated),
-        t_req_aff=put_cols(profiles.t_req_aff),
-        t_req_anti=put_cols(profiles.t_req_anti),
-        t_matches=put_cols(profiles.t_matches),
-        t_soft=put_cols(profiles.t_soft),
+        term_key=put(np.asarray(aff.term_key), replicated),
+        cnt0=np.asarray(aff.cnt0),
+        t_req_aff=put(np.asarray(aff.t_req_aff), replicated),
+        t_req_anti=put(np.asarray(aff.t_req_anti), replicated),
+        t_matches=put(np.asarray(aff.t_matches), replicated),
+        t_soft=put(np.asarray(aff.t_soft), replicated),
     )
     args = (
         nodes, rep(tasks), rep(jobs), rep(queues), rep(weights),
-        jax.device_put(np.asarray(eps), replicated),
-        jax.device_put(np.asarray(scalar_slot), replicated),
+        put(np.asarray(eps), replicated),
+        put(np.asarray(scalar_slot), replicated),
         aff,
     )
     if node_bias is not None:
         args = args + (put_node(node_bias),)
-    pid = jax.device_put(np.asarray(pid), replicated)
     if node_classes is not None:
         # Two-phase planes: the [N] class_id shards with the node axis
         # (it IS a node column); the [C, *] class tables and the [U, S]
@@ -317,7 +346,9 @@ def shard_wave_inputs(mesh: Mesh, solve_args: Sequence, pid, profiles,
                                        node_classes.taint_bits),
             ready=put_node_cached("cls_ready", node_classes.ready),
         )
-    return args, pid, profiles, node_classes
+    if placed is not None:
+        placed.update(tally)
+    return args, pid, profiles, node_classes, col_sharded
 
 
 def sharded_solve_wave_cycle(mesh: Mesh, solve_args: Sequence, pid,
@@ -327,24 +358,39 @@ def sharded_solve_wave_cycle(mesh: Mesh, solve_args: Sequence, pid,
                              epoch: Optional[int] = None,
                              taint_any=None,
                              node_classes=None,
-                             devincr=None):
+                             devincr=None,
+                             shape_marks: Optional[dict] = None,
+                             shard_span=contextlib.nullcontext,
+                             placed: Optional[dict] = None):
     """The fast path's solve dispatch on a mesh (FastCycle._allocate when
-    ``store.solve_mesh`` is set): pre-profiled inputs, node axis + count
+    the deployment's conf, ``store.solve_mesh`` or the environment names
+    one, ``mesh_from_env``): pre-profiled inputs, node axis + count
     tensors sharded per ``shard_wave_inputs``; epoch-stable planes
     (including the two-phase class planes) stay mesh-resident across
     cycles via ``plane_cache``.  ``devincr`` (ISSUE 9) threads the
     store's device-incremental context through — its persistent static
     planes and warm-shortlist candidates live replicated on this mesh
     (``DeviceIncremental.set_mesh``, called by the fast path before the
-    dispatch), so a mesh change voids them via the placement token."""
+    dispatch), so a mesh change voids them via the placement token.
+    ``shape_marks`` is ``solve_wave``'s: the store's high-water shape
+    buckets, as the one-device dispatch passes them.  ``shard_span``
+    opens the caller's span over the placement alone (``device:shard``)
+    and ``placed`` receives its counts (``shard_wave_inputs``)."""
     from ..ops.wave import solve_wave
 
-    args, pid, profiles, node_classes = shard_wave_inputs(
-        mesh, solve_args, pid, profiles, axis,
-        plane_cache=plane_cache, epoch=epoch, node_classes=node_classes,
-    )
+    placed = {} if placed is None else placed
+    with shard_span() as sp:
+        args, pid, profiles, node_classes, cnt0_sharding = shard_wave_inputs(
+            mesh, solve_args, pid, profiles, axis,
+            plane_cache=plane_cache, epoch=epoch, node_classes=node_classes,
+            placed=placed,
+        )
+        if sp is not None:
+            sp.args = {k: placed[k]
+                       for k in ("arrays", "bytes", "cache_hits")}
     kw = {} if wave is None else {"wave": wave}
     return solve_wave(*args, pid=pid, profiles=profiles,
                       taint_any=taint_any, node_classes=node_classes,
                       mesh_shards=int(mesh.devices.size),
-                      devincr=devincr, **kw)
+                      devincr=devincr, shape_marks=shape_marks,
+                      cnt0_sharding=cnt0_sharding, **kw)

@@ -232,7 +232,6 @@ def dispatch_plan(cyc, plan: WhatIfPlan) -> None:
     planes, so the sharded devsnap path carries the hypothetical
     cluster unchanged."""
     from .ops.wave import solve_wave
-    from .parallel.mesh import mesh_from_env
 
     m = cyc.m
     store = cyc.store
@@ -291,7 +290,7 @@ def dispatch_plan(cyc, plan: WhatIfPlan) -> None:
             assigned = np.asarray(res.assigned)
             never_ready = np.asarray(res.never_ready)
         else:
-            mesh = mesh_from_env(store)
+            mesh = cyc._solve_mesh()
             if mesh is not None:
                 payload = cyc._solve_mesh_dispatch(
                     mesh, inputs, pid, profiles, ncls)
