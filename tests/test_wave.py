@@ -279,3 +279,152 @@ def test_sparse_profile_tables_match_dense(monkeypatch):
     sparse = np.asarray(wave.solve_wave(*args2).assigned)
     assert np.array_equal(dense, sparse)
     assert (sparse >= 0).sum() == 5  # the 3 aff + 2 anti pending pods
+
+
+# ---- the hand-off of the inter-pod term data: entries against tables --------
+
+
+def _term_snapshot(mesh=None):
+    """A fast-path encode over required affinity, required anti-affinity,
+    a soft spread whose (profile, term) pair recurs, plain gangs and
+    residents that match two of the terms: ``(inputs, pid, profiles,
+    node_classes, taint_any)`` as ``FastCycle._solve_inputs`` hands them
+    to ``solve_wave``."""
+    from volcano_tpu.api import GROUP_NAME_ANNOTATION, AffinityTerm
+    from volcano_tpu.fastpath import FastCycle
+    from volcano_tpu.framework import parse_scheduler_conf
+
+    store = ClusterStore()
+    for z in range(4):
+        for i in range(4):
+            store.add_node(Node(
+                name=f"z{z}-n{i}",
+                allocatable={"cpu": "8", "memory": "16Gi", "pods": 32},
+                labels={"zone": f"z{z}"},
+            ))
+
+    def gang(name, n, labels, phase="Inqueue", node=None, **terms):
+        pg = PodGroup(name=name, min_member=n)
+        pg.status.phase = phase
+        store.add_pod_group(pg)
+        for k in range(n):
+            store.add_pod(Pod(
+                name=f"{name}-{k}", labels=labels,
+                containers=[{"cpu": "1", "memory": "1Gi"}],
+                annotations={GROUP_NAME_ANNOTATION: name},
+                **({"node_name": node, "phase": "Running"} if node else {}),
+                **terms))
+
+    db = AffinityTerm(match_labels={"app": "db"}, topology_key="zone")
+    lonely = AffinityTerm(match_labels={"app": "lonely"},
+                          topology_key="kubernetes.io/hostname")
+    gang("res-db", 2, {"app": "db"}, phase="Running", node="z1-n0")
+    gang("res-lonely", 1, {"app": "lonely"}, phase="Running", node="z2-n3")
+    gang("aff", 3, {"app": "db"}, affinity=[db])
+    gang("anti", 4, {"app": "lonely"}, anti_affinity=[lonely])
+    gang("spread", 4, {"app": "web"},
+         topology_spread=[("zone", 10), ("zone", 5)])
+    for g in range(3):
+        gang(f"plain{g}", 4, {"app": f"p{g}"})
+    store.solve_mesh = mesh
+    cyc = FastCycle(store, parse_scheduler_conf(
+        "actions: allocate\ntiers:\n- plugins:\n  - name: gang\n"
+        "  - name: predicates\n  - name: nodeorder\n"))
+    with store._lock:
+        cyc.derive()
+        cyc._proportion()
+        solve_jobs, task_rows = cyc._pending_rows(cyc._ordered_jobs())
+        inputs, pid, profiles, ncls = cyc._solve_inputs(
+            solve_jobs, task_rows, slim=True)
+    return inputs, pid, profiles, ncls, cyc._taint_any
+
+
+def _as_tables(inputs, profiles):
+    """The dense hand-off of the same data, built here cell by cell: the
+    ``[U, Ep]`` profile-term tables and the ``[Ep, D]`` count table."""
+    from volcano_tpu.ops.wave import SolveProfiles
+
+    t = profiles.terms
+    tabs = [np.zeros(t.shape, bool) for _ in range(3)]
+    soft = np.zeros(t.shape, np.float32)
+    for u, e, f, w in zip(t.rows, t.cols, t.flags, t.soft):
+        for bit in range(3):
+            tabs[bit][u, e] = bool((f >> bit) & 1)
+        soft[u, e] = w
+    aff = inputs[7]
+    cnt0 = np.zeros(aff.cnt0.shape, np.int32)
+    for e, d, v in zip(aff.cnt0.rows, aff.cnt0.cols, aff.cnt0.vals):
+        cnt0[e, d] = v
+    return ((*inputs[:7], aff._replace(cnt0=cnt0), *inputs[8:]),
+            SolveProfiles(*profiles[:9], *tabs, soft))
+
+
+@pytest.mark.parametrize("wave_size", [2048, 8], ids=["one-wave", "waves-of-8"])
+@pytest.mark.parametrize("placement", ["one-device", "mesh-4"])
+@pytest.mark.parametrize("thresholds", ["device-scatter", "dense-upload"])
+def test_term_entries_hand_off_matches_the_dense_one(
+        monkeypatch, thresholds, placement, wave_size):
+    """The fast path hands ``solve_wave`` the profile-term tables and the
+    resident counts as entries; the same snapshot handed over as dense
+    tables gives the same windows and the same result, element for
+    element, whether the tables are then born on the device (thresholds
+    lowered) or densified and uploaded, on one device and on a mesh."""
+    import volcano_tpu.ops.wave as wave
+    from volcano_tpu.arrays.affinity import CountEntries
+    from volcano_tpu.parallel.mesh import make_mesh, sharded_solve_wave_cycle
+
+    if placement == "mesh-4" and len(jax.devices()) < 4:
+        pytest.skip("needs 4 (virtual) devices")
+    if thresholds == "device-scatter":
+        monkeypatch.setattr(wave, "CNT0_SPARSE_MIN", 0)
+        monkeypatch.setattr(wave, "PROF_SPARSE_MIN", 0)
+    mesh = make_mesh(4) if placement == "mesh-4" else None
+    inputs, pid, profiles, ncls, taint_any = _term_snapshot(mesh)
+    terms, cnt = profiles.terms, inputs[7].cnt0
+    assert isinstance(profiles, wave.SparseProfiles)
+    assert isinstance(cnt, CountEntries)
+    # What the snapshot was built to hold: required terms of both kinds,
+    # the spread's recurring pair summed into one entry, and residents.
+    assert (terms.flags & 1).any() and (terms.flags & 2).any()
+    assert sorted(terms.soft[terms.soft != 0].tolist()) == [-15.0]
+    assert cnt.vals.tolist() == [2, 1]
+    order = np.lexsort((terms.cols, terms.rows))
+    assert np.array_equal(order, np.arange(len(order)))
+
+    windows = []
+    orig = wave._term_windows
+
+    def spy(*a, **k):
+        windows.append(orig(*a, **k))
+        return windows[-1]
+
+    monkeypatch.setattr(wave, "_term_windows", spy)
+
+    def solve(args, profs):
+        if mesh is not None:
+            res = sharded_solve_wave_cycle(
+                mesh, args, pid, profs, wave=wave_size,
+                taint_any=taint_any, node_classes=ncls)
+        else:
+            res = wave.solve_wave(
+                *args, pid=pid, profiles=profs, wave=wave_size,
+                taint_any=taint_any, node_classes=ncls)
+        return res, dict(wave.LAST_TWOPHASE["terms"])
+
+    by_entries, told_e = solve(inputs, profiles)
+    by_tables, told_t = solve(*_as_tables(inputs, profiles))
+    for field in ("assigned", "never_ready", "fit_failed", "fb_affinity",
+                  "fb_exhausted"):
+        assert np.array_equal(np.asarray(getattr(by_entries, field)),
+                              np.asarray(getattr(by_tables, field))), field
+    assert (np.asarray(by_entries.assigned) >= 0).sum() == 23
+    (wt_e, ew_e, dis_e), (wt_t, ew_t, dis_t) = windows
+    assert np.array_equal(wt_e, wt_t) and ew_e == ew_t and dis_e == dis_t
+    assert (wt_e < terms.shape[1]).any()
+    # Both count the same entries; only the dense hand-off has dense
+    # host tables to read, and only the dense upload builds any.
+    for k in ("prof_entries", "cnt0_entries"):
+        assert told_e[k] == told_t[k] > 0
+    assert told_e["prof_entries"] == len(terms.rows)
+    assert told_t["host_dense_bytes"] > told_e["host_dense_bytes"]
+    assert (told_e["host_dense_bytes"] == 0) == (thresholds == "device-scatter")

@@ -57,7 +57,13 @@ from .api.resource import (
     MIN_MILLI_SCALAR,
     Resource,
 )
-from .arrays.affinity import AffinityArgs, empty_affinity
+from .arrays.affinity import (
+    AffinityArgs,
+    CountEntries,
+    count_entries,
+    count_entries_of,
+    empty_affinity,
+)
 from .framework.arguments import Arguments, get_action_args
 from .framework.framework import POD_GROUP_UNSCHEDULABLE
 from .framework.session import _session_counter
@@ -1136,6 +1142,8 @@ class FastCycle:
                 "fetches": 0, "fetch_bytes": 0,
                 "aff_rows": 0, "aff_terms": 0, "aff_terms_padded": 0,
                 "aff_domains": 0, "aff_chunks": 0, "aff_device_bytes": 0,
+                "aff_prof_entries": 0, "aff_cnt0_entries": 0,
+                "aff_host_dense_bytes": 0,
                 "shortlist_fb_affinity": 0, "shortlist_fb_exhausted": 0,
             }
         return sc
@@ -1296,6 +1304,17 @@ class FastCycle:
             # one-hot's gate the dispatched program took (``dom_mm_on``).
             self._solve_counts().update(
                 mesh_shards=shards, aff_dom_mm=int(info.get("dom_mm", 0)))
+        terms = info.get("terms")
+        if terms:
+            # How the inter-pod term data crossed to this solve: its
+            # real entries, and the dense host tables read or built for
+            # it (0 where the tables were born on the device).  Of the
+            # entries the cycle's largest solve is kept, like the aff_*
+            # beside; the bytes add up over its solves.
+            sc = self._solve_counts()
+            for k in ("prof_entries", "cnt0_entries"):
+                sc["aff_" + k] = max(sc["aff_" + k], terms[k])
+            sc["aff_host_dense_bytes"] += terms["host_dense_bytes"]
         dvinfo = info.get("devincr")
         if dvinfo:
             # Device-incremental decision of this dispatch (ISSUE 9):
@@ -2047,13 +2066,16 @@ class FastCycle:
         static_key = (cls_tok, int(gen), wt, int(self._solve_np),
                       self.R)
         aff = inputs[7]
-        cnt0 = np.asarray(aff.cnt0)
+        # The count table's content token, from its entries (the table
+        # itself is never on the host); the size rule is the table's.
+        cnt0 = count_entries_of(aff.cnt0)
         warm_key = None
-        if cnt0.nbytes <= self._DEVINCR_CNT0_HASH_MAX:
-            if cnt0.any():
+        if 4 * cnt0.shape[0] * cnt0.shape[1] <= self._DEVINCR_CNT0_HASH_MAX:
+            if len(cnt0.rows):
                 h = hashlib.blake2b(digest_size=16)
                 h.update(repr(cnt0.shape).encode())
-                h.update(np.ascontiguousarray(cnt0).tobytes())
+                for col in (cnt0.rows, cnt0.cols, cnt0.vals):
+                    h.update(col.tobytes())
                 cnt0_tok = h.hexdigest()
             else:
                 cnt0_tok = f"z{cnt0.shape}"
@@ -3610,16 +3632,18 @@ class FastCycle:
         )
 
     def _term_cnt0(self, active_members: List[np.ndarray],
-                   term_key: np.ndarray, Ep: int) -> np.ndarray:
-        """[Ep, D] resident-member counts per domain for the active
-        terms — the only piece of the affinity encoding that moves with
-        pod placement, so it is recomputed each cycle even on an encode
-        cache hit (the membership structures it walks are cached)."""
+                   term_key: np.ndarray, Ep: int) -> CountEntries:
+        """The [Ep, D] resident-member counts per domain of the active
+        terms, as their entries (the table is born on the device,
+        ``ops/wave.solve_wave``) — the only piece of the affinity
+        encoding that moves with pod placement, so it is recomputed each
+        cycle even on an encode cache hit (the membership structures it
+        walks are cached)."""
         m = self.m
         D = max(1, len(m.domains))
-        cnt0 = np.zeros((Ep, D), I)
         node = m.p_node[:self.Pn]
         node_dom_raw = m.node_dom()
+        terms, doms = [np.zeros(0, np.int64)], [np.zeros(0, np.int64)]
         for le, members in enumerate(active_members):
             if not len(members):
                 continue
@@ -3628,13 +3652,19 @@ class FastCycle:
                 dom = node_dom_raw[node[residents], term_key[le]]
                 dom = dom[dom >= 0]
                 if len(dom):
-                    np.add.at(cnt0[le], dom, 1)
-        return cnt0
+                    terms.append(np.full(len(dom), le, np.int64))
+                    doms.append(dom)
+        return count_entries(np.concatenate(terms), np.concatenate(doms),
+                             (Ep, D))
 
     def _affinity_and_profiles(self, task_rows: np.ndarray, tasks,
                                Np: int):
-        """Affinity inputs + refined profile ids + SolveProfiles, all at
-        profile granularity — nothing dense in [P, E] is ever built.
+        """Affinity inputs + refined profile ids + the profile rows, all
+        at profile granularity — nothing dense in [P, E] is ever built,
+        and nothing dense in [U, Ep] or [Ep, D] either: the profile-term
+        tables and the resident counts leave as the entries they are
+        (``ops/wave.SparseProfiles``, ``arrays/affinity.CountEntries``)
+        and ``solve_wave`` has the tables born on the device.
 
         - Active-term compaction: only terms some pending task is involved
           with enter the solve; inactive terms cannot influence it (their
@@ -3652,8 +3682,6 @@ class FastCycle:
           per-domain resident counts (``_term_cnt0``) and the padded
           node-domain plane rebuild each cycle.
         """
-        from .ops.wave import SolveProfiles
-
         m = self.m
         P = len(task_rows)
 
@@ -3861,8 +3889,8 @@ class FastCycle:
                             combo: np.ndarray, term_parts, aff_empty,
                             P: int):
         """Renumber combo ids by first occurrence and gather one profile
-        row per distinct id (plus sparse [U, E] term columns)."""
-        from .ops.wave import SolveProfiles
+        row per distinct id (plus the [U, E] term tables' entries)."""
+        from .ops.wave import SparseProfiles, profile_term_entries
 
         _, first, inv = np.unique(combo, return_index=True,
                                   return_inverse=True)
@@ -3899,60 +3927,46 @@ class FastCycle:
                              tasks.aff_terms, tasks.tol_bits,
                              tasks.pref_bits, tasks.pref_w)
 
+        # The four [U, Ep] profile-term tables travel as the entries
+        # they are (ops/wave.SparseProfiles): one (profile, term) cell
+        # per membership and per reference of a profile's first row.
+        rows, cols, flags, soft = [], [], [], []
+
+        def cells(r, c, bit, w=None):
+            rows.append(r)
+            cols.append(c)
+            flags.append(np.full(len(r), bit, np.int8))
+            soft.append(np.zeros(len(r), F) if w is None else w)
+
         if term_parts is None:
             Ep = 1
-            u_req_aff = np.zeros((U, 1), bool)
-            u_req_anti = np.zeros((U, 1), bool)
-            u_matches = np.zeros((U, 1), bool)
-            u_soft = np.zeros((U, 1), F)
+            cells(np.zeros(0, np.int64), np.zeros(0, np.int64), 0)
         else:
             (member_locs, term_local, Ep, er_a, ei_a, er_n, ei_n,
              er_s, ei_s, ev_s, _pid_raw) = term_parts
             u_index = np.full(P, -1, np.int64)
             u_index[u] = np.arange(U)
-            u_req_aff = np.zeros((U, Ep), bool)
-            u_req_anti = np.zeros((U, Ep), bool)
-            u_matches = np.zeros((U, Ep), bool)
-            u_soft = np.zeros((U, Ep), F)
-            for le, loc in enumerate(member_locs):
-                if len(loc):
-                    sel = u_index[loc]
-                    sel = sel[sel >= 0]
-                    if len(sel):
-                        u_matches[sel, le] = True
+            sel = u_index[np.concatenate(member_locs)]
+            le = np.repeat(np.arange(len(member_locs)),
+                           [len(loc) for loc in member_locs])
+            cells(sel[sel >= 0], le[sel >= 0], 4)
 
-            def scatter(er, ei, out, val=None):
+            def refs(er, ei, bit, val=None):
                 ur = u_index[er]
                 keep = ur >= 0
                 lei = term_local[ei[keep]]
-                urk = ur[keep]
                 ok = lei >= 0
-                if val is None:
-                    out[urk[ok], lei[ok]] = True
-                else:
-                    np.add.at(out, (urk[ok], lei[ok]), val[keep][ok])
+                cells(ur[keep][ok], lei[ok], bit,
+                      None if val is None else val[keep][ok])
 
-            scatter(er_a, ei_a, u_req_aff)
-            scatter(er_n, ei_n, u_req_anti)
-            scatter(er_s, ei_s, u_soft, val=ev_s)
+            refs(er_a, ei_a, 1)
+            refs(er_n, ei_n, 2)
+            refs(er_s, ei_s, 0, ev_s)
+        terms = profile_term_entries(
+            *(np.concatenate(x) for x in (rows, cols, flags, soft)),
+            (U, Ep))
 
-        (f_req, f_init_req, f_ports, f_sel, f_affb, f_afft, f_tol,
-         f_prefb, f_prefw) = rows_by_field
-        return SolveProfiles(
-            req=g(f_req),
-            init_req=g(f_init_req),
-            ports=g(f_ports),
-            sel_bits=g(f_sel),
-            aff_bits=g(f_affb),
-            aff_terms=g(f_afft),
-            tol_bits=g(f_tol),
-            pref_bits=g(f_prefb),
-            pref_w=g(f_prefw),
-            t_req_aff=u_req_aff,
-            t_req_anti=u_req_anti,
-            t_matches=u_matches,
-            t_soft=u_soft,
-        )
+        return SparseProfiles(*[g(f) for f in rows_by_field], terms)
 
     # -------------------------------------------------------------- commit
 

@@ -72,7 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..arrays.affinity import AffinityArgs
+from ..arrays.affinity import AffinityArgs, count_entries_of
 from .allocate import (
     NEG,
     AllocResult,
@@ -222,6 +222,38 @@ class SolveProfiles(NamedTuple):
     t_req_anti: jnp.ndarray  # [U, E]
     t_matches: jnp.ndarray  # [U, E]
     t_soft: jnp.ndarray  # [U, E]
+
+
+class ProfileTermEntries(NamedTuple):
+    """The four ``[U, E]`` profile-term tables of ``SolveProfiles`` as
+    the entries they are: one per (profile, term) cell that is nonzero
+    in any of them, in (profile, term) order, no cell twice."""
+
+    rows: np.ndarray  # [n] int32 profile row
+    cols: np.ndarray  # [n] int32 term
+    flags: np.ndarray  # [n] int8: t_req_aff | t_req_anti << 1 | t_matches << 2
+    soft: np.ndarray  # [n] float32 t_soft
+    shape: tuple  # (U, E) of the tables they stand for
+
+
+class SparseProfiles(NamedTuple):
+    """``SolveProfiles`` on its way from the fast path's encode to
+    ``solve_wave`` (host only, never a jit argument): the nine small
+    per-profile fields as rows, the four term tables as ``terms``.  A
+    profile references its own gang's one or two terms, so the tables
+    are a few thousand entries in ``U x E`` cells (10 M at 10,000 nodes
+    x 100,000 pods); ``solve_wave`` has them born on the device."""
+
+    req: np.ndarray
+    init_req: np.ndarray
+    ports: np.ndarray
+    sel_bits: np.ndarray
+    aff_bits: np.ndarray
+    aff_terms: np.ndarray
+    tol_bits: np.ndarray
+    pref_bits: np.ndarray
+    pref_w: np.ndarray
+    terms: ProfileTermEntries
 
 
 class GState(NamedTuple):
@@ -2461,106 +2493,136 @@ def settled_pow2(marks: Optional[dict], axis: str, n: int, floor: int,
         n, floor, min_pad, room=draw_headroom(n)))
 
 
-def _pad_profiles_rows(profiles: SolveProfiles, marks=None) -> SolveProfiles:
+def _grow_profiles(sp: SparseProfiles, pad: int) -> SparseProfiles:
+    """``sp`` with ``pad`` inert zero rows more: the nine per-profile
+    fields are padded; the term tables have no entry there and only
+    grow in shape (they take their height where they are born)."""
+    if not pad:
+        return sp
+    U, E = sp.terms.shape
+    rows = [_np(a) for a in sp[:9]]
+    return SparseProfiles(
+        *[np.concatenate([a, np.zeros((pad, *a.shape[1:]), a.dtype)])
+          for a in rows],
+        sp.terms._replace(shape=(U + pad, E)))
+
+
+def _pad_profiles_rows(sp: SparseProfiles, marks=None) -> SparseProfiles:
     """Pad the profile table's row axis to a power of two (min 64) with
     inert zero rows.  The row count is data-dependent (distinct task
     profiles this cycle); unpadded it changes shape almost every cycle
     and forces an XLA recompile of the wave solver — ~7s per new shape,
     dwarfing the solve itself.  Padded rows are never referenced: pid and
     wave_prof only index real rows."""
-    U = int(_np(profiles.req).shape[0])
-    pad = settled_pow2(marks, "U", U, floor=64) - U
-    if pad == 0:
-        return profiles
-    def z(a):
-        a = _np(a)
-        return np.concatenate(
-            [a, np.zeros((pad, *a.shape[1:]), a.dtype)]
-        )
-
-    return SolveProfiles(*[z(a) for a in profiles])
+    U = sp.terms.shape[0]
+    return _grow_profiles(sp, settled_pow2(marks, "U", U, floor=64) - U)
 
 
-def _term_windows(profiles: SolveProfiles, aff: AffinityArgs,
-                  pid: np.ndarray, wave_prof: np.ndarray, n_waves: int,
-                  skip_cnt0: bool = False, skip_prof: bool = False,
-                  marks=None):
+def profile_term_entries(rows, cols, flags, soft, shape) -> ProfileTermEntries:
+    """Entries from (profile, term) cells named in any order and any
+    number of times: a cell's flags are ORed and its soft weights summed
+    in the order given (as ``np.add.at`` on the table sums them); a cell
+    left all zero is no entry."""
+    U, E = int(shape[0]), int(shape[1])
+    key, inv = np.unique(
+        np.asarray(rows, np.int64) * E + np.asarray(cols, np.int64),
+        return_inverse=True)
+    f = np.zeros(len(key), np.int8)
+    np.bitwise_or.at(f, inv, np.asarray(flags, np.int8))
+    w = np.zeros(len(key), np.float32)
+    np.add.at(w, inv, np.asarray(soft, np.float32))
+    keep = (f != 0) | (w != 0)
+    key = key[keep]
+    return ProfileTermEntries((key // E).astype(np.int32),
+                              (key % E).astype(np.int32),
+                              f[keep], w[keep], (U, E))
+
+
+def _sparse_profiles(profiles):
+    """``profiles`` with the term tables as entries, and the bytes of
+    dense host tables read for that: ``SparseProfiles`` pass through;
+    dense ``SolveProfiles`` (in-call profiling, the object path, tests)
+    are scanned for their entries."""
+    if isinstance(profiles, SparseProfiles):
+        return profiles, 0
+    t_aff, t_anti, t_mat, t_soft = (_np(t) for t in profiles[9:])
+    ur, ec = np.nonzero(t_aff | t_anti | t_mat | (t_soft != 0))
+    flags = (
+        t_aff[ur, ec].astype(np.int8)
+        | (t_anti[ur, ec].astype(np.int8) << 1)
+        | (t_mat[ur, ec].astype(np.int8) << 2)
+    )
+    terms = ProfileTermEntries(
+        ur.astype(np.int32), ec.astype(np.int32), flags,
+        t_soft[ur, ec].astype(np.float32),
+        (int(t_aff.shape[0]), int(t_aff.shape[1])))
+    return (SparseProfiles(*profiles[:9], terms),
+            t_aff.nbytes + t_anti.nbytes + t_mat.nbytes + t_soft.nbytes)
+
+
+def _dense_profile_tables(terms: ProfileTermEntries, u: int, e: int):
+    """The four ``[u, e]`` tables of ``terms`` on the host: how a small
+    set goes up (``PROF_SPARSE_MIN``), cell for cell what
+    ``_scatter_profile_tables`` gives a large one on the device."""
+    r, c = terms.rows, terms.cols
+    out = []
+    for bit in range(3):
+        t = np.zeros((u, e), bool)
+        t[r, c] = (terms.flags >> bit) & 1
+        out.append(t)
+    soft = np.zeros((u, e), np.float32)
+    soft[r, c] = terms.soft
+    return (*out, soft)
+
+
+def _pad_entries(k: int, *cols):
+    """Entry columns zero-padded to ``k``: a padded entry adds 0 to
+    cell (0, 0), a no-op."""
+    return tuple(
+        np.concatenate([c, np.zeros(k - len(c), c.dtype)]) if k > len(c)
+        else c for c in cols)
+
+
+def _term_windows(terms: ProfileTermEntries, wave_prof: np.ndarray,
+                  n_waves: int, marks=None):
     """Per-wave lists of the affinity terms the wave's profiles reference.
 
     Every [*, E] tensor in the kernel is gathered down to the wave's term
     list, bounding the affinity machinery by terms-per-wave instead of
-    total terms.  One dummy scratch row is appended to the term axis and
-    used as list padding, so the windowed count write-back scatters to
-    unique real rows (duplicates only hit the dummy).
-    Returns (profiles, aff, wave_terms [NW, EW], EW, iom) — iom being
-    the [U, E] nonzero union of the four profile-term tables (pre-dummy
-    columns; the sparse-shipping path reuses it).  ``skip_prof``: leave
-    the profile tables without the dummy column (the caller rebuilds
-    them on device at the dummy-extended width — skips four ~dense host
-    copies).
+    total terms.  One dummy scratch row (index E; ``solve_wave`` appends
+    it to every term axis) is the list padding, so the windowed count
+    write-back scatters to unique real rows (duplicates only hit the
+    dummy).  The lists come from ``terms`` grouped by profile: a walk
+    over the entries of each wave's profiles, not over [UM, E] table
+    rows.  Returns (wave_terms [NW, EW], EW, terms_disjoint).
     """
-    t_req_aff = _np(profiles.t_req_aff)
-    E = t_req_aff.shape[1]
-    iom = (
-        t_req_aff | _np(profiles.t_req_anti) | _np(profiles.t_matches)
-        | (_np(profiles.t_soft) != 0)
-    )
-    # Append the dummy scratch term row E.
-    def zc(a):
-        a = _np(a)
-        return np.concatenate(
-            [a, np.zeros((*a.shape[:-1], 1), a.dtype)], axis=-1
-        )
-
-    if not skip_prof:
-        profiles = profiles._replace(
-            t_req_aff=zc(profiles.t_req_aff),
-            t_req_anti=zc(profiles.t_req_anti),
-            t_matches=zc(profiles.t_matches),
-            t_soft=zc(profiles.t_soft),
-        )
-    repl = {
-        "term_key": np.concatenate(
-            [_np(aff.term_key), np.zeros(1, np.int32)]
-        ),
-    }
-    if not skip_cnt0:
-        # skip_cnt0: the caller rebuilds cnt0 on device with the dummy
-        # row included — skip the dense [Ep, D] host copy here.
-        repl["cnt0"] = np.concatenate(
-            [_np(aff.cnt0),
-             np.zeros((1, _np(aff.cnt0).shape[1]), _np(aff.cnt0).dtype)]
-        )
-    aff = aff._replace(**repl)
-    wp = _np(wave_prof)
-    U = iom.shape[0]
-    term_lists = []
-    ew = 1
-    for w in range(n_waves):
-        pids = np.unique(np.clip(wp[w], 0, U - 1))
-        terms = np.flatnonzero(iom[pids].any(axis=0))
-        term_lists.append(terms)
-        ew = max(ew, len(terms))
+    U, E = terms.shape
+    # Entries are in (profile, term) order: start[u]..start[u + 1] are
+    # profile u's.
+    start = np.searchsorted(terms.rows, np.arange(U + 1))
+    wp = np.clip(_np(wave_prof), 0, U - 1).astype(np.int64)
+    lo = start[wp].ravel()
+    n = start[wp + 1].ravel() - lo
+    total = int(n.sum())
+    # One key per (wave, term) pair present, each once, in order.
+    idx = np.repeat(lo - (np.cumsum(n) - n), n) + np.arange(total)
+    wave_of = np.repeat(np.repeat(np.arange(n_waves), wp.shape[1]), n)
+    key = np.unique(wave_of * (E + 1) + terms.cols[idx])
+    wave_of, term = key // (E + 1), key % (E + 1)
+    per_wave = np.bincount(wave_of, minlength=n_waves)
+    ew = max(1, int(per_wave.max()))
     EW = settled_pow2(marks, "EW", ew, floor=16, min_pad=4)
     wave_terms = np.full((n_waves, EW), E, np.int32)  # pad = dummy row
-    for w, terms in enumerate(term_lists):
-        wave_terms[w, :len(terms)] = terms
+    first = np.cumsum(per_wave) - per_wave
+    wave_terms[wave_of, np.arange(len(key)) - first[wave_of]] = term
     # Term sets are usually wave-disjoint (terms select a job's own app
     # label and jobs never split across waves): no wave then reads a
     # count another wave wrote, and the per-wave window write-back into
     # the global [E, D] tables — a full-table rewrite per wave under
     # XLA's scatter lowering, ~2 s/cycle at the north-star affinity
     # shape — can be skipped wholesale.
-    if term_lists:
-        all_terms = np.concatenate(term_lists)
-        terms_disjoint = bool(
-            len(all_terms) == len(np.unique(all_terms))
-        )
-    else:
-        terms_disjoint = True
-    # iom's dummy column is all-zero; callers reuse it as the nonzero
-    # union of the four tables (the sparse-shipping path).
-    return profiles, aff, wave_terms, int(EW), iom, terms_disjoint
+    terms_disjoint = bool(len(term) == len(np.unique(term)))
+    return wave_terms, int(EW), terms_disjoint
 
 
 def _wave_profiles(pid: np.ndarray, n_waves: int, wave: int, marks=None,
@@ -2705,8 +2767,9 @@ def solve_wave(
     precomputed profile ids — tasks with equal ids must have identical
     per-task solver inputs — and skips the feature-hashing pass.  With
     ``profiles`` also given (rows aligned to the pid numbering, which must
-    be by first occurrence), nothing per-task is recomputed here and
-    ``aff``'s task-level fields may be dummies.
+    be by first occurrence; ``SolveProfiles`` or ``SparseProfiles``),
+    nothing per-task is recomputed here and ``aff``'s task-level fields
+    may be dummies.
 
     ``node_bias`` (optional [N] f32, ops/topology.contig_bias) is an
     additive node-order bias folded into every profile's static score —
@@ -2746,11 +2809,21 @@ def solve_wave(
 
     ``cnt0_sharding`` (mesh callers, ``parallel/mesh.shard_wave_inputs``:
     the domain-axis sharding of the count tensors) is where ``aff.cnt0``
-    goes; the caller hands the table over as the HOST array it is and
-    places nothing.  One that ships sparse has its entries go up, and
-    the dense ``[Ep + 1, D]`` table exists only as the mesh's shards;
-    a small one is placed dense.  Either way the domain axis is
+    goes; the caller hands over what it has (entries, a host table) and
+    places nothing.  A table past ``CNT0_SPARSE_MIN`` has its entries go
+    up, and the dense ``[Ep + 1, D]`` table exists only as the mesh's
+    shards; a small one is placed dense.  Either way the domain axis is
     zero-padded to a multiple of the mesh.
+
+    The inter-pod term data comes as entries or as tables, by what the
+    caller has: the fast path's encode hands ``profiles`` as
+    ``SparseProfiles`` and ``aff.cnt0`` as ``CountEntries`` (nothing
+    dense in [U, Ep] or [Ep, D] ever stands on the host); in-call
+    profiling, the object path and tests hand dense tables, which are
+    scanned for their entries once.  From there it is one path: the
+    windows come from the entries, and a table goes up by its size —
+    born on the device from the entries past ``PROF_SPARSE_MIN`` /
+    ``CNT0_SPARSE_MIN``, densified here and uploaded under them.
     """
     P = int(tasks.job.shape[0])
     if (extra_ok is not None or extra_score is not None) and (
@@ -2777,35 +2850,36 @@ def solve_wave(
     n_waves = (P + pad) // wave
     if profiles is not None and pid is not None:
         pid = np.asarray(pid, np.int64)
+        sp, host_dense = _sparse_profiles(profiles)
         if pad:
             # Padded rows are all-zero features: append a fresh profile.
             fresh = int(pid.max() + 1) if len(pid) else 0
             pid = np.concatenate([pid, np.full(pad, fresh, np.int64)])
-            profiles = SolveProfiles(*[
-                np.concatenate(
-                    [_np(a), np.zeros((1, *np.asarray(a).shape[1:]),
-                                      np.asarray(a).dtype)]
-                )
-                for a in profiles
-            ])
+            sp = _grow_profiles(sp, 1)
         pid = pid.astype(np.int32)
-    elif pid is not None:
-        pid = np.asarray(pid, np.int64)
-        if pad:
-            fresh = (pid.max() + 1) if len(pid) else 0
-            pid = np.concatenate([pid, np.full(pad, fresh, np.int64)])
-        profiles, pid = _profiles_from_pid(tasks, aff, pid)
     else:
-        profiles, pid, extra_prof, score_prof = _profile_tasks(
-            tasks, aff, extra_ok, extra_score
-        )
-    u_before = int(_np(profiles.req).shape[0])
-    has_terms = bool(
-        _np(profiles.t_req_aff).any() or _np(profiles.t_req_anti).any()
-        or _np(profiles.t_soft).any()
-    )
-    profiles = _pad_profiles_rows(profiles, shape_marks)
-    u_pad = int(_np(profiles.req).shape[0]) - u_before
+        if pid is not None:
+            pid = np.asarray(pid, np.int64)
+            if pad:
+                fresh = (pid.max() + 1) if len(pid) else 0
+                pid = np.concatenate([pid, np.full(pad, fresh, np.int64)])
+            profiles, pid = _profiles_from_pid(tasks, aff, pid)
+        else:
+            profiles, pid, extra_prof, score_prof = _profile_tasks(
+                tasks, aff, extra_ok, extra_score
+            )
+        sp, host_dense = _sparse_profiles(profiles)
+    # From here the inter-pod term data is entries, whoever built it:
+    # ``sp.terms`` for the four [U, Ep] profile-term tables, ``cnt`` for
+    # the [Ep, D] count table.  ``host_dense`` adds up the bytes of
+    # dense host tables of either kind that this solve read or builds
+    # (the ``aff_host_dense_bytes`` solve count).
+    u_before = sp.terms.shape[0]
+    sp = _pad_profiles_rows(sp, shape_marks)
+    terms = sp.terms
+    U_rows, Ep = terms.shape
+    u_pad = U_rows - u_before
+    has_terms = bool((terms.flags & 3).any() or terms.soft.any())
     if extra_ok is not None:
         if u_pad:
             extra_prof = np.concatenate([
@@ -2827,7 +2901,7 @@ def solve_wave(
     # per-task field ships as a [1, ...] dummy, and the three [P] id
     # vectors narrow to int16 when their value ranges allow — at
     # 10k x 100k this cuts the per-solve upload ~6 MB -> ~0.7 MB.
-    R_ = int(profiles.req.shape[1])
+    R_ = int(sp.req.shape[1])
     job_in = tasks.job
     job_sh = getattr(job_in, "sharding", None)
     if job_sh is not None and not isinstance(job_in, np.ndarray):
@@ -2849,30 +2923,24 @@ def solve_wave(
         pref_bits=z1((1, 1, 1), np.uint32),
         pref_w=z1((1, 1), np.float32),
     )
-    if int(profiles.req.shape[0]) < 32767:
+    if U_rows < 32767:
         pid = _put(np.asarray(pid).astype(np.int16))
     if int(jobs.min_available.shape[0]) < 32767:
         job_h = _np(job_in)
         if job_h.dtype != np.int16:
             tasks = tasks._replace(job=_put(job_h.astype(np.int16)))
     cnt0_in = aff.cnt0
-    cnt0_host = _np(cnt0_in)
-    cnt0_sparse = cnt0_host.size > CNT0_SPARSE_MIN
+    cnt = count_entries_of(cnt0_in)
+    if cnt is not cnt0_in:  # a dense table was read for them
+        host_dense += int(cnt0_in.nbytes)
     # Where the count table, and whatever is rebuilt on the device
     # beside it, goes: the sharding a mesh caller named, else the
     # placement of a table that arrived committed.
     in_sharding = (cnt0_sharding if cnt0_sharding is not None
-                   else None if isinstance(cnt0_in, np.ndarray)
                    else getattr(cnt0_in, "sharding", None))
-    if cnt0_sparse:
-        # One scan serves both the feature bit and the sparse extraction
-        # (cnt0 is the largest host array on this path).
-        rows_nz, cols_nz = np.nonzero(cnt0_host)
-        cnt0_any = bool(len(rows_nz))
-    else:
-        cnt0_any = bool(cnt0_host.any())
+    cnt0_any = bool(len(cnt.rows))
     features = (
-        bool(_np(profiles.ports).any()),
+        bool(_np(sp.ports).any()),
         has_terms or cnt0_any,
         # Device-resident callers (ops/devsnap.py, the mesh plane cache)
         # pass the taint feature as a host-computed hint — fetching a
@@ -2887,56 +2955,29 @@ def solve_wave(
         extra_ok is not None,
         extra_score is not None,
     )
-    prof_sparse = (
-        _np(profiles.t_req_aff).size > PROF_SPARSE_MIN
-    )
-    profiles, aff, wave_terms, ew, prof_iom, terms_disjoint = (
-        _term_windows(
-            profiles, aff, pid, wave_prof, n_waves,
-            skip_cnt0=cnt0_sparse, skip_prof=prof_sparse,
-            marks=shape_marks,
-        )
-    )
+    wave_terms, ew, terms_disjoint = _term_windows(
+        terms, wave_prof, n_waves, marks=shape_marks)
+    # Every term axis gets the dummy scratch row (index Ep) that
+    # ``wave_terms`` pads with; the tables below are born with it.
+    aff = aff._replace(term_key=np.concatenate(
+        [_np(aff.term_key), np.zeros(1, np.int32)]))
     # Profile-term tables ([U, Ep] bool x3 + f32) reach ~75 MB at the
     # north-star affinity shape but are overwhelmingly zero (a profile
-    # references only its own job's terms).  Past the threshold, ship
-    # the sparse entries and rebuild dense on device instead of
-    # uploading the dense tables every cycle.
-    if prof_sparse:
-        # The tables stayed at the pre-dummy width (skip_prof): gather
-        # flags at prof_iom's nonzeros and rebuild on device at the
-        # dummy-extended width — the dummy column is all-zero, so the
-        # entry set is identical.
-        t_aff_h = _np(profiles.t_req_aff)
-        t_anti_h = _np(profiles.t_req_anti)
-        t_mat_h = _np(profiles.t_matches)
-        t_soft_h = _np(profiles.t_soft)
-        ur, ec = np.nonzero(prof_iom)
-        flags = (
-            t_aff_h[ur, ec].astype(np.int8)
-            | (t_anti_h[ur, ec].astype(np.int8) << 1)
-            | (t_mat_h[ur, ec].astype(np.int8) << 2)
-        )
-        soft_vals = t_soft_h[ur, ec].astype(np.float32)
-        k = settled_pow2(shape_marks, "prof_entries", len(ur), floor=16)
-        ppad = k - len(ur)
-        if ppad:
-            ur = np.concatenate([ur, np.zeros(ppad, np.int64)])
-            ec = np.concatenate([ec, np.zeros(ppad, np.int64)])
-            flags = np.concatenate([flags, np.zeros(ppad, np.int8)])
-            soft_vals = np.concatenate(
-                [soft_vals, np.zeros(ppad, np.float32)]
-            )
-        d_aff, d_anti, d_mat, d_soft = _scatter_profile_tables(
-            ur.astype(np.int32), ec.astype(np.int32), flags, soft_vals,
-            t_aff_h.shape[0], t_aff_h.shape[1] + 1,
+    # references only its own job's terms).  Past the threshold the
+    # entries go up and the tables are born on the device; a small set
+    # is densified here and uploaded.
+    if U_rows * Ep > PROF_SPARSE_MIN:
+        k = settled_pow2(shape_marks, "prof_entries", len(terms.rows),
+                         floor=16)
+        tables = _scatter_profile_tables(
+            *_pad_entries(k, terms.rows, terms.cols, terms.flags,
+                          terms.soft),
+            U_rows, Ep + 1,
         )
         if in_sharding is not None:
             try:
-                d_aff, d_anti, d_mat, d_soft = tuple(
-                    jax.device_put(x, in_sharding)
-                    for x in (d_aff, d_anti, d_mat, d_soft)
-                )
+                tables = tuple(
+                    jax.device_put(x, in_sharding) for x in tables)
             except ValueError:
                 # A partitioned sharding whose axis does not divide the
                 # rebuilt [U, Ep+1] tables (mesh callers sharding the
@@ -2945,48 +2986,36 @@ def solve_wave(
                 rep = jax.sharding.NamedSharding(
                     in_sharding.mesh, jax.sharding.PartitionSpec()
                 )
-                d_aff, d_anti, d_mat, d_soft = tuple(
-                    jax.device_put(x, rep)
-                    for x in (d_aff, d_anti, d_mat, d_soft)
-                )
-        profiles = profiles._replace(
-            t_req_aff=d_aff, t_req_anti=d_anti, t_matches=d_mat,
-            t_soft=d_soft,
-        )
-    if cnt0_sparse:
-        # Hyperscale [Ep, D] count tables reach hundreds of MB; ship the
-        # sparse resident entries (typically none on a fresh cycle) and
-        # scatter them on device — into the dummy-row-extended shape —
-        # instead of uploading (and host-copying) the dense zeros.
-        vals_nz = cnt0_host[rows_nz, cols_nz].astype(np.int32)
-        k = settled_pow2(shape_marks, "cnt0_entries", len(rows_nz),
+                tables = tuple(jax.device_put(x, rep) for x in tables)
+    else:
+        tables = _dense_profile_tables(terms, U_rows, Ep + 1)
+        host_dense += sum(t.nbytes for t in tables)
+    profiles = SolveProfiles(*sp[:9], *tables)
+    # The count table: ``[Ep + 1, D]`` with the domain axis zero-padded
+    # to a multiple of a mesh caller's mesh.
+    Ec, D = cnt.shape
+    d_dev = D + _mesh_pad(D, cnt0_sharding)
+    if Ec * D > CNT0_SPARSE_MIN:
+        # Hyperscale [Ep, D] count tables reach hundreds of MB; the
+        # resident entries (typically none on a fresh cycle) go up and
+        # are scattered on device instead of the dense zeros.  Mesh
+        # callers: the table is born under the placement the caller
+        # named for (or gave) cnt0, or the jit below sees committed
+        # arrays on incompatible device sets.
+        k = settled_pow2(shape_marks, "cnt0_entries", len(cnt.rows),
                          floor=16)
-        cpad = k - len(rows_nz)
-        if cpad:
-            # Padded entries add 0 to cell (0, 0): a no-op.
-            rows_nz = np.concatenate([rows_nz, np.zeros(cpad, np.int64)])
-            cols_nz = np.concatenate([cols_nz, np.zeros(cpad, np.int64)])
-            vals_nz = np.concatenate([vals_nz, np.zeros(cpad, np.int32)])
-        # Mesh callers: the rebuilt table is born under the placement
-        # the caller named for (or gave) cnt0, or the jit below sees
-        # committed arrays on incompatible device sets.
         scatter = (_scatter_cnt0 if in_sharding is None
                    else _scatter_cnt0_onto(in_sharding))
         aff = aff._replace(cnt0=scatter(
-            rows_nz.astype(np.int32), cols_nz.astype(np.int32), vals_nz,
-            cnt0_host.shape[0] + 1,
-            cnt0_host.shape[1] + _mesh_pad(cnt0_host.shape[1], cnt0_sharding),
-        ))
-    elif cnt0_sharding is not None:
-        # A table small enough to go up dense: _term_windows left it a
-        # host array with its dummy row; place it as the mesh caller
-        # asked.
-        dense = _np(aff.cnt0)
-        pad = _mesh_pad(dense.shape[1], cnt0_sharding)
-        if pad:
-            dense = np.concatenate(
-                [dense, np.zeros((dense.shape[0], pad), dense.dtype)], axis=1)
-        aff = aff._replace(cnt0=jax.device_put(dense, cnt0_sharding))
+            *_pad_entries(k, cnt.rows, cnt.cols, cnt.vals), Ec + 1, d_dev))
+    else:
+        # A table small enough to go up dense; a mesh caller's is placed
+        # as it asked.
+        dense = np.zeros((Ec + 1, d_dev), np.int32)
+        dense[cnt.rows, cnt.cols] = cnt.vals
+        host_dense += dense.nbytes
+        aff = aff._replace(cnt0=dense if cnt0_sharding is None
+                           else jax.device_put(dense, cnt0_sharding))
     # ---- two-phase solve prep (node classes + shortlists) ------------
     N_in = int(nodes.idle.shape[0])
     two_phase = _two_phase_on() and N_in > 0
@@ -3012,7 +3041,6 @@ def solve_wave(
     n_sh = int(mesh_shards) if mesh_shards else 1
     if n_sh > 1 and (N_in % n_sh):
         n_sh = 1
-    U_rows = int(profiles.req.shape[0])
     # Largest power of two <= COARSE_CHUNK: the profile axis is
     # pow2-padded, so a pow2 chunk always divides it (lax.map needs an
     # exact reshape).
@@ -3102,6 +3130,13 @@ def solve_wave(
         "mesh_shards": n_sh,
         "dom_mm": features[1] and dom_mm_on(D_dev, N_in),
         "devincr": dv.solve_info() if dv is not None else None,
+        # The hand-off of the inter-pod term data (None without terms):
+        # real entries before bucket padding, and the bytes of dense
+        # host tables this solve read or built for them.
+        "terms": {"prof_entries": int(len(terms.rows)),
+                  "cnt0_entries": int(len(cnt.rows)),
+                  "host_dense_bytes": int(host_dense)}
+        if features[1] else None,
     })
     if dv is not None:
         dv.end_solve()

@@ -213,15 +213,17 @@ def shard_wave_inputs(mesh: Mesh, solve_args: Sequence, pid, profiles,
 
     - ``aff.cnt0`` [E, D] shards on the DOMAIN axis (hostname domains
       are per-node, so D scales with N).  It is not placed here: it
-      stays the host array it is, and ``solve_wave`` reads it (feature
-      bit, term windows, sparse entries) and then places or rebuilds
-      it on the mesh under the domain-axis sharding this function
-      returns (``solve_wave``'s ``cnt0_sharding``).  A placement here
-      was fetched straight back: 820 MB of zeros at 50,000 nodes;
-    - ``pid`` and the profile rows stay host arrays as well:
-      ``solve_wave`` pads, windows and (past ``PROF_SPARSE_MIN``)
-      sparsifies them on the host before the jit sees them, so a
-      placement here was fetched straight back; the jit replicates
+      stays what the caller handed over (the fast path's
+      ``CountEntries``, a host table), and ``solve_wave`` reads it
+      (feature bit, entries) and then has the table born or placed on
+      the mesh under the domain-axis sharding this function returns
+      (``solve_wave``'s ``cnt0_sharding``).  A placement here was
+      fetched straight back: 820 MB of zeros at 50,000 nodes;
+    - ``pid`` and ``profiles`` (the fast path's ``SparseProfiles``: the
+      per-profile rows and the term tables' entries) stay on the host
+      as well: ``solve_wave`` pads and windows them and (past
+      ``PROF_SPARSE_MIN``) has the term tables born on the device, so
+      a placement here was fetched straight back; the jit replicates
       them (profile counts are tiny next to [*, N] and [E, D] state).
 
     The kernel's count-window contraction (cnt @ dom_ohT over D) then
@@ -316,7 +318,7 @@ def shard_wave_inputs(mesh: Mesh, solve_args: Sequence, pid, profiles,
     aff = type(aff)(
         node_dom=put_node_cached("node_dom", aff.node_dom),
         term_key=put(np.asarray(aff.term_key), replicated),
-        cnt0=np.asarray(aff.cnt0),
+        cnt0=aff.cnt0,
         t_req_aff=put(np.asarray(aff.t_req_aff), replicated),
         t_req_anti=put(np.asarray(aff.t_req_anti), replicated),
         t_matches=put(np.asarray(aff.t_matches), replicated),

@@ -88,7 +88,7 @@ MAX_FRAME = 8 << 30
 
 
 def _registry():
-    from .arrays.affinity import AffinityArgs
+    from .arrays.affinity import AffinityArgs, CountEntries
     from .ops.allocate import (
         SolveJobs,
         SolveNodes,
@@ -96,12 +96,16 @@ def _registry():
         SolveTasks,
     )
     from .ops.scoring import ScoreWeights
-    from .ops.wave import SolveProfiles
+    from .ops.wave import ProfileTermEntries, SolveProfiles, SparseProfiles
 
+    # The fast path's frames carry the inter-pod term data as the
+    # entries its encode emits (SparseProfiles, CountEntries): the
+    # child's solve_wave consumes them as the local one does.
     return {
         cls.__name__: cls
         for cls in (SolveNodes, SolveTasks, SolveJobs, SolveQueues,
-                    ScoreWeights, AffinityArgs, SolveProfiles)
+                    ScoreWeights, AffinityArgs, SolveProfiles,
+                    SparseProfiles, ProfileTermEntries, CountEntries)
     }
 
 
