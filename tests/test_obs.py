@@ -328,7 +328,6 @@ def test_flight_recorder_ring_is_bounded():
     fr = FlightRecorder(capacity=4)
     for i in range(10):
         fr.record(CycleRecord(session=f"s{i}"))
-    assert len(fr) == 4
     recs = fr.recent()
     assert [r.seq for r in recs] == [7, 8, 9, 10]
     assert fr.get(10).session == "s9"
@@ -336,7 +335,6 @@ def test_flight_recorder_ring_is_bounded():
     assert fr.recent(2)[0].seq == 9
     assert fr.recent(0) == []
     assert fr.recent(-3) == []
-    assert fr.last().seq == 10
 
 
 def test_lanes_survive_tracing_disabled(monkeypatch):

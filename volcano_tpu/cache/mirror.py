@@ -424,6 +424,10 @@ class StoreMirror:
         # status-sync / removed) through it.  None for bare mirrors and
         # under VOLCANO_TPU_JOURNEY=0; internally synchronized.
         self.journey = None
+        # The store's account of the time between two cycles
+        # (obs/trace.py ``BetweenAccount``), attached like the two
+        # above: a compaction counts itself there.
+        self.between = None
 
     # ================================================================ pods
 
@@ -634,10 +638,15 @@ class StoreMirror:
         self._pods_ref = pods
 
     # holds: _lock
-    def upsert_pod(self, pod: Pod, job_row_of) -> None:
-        """Insert or update a pod row.  ``job_row_of(job_id) -> row``."""
+    def upsert_pod(self, pod: Pod, job_row_of, st=None) -> None:
+        """Insert or update a pod row.  ``job_row_of(job_id) -> row``.
+        ``st`` is the timer of the one store event in
+        ``obs.trace.SAMPLE_STRIDE`` that is timed phase by phase
+        (``_Sample``), None for every other call."""
         self.mutation_seq += 1
         feat = self._feat(pod)
+        if st is not None:
+            st.mark("feat")
         status = int(pod.task_status())
         node_row = -1
         if pod.node_name:
@@ -658,19 +667,27 @@ class StoreMirror:
                 # group name after the fact (pg_controller_handler.go:72-105).
                 old = int(self.p_status[row])
                 if old != status:
+                    if st is not None:
+                        st.mark("columns")
                     if self.audit is not None:
                         self.audit.flow("pod-update", old, status)
+                        if st is not None:
+                            st.mark("audit")
                     if self.journey is not None:
                         self.journey.pod_event(pod.uid, "status-sync",
                                                status=status)
+                        if st is not None:
+                            st.mark("journey")
                 self.p_status[row] = status
                 self.p_node[row] = node_row
                 self.p_node_name[row] = pod.node_name or None
                 jid = pod.job_id()
                 self.p_job[row] = job_row_of(jid) if jid else -1
+                if st is not None:
+                    st.mark("columns")
                 return
             # Spec changed: tombstone the old row, fall through to re-add.
-            self.remove_pod(pod.uid)
+            self.remove_pod(pod.uid, st)
         row = len(self.p_uid)
         self.mark_pod_dirty(row)
         self.p_uid.append(pod.uid)
@@ -697,7 +714,11 @@ class StoreMirror:
         self.p_node_name = _grow(self.p_node_name, n)
 
         if self.audit is not None:
+            if st is not None:
+                st.mark("columns")
             self.audit.flow_added(status)
+            if st is not None:
+                st.mark("audit")
         self.p_status[row] = status
         self.p_node[row] = node_row
         self.p_node_name[row] = pod.node_name or None
@@ -705,10 +726,14 @@ class StoreMirror:
         jrow = job_row_of(jid) if jid else -1
         self.p_job[row] = jrow
         if self.journey is not None:
+            if st is not None:
+                st.mark("columns")
             self.journey.pod_event(
                 pod.uid, "enqueued", status=status,
                 queue=self.j_queue[jrow] if jrow >= 0 else "",
                 gang=jid)
+            if st is not None:
+                st.mark("journey")
         self.p_prio[row] = feat.priority
         self.p_create[row] = feat.create
         self.p_alive[row] = True
@@ -762,9 +787,11 @@ class StoreMirror:
                     self.terms_live += not members
                     members.append(row)
                     self.term_members_total += 1
+        if st is not None:
+            st.mark("columns")
 
     # holds: _lock
-    def remove_pod(self, uid: str) -> None:
+    def remove_pod(self, uid: str, st=None) -> None:
         row = self.p_row.pop(uid, None)
         if row is None:
             return
@@ -772,11 +799,17 @@ class StoreMirror:
         self.mark_pod_dirty(row)
         self.pod_obj_gen += 1
         if self.p_alive[row]:
+            if st is not None:
+                st.mark("columns")
             if self.audit is not None:
                 self.audit.flow_removed(int(self.p_status[row]))
+                if st is not None:
+                    st.mark("audit")
             if self.journey is not None:
                 self.journey.pod_event(uid, "removed",
                                        status=int(self.p_status[row]))
+                if st is not None:
+                    st.mark("journey")
         self.p_alive[row] = False
         self.p_uid[row] = None
         self.p_node_name[row] = None
@@ -784,6 +817,8 @@ class StoreMirror:
             self.p_pod_nones += 1
         self.p_pod[row] = None
         self.n_dead += 1
+        if st is not None:
+            st.mark("columns")
 
     # holds: _lock
     def set_pod_state(self, uid: str, status: int, node_row: int) -> None:
@@ -1186,6 +1221,9 @@ class StoreMirror:
         total = len(self.p_uid)
         if total < 4096 or self.n_dead * 2 < total:
             return
+        between = self.between
+        if between is not None:
+            t0_ns, gc_ns0 = between.clock(), between.gc_ns()
         live = np.flatnonzero(self.p_alive[:total])
         old = self
         fresh = StoreMirror.__new__(StoreMirror)
@@ -1292,6 +1330,7 @@ class StoreMirror:
         # renumbering untouched; only the handle must ride the swap.
         self.audit = audit
         self.journey = journey
+        self.between = between
         self.mutation_seq = seq + 1
         self.compact_gen = gen + 1
         self._node_dirty_rows = dirty
@@ -1302,6 +1341,8 @@ class StoreMirror:
         # (from fresh.__init__) is exactly right — only the monotone
         # agreement token must survive.
         self.dirty_seq = dseq + 1
+        if between is not None:
+            between.compacted(t0_ns, gc_ns0)
 
     # holds: _lock
     def resync_status(self, pods: Dict[str, "Pod"]) -> None:

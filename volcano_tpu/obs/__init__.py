@@ -17,7 +17,8 @@ unconditionally:
   solve's counts, pods considered / bound /
   dropped, staleness-guard drop counts by reason, in-flight fetch wait,
   device crash events, mirror ``mutation_seq``/``epoch`` at dispatch vs
-  commit, and the cycle's spans.
+  commit, what the store did between the previous cycle and this one
+  (``between``), and the cycle's spans.
 - ``export``   — Chrome/Perfetto ``trace_event`` JSON (loadable in
   ``chrome://tracing`` / https://ui.perfetto.dev), with flow arrows
   linking a pipelined solve's dispatch span in cycle N to its

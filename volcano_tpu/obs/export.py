@@ -16,7 +16,10 @@ Produces the JSON object format (``{"traceEvents": [...]}``) that both
   capacity-taken" is readable at the cycle where it happened;
 - metadata events name the process and the logical threads ("cycle",
   "rpc", "bind" — the bind dispatcher's per-batch ``bind:*`` events —
-  and "store" — object-model rebuilds, from whichever thread paid);
+  "store" — object-model rebuilds, from whichever thread paid, and one
+  ``between`` event per record over the interval its ``between`` block
+  describes, the block as ``args`` — and "gc" — the collector's
+  passes of generation 1 and 2, ``gc:gen1`` / ``gc:gen2``);
 - a lane span says so (``args.lane``): lanes partition the cycle, the
   spans nested under them (``commit:*``, ``device:*``) are children;
 - pod journeys (obs/journey.py, ISSUE 18) export as ASYNC tracks: one
@@ -35,7 +38,7 @@ from __future__ import annotations
 from typing import Dict, Iterable, List, Optional
 
 PID = 1
-_TID_ORDER = ("cycle", "rpc", "bind", "store")
+_TID_ORDER = ("cycle", "rpc", "bind", "store", "gc")
 
 
 def _tid_of(name: str, table: Dict[str, int]) -> int:
@@ -79,6 +82,17 @@ def trace_events(records: Iterable,
                 flows.setdefault(int(span.flow), []).append(
                     len(events) - 1
                 )
+        between = rec.between
+        if between:
+            events.append({
+                "name": "between", "cat": "store", "ph": "X",
+                "ts": between["t0_ns"] / 1e3,
+                "dur": (between["t1_ns"] - between["t0_ns"]) / 1e3,
+                "pid": PID, "tid": _tid_of("store", tid_table),
+                "args": {"cycle_seq": rec.seq, **{
+                    k: v for k, v in between.items()
+                    if k not in ("t0_ns", "t1_ns")}},
+            })
         base_ts = rec.t_wall * 1e6
         for msg in rec.device_events:
             events.append({

@@ -21,6 +21,9 @@ cycle 48231 drop 17 rows" investigation needs:
 - the dispatched and committed solve-ids (the cross-cycle link),
 - the counts of the cycle's solve (``solve``: rows, nodes, devincr
   mode, devsnap uploads, host<->device transfers and their bytes),
+- what the store did since the previous record was sealed
+  (``between``: event handler calls by kind, their estimated seconds,
+  the collector's passes, the bind worker's busy time),
 - the cycle's trace spans (``obs.trace``), and
 - the runtime auditor's anomalies for the cycle (``obs.audit``).
 
@@ -54,7 +57,7 @@ class CycleRecord:
         "committed_solve_id", "mutation_seq_at_dispatch",
         "mutation_seq_at_commit", "epoch_at_dispatch", "epoch_at_commit",
         "device_events", "error", "spans", "rebalance", "whatif",
-        "pool", "anomalies", "solve", "object_model",
+        "pool", "anomalies", "solve", "object_model", "between",
     )
 
     def __init__(self, session: str = "", path: str = "fast",
@@ -79,7 +82,8 @@ class CycleRecord:
                  pool: Optional[dict] = None,
                  anomalies: Optional[List[dict]] = None,
                  solve: Optional[dict] = None,
-                 object_model: Optional[Dict[str, int]] = None):
+                 object_model: Optional[Dict[str, int]] = None,
+                 between: Optional[dict] = None):
         self.seq = -1  # assigned by FlightRecorder.record
         self.session = session
         self.path = path
@@ -136,6 +140,14 @@ class CycleRecord:
         # that therefore left it alone.  None on the object path, which
         # reads the model.
         self.object_model = object_model
+        # What the store did between the previous record's seal and
+        # this cycle's start (``obs.trace.BetweenAccount.block``, put
+        # here by the cycle's ``CycleScope``): handler calls by kind,
+        # their estimated seconds by phase, the collector's passes, the
+        # bind worker's busy time.  A fixed set of keys whatever the
+        # interval's pod count; None for a record no store's tracer
+        # sealed.
+        self.between = between
 
     @property
     def unattributed_s(self) -> float:
@@ -183,6 +195,7 @@ class CycleRecord:
                       if self.solve is not None else None),
             "object_model": (dict(self.object_model)
                              if self.object_model is not None else None),
+            "between": self.between,
         }
         if include_spans:
             d["spans"] = [s.to_dict() for s in self.spans]
@@ -230,11 +243,3 @@ class FlightRecorder:
                 if rec.seq == seq:
                     return rec
         return None
-
-    def __len__(self) -> int:
-        with self._lock:
-            return len(self._ring)
-
-    def last(self) -> Optional[CycleRecord]:
-        with self._lock:
-            return self._ring[-1] if self._ring else None

@@ -34,9 +34,9 @@ scope belong to the calling thread (thread-local state: under the
 sharded control plane several cycle threads share one store's tracer,
 and the prologue of a cycle runs before the store lock is taken).
 Other threads (the bind dispatcher, remote RPC clients, whoever
-triggers an object-model rebuild) contribute through ``event()``,
-which appends a parentless record under the tracer's lock and never
-touches a stack.  ``drain()`` hands the calling thread's spans plus
+triggers an object-model rebuild, the collector's hook) contribute
+through ``event()``, which appends a parentless record to a deque and
+never touches a stack or a lock.  ``drain()`` hands the calling thread's spans plus
 every helper-thread event accumulated so far to the record being
 sealed.
 
@@ -50,14 +50,26 @@ in the same xplane as the device's operations.
 Span timestamps are monotonic (``perf_counter_ns``) shifted to the
 epoch by a per-tracer anchor captured at construction, so exported
 traces from one process share one timeline.
+
+Between two cycles (ISSUE 35): ``BetweenAccount`` counts the store's
+event handlers by kind, times one call in ``SAMPLE_STRIDE`` of each
+kind phase by phase, and is told of every pass of the collector by the
+process's one ``gc.callbacks`` hook (``_Collector``).  The cycle's
+frame takes a snapshot of it on entry and turns it into
+``CycleRecord.between`` at the seal: what happened since the previous
+record was sealed.  No lane, nothing per pod (docs/tracing.md,
+"Between two cycles").
 """
 
 from __future__ import annotations
 
+import collections
+import gc
 import itertools
 import os
 import threading
 import time
+import weakref
 from typing import Dict, List, Optional
 
 # The nested pair the lanes rule allows inside ``device``.
@@ -189,14 +201,18 @@ class Tracer:
         # epoch_ns = anchor + perf_counter_ns (captured together).
         self._anchor_ns = time.time_ns() - time.perf_counter_ns()
         self._tls = _ThreadState()
-        self._events: List[SpanRecord] = []  # guarded-by: _lock
+        # event() appends from any thread, drain() pops: a deque does
+        # both atomically, so no lock.  There must be none: the
+        # collector's hook appends here from whatever bytecode of
+        # whatever thread a pass interrupts, event() itself included.
+        self._events: collections.deque = collections.deque()
         self._ids = itertools.count(1)
-        # Guards _events: event() appends from any thread, drain()
-        # takes them.  span() itself is lock-free (thread-local).
-        self._lock = threading.Lock()
         # Factory of profiler annotations (``vc:<lane>``), handed in
         # through ``tracer_of`` by the cycle drivers; None = none.
         self.annotate = None
+        # The account of the time between two cycles, made by the
+        # owning store (``BetweenAccount``); None for a bare tracer.
+        self.between: Optional["BetweenAccount"] = None
 
     # ------------------------------------------------------------- spans
 
@@ -222,10 +238,9 @@ class Tracer:
         bind dispatcher).  ``t0_ns`` is a ``perf_counter_ns`` reading."""
         if not self.enabled:
             return
-        rec = SpanRecord(name, cat, self._anchor_ns + t0_ns, dur_ns,
-                         next(self._ids), 0, flow, tid, args)
-        with self._lock:
-            self._events.append(rec)
+        self._events.append(SpanRecord(
+            name, cat, self._anchor_ns + t0_ns, dur_ns, next(self._ids),
+            0, flow, tid, args))
 
     def timed_event(self, name: str, cat: str = "rpc",
                     tid: str = "rpc", flow: Optional[int] = None,
@@ -240,11 +255,12 @@ class Tracer:
         event accumulated so far (cycle end), and reset."""
         st = self._tls
         spans, st.spans = st.spans, []
-        with self._lock:
-            if self._events:
-                spans.extend(self._events)
-                self._events = []
-        return spans
+        pop = self._events.popleft
+        try:
+            while True:
+                spans.append(pop())
+        except IndexError:
+            return spans
 
     # ------------------------------------------------------------- cycle
 
@@ -265,7 +281,7 @@ class CycleScope:
     lanes, ``unattributed_ms`` and spans are final only then."""
 
     __slots__ = ("tracer", "flight", "lanes", "t_wall", "record",
-                 "_span", "_depth", "_t0_ns", "_stamp")
+                 "_span", "_depth", "_t0_ns", "_stamp", "_between")
 
     def __init__(self, tracer: Tracer, flight=None):
         self.tracer = tracer
@@ -278,6 +294,7 @@ class CycleScope:
         self._depth = 0
         self._t0_ns = 0
         self._stamp = ()
+        self._between = None
 
     def __enter__(self) -> "CycleScope":
         if self._depth == 0:
@@ -285,6 +302,12 @@ class CycleScope:
             self.t_wall = time.time()
             self._span.__enter__()
             self._t0_ns = self._span.t0
+            acct = self.tracer.between
+            if acct is not None:
+                # What the store did since the previous record was
+                # sealed, as this cycle finds it.
+                self._between = acct.snapshot(self._t0_ns)
+                acct.cycle_open(1)
         self._depth += 1
         return self
 
@@ -293,6 +316,8 @@ class CycleScope:
         if self._depth == 0:
             self._span.__exit__(exc_type, exc, tb)
             self.tracer._tls.scope = None
+            if self._between is not None:
+                self.tracer.between.cycle_open(-1)
             self._seal(time.perf_counter_ns())
         return False
 
@@ -333,10 +358,15 @@ class CycleScope:
         rec.duration_s = (now_ns - self._t0_ns) * 1e-9
         rec.lanes = dict(self.lanes)
         rec.spans = spans
+        snap, self._between = self._between, None
+        if snap is not None:
+            rec.between = self.tracer.between.block(snap, spans, now_ns)
         if self._depth:  # split(): the call goes on
             self.t_wall = time.time()
             self._t0_ns = now_ns
             self.lanes.clear()
+            if snap is not None:
+                self._between = self.tracer.between.snapshot(now_ns)
         if self.flight is not None:
             seq = self.flight.record(rec)
             for obj in self._stamp:
@@ -371,6 +401,323 @@ class _TimedEvent:
                      flow=self.flow, args=self.args)
         return False
 
+
+# ------------------------------------------- the time between two cycles
+
+# One call in SAMPLE_STRIDE of each kind is timed: the one its kind's
+# count picks (``counts[kind] % SAMPLE_STRIDE == 0``).  A prime, and not
+# the 64 a mask would give: gangs come in twos, fours and eights, and a
+# timer that always met a gang's first pod would read what only that
+# pod pays (the interning of its gang's terms, the gang's first journey
+# row).  With a prime the timed place moves through the gang.
+SAMPLE_STRIDE = 61
+
+# ``ClusterStore``'s public event handlers, "<Kind>/<event>" as its
+# ``_notify`` names them.
+EVENT_KINDS = tuple(
+    f"{kind}/{event}"
+    for kind, events in (
+        ("Pod", ("add", "update", "delete")),
+        ("PodGroup", ("add", "update", "delete")),
+        ("Node", ("add", "update", "delete")),
+        ("Queue", ("add", "update", "delete")),
+        ("PriorityClass", ("add", "delete")),
+        ("ResourceQuota", ("add",)),
+        ("Job", ("add", "update", "delete")),
+        ("Command", ("add", "delete")),
+    )
+    for event in events
+)
+# The three hot ones' places in it (``BetweenAccount.counts`` is a list).
+POD_ADD, POD_UPDATE, POD_DELETE = 0, 1, 2
+
+# The bind track's events during which the worker runs Python
+# (``bind:queue_wait`` is the batch waiting, not the worker working).
+BIND_BUSY = frozenset(("bind:materialize", "bind:binder",
+                       "bind:on_success", "bind:release"))
+
+
+class _Sample:
+    """One timed handler call: nanoseconds by phase.  Each ``mark``
+    gives the time since the previous one to a phase, so the phases sum
+    to the whole; time taken out (a collector pass, a compaction, each
+    counted exactly elsewhere) moves ``t`` forward instead.  A stamp
+    reads the clock on its way in and again on its way out, so that its
+    own bookkeeping is in no phase (what is left of a stamp in the
+    phases, the call and the return, is about a tenth of a
+    microsecond)."""
+
+    __slots__ = ("acct", "kind", "ns", "t", "clock")
+
+    def __init__(self, acct: "BetweenAccount", kind: int):
+        self.acct = acct
+        self.kind = kind
+        self.ns: Dict[str, int] = {}
+        self.clock = acct.clock
+        self.t = 0
+
+    def mark(self, phase: str) -> "_Sample":
+        now = self.clock()
+        ns = self.ns
+        ns[phase] = ns.get(phase, 0) + now - self.t
+        self.t = self.clock()
+        return self
+
+    def close(self, phase: str) -> None:
+        """The lock is released: the rest goes to ``phase`` and the
+        call into the account."""
+        self.mark(phase)
+        acct = self.acct
+        with acct._lock:
+            acct._samples[self.kind] += 1
+            sums = acct._sums[self.kind]
+            for name, ns in self.ns.items():
+                sums[name] = sums.get(name, 0) + ns
+            acct._open = None
+
+
+class BetweenAccount:
+    """What a store did between two cycles, by itself: its event
+    handlers' calls by kind (exact), their seconds by kind and phase
+    (from one timed call in ``SAMPLE_STRIDE``), the collector's passes
+    (exact, from ``_Collector``) and the pod table's compactions
+    (exact).  Lifetime counters; ``snapshot()`` copies them at a
+    cycle's start and ``block()`` turns the difference to the previous
+    sealed record's snapshot into ``CycleRecord.between``.
+
+    Who writes what: ``counts`` the handlers, under the STORE's lock;
+    ``_samples`` / ``_sums`` / ``_open`` a timed call, under ``_lock``;
+    the collector's numbers the hook, of which one runs at a time in a
+    process.  The hook takes no lock at all: a pass interrupts whatever
+    bytecode its thread was at, a locked region included."""
+
+    def __init__(self, tracer: "Tracer", clock=time.perf_counter_ns):
+        self._tracer = weakref.ref(tracer)
+        self.clock = clock
+        # By place in EVENT_KINDS: the calls, the timed calls, their
+        # nanoseconds by phase.
+        n = len(EVENT_KINDS)
+        self.counts: List[int] = [0] * n
+        self._samples: List[int] = [0] * n
+        self._sums: List[Dict[str, int]] = [{} for _ in range(n)]
+        self._open: Optional[_Sample] = None
+        # [passes, ns, collected] by generation, then of the passes
+        # inside an open cycle (any generation).
+        self._gc = [[0, 0, 0] for _ in range(4)]
+        self._gc_longest_ns = 0
+        self._compact = [0, 0]  # compactions, ns
+        self._cycles_open = 0
+        self._lock = threading.Lock()
+        self._base = self.snapshot(0)
+        self._t0_ns = time.perf_counter_ns()
+        tracer.between = self
+        _collector.watch(tracer)
+
+    # ---------------------------------------------------- the event path
+
+    def sample(self, kind: int) -> Optional[_Sample]:
+        """A timer for this call of ``kind``, whose count said it is
+        due; None while the tracer is off or another timed call is
+        open (a handler calling a handler, or two threads at once)."""
+        tr = self._tracer()
+        if tr is None or not tr.enabled:
+            return None
+        with self._lock:
+            if self._open is not None:
+                return None
+            st = self._open = _Sample(self, kind)
+        st.t = self.clock()
+        return st
+
+    def took_out(self, dur_ns: int) -> None:
+        """Time that is counted exactly elsewhere leaves the open timed
+        call, if there is one."""
+        st = self._open
+        if st is not None:
+            st.t += dur_ns
+
+    def compacted(self, t0_ns: int, gc_ns0: int) -> None:
+        """``StoreMirror.maybe_compact`` rebuilt the pod table: counted
+        whole, without the collector's passes inside it."""
+        dur = self.clock() - t0_ns - (self.gc_ns() - gc_ns0)
+        self._compact[0] += 1
+        self._compact[1] += dur
+        self.took_out(dur)
+
+    def gc_ns(self) -> int:
+        return sum(g[1] for g in self._gc)
+
+    # ------------------------------------------------------ the collector
+
+    def collected(self, tr: "Tracer", gen: int, info: dict, t0_ns: int,
+                  dur_ns: int) -> None:
+        """One pass of the collector, told by the process's hook."""
+        # A pass while a cycle is open on the store is the cycle's
+        # (``run_once()`` switches the collector off, so its ``gc``
+        # lane's own sweep), apart from the interval's.
+        mine = self._gc[3 if self._cycles_open else gen]
+        mine[0] += 1
+        mine[1] += dur_ns
+        collected = info.get("collected", 0)
+        mine[2] += collected
+        if not self._cycles_open and dur_ns > self._gc_longest_ns:
+            self._gc_longest_ns = dur_ns
+        self.took_out(dur_ns)
+        if gen:
+            # Generation 0 is counted and never recorded: its number
+            # grows with the batch.
+            tr.event(f"gc:gen{gen}", "gc", t0_ns, dur_ns, tid="gc",
+                     args={"collected": collected,
+                           "uncollectable": info.get("uncollectable", 0)})
+
+    def cycle_open(self, delta: int) -> None:
+        with self._lock:
+            self._cycles_open += delta
+
+    # ------------------------------------------------------- the record
+
+    def snapshot(self, t1_ns: int) -> dict:
+        """The counters as a cycle finds them at its start, ``t1_ns``."""
+        return {
+            "t1_ns": t1_ns,
+            "counts": list(self.counts),
+            "samples": list(self._samples),
+            "sums": [dict(v) for v in self._sums],
+            "gc": [list(g) for g in self._gc],
+            "compact": list(self._compact),
+        }
+
+    def block(self, snap: dict, spans: list, seal_ns: int) -> dict:
+        """``CycleRecord.between`` of the record being sealed: ``snap``
+        against the snapshot the previous sealed record took.  A
+        snapshot that no record took (a cycle that ended without one)
+        leaves the base where it was, so nothing counted is lost."""
+        tr = self._tracer()
+        anchor = tr._anchor_ns if tr is not None else 0
+        timing = tr is not None and tr.enabled
+        with self._lock:
+            base, t0_ns = self._base, self._t0_ns
+            if snap["t1_ns"] >= base["t1_ns"]:
+                self._base = snap
+            self._t0_ns = seal_ns
+            # No pass is the interval's while a cycle is open, so the
+            # longest is still what the snapshot would have found.
+            longest_ns, self._gc_longest_ns = self._gc_longest_ns, 0
+        t1_ns = snap["t1_ns"]
+        t0_ns = min(t0_ns, t1_ns)
+        out = {"t0_ns": anchor + t0_ns, "t1_ns": anchor + t1_ns,
+               "stride": SAMPLE_STRIDE}
+        events = out["events"] = {}
+        held_s = 0.0
+        for i, kind in enumerate(EVENT_KINDS):
+            n = max(0, snap["counts"][i] - base["counts"][i])
+            if not n:
+                continue
+            ev = events[kind] = {"n": n}
+            if not timing:
+                continue
+            k = max(0, snap["samples"][i] - base["samples"][i])
+            sums, sums0 = snap["sums"][i], base["sums"][i]
+            phases = {name: ns - sums0.get(name, 0)
+                      for name, ns in sums.items()}
+            whole = sum(phases.values())
+            # The estimate is sum x stride: each timed call stands for
+            # the stride's calls, whichever record they fall into, so
+            # estimates add up over records without a bias (a block of
+            # fewer calls than the stride has often no timed call, and
+            # then reads 0).
+            ev.update(samples=k, sampled_s=_s(whole),
+                      est_s=_s(whole * SAMPLE_STRIDE),
+                      phases={name: {"sampled_s": _s(ns),
+                                     "est_s": _s(ns * SAMPLE_STRIDE)}
+                              for name, ns in phases.items()})
+            held_s += _s((whole - phases.get("lock_wait", 0))
+                         * SAMPLE_STRIDE)
+        if not timing:
+            return out
+        out["lock_held_s"] = round(held_s, 9)
+        # The cycle's own passes come after its snapshot: read now.
+        snap["gc"][3] = list(self._gc[3])
+        gcd = out["gc"] = {
+            name: {"n": g[0] - g0[0], "s": _s(g[1] - g0[1]),
+                   "collected": g[2] - g0[2]}
+            for name, g, g0 in zip(("gen0", "gen1", "gen2", "in_cycle"),
+                                   snap["gc"], base["gc"])}
+        gcd["longest_s"] = _s(longest_ns)
+        out["compactions"] = snap["compact"][0] - base["compact"][0]
+        out["compact_s"] = _s(snap["compact"][1] - base["compact"][1])
+        out["bind_busy_s"] = _busy_inside(
+            spans, anchor + t0_ns, anchor + t1_ns)
+        return out
+
+
+def _busy_inside(spans: list, t0_ns: int, t1_ns: int) -> float:
+    """Seconds of [t0_ns, t1_ns] in which the bind worker ran: the
+    union of its ``BIND_BUSY`` events, cut to the interval."""
+    cuts = sorted(
+        (max(s.ts_ns, t0_ns), min(s.ts_ns + s.dur_ns, t1_ns))
+        for s in spans if s.tid == "bind" and s.name in BIND_BUSY)
+    total, end = 0, t0_ns
+    for a, b in cuts:
+        a = max(a, end)
+        if b > a:
+            total += b - a
+            end = b
+    return _s(total)
+
+
+def _s(ns) -> float:
+    """Nanoseconds as seconds, to the nanosecond."""
+    return round(ns * 1e-9, 9)
+
+
+class _Collector:
+    """The process's one ``gc.callbacks`` hook, however many stores it
+    makes: it times every pass of the collector and tells the accounts
+    of the live tracers, reached through weak references (a dropped
+    store's tracer and account go with it).  On the profiler's clock a
+    pass of generation 1 or 2 lies under ``vc:gc1`` / ``vc:gc2``."""
+
+    def __init__(self):
+        self.clock = time.perf_counter_ns
+        self._tracers: tuple = ()  # weakref.ref(Tracer), copy-on-write
+        self._lock = threading.Lock()
+        self._t0 = 0
+        self._ann = None
+
+    def watch(self, tracer: "Tracer") -> None:
+        with self._lock:
+            self._tracers = tuple(
+                r for r in self._tracers if r() is not None
+            ) + (weakref.ref(tracer),)
+            if self.hook not in gc.callbacks:
+                gc.callbacks.append(self.hook)
+
+    def hook(self, phase: str, info: dict) -> None:
+        gen = info.get("generation", 0)
+        if phase == "start":
+            if gen:
+                for ref in self._tracers:
+                    tr = ref()
+                    if (tr is not None and tr.enabled
+                            and tr.annotate is not None):
+                        self._ann = tr.annotate(f"vc:gc{gen}")
+                        self._ann.__enter__()
+                        break
+            self._t0 = self.clock()
+            return
+        t0 = self._t0
+        dur = self.clock() - t0
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        for ref in self._tracers:
+            tr = ref()
+            if tr is not None and tr.enabled and tr.between is not None:
+                tr.between.collected(tr, gen, info, t0, dur)
+
+
+_collector = _Collector()
 
 _NULL = Tracer(enabled=False)
 
