@@ -3,8 +3,10 @@ its traffic file and the per-layer metric files, all data.
 
 A later PR adds a configuration, a traffic mix, a cell or a per-layer metric
 by adding files and entries: ``configs/<config>.json``,
-``traffic/<mix>.json``, ``layer_metrics/<metric>.json`` under the benchmark's
-first path, found from the entries of ``BENCHMARK.json``.
+``traffic/<mix>.json``, ``layer_metrics/<metric>.json`` and, for a guarantee
+of the configuration's own, ``reference/<name>_ref.py`` under the benchmark's
+first path, found from the entries of ``BENCHMARK.json`` and the
+configuration's ``guarantees.checks``.
 """
 
 from __future__ import annotations
@@ -14,6 +16,8 @@ import os
 from dataclasses import dataclass
 from pathlib import Path
 from typing import List
+
+from . import checks
 
 ROOT = Path(__file__).resolve().parents[2]
 
@@ -36,10 +40,12 @@ class Cell:
     traffic: dict
     end_to_end: List[dict]      # BENCHMARK.json entries this cell reports
     per_layer: List[dict]       # entry merged with its layer_metrics file
+    home: Path = ROOT / "benchmark"     # where reference/<name>_ref.py lies
 
     def sizes(self) -> dict:
-        """Resident set, batch and warm-up rounds in pods, from the traffic's
-        shares of the configuration's backlog."""
+        """Resident set, batch, waiting pods and warm-up rounds in pods, from
+        the traffic's shares of the configuration's backlog, and the
+        traffic's other keys with their defaults."""
         backlog = int(self.config["backlog_pods"])
         t = self.traffic
         return {
@@ -49,6 +55,13 @@ class Cell:
             "max_cycles": int(t.get("max_cycles", 4)),
             "bind_wait_s": float(t.get("bind_wait_s", 10.0)),
             "profile_seconds": float(t.get("profile_seconds", 10.0)),
+            # The kubelet's side and the tiers; absent, nothing of them is.
+            "termination_cycles": int(t.get("termination_cycles", 0)),
+            "settle_cycles": int(t.get("settle_cycles", 0)),
+            "pods_run": bool(t.get("pods_run", False)),
+            "waiting_pods": int(round(backlog * float(t.get("waiting_fraction", 0.0)))),
+            "resident_class": t.get("resident_class"),
+            "batch_class": t.get("batch_class"),
         }
 
 
@@ -79,7 +92,13 @@ def load_cell(workload: str, benchmark_file: Path = ROOT / "BENCHMARK.json") -> 
         if _applies(entry, workload):
             spec = _read(home / "layer_metrics" / f"{entry['name']}.json")
             per_layer.append({**spec, **entry})
+    for name in checks.names(config):
+        if not checks.path_of(home, name).is_file():
+            raise SystemExit(f"{configs[w['config']]['file']}: guarantees."
+                             f"checks names {name!r}, and there is no "
+                             f"{checks.path_of(home, name)}")
     return Cell(
+        home=home,
         name=workload, chips=int(w["chips"]), config_name=w["config"],
         config=config, traffic_name=w["traffic"], traffic=traffic,
         end_to_end=[e for e in bench["end_to_end"] if _applies(e, workload)],

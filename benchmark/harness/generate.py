@@ -44,10 +44,21 @@ class Plan:
     gang_cpu: List[int] = field(default_factory=list)      # cores
     gang_mem_gi: List[int] = field(default_factory=list)
     gang_kind: List[str] = field(default_factory=list)     # "", affinity, ...
+    # What follows is set only by a configuration with ``priority_classes``
+    # or a traffic with ``waiting_fraction``; left as it is, a plan is what
+    # it was before they came.
+    gang_priority: List[str] = field(default_factory=list)  # class names
+    gang_size: Optional[np.ndarray] = None     # pods, where min_member is less
+    gang_max_unavailable: List[Optional[int]] = field(default_factory=list)
+    may_wait: bool = False                     # need not bind within its round
 
     @property
     def n_pods(self) -> int:
         return len(self.names)
+
+    def sizes(self) -> np.ndarray:
+        """Pods in each gang (``gang_min_member`` unless a class said less)."""
+        return self.gang_min_member if self.gang_size is None else self.gang_size
 
     def keys(self) -> List[str]:
         return [f"{NAMESPACE}/{n}" for n in self.names]
@@ -62,6 +73,13 @@ def node_alloc(config) -> np.ndarray:
     n = config["nodes"]
     row = [int(n["cpu"]) * 1000, int(n["memory_gi"]) * GI, int(n["pods"])]
     return np.tile(np.array(row, dtype=np.int64), (int(n["count"]), 1))
+
+
+def node_labels(config) -> List[dict]:
+    """Each node's labels as plain data: zones dealt round-robin."""
+    zones = int(config["nodes"].get("zones", 0))
+    return [{"zone": f"zone-{i % zones}"} if zones > 0 else {}
+            for i in range(int(config["nodes"]["count"]))]
 
 
 def queue_names(config) -> List[str]:
@@ -86,18 +104,33 @@ class Generator:
                     float(mix.get("anti_affinity", 0.0)),
                     float(mix.get("spread", 0.0)))
         self.zones = int(config["nodes"].get("zones", 0))
+        # Priority classes, optional: each may bring a gang shape of its own.
+        self.classes = {c["name"]: c for c in config.get("priority_classes", [])}
+        shares = np.array([float(c.get("share", 0.0))
+                           for c in self.classes.values()])
+        self._class_edges = np.cumsum(shares / shares.sum()) if shares.sum() else None
+        self.batch_class: Optional[str] = None     # of a plan that names none
         self._gangs_made = 0
         self._deck: List[int] = []
 
-    def plan(self, n_pods: int, tag: str, gang_size: Optional[int] = None) -> Plan:
+    def plan(self, n_pods: int, tag: str, gang_size: Optional[int] = None,
+             klass: Optional[str] = None, may_wait: bool = False) -> Plan:
         """``n_pods`` pods in gangs of the configuration's size(s) (or of
-        ``gang_size``); the last gang is cut to what is left."""
-        sizes = []
+        ``gang_size``); the last gang is cut to what is left.  Where the
+        configuration has priority classes every gang is of ``klass``, else
+        of ``self.batch_class``, else of a class drawn by the classes'
+        shares, and takes that class's ``gang`` where it states one."""
+        sizes, classes = [], []
         left = int(n_pods)
         while left > 0:
-            size = gang_size or int(self.gang_sizes[
-                int(self.rng.integers(len(self.gang_sizes)))
-                if len(self.gang_sizes) > 1 else 0])
+            shape = self.gang_sizes
+            if self.classes:
+                classes.append(klass or self.batch_class or self._draw_class())
+                own = self.classes[classes[-1]].get("gang")
+                if own:
+                    shape = [int(s) for s in own.get("sizes", [own.get("size", 1)])]
+            size = gang_size or int(shape[
+                int(self.rng.integers(len(shape))) if len(shape) > 1 else 0])
             size = min(size, left)
             sizes.append(size)
             left -= size
@@ -119,10 +152,33 @@ class Generator:
                 mem.append(m * GI)
                 gang.append(g)
         self._gangs_made += g_n
-        return Plan(tag, names, np.array(cpu, np.int64), np.array(mem, np.int64),
+        plan = Plan(tag, names, np.array(cpu, np.int64), np.array(mem, np.int64),
                     np.array(gang, np.int64), gang_names,
                     np.array(sizes, np.int64), gang_queue, gang_cpu, gang_mem,
-                    kinds)
+                    kinds, may_wait=may_wait)
+        if classes:
+            self._shape_by_class(plan, classes)
+        return plan
+
+    def _draw_class(self) -> str:
+        if self._class_edges is None:
+            return next(iter(self.classes))
+        i = int(np.searchsorted(self._class_edges, self.rng.random(), "right"))
+        return list(self.classes)[min(i, len(self.classes) - 1)]
+
+    def _shape_by_class(self, plan: Plan, classes: List[str]) -> None:
+        """The gang plugin's floor and the ledger's budget, where a class
+        states them: ``min_member`` under the size makes a gang elastic."""
+        plan.gang_priority = classes
+        sizes = plan.gang_min_member
+        floor = sizes.copy()
+        for g, name in enumerate(classes):
+            own = self.classes[name].get("gang") or {}
+            if "min_member" in own:
+                floor[g] = min(int(own["min_member"]), int(sizes[g]))
+            plan.gang_max_unavailable.append(own.get("max_unavailable"))
+        if (floor != sizes).any():
+            plan.gang_size, plan.gang_min_member = sizes, floor
 
     def _deal(self, g_n: int) -> np.ndarray:
         """Combination of each of the next ``g_n`` gangs: dealt from a deck
@@ -161,14 +217,10 @@ def to_nodes(config):
     from volcano_tpu.api import Node
 
     n = config["nodes"]
-    zones = int(n.get("zones", 0))
     alloc = {"cpu": str(n["cpu"]), "memory": f"{n['memory_gi']}Gi",
              "pods": int(n["pods"])}
-    out = []
-    for i, name in enumerate(node_names(config)):
-        labels = {"zone": f"zone-{i % zones}"} if zones > 0 else {}
-        out.append(Node(name=name, allocatable=dict(alloc), labels=labels))
-    return out
+    return [Node(name=name, allocatable=dict(alloc), labels=labels)
+            for name, labels in zip(node_names(config), node_labels(config))]
 
 
 def to_queues(config):
@@ -176,8 +228,24 @@ def to_queues(config):
 
     q = config.get("queues", {})
     weights = q.get("weights") or [1]
-    return [Queue(name=name, weight=int(weights[i % len(weights)]))
-            for i, name in enumerate(queue_names(config)) if i > 0]
+    reclaimable = q.get("reclaimable")
+    out = []
+    for i, name in enumerate(queue_names(config)):
+        # The store makes ``default`` itself (weight 1, reclaimable); it is
+        # stated again only where the file says what it may give up.
+        if i > 0 or reclaimable is not None:
+            queue = Queue(name=name, weight=int(weights[i % len(weights)]))
+            if reclaimable is not None:
+                queue.reclaimable = bool(reclaimable[i % len(reclaimable)])
+            out.append(queue)
+    return out
+
+
+def to_priority_classes(config):
+    from volcano_tpu.api import PriorityClass
+
+    return [PriorityClass(name=c["name"], value=int(c["value"]))
+            for c in config.get("priority_classes", [])]
 
 
 # Pod fields no gang of the benchmark sets: one empty list and one empty dict
@@ -194,21 +262,25 @@ _UNSET = dict(
     topology_spread=_NO_LIST, env=_NO_DICT, volumes=_NO_LIST)
 
 
-def to_objects(plan: Plan, stamps):
+def to_objects(plan: Plan, stamps, priority_values=None):
     """The plan as API objects: one list of gangs, each ``(pod_group,
     [pods])``.  ``stamps`` is an iterator of rising creation timestamps (the
     run's own, so that job order does not depend on the clock).  Sub-objects
     a gang's pods share (annotations, labels, containers) are shared by
     reference, as ``synth.tier_cluster`` does: the store treats pod specs as
-    immutable."""
+    immutable.  ``priority_values`` (class name -> value) is read only for a
+    plan whose gangs carry classes."""
     from volcano_tpu.api import (GROUP_NAME_ANNOTATION, AffinityTerm, Pod,
                                  PodGroup)
 
     gangs = []
     start = 0
+    sizes = plan.sizes()
+    classes = plan.gang_priority
     for g, gname in enumerate(plan.gang_names):
-        size = int(plan.gang_min_member[g])
-        pg = PodGroup(name=gname, min_member=size, queue=plan.gang_queue[g],
+        size = int(sizes[g])
+        pg = PodGroup(name=gname, min_member=int(plan.gang_min_member[g]),
+                      queue=plan.gang_queue[g],
                       creation_timestamp=float(next(stamps)))
         anno = {GROUP_NAME_ANNOTATION: gname}
         labels = {"app": gname}
@@ -224,6 +296,13 @@ def to_objects(plan: Plan, stamps):
                 match_labels=labels, topology_key="kubernetes.io/hostname")]
         elif kind == "spread":
             extra["topology_spread"] = [("zone", 10)]
+        if classes:
+            # The job's rank is its PodGroup's class; a pod carries the
+            # class and its value, as an admitted pod does.
+            pg.priority_class = classes[g]
+            pg.max_unavailable = plan.gang_max_unavailable[g]
+            extra.update(priority_class=classes[g],
+                         priority=priority_values[classes[g]])
         pods = []
         for k in range(size):
             name = plan.names[start + k]

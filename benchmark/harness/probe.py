@@ -68,8 +68,9 @@ class ProbeVerdict:
 def drive(driver, gen, batch_pods: int, par: dict) -> int:
     """Go on with the timed ``driver`` after the window: the fill, the
     one-pod gangs before the drain, the drain, the rest of them (``par`` is
-    the configuration's ``probe`` block).  Returns the index in
-    ``driver.rounds`` of the first probe round."""
+    the configuration's ``probe`` block; where the traffic names the
+    batches' priority class, ``gen`` deals it to these too).  Returns the
+    index in ``driver.rounds`` of the first probe round."""
     probes, before = int(par["probes"]), int(par.get("before_drain", 0))
     keep = int(par.get("keep_pods", 0))
 
@@ -88,19 +89,22 @@ def drive(driver, gen, batch_pods: int, par: dict) -> int:
 
 def check(node_names: Sequence[str], alloc: np.ndarray,
           events: Sequence[validate.RoundEvents], first_probe: int,
-          control_dtype=None) -> Tuple[validate.Verdict, ProbeVerdict]:
+          control_dtype=None, live_keys=None
+          ) -> Tuple[validate.Verdict, ProbeVerdict]:
     """The guarantees over every round, and every probe round's node held to
     the float64 reference's answer, computed from the ledger as it stands
     before that round.  With ``control_dtype`` the control stands in the
     program's place: at each probe the node "chosen" is the one the same
     reference names when computed in that lower precision (it has to
-    miss); the ledger still follows the program's binds."""
+    miss); the ledger still follows the program's binds.  ``live_keys``:
+    the keys the store held after the last round, for conservation."""
     ledger = validate.Ledger(node_names, alloc)
     out = ProbeVerdict()
     for i, ev in enumerate(events):
         if i >= first_probe:
             _compare(ledger, ev, control_dtype, out)
         ledger.apply(ev)
+    ledger.close(live_keys)
     return ledger.verdict, out
 
 

@@ -11,6 +11,13 @@ nothing to read returns None and the harness leaves the metric out.
              ``quantity: "busy"`` (union of all operations) or ``pattern``, a
              regular expression over program names
     counter  a named counter of the run
+    record   a number out of a block of the program's cycle records
+             (``solve``, ``whatif``, ``between``): ``key`` is dotted, its
+             first part the block (``solve.aff_terms``, ``whatif.victims``,
+             ``between.gc.gen2.n``); ``reduce``: ``sum`` (default) or ``max``
+             over the round's cycles that have the number
+    span_self  the self time (its own minus its direct children's) of the
+             program's spans of one ``name``, summed over a round's cycles
 
 Every per-round reading is reduced by the median over the counted rounds and
 multiplied by ``scale``.
@@ -82,8 +89,48 @@ def read_counter(args: dict, obs: Observed) -> Optional[float]:
     return None if value is None else value * float(args.get("scale", 1.0))
 
 
+def _dig(record: dict, key: str):
+    value = record
+    for part in key.split("."):
+        if not isinstance(value, dict) or part not in value:
+            return None
+        value = value[part]
+    return value if isinstance(value, (int, float)) \
+        and not isinstance(value, bool) else None
+
+
+def read_record(args: dict, obs: Observed) -> Optional[float]:
+    how = {"sum": sum, "max": max}[args.get("reduce", "sum")]
+    values = []
+    for r in obs.rounds:
+        found = [v for v in (_dig(rec, args["key"]) for rec in r.records)
+                 if v is not None]
+        if found:
+            values.append(float(how(found)))
+    return _median(values, float(args.get("scale", 1.0)))
+
+
+def read_span_self(args: dict, obs: Observed) -> Optional[float]:
+    values = []
+    for r in obs.rounds:
+        total, hit = 0.0, False
+        for rec in r.records:
+            children: Dict[object, int] = {}
+            for _name, dur, _sid, parent in rec["spans"]:
+                if parent is not None:
+                    children[parent] = children.get(parent, 0) + dur
+            for name, dur, sid, _parent in rec["spans"]:
+                if name == args["name"]:
+                    total += (dur - children.get(sid, 0)) / 1e9
+                    hit = True
+        if hit:
+            values.append(total)
+    return _median(values, float(args.get("scale", 1.0)))
+
+
 READERS = {"span": read_span, "lane": read_lane, "profile": read_profile,
-           "counter": read_counter}
+           "counter": read_counter, "record": read_record,
+           "span_self": read_span_self}
 
 
 def read(spec: dict, obs: Observed) -> Optional[float]:

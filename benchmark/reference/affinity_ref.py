@@ -126,6 +126,33 @@ def violations(gang_kind: Sequence[str], pod_gang, pod_node, node_zone) -> dict:
     return out
 
 
+def check(events, nodes, config) -> Dict[str, int]:
+    """The configuration's check (``guarantees.checks: ["affinity"]``, the slot of
+    the benchmark's ``checks.py``): ``violations`` over every round of the run that
+    dealt a kind, from the round's plan, the binds seen in it and the nodes'
+    ``zone`` labels.  The two counts that are guarantees; a pod not bound in
+    its round is counted by the validator, and here by nobody."""
+    index = {name: i for i, name in enumerate(nodes["names"])}
+    zone_ids: Dict[str, int] = {}
+    node_zone = np.array([zone_ids.setdefault(labels["zone"], len(zone_ids))
+                          if "zone" in labels else -1
+                          for labels in nodes["labels"]], dtype=np.int64)
+    out = {"affinity_outside": 0, "anti_shared": 0}
+    for ev in events:
+        plan = ev.plan
+        if not any(plan.gang_kind):
+            continue
+        host = {}
+        for _t, keys, hosts in ev.arrivals:
+            host.update(zip(keys, hosts))
+        pod_node = np.array([index.get(host.get(key), -1)
+                             for key in plan.keys()], dtype=np.int64)
+        v = violations(plan.gang_kind, plan.gang, pod_node, node_zone)
+        out["affinity_outside"] += v["affinity_outside"]
+        out["anti_shared"] += v["anti_shared"]
+    return out
+
+
 # ---- (b) where one pending pod may go --------------------------------------
 
 

@@ -103,6 +103,8 @@ def end_to_end(counted, setup_s: float, window_end_ns: int) -> dict:
     backlog_ms = []
     latencies = []
     for r in counted:
+        if r.plan.may_wait:
+            continue                    # over the pods that may not wait
         index = {k: i for i, k in enumerate(r.plan.keys())}
         t_bind = np.full(r.plan.n_pods, -1, dtype=np.int64)
         for t, keys, _hosts in r.arrivals:
@@ -171,9 +173,19 @@ def set_up(cell, seed: int, trace: bool, make_scheduler=None,
     driver = loop.Driver(cell.config, max_cycles=sizes["max_cycles"],
                          make_scheduler=make_scheduler or loop.default_scheduler,
                          read_lanes=trace, bind_wait_s=sizes["bind_wait_s"],
-                         annotate=jax.profiler.TraceAnnotation if trace else None)
+                         annotate=jax.profiler.TraceAnnotation if trace else None,
+                         termination_cycles=sizes["termination_cycles"],
+                         settle_cycles=sizes["settle_cycles"],
+                         pods_run=sizes["pods_run"])
     if sizes["resident_pods"]:
-        driver.round(gen.plan(sizes["resident_pods"], "resident"), 0)
+        driver.round(gen.plan(sizes["resident_pods"], "resident",
+                              klass=sizes["resident_class"]), 0)
+    if sizes["waiting_pods"]:
+        # The tier that may wait: submitted onto the full cluster, one cycle.
+        driver.round(gen.plan(sizes["waiting_pods"], "waiting",
+                              klass=sizes["resident_class"], may_wait=True), 0)
+    # From here on every batch is of the traffic's batch class, if it names one.
+    gen.batch_class = sizes["batch_class"]
     for i in range(sizes["warmup_rounds"]):
         driver.round(gen.plan(sizes["batch_pods"], f"warm{i:02d}"),
                      sizes["batch_pods"])
@@ -200,7 +212,7 @@ def run(cell, seed: int, seconds: float, trace: bool, make_scheduler=None,
     returns the result object.  ``make_scheduler`` and ``bind_wait_s`` are
     for the tests, which break the timed path underneath to see ``correct``
     come out false."""
-    from benchmark.harness import generate, loop, probe, readers
+    from benchmark.harness import checks, generate, loop, probe, readers
 
     t_process = _T_PROCESS if t_process is None else t_process
     info = require_chips(cell)
@@ -241,11 +253,19 @@ def run(cell, seed: int, seconds: float, trace: bool, make_scheduler=None,
     t_probe = time.perf_counter()
     first_probe = probe.drive(driver, gen, batch, cell.config["probe"])
     all_rounds = list(driver.rounds)
+    live_keys = driver.live_keys()
     driver.close()
     t_check = time.perf_counter()
+    events = [r.events() for r in all_rounds]
     verdict, probe_verdict = probe.check(
         generate.node_names(cell.config), generate.node_alloc(cell.config),
-        [r.events() for r in all_rounds], first_probe)
+        events, first_probe, live_keys=live_keys)
+    t_own = time.perf_counter()
+    # The configuration's own guarantees, by name (harness/checks.py).
+    verdict.extra = checks.run(cell.home, cell.config, events, {
+        "names": generate.node_names(cell.config),
+        "labels": generate.node_labels(cell.config)})
+    own_s = time.perf_counter() - t_own
     for line in verdict.lines() + probe_verdict.lines():
         say(line)
     if control:
@@ -254,16 +274,16 @@ def run(cell, seed: int, seconds: float, trace: bool, make_scheduler=None,
 
         _, ctl = probe.check(
             generate.node_names(cell.config), generate.node_alloc(cell.config),
-            [r.events() for r in all_rounds], first_probe,
-            control_dtype=ml_dtypes.bfloat16)
+            events, first_probe, control_dtype=ml_dtypes.bfloat16)
         say(f"control: with the reference in bfloat16 in the program's place "
             f"{ctl.misses} of {ctl.probes} choices differ, worst shortfall "
             f"{ctl.worst_shortfall:.6f} ("
             + "".join("x" if m else "." for m in ctl.missed) + ")")
     say(f"after the window: the probe's fill and {probe_verdict.probes} "
         f"one-pod cycles {t_check - t_probe:.3f} s, validation and reference "
-        f"{time.perf_counter() - t_check:.3f} s (neither in setup_s nor in "
-        "the window)")
+        f"{time.perf_counter() - t_check:.3f} s, of which the configuration's "
+        f"own checks {checks.names(cell.config)} {own_s:.3f} s (none of it in "
+        "setup_s or in the window)")
 
     e2e = end_to_end(counted, setup_s, window_end_ns)
     attempted = e2e["_pods"]
@@ -281,6 +301,15 @@ def run(cell, seed: int, seconds: float, trace: bool, make_scheduler=None,
         "s) " + ", ".join(
             f"{k} {statistics.median(r.spans()[k] for r in counted):.3f}"
             for k in ("submit", "schedule", "complete")))
+    say(f"window: {sum(len(r.evictions) for r in counted)} evictions seen, "
+        f"{sum(len(r.terminations) for r in counted)} terminations ended; "
+        f"cycles after the completions per round "
+        f"{sorted(set(r.settle_cycles for r in counted))}, settle median "
+        f"{statistics.median(r.spans()['settle'] for r in counted):.6f} s; "
+        f"longest wait for a cycle's hand-over "
+        f"{max(r.wait_s for r in all_rounds):.3f} s, "
+        f"{sum(r.waits_timed_out for r in all_rounds)} waits of the run "
+        f"reached bind_wait_s ({sizes['bind_wait_s']} s; should be 0)")
     think_s = e2e["_span_s"] - e2e["_round_s"]
     say(f"window: first submit to last completion {e2e['_span_s']:.3f} s "
         f"(what bind_rate runs on), of which {think_s:.3f} s "
@@ -322,6 +351,18 @@ def run(cell, seed: int, seconds: float, trace: bool, make_scheduler=None,
     if trace and profile:
         result["breakdown"] = {"device_ops": profile["device_ops"],
                                "idle_gaps": profile["idle_gaps"]}
+    # Every number compared, beside its limit: last in the line, and the
+    # last lines on standard error.
+    compared = {name: {"value": value, "limit": 0}
+                for name, value in verdict.compared().items()}
+    compared["probe_misses"] = {"value": probe_verdict.misses, "limit": 0}
+    compared["not_bound_in_round"] = {"value": attempted - e2e["_bound"],
+                                      "limit": 0}
+    compared["fullest_node"] = {"value": verdict.worst_fill, "limit": 1.0}
+    result["compared"] = compared
+    for name, c in compared.items():
+        print(f"compared: {name} = {c['value']} (limit {c['limit']})",
+              file=sys.stderr, flush=True)
     return result
 
 

@@ -46,7 +46,14 @@ def test_the_file_loads_and_states_its_deployment():
     assert set(north["guarantees"]) < set(cfg["guarantees"])
     for name in ("pod_affinity", "pod_anti_affinity"):
         said = cfg["guarantees"][name]
-        assert "affinity_ref.py" in said and "not yet by this cell's correct" in said
+        assert "affinity_ref.py" in said and "not yet" not in said
+        assert "since PR 40 by this cell's correct" in said
+    # the configuration's own check enters correct by name (ISSUE 40)
+    assert cfg["guarantees"]["checks"] == ["affinity"]
+    assert c.home == ROOT / "benchmark"
+    from benchmark.harness import checks
+    assert checks.names(cfg) == ["affinity"]
+    assert checks.load(c.home, "affinity") is affinity_ref.check
     assert any("soft" in a and "weight 10" in a for a in cfg["assumed"])
     assert c.sizes()["batch_pods"] == 100000 and c.sizes()["resident_pods"] == 0
     # every per-layer metric of the benchmark lists no cells, so all are its
@@ -137,7 +144,7 @@ def test_a_tenth_size_copy_runs_end_to_end(tmp_path, monkeypatch, capsys):
     real = json.loads((ROOT / "BENCHMARK.json").read_text())
     home = tmp_path / "benchmark"
     home.mkdir()
-    for part in ("layer_metrics", "traffic"):
+    for part in ("layer_metrics", "traffic", "reference"):
         os.symlink(ROOT / "benchmark" / part, home / part)
     (home / "configs").mkdir()
     cfg = json.loads((ROOT / "benchmark" / "configs" / "affinity-10k.json").read_text())
@@ -179,7 +186,12 @@ def test_a_tenth_size_copy_runs_end_to_end(tmp_path, monkeypatch, capsys):
     assert {"host_lanes_ms", "commit_lane_ms", "device_lane_ms",
             "ingest_us_per_pod"} <= set(result["metrics"])
     assert result["metrics"]["compiles_in_window"]["value"] == 0
-    # the two guarantees the cell's correct cannot hold yet, on its binds
+    # the two guarantees are in the cell's correct, by the file's checks
+    assert "validate: affinity_outside = 0 (limit 0)" in lines
+    assert "validate: anti_shared = 0 (limit 0)" in lines
+    assert result["compared"]["anti_shared"] == {"value": 0, "limit": 0}
+    assert any("own checks ['affinity']" in ln for ln in lines)
+    # and by hand, round by round, on the same binds
     index = {n: i for i, n in enumerate(generate.node_names(cfg))}
     zone = np.arange(1000) % 16
     rounds = [r for r in seen["driver"].rounds if r.plan.n_pods == 10000]

@@ -1,7 +1,10 @@
 """A cell is data: a toy configuration and a toy traffic mix, written by the
 test into a temporary directory, run ``run.py``'s whole path on the CPU
 (set-up, window, validation, probe, result line).  The same path with the
-timed path broken underneath comes out ``correct: false``."""
+timed path broken underneath comes out ``correct: false``.  A second toy
+fills its nodes with a low priority class and sends bursts of a high one:
+the round holds evictions, the kubelet's side ends them, and the same path
+with the eviction path broken comes out ``correct: false``."""
 
 import json
 import os
@@ -14,7 +17,19 @@ from benchmark.harness import cell as cell_mod
 from benchmark.harness import loop
 
 ROOT = cell_mod.ROOT
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
+TOY_REF = '''"""The toy's own guarantee: every bind names a node with a zone."""
+
+
+def check(events, nodes, config):
+    assert len(nodes["labels"]) == len(nodes["names"]) == config["nodes"]["count"]
+    zoned = {n for n, labels in zip(nodes["names"], nodes["labels"])
+             if "zone" in labels}
+    return {"off_the_zones": sum(
+        host not in zoned for ev in events
+        for _t, _keys, hosts in ev.arrivals for host in hosts)}
+'''
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -32,7 +47,10 @@ def toy(tmp_path, monkeypatch):
     config["nodes"].update(count=24, zones=3)
     config["gang"] = {"sizes": [2, 4]}
     config["queues"] = {"count": 2, "weights": [1, 3]}
+    config["guarantees"]["checks"] = ["toy"]
     (home / "configs" / "toy.json").write_text(json.dumps(config))
+    (home / "reference").mkdir()
+    (home / "reference" / "toy_ref.py").write_text(TOY_REF)
     (home / "traffic" / "drip.json").write_text(json.dumps({
         "name": "drip", "resident_fraction": 0.5, "batch_fraction": 0.1,
         "warmup_rounds": 2, "max_cycles": 4, "profile_seconds": 0.2}))
@@ -45,10 +63,22 @@ def toy(tmp_path, monkeypatch):
                         "why": "toy"}]
     real["workloads"] = [{"name": "toy.drip", "config": "toy",
                           "traffic": "drip", "chips": 1, "why": "toy"}]
-    real["per_layer"].append({
-        "name": "schedule_ms", "unit": "ms", "better": "lower",
-        "source": "host_clock", "layer": "cycle driver",
-        "moves": "backlog_to_bind_ms", "workloads": ["toy.drip"]})
+    (home / "layer_metrics" / "solve_rows.json").write_text(json.dumps({
+        "name": "solve_rows", "unit": "rows", "layer": "solve",
+        "moves": "backlog_to_bind_ms", "reader": "record",
+        "args": {"key": "solve.rows", "reduce": "max"}}))
+    (home / "layer_metrics" / "commit_self_ms.json").write_text(json.dumps({
+        "name": "commit_self_ms", "unit": "ms",
+        "layer": "fast cycle host lanes", "moves": "backlog_to_bind_ms",
+        "reader": "span_self", "args": {"name": "commit", "scale": 1e3}}))
+    for name, unit, source, layer in (
+            ("schedule_ms", "ms", "host_clock", "cycle driver"),
+            ("solve_rows", "rows", "program_counter", "solve"),
+            ("commit_self_ms", "ms", "program_span", "fast cycle host lanes")):
+        real["per_layer"].append({
+            "name": name, "unit": unit, "better": "lower", "source": source,
+            "layer": layer, "moves": "backlog_to_bind_ms",
+            "workloads": ["toy.drip"]})
     path = tmp_path / "BENCHMARK.json"
     path.write_text(json.dumps(real))
     # main() sets this when it is unset; keep the test's process as it was.
@@ -57,6 +87,115 @@ def toy(tmp_path, monkeypatch):
                                       str(tmp_path / "xla")))
     monkeypatch.setattr(bench_run, "OUT_DIR", tmp_path / "out")
     return path
+
+
+CONF_PREEMPT = """actions: "enqueue, allocate, preempt, reclaim, backfill"
+tiers:
+- plugins:
+  - name: priority
+  - name: gang
+  - name: conformance
+- plugins:
+  - name: drf
+  - name: predicates
+  - name: proportion
+  - name: nodeorder
+  - name: binpack
+"""
+PREEMPT_REF = '''"""The toy's own guarantee: no pod of the highest class is a victim."""
+
+
+def check(events, nodes, config):
+    top = max(config["priority_classes"], key=lambda c: c["value"])["name"]
+    klass = {}
+    for ev in events:
+        plan = ev.plan
+        for key, g in zip(plan.keys(), plan.gang.tolist()):
+            klass[key] = plan.gang_priority[g]
+    return {"victim_of_the_top_class": sum(
+        klass.get(key) == top for ev in events for _t, key in ev.evictions)}
+'''
+
+
+@pytest.fixture
+def toy_preempt(request, tmp_path, monkeypatch):
+    """A cell that holds evictions, by files and entries alone: 48 nodes of
+    4 pod sizes each, kept full by single pods of class ``low`` (192
+    resident, 12 more that may wait), two weighted queues of which one may
+    be reclaimed from, bursts of two gangs of 4 of class ``high``,
+    CONF_PREEMPT.  The program's own default lane plans the evictions (the
+    suite's conftest pins the host walk for the legacy tests, which restores
+    no victim; the harness itself sets no knob).  A test may give the cycles a
+    termination takes (``request.param``; 0, and two settle cycles, else)."""
+    grace = getattr(request, "param", 0)
+    monkeypatch.delenv("VOLCANO_TPU_EVICT_DEVICE", raising=False)
+    monkeypatch.delenv("VOLCANO_TPU_EVICT_CAP", raising=False)
+    real = json.loads((ROOT / "BENCHMARK.json").read_text())
+    home = tmp_path / "benchmark"
+    shutil.copytree(ROOT / "benchmark" / "layer_metrics", home / "layer_metrics")
+    for part in ("configs", "traffic", "reference"):
+        (home / part).mkdir()
+    config = json.loads((ROOT / "benchmark" / "configs" / "binpack-1k.json").read_text())
+    config.update(name="toypre", backlog_pods=192, scheduler_conf=CONF_PREEMPT)
+    config["nodes"].update(count=48, cpu=8, memory_gi=32, pods=16, zones=0)
+    config["pods"] = {"cpu_choices": [2], "mem_gi_choices": [4]}
+    config["gang"] = {"size": 4}
+    config["queues"] = {"count": 2, "weights": [1, 2],
+                        "reclaimable": [True, False]}
+    config["priority_classes"] = [
+        {"name": "low", "value": 10, "share": 0.9,
+         "gang": {"size": 1, "min_member": 1}},
+        {"name": "high", "value": 1000, "share": 0.1,
+         "gang": {"size": 4, "min_member": 4}}]
+    config["probe"] = {"probes": 6, "before_drain": 0, "keep_pods": 150}
+    config["guarantees"]["checks"] = ["toypre"]
+    (home / "configs" / "toypre.json").write_text(json.dumps(config))
+    (home / "reference" / "toypre_ref.py").write_text(PREEMPT_REF)
+    (home / "traffic" / "pre.json").write_text(json.dumps({
+        "name": "pre", "resident_fraction": 1.0, "batch_fraction": 1 / 24,
+        "waiting_fraction": 1 / 16, "warmup_rounds": 2, "max_cycles": 6,
+        "settle_cycles": 2 + grace, "termination_cycles": grace, "pods_run": True,
+        "resident_class": "low", "batch_class": "high", "bind_wait_s": 5.0,
+        "profile_seconds": 0.2}))
+    (home / "layer_metrics" / "whatif_victims.json").write_text(json.dumps({
+        "name": "whatif_victims", "unit": "pods", "layer": "what-if engine",
+        "moves": "backlog_to_bind_ms", "reader": "record",
+        "args": {"key": "whatif.victims", "reduce": "sum"}}))
+    (home / "layer_metrics" / "whatif_solve_ms.json").write_text(json.dumps({
+        "name": "whatif_solve_ms", "unit": "ms", "layer": "what-if engine",
+        "moves": "backlog_to_bind_ms", "reader": "span_self",
+        "args": {"name": "whatif_solve", "scale": 1e3}}))
+    real["configs"] = [{"name": "toypre", "source": "a test",
+                        "file": "benchmark/configs/toypre.json", "reduced": [],
+                        "why": "toy"}]
+    real["workloads"] = [{"name": "toypre.pre", "config": "toypre",
+                          "traffic": "pre", "chips": 1, "why": "toy"}]
+    for name, unit, source in (("whatif_victims", "pods", "program_counter"),
+                               ("whatif_solve_ms", "ms", "program_span")):
+        real["per_layer"].append({
+            "name": name, "unit": unit, "better": "lower", "source": source,
+            "layer": "what-if engine", "moves": "backlog_to_bind_ms",
+            "workloads": ["toypre.pre"]})
+    path = tmp_path / "BENCHMARK.json"
+    path.write_text(json.dumps(real))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                       os.environ.get("JAX_COMPILATION_CACHE_DIR",
+                                      str(tmp_path / "xla")))
+    monkeypatch.setattr(bench_run, "OUT_DIR", tmp_path / "out")
+    return path
+
+
+def _keep_driver(monkeypatch):
+    seen = {}
+    set_up = bench_run.set_up
+
+    def keep(*a, **kw):
+        out = set_up(*a, **kw)
+        seen["driver"] = out[0]
+        return out
+
+    monkeypatch.setattr(bench_run, "set_up", keep)
+    return seen
 
 
 def _last_line(capsys):
@@ -81,6 +220,10 @@ def test_toy_cell_runs_end_to_end(toy, capsys, trace):
     names = set(result["metrics"])
     if trace:
         assert "schedule_ms" in names and "ingest_us_per_pod" in names
+        # a record metric and a span_self metric, added by files alone
+        assert result["metrics"]["solve_rows"]["value"] == 24
+        assert 0 < result["metrics"]["commit_self_ms"]["value"] \
+            < result["metrics"]["commit_lane_ms"]["value"]
         assert "host_lanes_ms" in names and "commit_lane_ms" in names
         assert "device_busy_ms_per_round" not in names  # nothing to read
         assert "bind_rate" not in names
@@ -91,6 +234,86 @@ def test_toy_cell_runs_end_to_end(toy, capsys, trace):
         assert set(m) == {"value", "unit"} and m["value"] >= 0
     assert any("CPU rehearsal" in ln for ln in lines)
     assert any("probe: 0 of 48" in ln for ln in lines)
+    # the configuration's own check, by name, is in the verdict
+    assert "validate: off_the_zones = 0 (limit 0)" in lines
+    # every number compared beside its limit, last in the line
+    assert list(result)[-1] == "compared"
+    assert result["compared"]["off_the_zones"] == {"value": 0, "limit": 0}
+    assert {"unknown", "double", "unbound", "oversubscribed", "split",
+            "evicted_unknown", "evicted_twice", "never_terminated",
+            "gang_broken", "lost", "ghost", "probe_misses"} \
+        <= set(result["compared"])
+    assert any("0 evictions seen" in ln for ln in lines)
+
+
+@pytest.mark.parametrize("trace,toy_preempt", [(0, 0), (1, 1)],
+                         indirect=["toy_preempt"])
+def test_toy_preempt_cell_holds_evictions(toy_preempt, monkeypatch, capsys, trace):
+    """Untraced with terminations that end before the next cycle, traced
+    with a cycle of grace (the room is Releasing for one cycle more, so a
+    round takes one cycle more)."""
+    grace = trace
+    seen = _keep_driver(monkeypatch)
+    cell = cell_mod.load_cell("toypre.pre", toy_preempt)
+    result = bench_run.run(cell, seed=2**31 + 40, seconds=0.5, trace=bool(trace))
+    out = capsys.readouterr().out
+    driver = seen["driver"]
+    assert result["correct"] is True and result["failed"] == 0, out[-3000:]
+    compared = result["compared"]
+    for name in ("never_terminated", "evicted_unknown", "evicted_twice",
+                 "gang_broken", "lost", "ghost", "double", "oversubscribed",
+                 "unknown", "unbound", "split", "victim_of_the_top_class",
+                 "probe_misses"):
+        assert compared[name] == {"value": 0, "limit": 0}, name
+        if name != "probe_misses":
+            assert f"validate: {name} = 0 (limit 0)" in out
+    counted = [r for r in driver.rounds if r.plan.tag.startswith("w0")]
+    evictions = sum(len(r.evictions) for r in counted)
+    assert len(counted) >= 1 and evictions > 0
+    assert sum(len(r.terminations) for r in counted) == evictions
+    assert f"{evictions} evictions seen, {evictions} terminations ended" in out
+    # the warm-up rounds are of the window's shape: bursts onto a full cluster
+    window = [r for r in driver.rounds if r.plan.tag.startswith("warm")] + counted
+    assert len(window) >= 3, len(window)
+    assert all(len(r.evictions) >= 4 for r in window), [len(r.evictions) for r in window]
+    # the evictor's stamps are the binder's clock; every one is inside its round
+    for r in driver.rounds:
+        assert all(r.t_start <= t <= r.t_end for t, _key in r.evictions)
+        assert all(r.t_start <= t <= r.t_end for t, _key in r.terminations)
+    # a round needs an evicting cycle and a binding one, and waits for neither
+    assert all(r.cycles >= 2 + grace for r in window)
+    assert all(r.waits_timed_out == 0 and r.wait_s < 1.0 < driver.bind_wait_s
+               for r in driver.rounds)
+    assert "0 waits of the run reached bind_wait_s" in out
+    # the waiting tier takes the room after the completions, inside the round
+    assert all(r.settle_cycles == 2 + grace and r.spans()["settle"] > 0
+               for r in window)
+    assert driver.termination_cycles == grace
+    assert all(r.t_scheduled <= r.t_completed < r.t_end for r in window)
+    assert all(r.spans()["round"] == pytest.approx(sum(
+        r.spans()[k] for k in ("submit", "schedule", "complete", "settle")))
+        for r in window)
+    # pods that may wait are in nobody's attempted
+    waiting = [r for r in driver.rounds if r.plan.may_wait]
+    assert len(waiting) == 1 and waiting[0].plan.n_pods == 12
+    assert waiting[0].cycles == 1
+    assert result["attempted"] == sum(r.plan.n_pods for r in counted) == 8 * len(counted)
+    assert "12 pods may wait" in out
+    # classes, as the API wants them
+    pods = list(driver.fifo)[-1][1]
+    assert {(p.priority_class, p.priority) for p in pods} == {("high", 1000)}
+    assert driver.store.priority_classes["low"].value == 10
+    assert [(q.name, q.weight, q.reclaimable)
+            for q in driver.store.raw_queues.values()] \
+        == [("default", 1, True), ("queue-1", 2, False)]
+    if trace:
+        assert result["metrics"]["whatif_victims"]["value"] >= 4
+        assert result["metrics"]["whatif_solve_ms"]["value"] > 0
+        assert {"order_lane_ms", "derive_lane_ms", "enqueue_lane_ms"} \
+            <= set(result["metrics"])
+    else:
+        assert set(result["metrics"]) == {"bind_rate", "backlog_to_bind_ms",
+                                          "submit_to_bind_p95_ms", "setup_s"}
 
 
 class _Idle:
@@ -146,24 +369,120 @@ def _broken_second_choice(store, conf):
     return loop.default_scheduler(store, conf)
 
 
-@pytest.mark.parametrize("broken,symptom", [
-    (_broken_idle, "validate: unbound ="),
-    (_broken_misdirect, "validate: oversubscribed ="),
-    (_broken_second_choice, "probe:")])
-def test_broken_timed_path_is_not_correct(toy, capsys, broken, symptom):
-    cell = cell_mod.load_cell("toy.drip", toy)
-    # keep the idle scheduler's rounds short
-    result = bench_run.run(cell, seed=7, seconds=0.5, trace=False,
-                           make_scheduler=broken, bind_wait_s=0.01)
+# ---- the eviction path, broken (on the toy that holds evictions) ------------
+
+
+def _broken_kubelet(store, conf):
+    """The kubelet's side ends no termination: the room stays Releasing."""
+    import pytest as _pytest
+
+    patch = _pytest.MonkeyPatch()
+    patch.setattr(loop.Driver, "_end_termination", lambda self, key, pod: None)
+    _broken_kubelet.undo = patch.undo
+    return loop.default_scheduler(store, conf)
+
+
+class _EvictsTheBatch:
+    """A scheduler that, once a gang of the batch's own class runs, evicts
+    one of its pods through the store."""
+
+    def __init__(self, real, store):
+        self.real, self.store, self.done = real, store, False
+
+    def run_once(self):
+        self.real.run_once()
+        if not self.done:
+            for pod in list(self.store.pods.values()):
+                if pod.priority_class == "high" and pod.phase == "Running" \
+                        and not pod.deleting:
+                    self.store.evict(pod, "a test")     # reads pod.uid alone
+                    self.done = True
+                    break
+
+
+def _broken_evicts_the_batch(store, conf):
+    return _EvictsTheBatch(loop.default_scheduler(store, conf), store)
+
+
+class _TwiceInALife:
+    """A binder slot that hands a key's second life over twice."""
+
+    def __init__(self, inner):
+        self.inner, self.seen = inner, set()
+
+    def bind_keys(self, keys, hosts):
+        keys, hosts = list(keys), list(hosts)
+        again = [(k, h) for k, h in zip(keys, hosts) if k in self.seen]
+        self.seen.update(keys)
+        self.inner.bind_keys(keys + [k for k, _h in again],
+                             hosts + [h for _k, h in again])
+
+
+def _broken_double_of_a_restored_key(store, conf):
+    store.binder = _TwiceInALife(store.binder)
+    return loop.default_scheduler(store, conf)
+
+
+class _DeletesBehindTheClient:
+    """A scheduler that once deletes a running pod nobody asked it to."""
+
+    def __init__(self, real, store):
+        self.real, self.store, self.calls = real, store, 0
+
+    def run_once(self):
+        self.real.run_once()
+        self.calls += 1
+        if self.calls == 8:
+            low = [p for p in self.store.pods.values()
+                   if p.phase == "Running" and not p.deleting
+                   and p.priority_class == "low"]
+            # the youngest: the oldest finish before the run ends, and
+            # the ledger then forgets them with the client
+            self.store.delete_pod(low[-1])
+
+
+def _broken_deletes_behind_the_client(store, conf):
+    return _DeletesBehindTheClient(loop.default_scheduler(store, conf), store)
+
+
+EVICTION_PATH = {_broken_kubelet, _broken_evicts_the_batch,
+                 _broken_double_of_a_restored_key,
+                 _broken_deletes_behind_the_client}
+
+
+@pytest.mark.parametrize("broken,symptoms", [
+    (_broken_idle, ["validate: unbound ="]),
+    (_broken_misdirect, ["validate: oversubscribed ="]),
+    (_broken_second_choice, ["probe:"]),
+    (_broken_kubelet, ["validate: never_terminated =", "validate: unbound ="]),
+    (_broken_evicts_the_batch, ["validate: victim_of_the_top_class ="]),
+    (_broken_double_of_a_restored_key, ["validate: double ="]),
+    (_broken_deletes_behind_the_client, ["validate: lost ="])])
+def test_broken_timed_path_is_not_correct(request, capsys, broken, symptoms):
+    if broken in EVICTION_PATH:
+        path = request.getfixturevalue("toy_preempt")
+        cell, wait = cell_mod.load_cell("toypre.pre", path), None
+    else:
+        path = request.getfixturevalue("toy")
+        # keep the idle scheduler's rounds short
+        cell, wait = cell_mod.load_cell("toy.drip", path), 0.01
+    try:
+        result = bench_run.run(cell, seed=7, seconds=0.5, trace=False,
+                               make_scheduler=broken, bind_wait_s=wait)
+    finally:
+        getattr(broken, "undo", lambda: None)()
     out = capsys.readouterr().out
     assert result["correct"] is False
     assert result["failed"] > 0
-    line = [ln for ln in out.splitlines() if ln.startswith(symptom)][0]
-    assert int(line.split("=")[-1].split()[0] if "=" in line
-               else line.split()[1]) > 0
-    if symptom == "probe:":       # nothing but the node choice is wrong
+    for symptom in symptoms:
+        line = [ln for ln in out.splitlines() if ln.startswith(symptom)][0]
+        assert int(line.split("=")[-1].split()[0] if "=" in line
+                   else line.split()[1]) > 0, line
+    if symptoms == ["probe:"]:    # nothing but the node choice is wrong
         assert "validate: unbound = 0" in out
         assert "validate: oversubscribed = 0" in out
+    if broken in EVICTION_PATH:   # and no cycle's wait was a time-out
+        assert "0 waits of the run reached bind_wait_s" in out
 
 
 def test_no_accelerator_and_no_cpu_named_fails(toy, monkeypatch):

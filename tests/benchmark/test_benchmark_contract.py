@@ -109,7 +109,14 @@ def test_every_cells_files_resolve(name):
                               / f"{spec['name']}.json").read_text())
         for key in ("unit", "layer", "moves"):
             assert on_file[key] == spec[key], (spec["name"], key)
-        assert spec["reader"] in ("span", "lane", "profile", "counter")
+        assert spec["reader"] in ("span", "lane", "profile", "counter",
+                                  "record", "span_self")
+    # none of the seven holds an eviction, a tier or a pod that may wait
+    assert (sizes["termination_cycles"], sizes["settle_cycles"],
+            sizes["pods_run"], sizes["waiting_pods"], sizes["resident_class"],
+            sizes["batch_class"]) == (0, 0, False, 0, None, None)
+    assert "priority_classes" not in c.config
+    assert "reclaimable" not in c.config.get("queues", {})
     # demand stays under capacity, by the file's own arithmetic
     nodes = c.config["nodes"]
     pods = sizes["resident_pods"] + sizes["batch_pods"]
@@ -131,3 +138,35 @@ def test_the_harness_takes_only_what_it_may_from_the_program():
     assert "volcano_tpu" not in ref.split('"""', 2)[2]
     val = (ROOT / "benchmark" / "harness" / "validate.py").read_text()
     assert "import volcano_tpu" not in val and "from volcano_tpu" not in val
+    for f in (ROOT / "benchmark" / "reference").glob("*.py"):
+        body = f.read_text()
+        assert "import volcano_tpu" not in body and "from volcano_tpu" not in body, f
+    # What is imported from the program, all of it, and what the kubelet's
+    # side of a cycle calls on the store: README.md names each.
+    taken = set()
+    for f in (ROOT / "benchmark").rglob("*.py"):
+        for mod, names in re.findall(
+                r"^\s*from (volcano_tpu[\w.]*) import (\([^)]*\)|[^\n]+)",
+                f.read_text(), re.M):
+            taken |= {(mod, n.strip()) for n in names.strip("()").split(",")
+                      if n.strip()}
+    assert taken == {
+        ("volcano_tpu", "device"), ("volcano_tpu.cache", "ClusterStore"),
+        ("volcano_tpu.scheduler", "Scheduler"),
+        ("volcano_tpu.api", "Node"), ("volcano_tpu.api", "Queue"),
+        ("volcano_tpu.api", "PriorityClass"), ("volcano_tpu.api", "PodPhase"),
+        ("volcano_tpu.api", "GROUP_NAME_ANNOTATION"),
+        ("volcano_tpu.api", "AffinityTerm"), ("volcano_tpu.api", "Pod"),
+        ("volcano_tpu.api", "PodGroup")}
+    readme = (ROOT / "benchmark" / "README.md").read_text()
+    for _mod, name in taken:
+        assert f"`{name}`" in readme or f"`volcano_tpu.{name}`" in readme, name
+    loop_py = (ROOT / "benchmark" / "harness" / "loop.py").read_text()
+    calls = set(re.findall(r"\bstore\.(\w+)\b", loop_py))
+    assert calls == {"add_queue", "add_priority_class", "add_node",
+                     "async_bind", "add_pod_group", "add_pod", "update_pod",
+                     "delete_pod", "delete_pod_group", "flush_binds", "pods",
+                     "flight", "close"}, calls
+    for name in calls - {"async_bind", "close"}:
+        assert f"`{name}`" in readme or f"`store.{name}" in readme \
+            or f"`ClusterStore.{name}" in readme, name

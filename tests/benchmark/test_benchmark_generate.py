@@ -7,6 +7,7 @@ import itertools
 from collections import Counter
 
 import numpy as np
+import pytest
 
 from benchmark.harness import generate
 
@@ -63,3 +64,124 @@ def test_nodes_carry_the_configurations_shape():
     assert nodes[0].allocatable == {"cpu": "64", "memory": "256Gi", "pods": 256}
     alloc = generate.node_alloc(CONFIG)
     assert alloc[0].tolist() == [64000, 256 * 2**30, 256]
+
+
+# ---- ISSUE 40: priorities, reclaimable queues, pods that may wait ----------
+
+# sha256 over the plans of a cell's set-up, first window round and first probe
+# (names, requests, gangs, queues, kinds) for seed 2**31 + 40, computed with
+# the parent's generate.py (commit d4b4efd), first 16 hex digits.
+PARENT_DIGEST = {
+    "north-10k.burst": "5752b2e4e28006e6",
+    "binpack-1k.burst": "dc82f6be543950e3",
+    "north-10k.churn": "4aefe150372b5d4b",
+    "drf-5k.burst": "4b9f7e76d3493d02",
+    "affinity-10k.burst": "8883f58fbe26b3b5",
+    "binpack-1k.churn": "25e21d0e29501fa3",
+    "hyper-50k.burst": "8883f58fbe26b3b5",
+}
+
+
+def _digest(plan):
+    import hashlib
+    import json
+
+    h = hashlib.sha256()
+    for part in (plan.tag, plan.names, plan.cpu_milli.tolist(),
+                 plan.mem_bytes.tolist(), plan.gang.tolist(), plan.gang_names,
+                 plan.gang_min_member.tolist(), plan.gang_queue, plan.gang_cpu,
+                 plan.gang_mem_gi, plan.gang_kind):
+        h.update(json.dumps(part).encode())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("cell_name", sorted(PARENT_DIGEST))
+def test_the_seven_cells_plans_are_the_parents_byte_for_byte(cell_name):
+    import hashlib
+
+    from benchmark.harness import cell as cell_mod
+
+    c = cell_mod.load_cell(cell_name)
+    s = c.sizes()
+    gen = generate.Generator(c.config, 2**31 + 40)
+    plans = []
+    if s["resident_pods"]:
+        plans.append(gen.plan(s["resident_pods"], "resident",
+                              klass=s["resident_class"]))
+    for i in range(s["warmup_rounds"]):
+        plans.append(gen.plan(s["batch_pods"], f"warm{i:02d}"))
+    plans.append(gen.plan(s["batch_pods"], "w0000"))
+    plans.append(gen.plan(1, "probe000", gang_size=1))
+    h = hashlib.sha256()
+    for plan in plans:
+        h.update(_digest(plan).encode())
+        # and nothing of what ISSUE 40 added is set
+        assert (plan.gang_priority, plan.gang_size, plan.gang_max_unavailable,
+                plan.may_wait) == ([], None, [], False)
+        assert plan.sizes() is plan.gang_min_member
+    assert h.hexdigest()[:16] == PARENT_DIGEST[cell_name]
+
+
+TIERS = {**CONFIG, "gang": {"size": 4},
+         "queues": {"count": 2, "weights": [1, 3], "reclaimable": [True, False]},
+         "priority_classes": [
+             {"name": "low", "value": 10, "share": 0.75,
+              "gang": {"sizes": [1, 2], "min_member": 1, "max_unavailable": 2}},
+             {"name": "high", "value": 1000, "share": 0.25}]}
+
+
+def test_a_class_brings_its_rank_and_its_own_gang():
+    gen = generate.Generator(TIERS, BIG_SEED)
+    low = gen.plan(60, "res", klass="low")
+    assert set(low.gang_priority) == {"low"} and not low.may_wait
+    assert set(low.sizes().tolist()) == {1, 2}          # the class's own sizes
+    assert set(low.gang_min_member.tolist()) == {1}     # elastic: floor 1
+    assert low.gang_size is not None and low.sizes().sum() == low.n_pods == 60
+    assert low.gang_max_unavailable == [2] * len(low.gang_names)
+    high = gen.plan(40, "burst", klass="high", may_wait=True)
+    assert set(high.gang_priority) == {"high"} and high.may_wait
+    assert high.gang_size is None and set(high.sizes().tolist()) == {4}
+    assert high.gang_max_unavailable == [None] * 10
+    values = {"low": 10, "high": 1000}
+    stamps = itertools.count(1)
+    for plan, value in ((low, 10), (high, 1000)):
+        gangs = generate.to_objects(plan, stamps, values)
+        assert sum(len(pods) for _pg, pods in gangs) == plan.n_pods
+        for g, (pg, pods) in enumerate(gangs):
+            assert pg.priority_class == plan.gang_priority[g]
+            assert pg.min_member == plan.gang_min_member[g] <= len(pods)
+            assert len(pods) == plan.sizes()[g]
+            assert pg.max_unavailable == plan.gang_max_unavailable[g]
+            assert {(p.priority_class, p.priority) for p in pods} \
+                == {(pg.priority_class, value)}
+    # names and keys stay what the validator expects
+    assert low.keys()[0] == "default/res-pg-000000-0"
+
+
+def test_without_a_named_class_the_shares_deal_them_from_the_seed():
+    a = generate.Generator(TIERS, 11).plan(400, "x")
+    b = generate.Generator(TIERS, 11).plan(400, "x")
+    assert a.gang_priority == b.gang_priority and a.names == b.names
+    share = Counter(a.gang_priority)
+    assert set(share) == {"low", "high"}
+    assert 0.6 < share["low"] / len(a.gang_priority) < 0.9
+    other = generate.Generator(TIERS, 12)
+    assert other.plan(400, "x").gang_priority != a.gang_priority
+    # the traffic's batch class, once set, is every unnamed plan's
+    other.batch_class = "high"
+    assert set(other.plan(40, "y").gang_priority) == {"high"}
+    assert set(other.plan(40, "z", klass="low").gang_priority) == {"low"}
+
+
+def test_queues_say_what_may_be_reclaimed_and_classes_reach_the_store():
+    queues = generate.to_queues(TIERS)
+    # the default queue is stated again, with its weight, where the file says
+    # what may be reclaimed from it
+    assert [(q.name, q.weight, q.reclaimable) for q in queues] \
+        == [("default", 1, True), ("queue-1", 3, False)]
+    plain = generate.to_queues({**TIERS, "queues": {"count": 2, "weights": [1, 3]}})
+    assert [(q.name, q.weight, q.reclaimable) for q in plain] == [("queue-1", 3, True)]
+    assert [(c.name, c.value) for c in generate.to_priority_classes(TIERS)] \
+        == [("low", 10), ("high", 1000)]
+    assert generate.to_priority_classes(CONFIG) == []
+    assert generate.node_labels(CONFIG)[5] == {"zone": "zone-1"}
