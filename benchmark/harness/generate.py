@@ -110,6 +110,14 @@ class Generator:
                            for c in self.classes.values()])
         self._class_edges = np.cumsum(shares / shares.sum()) if shares.sum() else None
         self.batch_class: Optional[str] = None     # of a plan that names none
+        # A class may name the queues its gangs are dealt to, in turn; one
+        # that names none is dealt over all of them, as every gang was.
+        self._class_queues = {name: list(c["queues"])
+                              for name, c in self.classes.items() if "queues" in c}
+        for name, own in self._class_queues.items():
+            if not own or set(own) - set(self.queues):
+                raise ValueError(f"priority class {name!r} names queues {own}; "
+                                 f"the configuration has {self.queues}")
         self._gangs_made = 0
         self._deck: List[int] = []
 
@@ -143,7 +151,9 @@ class Generator:
             c, m = self.combos[int(combo_of[g])]
             gname = f"{tag}-pg-{g:06d}"
             gang_names.append(gname)
-            gang_queue.append(self.queues[(self._gangs_made + g) % len(self.queues)])
+            queues = self._class_queues.get(classes[g], self.queues) \
+                if classes else self.queues
+            gang_queue.append(queues[(self._gangs_made + g) % len(queues)])
             gang_cpu.append(c)
             gang_mem.append(m)
             for k in range(size):

@@ -1,0 +1,201 @@
+"""``benchmark/README.md``'s table "Adding to it: files and entries only",
+made row by row on a copy of the real benchmark in every run of the suite: a
+traffic mix and a plain cell under it, a second plain cell on four chips, a
+configuration that holds evictions (``preempt-10k`` at ``PERF.md`` section
+7 (3)'s shape) with its traffic, its check and its cell, a per-layer metric
+of that cell alone and one of every cell.  New files and appended entries,
+nothing else; the contract and the configuration tests' loading assertions
+then pass on the copy, and the cells the benchmark has load as they did.
+Files are loaded; no scheduler runs.
+
+Every name added here ends in ``-added`` or ``_added``, so that the real
+``preempt-10k`` (or any other cell, mix or metric) can come in beside them:
+a later PR gives its own files other names than these."""
+
+import copy
+import json
+import shutil
+
+import pytest
+
+import test_benchmark_affinity_config as affinity_test
+import test_benchmark_contract as contract
+import test_benchmark_drf_config as drf_test
+import test_benchmark_hyper_config as hyper_test
+from benchmark.harness import cell as cell_mod
+from benchmark.harness import generate
+from test_benchmark_cell import CONF_PREEMPT, toy_preempt  # noqa: F401
+
+ROOT = cell_mod.ROOT
+EVICT = "preempt-10k-added.evict-added"
+NEW_CELLS = {
+    "affinity-10k.churn-added": ("affinity-10k", "churn-added", 1),  # (1) plain
+    "hyper-50k.churn-added": ("hyper-50k", "churn-added", 4),   # (2) four chips
+    EVICT: ("preempt-10k-added", "evict-added", 1),         # (3) holds evictions
+}
+# (4) of the evicting cell alone, (5) of every cell
+NEW_METRICS = {
+    "preempt_plan_ms_added": ("what-if engine", "span_self",
+                              {"name": "preempt_plan", "scale": 1e3}, [EVICT]),
+    "encode_lane_ms_added": ("fast cycle host lanes", "lane",
+                             {"lanes": ["encode"], "scale": 1e3}, None),
+}
+ALL_CELLS_METRIC = "encode_lane_ms_added"
+STUB_REF = '''"""A stand-in: the victims' reference comes with the cell."""
+
+
+def check(events, nodes, config):
+    return {"victims_unjudged": 0}
+'''
+
+
+def add_to(root):
+    """The additions under ``root``, which holds a ``BENCHMARK.json`` and its
+    ``benchmark/``; no file that is there is written to but that list."""
+    home = root / "benchmark"
+    cfg = json.loads((home / "configs" / "north-10k.json").read_text())
+    nodes = cfg["nodes"]
+    cfg.update(
+        name="preempt-10k-added", source="BASELINE.json configs[3]: Preempt + reclaim "
+        "actions with PriorityClass, 10k nodes, oversubscribed queues",
+        scheduler_conf=CONF_PREEMPT, reduced=["chips"],
+        pods={"cpu_choices": [16], "mem_gi_choices": [32]},
+        backlog_pods=nodes["count"] * nodes["cpu"] // 16,   # four a node: full
+        queues={"count": 4, "weights": [1, 2, 4, 8], "reclaimable": [True] * 4},
+        priority_classes=[
+            {"name": "low", "value": 10, "share": 0.9,
+             "gang": {"size": 8, "min_member": 1, "max_unavailable": 8}},
+            # over its share, ``default`` may not grow: the bursts are the
+            # other three tenants'
+            {"name": "high", "value": 1000, "share": 0.1,
+             "queues": ["queue-1", "queue-2", "queue-3"]}],
+        guarantees=dict(cfg["guarantees"], checks=["preempt_added"]))
+    traffic = {
+        "name": "evict-added", "resident_fraction": 1.0,
+        "batch_fraction": 8 / cfg["backlog_pods"], "waiting_fraction": 0.01,
+        "warmup_rounds": 4, "max_cycles": 4, "settle_cycles": 2,
+        "termination_cycles": 0, "pods_run": True, "resident_class": "low",
+        "batch_class": "high"}
+    churn = json.loads((home / "traffic" / "churn.json").read_text())
+    new = {home / "configs" / "preempt-10k-added.json": json.dumps(cfg),
+           home / "traffic" / "evict-added.json": json.dumps(traffic),
+           home / "traffic" / "churn-added.json":
+               json.dumps(dict(churn, name="churn-added")),
+           home / "reference" / "preempt_added_ref.py": STUB_REF}
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({
+        "name": cfg["name"], "source": cfg["source"], "reduced": cfg["reduced"],
+        "file": "benchmark/configs/preempt-10k-added.json", "why": "a full cluster"})
+    for name, (config, mix, chips) in NEW_CELLS.items():
+        bench["workloads"].append({"name": name, "config": config,
+                                   "traffic": mix, "chips": chips, "why": name})
+    for name, (layer, reader, args, cells) in NEW_METRICS.items():
+        on_file = {"name": name, "unit": "ms", "layer": layer,
+                   "moves": "backlog_to_bind_ms", "reader": reader, "args": args}
+        new[home / "layer_metrics" / f"{name}.json"] = json.dumps(on_file)
+        entry = {"name": name, "unit": "ms", "better": "lower", "layer": layer,
+                 "source": "program_span", "moves": "backlog_to_bind_ms"}
+        bench["per_layer"].append(dict(entry, workloads=cells) if cells else entry)
+    for path, text in new.items():
+        assert not path.exists(), path
+        path.write_text(text)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
+    return root / "BENCHMARK.json"
+
+
+@pytest.fixture(scope="module")
+def grown(tmp_path_factory):
+    """``BENCHMARK.json`` of a copy of the real benchmark with the additions."""
+    root = tmp_path_factory.mktemp("grown")
+    shutil.copy(ROOT / "BENCHMARK.json", root)
+    shutil.copytree(ROOT / "benchmark", root / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (root / "tests" / "benchmark").mkdir(parents=True)   # a path of the file's
+    return add_to(root)
+
+
+def test_no_byte_of_an_original_file_differs_and_entries_are_only_appended(grown):
+    for f in (ROOT / "benchmark").rglob("*"):
+        if f.is_file() and "__pycache__" not in f.parts:
+            assert (grown.parent / f.relative_to(ROOT)).read_bytes() == f.read_bytes(), f
+    new = json.loads(grown.read_text())
+    for key, was in contract.BENCH.items():
+        if isinstance(was, list) and isinstance(was[0], dict):
+            assert all(entry in new[key] for entry in was), key
+        else:
+            assert new[key] == was, key
+    assert [w["name"] for w in new["workloads"]] == contract.CELLS + list(NEW_CELLS)
+
+
+@pytest.mark.parametrize("test", [
+    contract.test_top_level_keys_and_size,
+    contract.test_every_file_under_paths_has_an_allowed_name,
+    contract.test_todays_cells_and_configurations_are_still_there,
+    contract.test_configs, contract.test_workloads, contract.test_metrics],
+    ids=lambda f: f.__name__[5:])
+def test_the_contract_holds_of_the_grown_benchmark(grown, test):
+    test(json.loads(grown.read_text()), grown.parent)
+
+
+@pytest.mark.parametrize("name", list(NEW_CELLS))
+def test_every_added_cell_resolves(grown, name):
+    contract.test_every_cells_files_resolve(name, grown)
+    c = cell_mod.load_cell(name, grown)
+    assert contract.holds_evictions(c) == (name == EVICT)
+    assert c.chips == NEW_CELLS[name][2]
+    # what every cell of the benchmark reports, and what was added for it
+    every = [m["name"] for m in contract.per_layer_of(contract.BENCH, name)]
+    assert [m["name"] for m in c.per_layer] == every + [
+        m for m, (*_file, cells) in NEW_METRICS.items()
+        if cells is None or name in cells]
+
+
+@pytest.mark.parametrize("test", [
+    affinity_test.test_the_file_loads_and_states_its_deployment,
+    drf_test.test_the_file_loads_and_states_its_deployment,
+    hyper_test.test_the_cell_is_a_four_chip_cell_within_their_share],
+    ids=["affinity", "drf", "hyper"])
+def test_the_configuration_tests_load_from_the_grown_benchmark(grown, test):
+    test(grown)
+
+
+@pytest.mark.parametrize("name", contract.CELLS)
+def test_the_benchmarks_cells_load_as_they_did(grown, name):
+    contract.test_every_cells_files_resolve(name, grown)
+    was, now = cell_mod.load_cell(name), cell_mod.load_cell(name, grown)
+    assert (now.sizes(), now.end_to_end, now.chips, now.config, now.traffic) \
+        == (was.sizes(), was.end_to_end, was.chips, was.config, was.traffic)
+    added = [m for m in now.per_layer if m not in was.per_layer]
+    assert [m for m in now.per_layer if m in was.per_layer] == was.per_layer
+    assert [m["name"] for m in added] == [ALL_CELLS_METRIC]
+
+
+def test_the_evicting_cells_bursts_go_to_the_queues_its_class_names(grown):
+    c = cell_mod.load_cell(EVICT, grown)
+    gen = generate.Generator(c.config, 2**31 + 42)
+    low = gen.plan(64, "resident", klass=c.sizes()["resident_class"])
+    gen.batch_class = c.sizes()["batch_class"]
+    bursts = [gen.plan(c.sizes()["batch_pods"], f"w{i}") for i in range(6)]
+    assert set(low.gang_queue) == set(generate.queue_names(c.config))
+    assert [b.gang_queue for b in bursts] \
+        == [["queue-3"], ["queue-1"], ["queue-2"]] * 2
+
+
+@pytest.mark.parametrize("where,key,value,clause", [
+    ("config", "guarantees", {"checks": []}, "a check of the victims"),
+    ("traffic", "pods_run", False, "the residents run"),
+    ("traffic", "resident_fraction", 0.99, "finds the cluster full")])
+def test_an_eviction_cell_that_states_less_fails_its_clause(grown, where, key,
+                                                            value, clause):
+    c = copy.deepcopy(cell_mod.load_cell(EVICT, grown))
+    contract.eviction_clause(c)
+    getattr(c, where)[key] = value
+    with pytest.raises(AssertionError, match=clause):
+        contract.eviction_clause(c)
+
+
+def test_the_toys_files_pass_the_eviction_clause(toy_preempt):  # noqa: F811
+    """The toy that runs evictions on the CPU (``test_benchmark_cell.py``)
+    and the clause cannot drift apart: if one misses, the toy's files change."""
+    contract.test_every_cells_files_resolve("toypre.pre", toy_preempt)
+    assert contract.holds_evictions(cell_mod.load_cell("toypre.pre", toy_preempt))

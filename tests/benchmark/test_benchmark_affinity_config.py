@@ -14,16 +14,17 @@ from benchmark import run as bench_run
 from benchmark.harness import cell as cell_mod
 from benchmark.harness import generate
 from benchmark.reference import affinity_ref
+from test_benchmark_contract import reports_its_per_layer
 
 ROOT = cell_mod.ROOT
 SEED = 2**31 + 3131
 
 
-def test_the_file_loads_and_states_its_deployment():
-    c = cell_mod.load_cell("affinity-10k.burst")
+def test_the_file_loads_and_states_its_deployment(bench_file=ROOT / "BENCHMARK.json"):
+    c = cell_mod.load_cell("affinity-10k.burst", bench_file)
     cfg = c.config
     assert c.chips == 1 and c.config_name == "affinity-10k" and c.traffic_name == "burst"
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench = json.loads(bench_file.read_text())
     entry = next(e for e in bench["configs"] if e["name"] == "affinity-10k")
     assert entry["source"] == cfg["source"] and "configs[4]" in cfg["source"]
     assert entry["reduced"] == cfg["reduced"] == ["nodes", "pods", "chips"]
@@ -50,16 +51,16 @@ def test_the_file_loads_and_states_its_deployment():
         assert "since PR 40 by this cell's correct" in said
     # the configuration's own check enters correct by name (ISSUE 40)
     assert cfg["guarantees"]["checks"] == ["affinity"]
-    assert c.home == ROOT / "benchmark"
+    assert c.home == bench_file.parent / "benchmark"
     from benchmark.harness import checks
     assert checks.names(cfg) == ["affinity"]
-    assert checks.load(c.home, "affinity") is affinity_ref.check
+    assert checks.load(ROOT / "benchmark", "affinity") is affinity_ref.check
     assert any("soft" in a and "weight 10" in a for a in cfg["assumed"])
     assert c.sizes()["batch_pods"] == 100000 and c.sizes()["resident_pods"] == 0
-    # every per-layer metric of the benchmark lists no cells, so all are its
-    assert len(c.per_layer) == len(bench["per_layer"])
+    # its per-layer metrics: those that list no cells and those that name it
+    reports_its_per_layer(c, bench)
     # and the cell that came in with it
-    churn = cell_mod.load_cell("binpack-1k.churn")
+    churn = cell_mod.load_cell("binpack-1k.churn", bench_file)
     assert (churn.sizes()["resident_pods"], churn.sizes()["batch_pods"],
             churn.sizes()["warmup_rounds"]) == (10000, 100, 3)
 

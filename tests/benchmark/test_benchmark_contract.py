@@ -1,5 +1,11 @@
 """``BENCHMARK.json`` against the contract's shape: allowed characters, the
-keys of every entry, bounds, and that every cell's files resolve."""
+keys of every entry, bounds, and that every cell's files resolve.
+
+Every test here is a function of ``(bench, root)``, the repo's own by
+default: ``test_benchmark_additions.py`` calls them on a copy that has gained
+cells, a configuration and metrics by files and entries alone.  What today's
+benchmark holds is named once, here (``SEVEN``, ``FIVE``, ``NINETEEN``), as
+what must still be there; nothing counts a list a later PR may grow."""
 
 import json
 import re
@@ -15,44 +21,83 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 CELLS = [w["name"] for w in BENCH["workloads"]]
+# Today's cells, configurations and all-cell per-layer metrics: a later PR
+# adds to them and takes none away.
+SEVEN = {"north-10k.burst", "binpack-1k.burst", "north-10k.churn",
+         "drf-5k.burst", "affinity-10k.burst", "binpack-1k.churn",
+         "hyper-50k.burst"}
+FIVE = {"north-10k", "binpack-1k", "drf-5k", "affinity-10k", "hyper-50k"}
+NINETEEN = {
+    "ingest_us_per_pod", "complete_us_per_pod", "host_lanes_ms",
+    "commit_lane_ms", "device_lane_ms", "device_busy_ms_per_round",
+    "compiles_in_window", "cycle_unattributed_ms", "cycle_prologue_ms",
+    "solve_prep_ms", "device_dispatch_ms", "cycle_obs_ms", "bind_handoff_ms",
+    "cycle_gc_ms", "solve_wave_ms_per_round", "coarse_shortlist_ms_per_round",
+    "order_lane_ms", "derive_lane_ms", "enqueue_lane_ms"}
+EVICTION_KEYS = ("termination_cycles", "settle_cycles", "pods_run",
+                 "waiting_fraction", "resident_class", "batch_class")
 
 
 def _line(text):
     return 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
 
 
-def test_top_level_keys_and_size():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+def per_layer_of(bench, cell):
+    """The ``per_layer`` entries a cell reports: those that list no cells,
+    and those that name it."""
+    return [m for m in bench["per_layer"] if cell in m.get("workloads", [cell])]
+
+
+def reports_its_per_layer(c, bench):
+    """A loaded cell's per-layer metrics are those entries, today's
+    nineteen among them and whatever files have added since."""
+    names = [m["name"] for m in c.per_layer]
+    assert names == [m["name"] for m in per_layer_of(bench, c.name)]
+    assert set(names) >= NINETEEN
+
+
+def test_top_level_keys_and_size(bench=BENCH, root=ROOT):
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert (ROOT / "BENCHMARK.json").stat().st_size <= 64 * 1024
-    assert isinstance(BENCH["run_seconds"], int) and 1 <= BENCH["run_seconds"] <= 51
-    assert 1 <= len(BENCH["command"]) <= 32 and all(map(_line, BENCH["command"]))
-    assert 1 <= len(BENCH["paths"]) <= 16
-    for p in BENCH["paths"]:
+    assert (root / "BENCHMARK.json").stat().st_size <= 64 * 1024
+    assert isinstance(bench["run_seconds"], int) and 1 <= bench["run_seconds"] <= 51
+    assert 1 <= len(bench["command"]) <= 32 and all(map(_line, bench["command"]))
+    assert 1 <= len(bench["paths"]) <= 16
+    for p in bench["paths"]:
         assert PATH.match(p) and not p.startswith("/") and ".." not in p
-        assert (ROOT / p).is_dir()
+        assert (root / p).is_dir()
 
 
-def test_every_file_under_paths_has_an_allowed_name():
-    for p in BENCH["paths"]:
-        for f in (ROOT / p).rglob("*"):
+def test_every_file_under_paths_has_an_allowed_name(bench=BENCH, root=ROOT):
+    for p in bench["paths"]:
+        for f in (root / p).rglob("*"):
             if "__pycache__" in f.parts or f.suffix == ".pyc":
                 continue
-            assert PATH.match(str(f.relative_to(ROOT))), f
+            assert PATH.match(str(f.relative_to(root))), f
 
 
-def test_configs():
-    names = [c["name"] for c in BENCH["configs"]]
+def test_todays_cells_and_configurations_are_still_there(bench=BENCH, root=ROOT):
+    """The one place that names them: as subsets, and the seven as plain."""
+    assert SEVEN <= {w["name"] for w in bench["workloads"]}
+    assert FIVE <= {c["name"] for c in bench["configs"]}
+    assert NINETEEN <= {m["name"] for m in bench["per_layer"]
+                        if "workloads" not in m}
+    for name in sorted(SEVEN):
+        assert not holds_evictions(cell_mod.load_cell(name, root / "BENCHMARK.json"))
+
+
+def test_configs(bench=BENCH, root=ROOT):
+    names = [c["name"] for c in bench["configs"]]
     assert len(set(names)) == len(names) and 1 <= len(names) <= 24
-    files = [c["file"] for c in BENCH["configs"]]
+    files = [c["file"] for c in bench["configs"]]
     assert len(set(files)) == len(files)
-    used = {w["config"] for w in BENCH["workloads"]}
-    for c in BENCH["configs"]:
+    used = {w["config"] for w in bench["workloads"]}
+    for c in bench["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and c["name"] in used
         assert _line(c["source"]) and _line(c["why"])
-        assert any(c["file"].startswith(p + "/") for p in BENCH["paths"])
-        held = json.loads((ROOT / c["file"]).read_text())
+        assert any(c["file"].startswith(p + "/") for p in bench["paths"])
+        held = json.loads((root / c["file"]).read_text())
         assert held["source"] == c["source"] and held["reduced"] == c["reduced"]
         assert len(c["reduced"]) <= 16 and all(NAME.match(k) for k in c["reduced"])
         for key in ("guarantees", "capacity_arithmetic", "assumed",
@@ -60,24 +105,26 @@ def test_configs():
             assert key in held
 
 
-def test_workloads():
-    assert len(set(CELLS)) == len(CELLS) and 1 <= len(CELLS) <= 24
-    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+def test_workloads(bench=BENCH, root=ROOT):
+    cells = [w["name"] for w in bench["workloads"]]
+    assert len(set(cells)) == len(cells) and 1 <= len(cells) <= 24
+    pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
     assert len(set(pairs)) == len(pairs)
-    configs = {c["name"] for c in BENCH["configs"]}
-    for w in BENCH["workloads"]:
+    configs = {c["name"] for c in bench["configs"]}
+    for w in bench["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
         assert w["config"] in configs and w["chips"] in (1, 4) and _line(w["why"])
-    four = sum(1 for w in BENCH["workloads"] if w["chips"] == 4)
-    assert four <= max(1, len(CELLS) // 2)
+    four = sum(1 for w in bench["workloads"] if w["chips"] == 4)
+    assert four <= max(1, len(cells) // 2)
 
 
-def test_metrics():
-    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
-    layer = {m["name"]: m for m in BENCH["per_layer"]}
-    assert len(e2e) == len(BENCH["end_to_end"]) <= 16
-    assert len(layer) == len(BENCH["per_layer"]) <= 128
+def test_metrics(bench=BENCH, root=ROOT):
+    cells = [w["name"] for w in bench["workloads"]]
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    layer = {m["name"]: m for m in bench["per_layer"]}
+    assert len(e2e) == len(bench["end_to_end"]) <= 16
+    assert len(layer) == len(bench["per_layer"]) <= 128
     assert not set(e2e) & set(layer)
     assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25
     for m in e2e.values():
@@ -91,12 +138,44 @@ def test_metrics():
     for m in list(e2e.values()) + list(layer.values()):
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
         assert m["better"] in ("lower", "higher")
-        assert set(m.get("workloads", CELLS)) <= set(CELLS)
+        assert set(m.get("workloads", cells)) <= set(cells)
+
+
+def holds_evictions(c):
+    """A cell is *plain* when its configuration has no tiers and no queue
+    that may be reclaimed from and its traffic none of the keys of the
+    kubelet's side and the tiers; else it is an eviction cell."""
+    return ("priority_classes" in c.config
+            or "reclaimable" in c.config.get("queues", {})
+            or any(key in c.traffic for key in EVICTION_KEYS))
+
+
+def eviction_clause(c):
+    """What an eviction cell's files must state, from their numbers alone:
+    a cluster kept full *is* the deployment."""
+    sizes, cfg = c.sizes(), c.config
+    classes = {k["name"]: k for k in cfg.get("priority_classes", [])}
+    low, high = classes.get(sizes["resident_class"]), classes.get(sizes["batch_class"])
+    assert low and high and high["value"] > low["value"], \
+        "the batch's class outranks the residents'"
+    gang = low.get("gang") or cfg["gang"]
+    size = max(gang.get("sizes", [gang.get("size", 1)]))
+    assert size == 1 or gang.get("min_member", size) < size, \
+        "a resident gang can lose a pod"
+    assert sizes["pods_run"] and sizes["resident_pods"] > 0, \
+        "the residents run"
+    nodes, cpu = cfg["nodes"], cfg["pods"]["cpu_choices"]
+    # full whatever the deck deals: a burst fits only by taking room
+    assert (sizes["resident_pods"] + sizes["batch_pods"]) * min(cpu) \
+        > nodes["count"] * nodes["cpu"], "a burst finds the cluster full"
+    assert sizes["settle_cycles"] >= 1 + sizes["termination_cycles"], \
+        "the victims settle before the next burst"
+    assert cfg["guarantees"].get("checks"), "a check of the victims, by name"
 
 
 @pytest.mark.parametrize("name", CELLS)
-def test_every_cells_files_resolve(name):
-    c = cell_mod.load_cell(name)
+def test_every_cells_files_resolve(name, bench_file=ROOT / "BENCHMARK.json"):
+    c = cell_mod.load_cell(name, bench_file)
     assert c.config["name"] == c.config_name
     assert c.traffic["name"] == c.traffic_name
     sizes = c.sizes()
@@ -105,12 +184,14 @@ def test_every_cells_files_resolve(name):
     names = {m["name"] for m in c.end_to_end}
     assert "setup_s" in names and len(names) >= 2 and c.per_layer
     for spec in c.per_layer:
-        on_file = json.loads((ROOT / "benchmark" / "layer_metrics"
+        on_file = json.loads((c.home / "layer_metrics"
                               / f"{spec['name']}.json").read_text())
         for key in ("unit", "layer", "moves"):
             assert on_file[key] == spec[key], (spec["name"], key)
         assert spec["reader"] in ("span", "lane", "profile", "counter",
                                   "record", "span_self")
+    if holds_evictions(c):
+        return eviction_clause(c)
     # none of the seven holds an eviction, a tier or a pod that may wait
     assert (sizes["termination_cycles"], sizes["settle_cycles"],
             sizes["pods_run"], sizes["waiting_pods"], sizes["resident_class"],

@@ -9,17 +9,18 @@ from collections import Counter
 from benchmark import run as bench_run
 from benchmark.harness import cell as cell_mod
 from benchmark.harness import generate
+from test_benchmark_contract import reports_its_per_layer
 
 ROOT = cell_mod.ROOT
 SEED = 2**31 + 2027
 
 
-def test_the_file_loads_and_states_its_deployment():
-    c = cell_mod.load_cell("drf-5k.burst")
+def test_the_file_loads_and_states_its_deployment(bench_file=ROOT / "BENCHMARK.json"):
+    c = cell_mod.load_cell("drf-5k.burst", bench_file)
     cfg = c.config
     assert c.chips == 1 and c.config_name == "drf-5k" and c.traffic_name == "burst"
-    entry = next(e for e in json.loads((ROOT / "BENCHMARK.json").read_text())
-                 ["configs"] if e["name"] == "drf-5k")
+    bench = json.loads(bench_file.read_text())
+    entry = next(e for e in bench["configs"] if e["name"] == "drf-5k")
     assert entry["source"] == cfg["source"] and "configs[2]" in cfg["source"]
     assert entry["reduced"] == cfg["reduced"] == ["chips"]
     assert cfg["nodes"]["count"] == 5000 and cfg["backlog_pods"] == 50000
@@ -32,9 +33,8 @@ def test_the_file_loads_and_states_its_deployment():
     assert set(north["guarantees"]) < set(cfg["guarantees"])
     assert {"queue_share", "queue_share_under_contention"} <= set(cfg["guarantees"])
     assert c.sizes()["batch_pods"] == 50000 and c.sizes()["resident_pods"] == 0
-    # every per-layer metric of the benchmark lists no cells, so all are its
-    assert len(c.per_layer) == len(json.loads(
-        (ROOT / "BENCHMARK.json").read_text())["per_layer"])
+    # its per-layer metrics: those that list no cells and those that name it
+    reports_its_per_layer(c, bench)
 
 
 def test_the_worst_deal_is_what_the_file_reckons():

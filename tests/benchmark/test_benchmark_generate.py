@@ -185,3 +185,36 @@ def test_queues_say_what_may_be_reclaimed_and_classes_reach_the_store():
         == [("low", 10), ("high", 1000)]
     assert generate.to_priority_classes(CONFIG) == []
     assert generate.node_labels(CONFIG)[5] == {"zone": "zone-1"}
+
+
+# ---- ISSUE 42: a class may name the queues it is dealt to ------------------
+
+FOUR_QUEUES = {**TIERS, "queues": {"count": 4, "weights": [1, 2, 4, 8]}}
+
+
+def _with_queues(names):
+    low, high = TIERS["priority_classes"]
+    return {**FOUR_QUEUES, "priority_classes": [low, {**high, "queues": names}]}
+
+
+def test_a_class_that_names_its_queues_is_dealt_there_and_only_there():
+    named = ["queue-1", "queue-2", "queue-3"]
+    gen = generate.Generator(_with_queues(named), BIG_SEED)
+    low = gen.plan(60, "res", klass="low")
+    high = gen.plan(48, "burst", klass="high")
+    # in turn over its own three; the class that names none over all four
+    assert Counter(high.gang_queue) == {q: 4 for q in named}
+    assert set(low.gang_queue) == {"default", *named}
+    # the queues apart, both plans are what they are without the key
+    plain = generate.Generator(FOUR_QUEUES, BIG_SEED)
+    assert plain.plan(60, "res", klass="low").gang_queue == low.gang_queue
+    same = plain.plan(48, "burst", klass="high")
+    assert (same.names, same.gang_cpu, same.gang_priority) \
+        == (high.names, high.gang_cpu, high.gang_priority)
+    assert set(same.gang_queue) == {"default", *named}
+
+
+@pytest.mark.parametrize("names", [["queue-1", "queue-4"], []])
+def test_a_class_that_names_a_queue_the_configuration_lacks_raises(names):
+    with pytest.raises(ValueError, match="names queues"):
+        generate.Generator(_with_queues(names), BIG_SEED)
