@@ -454,6 +454,34 @@ def test_a_pass_inside_an_open_cycle_is_the_cycles(gen, clock, monkeypatch):
     assert g["longest_s"] == 0.0
 
 
+def test_full_passes_are_counted_by_who_started_them(clock, monkeypatch):
+    """``gc.full_by`` (ISSUE 43): the scheduler's own full passes under
+    their reason, any other under ``allocator``, seal to seal, so the
+    one at the end of the cycle's ``gc`` lane is this record's too."""
+    store = _clocked_store(clock, monkeypatch)
+    with trace.scheduled_pass("growth"):
+        _pass(1)                        # a young pass has no starter
+        _pass(2, collected=4)           # the bind worker's idle slot
+    _pass(2)                            # the allocator's count, a caller's collect()
+    with store.tracer.cycle(store.flight) as scope:
+        with trace.scheduled_pass("cycles"):
+            _pass(2, collected=1)       # the end of run_once()'s gc lane
+        scope.submit(CycleRecord(path="test"))
+    rec = store.flight.recent()[-1]
+    g = rec.between["gc"]
+    assert g["full_by"] == {"cycles": 1, "growth": 1, "allocator": 1}
+    assert g["gen2"] == {"n": 2, "s": 2e-6, "collected": 4}
+    assert g["in_cycle"] == {"n": 1, "s": 1e-6, "collected": 1}
+    assert [(s.name, s.args.get("reason")) for s in rec.spans
+            if s.cat == "gc"] == [
+        ("gc:gen1", None), ("gc:gen2", "growth"), ("gc:gen2", "allocator"),
+        ("gc:gen2", "cycles")]
+    assert trace._collector.reason is None
+    # And the next record has none of them.
+    assert _seal(store).between["gc"]["full_by"] == {
+        "cycles": 0, "growth": 0, "allocator": 0}
+
+
 def test_a_real_pass_is_counted_and_recorded_without_a_hand(monkeypatch):
     store = _store()
     gc.collect()
@@ -545,7 +573,8 @@ def test_a_gen2_pass_reaches_the_records_spans_and_the_gc_track(
     assert [s.name for s in passes] == ["gc:gen1", "gc:gen2"]
     gen2 = passes[1]
     assert gen2.tid == "gc" and gen2.dur_ns == 1000
-    assert gen2.args == {"collected": 7, "uncollectable": 1}
+    assert gen2.args == {"collected": 7, "uncollectable": 1,
+                         "reason": "allocator"}
     # On the tracer's clock: the hook's fifth reading began it.
     assert gen2.ts_ns == store.tracer._anchor_ns + 5000
     events = export.trace_events([rec])

@@ -332,6 +332,43 @@ def test_flush_timeout_returns_false_on_wedged_binder():
     d.stop()
 
 
+def test_the_idle_slot_comes_once_the_queue_is_empty(monkeypatch):
+    """``idle_slot`` (ISSUE 43): after a batch is delivered and let go
+    of, and only when no other waits: behind the last bind, in front
+    of none.  A slot that fails leaves the worker alive."""
+    from volcano_tpu.cache.bindqueue import BindDispatcher
+
+    release = threading.Event()
+    seen = []
+
+    class Held:
+        def bind_keys(self, keys, hosts):
+            assert release.wait(10)
+            seen.append(("bound", len(keys)))
+
+    def slot():
+        seen.append(("idle", d.flush(0)))
+        raise RuntimeError("a slot's failure is not the worker's")
+
+    monkeypatch.setattr(BindDispatcher, "idle_slot", slot)
+    d = BindDispatcher(Held(), lambda pairs: None)
+    try:
+        d.dispatch(["a/b"], ["n0"], [None])
+        d.dispatch(["a/c", "a/d"], ["n0", "n1"], [None, None])
+        release.set()
+        assert d.flush(timeout=10)
+        deadline = time.time() + 10
+        while len(seen) < 3 and time.time() < deadline:
+            time.sleep(0.005)
+        # None between the two batches; nothing was in flight at the slot.
+        assert seen == [("bound", 1), ("bound", 2), ("idle", True)]
+        d.dispatch(["a/e"], ["n0"], [None])     # the worker lives on
+        assert d.flush(timeout=10)
+    finally:
+        release.set()
+        d.stop()
+
+
 def test_deferred_record_walk_sets_node_name_post_cycle():
     """Async watcher-free cycles ship the bind batch as object arrays;
     the dispatcher worker applies the pod.node_name record walk

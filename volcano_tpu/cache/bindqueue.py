@@ -60,6 +60,14 @@ BACKOFF_MAX = 60.0
 class BindDispatcher:
     """Single worker thread draining batched bind requests."""
 
+    # The worker's idle slot: called, with no argument, by the worker
+    # each time it has delivered and let go of a batch and found its
+    # queue empty, the program's own thread with nothing to do until the
+    # next cycle.  The scheduler's collector policy puts its full pass
+    # here (scheduler.py ``_FullPasses``), for every dispatcher of the
+    # process: behind the last bind, in front of none.
+    idle_slot: Optional[Callable[[], object]] = None
+
     def __init__(self, binder,
                  on_failure: Callable[[List[Tuple[str, object]]], None],
                  on_success: Optional[Callable[[List[str], List[str]], None]] = None,
@@ -151,6 +159,13 @@ class BindDispatcher:
             with self._cv:
                 self._inflight -= 1
                 self._cv.notify_all()
+                idle = not self._q
+            idle_slot = BindDispatcher.idle_slot    # the process's one
+            if idle and idle_slot is not None:
+                try:
+                    idle_slot()
+                except Exception:
+                    log.exception("bind worker's idle slot failed")
 
     def _deliver(self) -> Tuple[int, int]:
         """Pop the head batch and deliver it: the binder calls, the

@@ -511,12 +511,15 @@ class ClusterStore:
         dispatcher's callbacks pin this store, so long-lived processes
         creating many stores must close them."""
         from ..pipeline import abandon_inflight, abandon_inflight_plan
+        from ..scheduler import release_collector
 
         # A parked pipelined solve holds device buffers (or a remote
         # solver's reply slot); drop it with the store.  A parked
         # rebalance plan mutates nothing until committed — drop it too.
         abandon_inflight(self)
         abandon_inflight_plan(self)
+        # Its Schedulers hold the collector's policy no longer.
+        release_collector(self)
         with self._lock:
             # Mesh plane cache pins per-device arrays across cycles;
             # a closed store must release them with everything else.

@@ -64,6 +64,7 @@ record was sealed.  No lane, nothing per pod (docs/tracing.md,
 from __future__ import annotations
 
 import collections
+import contextlib
 import gc
 import itertools
 import os
@@ -74,6 +75,12 @@ from typing import Dict, List, Optional
 
 # The nested pair the lanes rule allows inside ``device``.
 NESTED_LANES = ("device_coarse", "device_fine")
+
+# Who started a full pass of the collector (``between.gc.full_by``): the
+# scheduler, every ``GC_FULL_EVERY`` cycles or because the heap doubled,
+# or anyone else (the allocator's count, a ``gc.collect()`` of the
+# caller's).
+FULL_PASS_STARTERS = ("cycles", "growth", "allocator")
 
 
 class SpanRecord:
@@ -504,6 +511,10 @@ class BetweenAccount:
         # [passes, ns, collected] by generation, then of the passes
         # inside an open cycle (any generation).
         self._gc = [[0, 0, 0] for _ in range(4)]
+        # Full passes by who started them (FULL_PASS_STARTERS), inside
+        # a cycle or not; the counts at the last seal.
+        self._full = [0] * len(FULL_PASS_STARTERS)
+        self._full_base = list(self._full)
         self._gc_longest_ns = 0
         self._compact = [0, 0]  # compactions, ns
         self._cycles_open = 0
@@ -550,8 +561,10 @@ class BetweenAccount:
     # ------------------------------------------------------ the collector
 
     def collected(self, tr: "Tracer", gen: int, info: dict, t0_ns: int,
-                  dur_ns: int) -> None:
-        """One pass of the collector, told by the process's hook."""
+                  dur_ns: int, starter: str) -> None:
+        """One pass of the collector, told by the process's hook;
+        ``starter`` is the scheduler's reason for a full pass of its
+        own (``scheduled_pass``), ``allocator`` for any other."""
         # A pass while a cycle is open on the store is the cycle's
         # (``run_once()`` switches the collector off, so its ``gc``
         # lane's own sweep), apart from the interval's.
@@ -566,9 +579,13 @@ class BetweenAccount:
         if gen:
             # Generation 0 is counted and never recorded: its number
             # grows with the batch.
+            args = {"collected": collected,
+                    "uncollectable": info.get("uncollectable", 0)}
+            if gen == 2:
+                self._full[FULL_PASS_STARTERS.index(starter)] += 1
+                args["reason"] = starter
             tr.event(f"gc:gen{gen}", "gc", t0_ns, dur_ns, tid="gc",
-                     args={"collected": collected,
-                           "uncollectable": info.get("uncollectable", 0)})
+                     args=args)
 
     def cycle_open(self, delta: int) -> None:
         with self._lock:
@@ -603,6 +620,10 @@ class BetweenAccount:
             # No pass is the interval's while a cycle is open, so the
             # longest is still what the snapshot would have found.
             longest_ns, self._gc_longest_ns = self._gc_longest_ns, 0
+            # Full passes by starter run seal to seal, the cycle's own
+            # (a due pass at the end of its ``gc`` lane) included.
+            full = list(self._full)
+            full0, self._full_base = self._full_base, full
         t1_ns = snap["t1_ns"]
         t0_ns = min(t0_ns, t1_ns)
         out = {"t0_ns": anchor + t0_ns, "t1_ns": anchor + t1_ns,
@@ -644,6 +665,8 @@ class BetweenAccount:
             for name, g, g0 in zip(("gen0", "gen1", "gen2", "in_cycle"),
                                    snap["gc"], base["gc"])}
         gcd["longest_s"] = _s(longest_ns)
+        gcd["full_by"] = {name: n - n0 for name, n, n0
+                          in zip(FULL_PASS_STARTERS, full, full0)}
         out["compactions"] = snap["compact"][0] - base["compact"][0]
         out["compact_s"] = _s(snap["compact"][1] - base["compact"][1])
         out["bind_busy_s"] = _busy_inside(
@@ -676,10 +699,13 @@ class _Collector:
     makes: it times every pass of the collector and tells the accounts
     of the live tracers, reached through weak references (a dropped
     store's tracer and account go with it).  On the profiler's clock a
-    pass of generation 1 or 2 lies under ``vc:gc1`` / ``vc:gc2``."""
+    pass of generation 1 or 2 lies under ``vc:gc1`` / ``vc:gc2``.
+    ``reason`` is set around a full pass that the scheduler starts
+    (``scheduled_pass``); one pass runs at a time in a process."""
 
     def __init__(self):
         self.clock = time.perf_counter_ns
+        self.reason: Optional[str] = None
         self._tracers: tuple = ()  # weakref.ref(Tracer), copy-on-write
         self._lock = threading.Lock()
         self._t0 = 0
@@ -711,13 +737,27 @@ class _Collector:
         ann, self._ann = self._ann, None
         if ann is not None:
             ann.__exit__(None, None, None)
+        starter = (gen == 2 and self.reason) or "allocator"
         for ref in self._tracers:
             tr = ref()
             if tr is not None and tr.enabled and tr.between is not None:
-                tr.between.collected(tr, gen, info, t0, dur)
+                tr.between.collected(tr, gen, info, t0, dur, starter)
 
 
 _collector = _Collector()
+
+
+@contextlib.contextmanager
+def scheduled_pass(reason: str):
+    """Around a full pass that the scheduler starts itself
+    (``scheduler.py`` ``_FullPasses``): the accounts count it under
+    ``reason``, one of ``FULL_PASS_STARTERS``, and not under
+    ``allocator``."""
+    _collector.reason = reason
+    try:
+        yield
+    finally:
+        _collector.reason = None
 
 _NULL = Tracer(enabled=False)
 

@@ -9,11 +9,14 @@ config.
 
 from __future__ import annotations
 
+import gc
 import logging
+import sys
 import threading
 import time
+import weakref
 from pathlib import Path
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 from jax.profiler import TraceAnnotation
 
@@ -28,7 +31,7 @@ from .framework import (
     parse_scheduler_conf,
 )
 from .metrics import metrics
-from .obs.trace import tracer_of
+from .obs.trace import scheduled_pass, tracer_of
 
 log = logging.getLogger(__name__)
 
@@ -132,6 +135,9 @@ class Scheduler:
         self._lifecycle_lock = threading.Lock()
         # guarded-by: _lifecycle_lock
         self._thread: Optional[threading.Thread] = None
+        # The loop thread's ident while _loop runs (written by that
+        # thread alone): run_once() asks whether its caller is the loop.
+        self._loop_ident: Optional[int] = None
         self._last_conf = None
         self._consecutive_failures = 0
 
@@ -167,16 +173,24 @@ class Scheduler:
         actions) run on the vectorized fast path over the store's array
         mirror; anything else uses the object-session path.
 
-        The cyclic GC is suspended for the duration of the cycle: at
-        100k-pod scale a generation-2 collection walks the store's
-        millions of live objects (plus jax's gc callback) and was
-        measured adding 2.3 s to a 0.9 s preempt+reclaim cycle.  A
-        young-generation sweep runs after the cycle, off the latency
-        path; the service loop performs periodic full collections
-        between periods (service.py) so cyclic garbage still gets
-        reclaimed."""
-        import gc
-
+        The collector (``_FullPasses``, below ``GC_FULL_EVERY``): it is
+        switched off for the duration of the cycle (at 100k-pod scale a
+        generation-2 pass walks the store's millions of live objects
+        and was measured adding 2.3 s to a 0.9 s preempt+reclaim
+        cycle), and from a scheduler's first cycle on the allocator's
+        count starts no full pass between cycles either: automatic
+        collection is confined to generations 0 and 1, and a full pass
+        is started by the scheduler, when it is due (``GC_FULL_EVERY``
+        cycles, or the heap doubled since the last one) and no bind is
+        on its way.  The ``gc`` lane sweeps generation 0 and, where this
+        call is not the loop's and nothing is left to hand over
+        (synchronous binds), runs a due full pass at its end; with the
+        async dispatcher the bind worker runs it once it has delivered
+        the cycle's batch and found its queue empty, and ``_loop`` in
+        its period slack.  A caller that switched the collector off, or
+        moved the third threshold itself, keeps what it set.
+        ``stop()`` and the store's ``close()`` put the threshold
+        back."""
         # The cycle's frame (obs/trace.py CycleScope): the record of
         # this cycle covers entry to exit of run_once(), every lane of
         # it is a top-level span, and the record is sealed when the
@@ -184,15 +198,20 @@ class Scheduler:
         tracer = tracer_of(self.store, annotate=TraceAnnotation)
         with tracer.cycle(getattr(self.store, "flight", None)) as scope:
             gc_was_enabled = gc.isenabled()
+            _full_passes.enter(self)
             if gc_was_enabled:
                 gc.disable()
             try:
                 self._run_once_inner()
             finally:
+                _full_passes.leave()
                 if gc_was_enabled:
                     gc.enable()
                     with scope.lane("gc"):
                         gc.collect(0)
+                        if threading.get_ident() != self._loop_ident:
+                            # _loop's pass waits for the period slack.
+                            _full_passes.run_if_due()
 
     def _run_once_inner(self) -> None:
         # The frame run_once() opened on this thread.
@@ -382,26 +401,25 @@ class Scheduler:
     def healthy(self) -> bool:
         return self._consecutive_failures < self.UNHEALTHY_AFTER
 
-    # Full (gen-2) garbage collections run between periods every N
-    # cycles: run_once suspends the cyclic GC while the cycle runs, so
-    # cyclic garbage must be swept here, in the period slack, where the
-    # multi-second walk of a 100k-pod store's object graph cannot touch
-    # cycle latency.
+    # A full (generation-2) pass of the collector is due every N cycles
+    # (the process's, whichever Scheduler ran them), or sooner where
+    # the heap grows (_FullPasses, below): run_once() suspends the
+    # collector while a cycle runs and the allocator starts no full
+    # pass between cycles, so cyclic garbage is swept by the
+    # scheduler, where the multi-second walk of a 100k-pod store's
+    # object graph lies in front of no bind.
     GC_FULL_EVERY = 120
 
     def _loop(self):
-        import gc
-
-        cycles = 0
+        self._loop_ident = threading.get_ident()
         while not self._stop.is_set():
             t0 = time.time()
             try:
                 if self.gate is None or self.gate():
                     self.run_once()
                     self._consecutive_failures = 0
-                    cycles += 1
-                    if cycles % self.GC_FULL_EVERY == 0:
-                        gc.collect()
+                    # In the period slack, outside the cycle's record.
+                    _full_passes.run_if_due()
                 else:
                     # A standby runs no cycles; stale leader-era failures
                     # must not keep its health check red.
@@ -414,6 +432,7 @@ class Scheduler:
                 )
             elapsed = time.time() - t0
             self._stop.wait(max(self.schedule_period - elapsed, 0.0))
+        self._loop_ident = None
 
     # stop(): how long to wait for the loop thread.  Cycles never block
     # on the device any more (the pipelined dispatch is asynchronous and
@@ -446,11 +465,13 @@ class Scheduler:
                     )
                     return
                 self._thread = None
-        # Only after the thread is dead: the cycle thread owns the
+        # Only after the thread is dead: its next cycle would take the
+        # collector's policy up again, and the cycle thread owns the
         # in-flight handle while it runs.  A sharded loop drains only
         # its OWN slot — its siblings' parked solves are still live.
         from .pipeline import abandon_inflight, abandon_inflight_plan
 
+        _full_passes.release(self)
         if self.shard is not None:
             abandon_inflight(self.store, shard=self.shard.index)
             if self.shard.runs_evictions:
@@ -458,3 +479,159 @@ class Scheduler:
         else:
             abandon_inflight(self.store)
             abandon_inflight_plan(self.store)
+
+
+class _FullPasses:
+    """Who starts a full (generation-2) pass of the collector, and
+    when: the scheduler, never the allocator's count.
+
+    CPython starts a full pass when the objects promoted since the last
+    one exceed a quarter of what that one found alive, wherever the
+    allocation that trips the count happens to be: a store taking in
+    100,000 pods walks its whole heap six times on the way up, inside
+    ``add_pod``, and frees nothing.  One policy for the process, since
+    the collector is the process's:
+
+    - From the first cycle a ``Scheduler`` runs, the third threshold is
+      out of reach (the first two stay what the caller had), so the
+      allocator starts passes of generations 0 and 1 only.  A caller
+      that switched the collector off, or gave the third threshold a
+      value of its own, is left alone.  The Schedulers that have run a
+      cycle hold the policy; when the last has stopped, been dropped or
+      had its store closed, the threshold goes back to what was found.
+    - A full pass is due when ``Scheduler.GC_FULL_EVERY`` cycles have
+      run since the last one (``cycles``), or when the heap has doubled
+      since (``growth``): the interpreter's count of allocated blocks
+      (``sys.getallocatedblocks()``: every small object alive, cyclic
+      garbage too, which only a pass frees) is at least twice what the
+      last pass left, and ``GROWTH_FLOOR`` more.  So a store on its way
+      up is walked once a doubling, one that takes in and lets go of
+      the same 100,000 pods round after round not at all, and cyclic
+      garbage cannot outgrow the heap the last pass left.  Both numbers
+      are read, not kept: no census, nothing counted per object.
+    - A due pass starts only while no cycle is open in the process and
+      no holder's store has a bind on its way to the binder, at one of
+      three places: the end of ``run_once()``'s ``gc`` lane, the bind
+      worker's idle slot (``cache/bindqueue.py``), ``_loop``'s period
+      slack.  It holds the lock, so a cycle that wants to start waits
+      for it, as it would for the interpreter.
+    """
+
+    # CPython's own third threshold: another value is the caller's.
+    THIRD_DEFAULT = 10
+    OUT_OF_REACH = 10 ** 9
+    # ``growth`` needs this many blocks more than the last pass left,
+    # so that a young process does not collect every cycle: a pass
+    # over so few objects is some milliseconds.
+    GROWTH_FLOOR = 250_000
+
+    def __init__(self):
+        # Re-entrant: a pass, automatic or ours, may drop a holder on
+        # the thread that holds the lock, and its weak reference's
+        # callback takes it again.
+        self._lock = threading.RLock()
+        # guarded-by: _lock
+        self._holders: Dict[int, weakref.ref] = {}
+        self._installed = False     # the third threshold is ours
+        self._open = 0      # cycles open in the process
+        self._cycles = 0    # cycles since the last full pass
+        self._live = 0      # allocated blocks the last full pass left
+
+    # ------------------------------------------------------ the holders
+
+    def enter(self, sched: "Scheduler") -> None:
+        """A cycle of ``sched`` starts (before it switches the
+        collector off, so that the caller's setting is what is read)."""
+        with self._lock:
+            self._open += 1
+            key = id(sched)
+            if key not in self._holders:
+                if not self._holders:
+                    from .cache.bindqueue import BindDispatcher
+
+                    BindDispatcher.idle_slot = self.run_if_due
+                self._holders[key] = weakref.ref(
+                    sched, lambda _ref, key=key: self._drop(key))
+            if not self._installed and gc.isenabled():
+                first, second, third = gc.get_threshold()
+                if third == self.THIRD_DEFAULT:
+                    gc.set_threshold(first, second, self.OUT_OF_REACH)
+                    self._installed = True
+
+    def leave(self) -> None:
+        """The cycle ends."""
+        with self._lock:
+            self._open -= 1
+            self._cycles += 1
+
+    def release(self, sched: Optional["Scheduler"] = None,
+                store=None) -> None:
+        """``sched`` stopped, or ``store`` closed: they hold the policy
+        no longer (the next cycle takes it up again)."""
+        with self._lock:
+            for key, ref in list(self._holders.items()):
+                holder = ref()
+                if holder is None or holder is sched or (
+                        store is not None and holder.store is store):
+                    self._drop(key)
+
+    def _drop(self, key: int) -> None:
+        with self._lock:
+            self._holders.pop(key, None)
+            if self._holders:
+                return
+            from .cache.bindqueue import BindDispatcher
+
+            BindDispatcher.idle_slot = None
+            if self._installed:
+                self._installed = False
+                first, second, third = gc.get_threshold()
+                if third == self.OUT_OF_REACH:      # still ours to undo
+                    gc.set_threshold(first, second, self.THIRD_DEFAULT)
+
+    # --------------------------------------------------------- the pass
+
+    def _due(self) -> Optional[str]:
+        if self._cycles >= Scheduler.GC_FULL_EVERY:
+            return "cycles"
+        if sys.getallocatedblocks() >= 2 * self._live + self.GROWTH_FLOOR:
+            return "growth"
+        return None
+
+    def _handing_over(self) -> bool:
+        with self._lock:
+            holders = list(self._holders.values())
+        for ref in holders:
+            sched = ref()
+            flush = getattr(getattr(sched, "store", None),
+                            "flush_binds", None)
+            if flush is not None and not flush(0):
+                return True
+        return False
+
+    def run_if_due(self) -> bool:
+        """Run a full pass if one is due and nothing stands in front of
+        it: no open cycle, no bind on its way, a collector the caller
+        left on.  Returns whether it ran."""
+        with self._lock:
+            if self._open or not gc.isenabled() or self._handing_over():
+                return False
+            # Asked last: counting the blocks of a 100,000-pod heap is
+            # a third of a millisecond.
+            reason = self._due()
+            if reason is None:
+                return False
+            with scheduled_pass(reason):
+                gc.collect()
+            self._live = sys.getallocatedblocks()
+            self._cycles = 0
+            return True
+
+
+_full_passes = _FullPasses()
+
+
+def release_collector(store) -> None:
+    """``store`` is closed: the Schedulers that ran cycles on it hold
+    the collector's policy no longer (``ClusterStore.close()``)."""
+    _full_passes.release(store=store)
