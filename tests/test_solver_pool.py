@@ -154,6 +154,18 @@ def test_pool_of_one_bitwise_equal_to_single_client(servers,
     assert pool_bytes == single_bytes
 
 
+class _ExactShapePool(SolverPool):
+    """Asks the servers for the exact task shape (the manifest's ``wave``
+    key), so a toy's solve on the CPU is a millisecond whatever the wave
+    size and the timing below is the injected straggler's alone."""
+
+    def solve_async(self, solve_args, pid, profiles, wave=None,
+                    devincr=None):
+        return super().solve_async(
+            solve_args, pid, profiles,
+            wave=max(1, len(solve_args[1].real)), devincr=devincr)
+
+
 def test_hedged_dispatch_first_wins_and_drains(servers, monkeypatch):
     """A straggling primary past its rolling-p99 deadline re-dispatches
     the identical frame to the second replica; the first valid reply
@@ -165,7 +177,7 @@ def test_hedged_dispatch_first_wins_and_drains(servers, monkeypatch):
     monkeypatch.setenv("VOLCANO_TPU_POOL_HEDGE_MIN_MS", "20")
     for s in servers:
         s.solve_delay_fn = lambda i: 0.25 if i % 4 == 0 else 0.0
-    pool = SolverPool([f"127.0.0.1:{s.port}" for s in servers])
+    pool = _ExactShapePool([f"127.0.0.1:{s.port}" for s in servers])
     binds_h, states_h = _pool_loop(pool, cycles=12, churn=False)
     snap = pool.health_snapshot()
     assert snap["hedge_dispatches"] >= 1, snap
@@ -187,7 +199,7 @@ def test_hedged_dispatch_first_wins_and_drains(servers, monkeypatch):
     monkeypatch.setenv("VOLCANO_TPU_POOL_HEDGE_P99_MULT", "0")
     for s in servers:
         s.solve_delay_fn = None
-    pool2 = SolverPool([f"127.0.0.1:{s.port}" for s in servers])
+    pool2 = _ExactShapePool([f"127.0.0.1:{s.port}" for s in servers])
     binds_n, states_n = _pool_loop(pool2, cycles=12, churn=False)
     assert pool2.health_snapshot()["hedge_dispatches"] == 0
     pool2.close()

@@ -29,7 +29,11 @@ def _placed(res):
 
 def _check_invariants(args, res):
     nodes, tasks, jobs = args[0], args[1], args[2]
+    # whole waves come back: past the caller's rows is padding, never bound
+    rows = np.asarray(tasks.real).shape[0]
     assigned = np.asarray(res.assigned)
+    assert (assigned[rows:] == -1).all()
+    assigned = assigned[:rows]
     idle0 = np.asarray(nodes.idle)
     req = np.asarray(tasks.req)
     use = np.zeros_like(idle0)

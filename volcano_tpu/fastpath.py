@@ -3347,14 +3347,15 @@ class FastCycle:
         self._flush_aggr()
         m = self.m
         P = len(task_rows)
-        # Task axis stays exact: solve_wave pads to wave multiples (the
-        # jit-shape bucket), so a power-of-two pad here would only add waves.
-        Pp = P
         N = self.Nn
         Np = _pow2(max(N, 1))
         R = self.R
         J = len(solve_jobs)
-        Jp = _pow2(max(J, 1), 4)
+        # The job axis follows the pending rows: held at its high-water
+        # mark like the solve's other data-dependent buckets.
+        from .ops.wave import settle
+        Jp = settle(self.store._solve_shape_marks, "J", J,
+                    _pow2(max(J, 1), 4))
         Qp = _pow2(max(self.Qn, 1), 4)
 
         LW = _pow2(max(1, (len(m.labels) + 31) // 32), 1)
@@ -3530,11 +3531,8 @@ class FastCycle:
         sj = np.asarray(solve_jobs, np.int64)
         jrank = np.zeros(self.Jn + 1, I)
         jrank[sj] = np.arange(J, dtype=I)
-        tjob = jrank[self.jobr[task_rows]]
-        t_job = np.full((Pp,), -1, I)
-        t_job[:P] = tjob
-        t_real = np.zeros((Pp,), bool)
-        t_real[:P] = True
+        t_job = jrank[self.jobr[task_rows]]
+        t_real = np.ones((P,), bool)
 
         if slim:
             # Wave-solver path: the kernel reads only job/real per-task

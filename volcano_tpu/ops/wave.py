@@ -2804,13 +2804,15 @@ def solve_wave(
 ) -> AllocResult:
     """Wave-batched solve; same signature/result as ``allocate.solve``.
 
-    Pads the task axis to a multiple of ``wave`` (padded rows are inert),
-    deduplicates tasks into profiles host-side, and truncates the result
-    back to the caller's task count.  ``pid`` (optional [P] int32) supplies
-    precomputed profile ids — tasks with equal ids must have identical
-    per-task solver inputs — and skips the feature-hashing pass.  With
-    ``profiles`` also given (rows aligned to the pid numbering, which must
-    be by first occurrence; ``SolveProfiles`` or ``SparseProfiles``),
+    Pads the task axis to whole waves, the shape bucket of this axis
+    (padded rows are inert and stay in ``assigned`` / ``pipelined`` as
+    -1 past the caller's rows: a slice there would be a program per row
+    count), and deduplicates tasks into profiles host-side.  ``pid``
+    (optional [P] int32) supplies precomputed profile ids — tasks with
+    equal ids must have identical per-task solver inputs — and skips
+    the feature-hashing pass.  With ``profiles`` also given (rows
+    aligned to the pid numbering, which must be by first occurrence;
+    ``SolveProfiles`` or ``SparseProfiles``),
     nothing per-task is recomputed here and ``aff``'s task-level fields
     may be dummies.
 
@@ -2874,7 +2876,6 @@ def solve_wave(
         raise ValueError(
             "extra_ok/extra_score require in-call profile computation"
         )
-    wave = int(min(wave, max(1, P)))
     pad = (-P) % wave
     if pad:
         tasks = _pad_tasks(tasks, pad)
@@ -3183,8 +3184,4 @@ def solve_wave(
     })
     if dv is not None:
         dv.end_solve()
-    if pad:
-        res = res._replace(
-            assigned=res.assigned[:P], pipelined=res.pipelined[:P]
-        )
     return res
