@@ -509,6 +509,51 @@ def test_a_compaction_is_counted_whole_and_taken_out_of_the_timed_delete():
     assert ev["est_s"] / ev["n"] < block["compact_s"] / 10
 
 
+def _sized(name, cpu):
+    pod = _pod(name)
+    pod.containers = [{"cpu": str(cpu), "memory": "1Gi"}]
+    return pod
+
+
+@pytest.mark.parametrize("specs", [1, 9, 200])
+def test_the_specs_encoded_are_counted_exactly_and_once(specs):
+    """``specs_encoded`` is the pods whose spec the mirror had not met
+    (``StoreMirror._feat``): the distinct specs of a first batch, none
+    of a second batch of the same specs, and the one a new spec adds."""
+    store = _store()
+    store.add_pod_group(PodGroup(name="pg"))
+    for i in range(1000):
+        store.add_pod(_sized(f"a{i}", 1 + i % specs))
+    block = _seal(store).between
+    assert block["events"]["Pod/add"]["n"] == 1000
+    assert block["specs_encoded"] == specs
+    for i in range(1000):
+        store.add_pod(_sized(f"b{i}", 1 + i % specs))
+    store.update_pod(_sized("b0", 1))           # met: an update is none
+    assert _seal(store).between["specs_encoded"] == 0
+    store.add_pod(_sized("c0", 1000))
+    store.update_pod(_sized("c0", 1001))        # not met: an update is one
+    assert _seal(store).between["specs_encoded"] == 2
+    assert _seal(store).between["specs_encoded"] == 0
+
+
+def test_the_specs_met_before_a_compaction_are_met_after_it():
+    store = _store()
+    store.add_pod_group(PodGroup(name="pg"))
+    pods = [_sized(f"p{i}", 1 + i % 7) for i in range(4200)]
+    for pod in pods:
+        store.add_pod(pod)
+    assert _seal(store).between["specs_encoded"] == 7
+    for pod in pods[:2200]:
+        store.delete_pod(pod)
+    assert store.mirror.compact_gen >= 1
+    for i in range(100):
+        store.add_pod(_sized(f"q{i}", 1 + i % 7))
+    block = _seal(store).between
+    assert block["compactions"] == 1 and block["specs_encoded"] == 0
+    assert store._between.specs_encoded == 7    # a lifetime count
+
+
 # ------------------------------------------- (5) one hook, weak references
 
 
@@ -551,7 +596,10 @@ def test_with_tracing_off_the_counts_stay_and_nothing_is_timed(
     assert rec.between["events"] == {
         "PodGroup/add": {"n": 1}, "Pod/add": {"n": 130},
         "Pod/delete": {"n": 130}}
-    assert set(rec.between) == {"t0_ns", "t1_ns", "stride", "events"}
+    # The one spec of the 130 pods was encoded once: counted, not timed.
+    assert rec.between["specs_encoded"] == 1
+    assert set(rec.between) == {"t0_ns", "t1_ns", "stride", "events",
+                                "specs_encoded"}
     assert rec.spans == [] and not store.tracer._events
     assert [name for name, _ in log.seen] == ["vc:cycle"]  # no vc:gc2
 

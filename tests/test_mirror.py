@@ -1,14 +1,18 @@
 """Mirror maintenance: compaction, dynamic updates, fallback eligibility."""
 
+import copy
 import os
 
 import numpy as np
+import pytest
 
 from volcano_tpu.api import (
     GROUP_NAME_ANNOTATION,
     Node,
     Pod,
     PodGroup,
+    TaskInfo,
+    Toleration,
 )
 from volcano_tpu.cache import ClusterStore
 from volcano_tpu.scheduler import Scheduler
@@ -173,3 +177,237 @@ def test_job_uid_rank_extends_its_uid_array_and_stays_the_full_sort():
     assert m.compact_gen > gen and m._j_uid_arr is first
     store.add_pod_group(PodGroup(name="after", min_member=1))
     check()
+
+
+# ---------------------------------------------- one record a distinct spec
+
+_ROW_COLUMNS = ("p_status", "p_node", "p_node_name", "p_job", "p_prio",
+                "p_create", "p_alive", "p_be", "p_has_ip", "p_has_tol",
+                "p_critical", "p_prof")
+_P_COLUMNS = _ROW_COLUMNS + ("p_aff_lo", "p_aff_hi", "p_pref_lo", "p_pref_hi")
+_ROW_CSR = ("c_req", "c_init_req", "c_sel", "c_ports", "c_ip_aff",
+            "c_ip_anti", "c_ip_soft")
+_CSR_COLUMNS = _ROW_CSR + ("c_aff_alt", "c_pref")
+_INTERNERS = ("profiles", "terms", "labels", "ports", "scalar_slots",
+              "topo_keys")
+_RECORD = ("req", "init_req", "sel", "ports", "aff_alts", "pref",
+           "ip_req_aff", "ip_req_anti", "ip_soft", "has_ip", "priority",
+           "best_effort", "prof")
+
+
+def _csr_row(col, r):
+    lo, hi = int(col.off[r]), int(col.off[r + 1])
+    vals = col.val[lo:hi].tolist() if col.has_val else None
+    return col.idx[lo:hi].tolist(), vals
+
+
+def _tols(tols):
+    return [(t.key, t.operator, t.value, t.effect) for t in tols]
+
+
+def _row_view(m, r):
+    """Everything the mirror holds of pod row ``r``, as plain values."""
+    feat = m.p_feat[r]
+    return (
+        m.p_key[r],
+        [getattr(m, name)[r] for name in _ROW_COLUMNS],
+        [_csr_row(getattr(m, name), r) for name in _ROW_CSR],
+        [_csr_row(m.c_aff_alt, a)
+         for a in range(m.p_aff_lo[r], m.p_aff_hi[r])],
+        [(_csr_row(m.c_pref, a), m.pref_w[a])
+         for a in range(m.p_pref_lo[r], m.p_pref_hi[r])],
+        _tols(m._pod_tols[r]),
+        [getattr(feat, name) for name in _RECORD] + [_tols(feat.tol)],
+        (feat.req_res.milli_cpu, feat.req_res.memory,
+         dict(feat.req_res.scalars or {}), feat.init_res.milli_cpu,
+         feat.init_res.memory, dict(feat.init_res.scalars or {})),
+    )
+
+
+def _mirrors_agree(a, b, same_rows):
+    """Two mirrors hold the same: every interned index, every live
+    pod's row by uid and every term's members by uid; where the two
+    were to lay their rows out alike, every column whole as well."""
+    for name in _INTERNERS:
+        assert getattr(a, name).items == getattr(b, name).items, name
+    assert a.term_info == b.term_info
+    assert set(a.p_row) == set(b.p_row)
+    for uid, ra in a.p_row.items():
+        assert _row_view(a, ra) == _row_view(b, b.p_row[uid]), uid
+    for ma, mb in zip(a.term_members, b.term_members):
+        assert (sorted(a.p_uid[r] for r in ma if a.p_alive[r])
+                == sorted(b.p_uid[r] for r in mb if b.p_alive[r]))
+    if not same_rows:
+        return
+    n = len(a.p_uid)
+    assert a.p_uid == b.p_uid and a.p_key == b.p_key
+    assert (a.n_dead, a.compact_gen) == (b.n_dead, b.compact_gen)
+    for name in _P_COLUMNS:
+        np.testing.assert_array_equal(getattr(a, name)[:n],
+                                      getattr(b, name)[:n], err_msg=name)
+    for name in _CSR_COLUMNS:
+        ca, cb = getattr(a, name), getattr(b, name)
+        assert (ca._n, ca._len) == (cb._n, cb._len), name
+        np.testing.assert_array_equal(ca.off[:ca._n + 1], cb.off[:cb._n + 1])
+        np.testing.assert_array_equal(ca.idx[:ca._len], cb.idx[:cb._len])
+        if ca.has_val:
+            np.testing.assert_array_equal(ca.val[:ca._len], cb.val[:cb._len])
+    assert a.pref_w == b.pref_w
+    assert a.term_members == b.term_members
+    assert a._pods_by_pair == b._pods_by_pair
+
+
+def _gang_pod(i, **spec):
+    g = i // 4
+    spec.setdefault("labels", {"app": f"g{g % 3}"})
+    spec.setdefault("containers", [{"cpu": str(1 + g % 3), "memory": "2Gi"}])
+    return Pod(name=f"p{i}", uid=f"u{i}", creation_timestamp=float(i + 1),
+               annotations={GROUP_NAME_ANNOTATION: f"pg{g % 2}"}, **spec)
+
+
+def _shared(i, held):
+    """A gang's pods share their sub-objects by reference, as the
+    benchmark's and ``synth``'s do."""
+    g = i // 4
+    if held.get("g") != g:
+        held.update(g=g, labels={"app": f"g{g % 3}"},
+                    containers=[{"cpu": str(1 + g % 3), "memory": "2Gi"}])
+    return _gang_pod(i, labels=held["labels"], containers=held["containers"])
+
+
+def _mutated(i, held):
+    """One containers list for every pod, changed in place on the way:
+    a pod is encoded as the list reads when it is added."""
+    box = held.setdefault("containers", [{"cpu": "1", "memory": "2Gi"}])
+    if i % 1000 == 500:
+        box[0]["cpu"] = str(1 + i // 1000)
+    if i % 1000 == 750:
+        box.append({"cpu": "250m", "example.com/gpu": 1})
+    if i % 1000 == 900:
+        box.pop()
+    return _gang_pod(i, containers=box)
+
+
+def _term(i):
+    from volcano_tpu.api import AffinityTerm
+    return AffinityTerm(match_labels={"app": f"g{i // 4 % 3}"},
+                        topology_key="zone")
+
+
+_SPEC_CASES = {
+    # name: (pod of index i, specs encoded with the memo on or None for
+    #        "one an add", SPEC_MEMO_CAP of the store with the memo)
+    "shared-by-reference": (_shared, 3, None),
+    "fresh-objects": (lambda i, held: _gang_pod(i), 3, None),
+    "list-mutated-between-adds": (_mutated, None, None),
+    "equal-terms-two-namespaces": (
+        lambda i, held: _gang_pod(
+            i, namespace=("a", "b")[i // 4 % 2], affinity=[_term(i)],
+            preferred_anti_affinity=[(_term(i + 4), 5)]), None, None),
+    "spread-in-two-jobs": (
+        lambda i, held: _gang_pod(
+            i, containers=[{"cpu": "1"}], topology_spread=[("zone", 10)],
+            node_selector={"zone": "z1"}, host_ports=[8080 + i // 8 % 2]),
+        4, None),
+    "two-priorities": (
+        lambda i, held: _gang_pod(i, priority=(None, 1, 7)[i % 3],
+                                  containers=[{"cpu": "1"}]), 3, None),
+    "toleration": (
+        lambda i, held: _gang_pod(
+            i, containers=[{"cpu": "1"}], init_containers=[{"cpu": "2"}],
+            required_node_affinity=[{"zone": "z0"}, {"zone": "z1"}],
+            preferred_node_affinity=[({"rack": "r1"}, 3)],
+            tolerations=[Toleration(key="gpu", operator="Exists",
+                                    effect=("", "NoSchedule")[i % 2])]),
+        2, None),
+    "a-spec-a-pod": (
+        lambda i, held: _gang_pod(i, containers=[{"cpu": f"{i + 1}m"}]),
+        None, None),
+    "cap-reached-mid-stream": (
+        lambda i, held: _gang_pod(i, containers=[{"cpu": str(1 + i // 16 % 5)}]),
+        None, 3),
+    "update-with-fresh-object-of-equal-spec": (
+        lambda i, held: _gang_pod(i), 3, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SPEC_CASES))
+def test_a_spec_encoded_once_leaves_the_mirror_it_left_encoded_per_pod(case):
+    """``StoreMirror._feat`` gives every pod of one spec one record
+    (ISSUE 46).  The same seeded stream of adds, updates, binds
+    (copy-on-write), deletes and compactions into two stores, one of
+    which forgets every spec at once (``SPEC_MEMO_CAP`` 0: a spec a
+    pod, what the mirror did before), leaves the two mirrors alike:
+    columns, CSR columns, interned indices, term members."""
+    make, specs, cap = _SPEC_CASES[case]
+    fresh_update = case.startswith("update-with-fresh")
+
+    def run(memo_cap):
+        store = ClusterStore()
+        if memo_cap is not None:
+            store.mirror.SPEC_MEMO_CAP = memo_cap
+        for z in range(2):
+            store.add_node(Node(name=f"n{z}", labels={"zone": f"z{z}"},
+                                allocatable={"cpu": "9000", "memory": "9000Gi",
+                                             "example.com/gpu": 9000}))
+        for g in range(2):
+            store.add_pod_group(PodGroup(name=f"pg{g}", min_member=1))
+        rng = np.random.default_rng(46)
+        held, live, n_added, in_place = {}, [], 0, 0
+
+        def add(k):
+            nonlocal n_added
+            for _ in range(k):
+                pod = make(n_added, held)
+                store.add_pod(pod)
+                live.append(pod.uid)
+                n_added += 1
+
+        add(4300)
+        for uid in rng.choice(live, 300, replace=False).tolist():
+            pod = store.pods[uid]
+            if rng.random() < 0.5:
+                store.bind(TaskInfo(pod), f"n{int(rng.integers(2))}")
+            elif fresh_update:
+                # What a client decodes from the wire: a new object,
+                # equal in everything, running.
+                dead0 = store.mirror.n_dead
+                new = make(int(uid[1:]), {})
+                new.phase = "Running"
+                new.node_name = "n0"
+                store.update_pod(new)
+                in_place += store.mirror.n_dead == dead0
+            else:
+                new = copy.copy(pod)
+                new.phase = "Running"
+                new.node_name = "n1"
+                store.update_pod(new)
+        gen = store.mirror.compact_gen
+        for uid in live[:2300]:
+            store.delete_pod(store.pods[uid])
+        del live[:2300]
+        assert store.mirror.compact_gen > gen
+        add(200)        # specs met before the compaction are still met
+        return store, in_place
+
+    (memo, in_place), (plain, replaced) = run(cap), run(0)
+    _mirrors_agree(memo.mirror, plain.mirror, same_rows=not fresh_update)
+    assert plain.mirror._spec_memo == {}
+    encoded = memo._between.specs_encoded
+    assert plain._between.specs_encoded >= 4500
+    if specs is not None:
+        assert encoded == specs
+    elif case == "a-spec-a-pod":
+        assert encoded == 4500          # the key's microsecond, no more
+    else:
+        assert 3 < encoded < 4500 // 4
+    if fresh_update:
+        # With the memo the new object is handed the row's own record and
+        # takes the "same spec blob" branch of ``upsert_pod``, which
+        # rewrites status, node, job and creation time where the row
+        # is (the priority is the record's); per pod it is a row's death
+        # and another's birth.
+        assert in_place > 100 and replaced == 0
+        row = memo.mirror.p_row["u4299"]
+        assert memo.mirror.p_prio[row] == 1
+        assert memo.mirror.p_create[row] == 4300.0

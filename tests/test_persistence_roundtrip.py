@@ -7,12 +7,14 @@ survive, saves are atomic under concurrent churn, and failure modes
 (version mismatch, corrupt file) are loud.
 """
 
+import copy
 import pickle
 import threading
 
 import pytest
 
-from volcano_tpu.api import GROUP_NAME_ANNOTATION, Node, Pod, PodGroup, PodGroupPhase
+from volcano_tpu.api import (GROUP_NAME_ANNOTATION, Node, Pod, PodGroup,
+                             PodGroupPhase, TaskInfo)
 from volcano_tpu.cache import ClusterStore
 from volcano_tpu.controllers import ControllerManager, Job, TaskSpec
 from volcano_tpu.controllers.apis import Command, VolumeSpec
@@ -154,3 +156,69 @@ def test_no_temp_files_left_behind(tmp_path):
     leftovers = [p.name for p in tmp_path.iterdir()
                  if p.name.startswith(".vctpu-ckpt-")]
     assert leftovers == []
+
+
+# --------------------------------- one record a pod spec (ISSUE 46) and both
+
+def _spec_pods(n):
+    return [Pod(name=f"s{i}", uid=f"s{i}",
+                annotations={GROUP_NAME_ANNOTATION: "pg"},
+                containers=[{"cpu": "500m", "memory": "1Gi"},
+                            {"cpu": "1", "example.com/gpu": 2}],
+                init_containers=[{"cpu": "3"}])
+            for i in range(n)]
+
+
+@pytest.mark.parametrize("which", [1, 2, 5])
+def test_a_pod_handed_its_specs_record_answers_with_the_parsed_request(which):
+    """The pods after a spec's first parse nothing, and still hand a
+    ``TaskInfo`` the request a parse would give, as a copy of its own:
+    the record's ``Resource`` is every such pod's."""
+    store = ClusterStore()
+    store.add_pod_group(PodGroup(name="pg", min_member=1))
+    pods = _spec_pods(6)
+    for pod in pods:
+        store.add_pod(pod)
+    assert store._between.specs_encoded == 1
+    parsed = TaskInfo(_spec_pods(1)[0])         # never met a store
+    feat = store.mirror.p_feat[store.mirror.p_row[pods[which].uid]]
+    assert feat is store.mirror.p_feat[store.mirror.p_row[pods[0].uid]]
+    assert pods[which].resource_request() is feat.req_res
+    assert pods[which].init_resource_request() is feat.init_res
+    ti = TaskInfo(pods[which])
+    assert ti.resreq == parsed.resreq and ti.init_resreq == parsed.init_resreq
+    assert (ti.resreq.milli_cpu, ti.resreq.memory, ti.resreq.scalars) == (
+        1500.0, float(2 ** 30), {"example.com/gpu": 2000.0})
+    assert ti.init_resreq.milli_cpu == 3000.0
+    assert ti.resreq is not feat.req_res and ti.init_resreq is not feat.init_res
+    ti.resreq.add(ti.resreq)                    # a task's own to change
+    assert TaskInfo(pods[0]).resreq == parsed.resreq
+    assert store.jobs["default/pg"].tasks[pods[which].uid].resreq == parsed.resreq
+
+
+def test_a_restored_store_encodes_again_and_shares_no_record(tmp_path):
+    store = ClusterStore()
+    store.add_pod_group(PodGroup(name="pg", min_member=1))
+    for pod in _spec_pods(5):
+        store.add_pod(pod)
+    path = str(tmp_path / "ckpt.bin")
+    save_store(store, path)
+    assert all(not hasattr(pod, "_mirror_feat") and
+               not hasattr(pod, "_req_cache")
+               for pod in pickle.load(open(path, "rb"))["pods"])
+    restored = load_store(path)
+    assert restored._between.specs_encoded == 1
+    assert len(restored.mirror._spec_memo) == 1
+    mine = {id(f) for f in store.mirror.p_feat}
+    theirs = restored.mirror.p_feat
+    assert len({id(f) for f in theirs}) == 1 and id(theirs[0]) not in mine
+    assert theirs[0].profiles is restored.mirror.profiles
+    assert theirs[0].req_res is not store.mirror.p_feat[0].req_res
+    # A record is one mirror's: a pod that brings another store's along
+    # (a copy, caches and all) is encoded by the store it is added to.
+    other = ClusterStore()
+    other.add_pod(Pod(name="first", containers=[{"cpu": "7"}]))
+    guest = copy.copy(store.pods["s0"])
+    other.add_pod(guest)
+    assert guest._mirror_feat.profiles is other.mirror.profiles
+    assert other.mirror.p_prof[other.mirror.p_row["s0"]] == 1

@@ -14,8 +14,10 @@ Design:
   and inter-pod affinity terms are interned against store-scoped
   *append-only* dictionaries and stored as CSR segments (flat index/value
   buffers + per-row offsets).  Because the dictionaries only grow, encoded
-  rows never go stale.  The feature blob is cached on the ``Pod`` object, so
-  the copy-on-write pod replacement done by ``bind``/``evict`` reuses it.
+  rows never go stale.  A spec is encoded once, by the first pod that
+  brings it: the feature blob is shared by every pod of equal spec and
+  cached on the ``Pod`` object, so the copy-on-write pod replacement done
+  by ``bind``/``evict`` reuses it.
 - **Dynamic per-pod state is three scalars** (status i8-equivalent, node
   row, job row) updated in place.
 - **Everything aggregate is derived per cycle by vectorized reductions**
@@ -159,7 +161,9 @@ def _grow(a: np.ndarray, n: int) -> np.ndarray:
 
 @dataclass
 class _PodFeat:
-    """Static per-pod encoded features (cached on the Pod object)."""
+    """A pod spec's encoded features: one record per distinct spec,
+    shared by every row of that spec and cached on the ``Pod`` objects
+    (``StoreMirror._feat``).  Nobody writes to one after it is made."""
 
     req: Tuple[list, list]  # (slot idxs, values)
     init_req: Tuple[list, list]
@@ -173,9 +177,75 @@ class _PodFeat:
     ip_soft: List[Tuple[int, float]]
     has_ip: bool
     priority: int
-    create: float
     best_effort: bool
-    key: tuple = ()
+    prof: int  # the spec's place in ``profiles``
+    # The table ``prof`` and, with it, every index above belong to: a
+    # record is one mirror's, and a pod that brings another's (a copy
+    # handed to a second store) is encoded again.
+    profiles: Interner
+    # What the spec's pods answer ``resource_request()`` /
+    # ``init_resource_request()`` with (readers clone, api/info.py).
+    req_res: Resource
+    init_res: Resource
+
+
+def _same_frame(was: Pod, pod: Pod) -> bool:
+    """What a row holds of its pod besides the spec's record and the
+    dynamic state: the key, the labels its term memberships were found
+    by, what makes it critical.  A copy-on-write copy shares them."""
+    return ((was.labels is pod.labels or was.labels == pod.labels)
+            and was.namespace == pod.namespace and was.name == pod.name
+            and was.priority_class == pod.priority_class)
+
+
+def _term_key(term) -> tuple:
+    return (tuple(term.match_labels.items()), term.topology_key,
+            tuple(term.namespaces))
+
+
+def _spec_key(pod: Pod) -> tuple:
+    """The values ``StoreMirror._feat`` reads of ``pod``, and nothing
+    else: two pods of one key encode alike, whatever objects they share.
+    Dicts go in as they iterate (the order decides which scalar slot or
+    label pair is interned first).  A pod that sets only containers and
+    a priority, the common one, pays for those alone."""
+    if (pod.node_selector or pod.tolerations or pod.host_ports
+            or pod.required_node_affinity or pod.preferred_node_affinity
+            or pod.affinity or pod.anti_affinity or pod.preferred_affinity
+            or pod.preferred_anti_affinity or pod.topology_spread):
+        inter_pod = (pod.affinity or pod.anti_affinity
+                     or pod.preferred_affinity
+                     or pod.preferred_anti_affinity)
+        rest = (
+            tuple(pod.node_selector.items()),
+            tuple([(t.key, t.operator, t.value, t.effect)
+                   for t in pod.tolerations]),
+            tuple(pod.host_ports),
+            tuple([tuple(alt.items())
+                   for alt in pod.required_node_affinity]),
+            tuple([(tuple(sel_d.items()), w)
+                   for sel_d, w in pod.preferred_node_affinity]),
+            # Inter-pod terms, with the namespace a term that names
+            # none resolves in.
+            pod.namespace if inter_pod else None,
+            tuple([_term_key(t) for t in pod.affinity]),
+            tuple([_term_key(t) for t in pod.anti_affinity]),
+            tuple([(_term_key(t), w) for t, w in pod.preferred_affinity]),
+            tuple([(_term_key(t), w)
+                   for t, w in pod.preferred_anti_affinity]),
+            # Spread is a term on the pod's own job.
+            pod.job_id() if pod.topology_spread else None,
+            tuple(pod.topology_spread),
+        )
+    else:
+        rest = ()
+    return (
+        tuple([tuple(c.items()) for c in pod.containers]),
+        tuple([tuple(c.items()) for c in pod.init_containers])
+        if pod.init_containers else (),
+        pod.priority,
+        rest,
+    )
 
 
 class StoreMirror:
@@ -219,6 +289,10 @@ class StoreMirror:
         # excludes job identity; job-dependent inter-pod matches are
         # refined per cycle by the fast path.
         self.profiles = Interner()
+        # ``_feat``'s memo: a spec's values (``_spec_key``) -> its record.
+        # Of the interners above, so it goes where they go: over a
+        # compaction, and never into a checkpoint.
+        self._spec_memo: Dict[tuple, _PodFeat] = {}  # guarded-by: _lock
 
         # ------------------------------------------------------- pod table
         cap = 1024
@@ -431,11 +505,42 @@ class StoreMirror:
 
     # ================================================================ pods
 
+    # The most specs ``_feat`` remembers; one more and it forgets them
+    # all (a gang with an inter-pod term of its own is a spec of its own,
+    # 2,500 a round at ``affinity-10k``, and is never asked for again).
+    SPEC_MEMO_CAP = 8192
+
     # holds: _lock
     def _feat(self, pod: Pod) -> _PodFeat:
+        """``pod``'s encoded spec: the record its object carries (a
+        copy-on-write copy's), else the one an equal spec was given
+        before, else a new one, which is the only case that parses,
+        interns or counts (``BetweenAccount.specs_encoded``)."""
         feat = getattr(pod, "_mirror_feat", None)
-        if feat is not None:
+        if feat is not None and feat.profiles is self.profiles:
             return feat
+        key = _spec_key(pod)
+        feat = self._spec_memo.get(key)
+        if feat is None:
+            feat = self._encode(pod)
+            self._spec_memo[key] = feat
+            if len(self._spec_memo) > self.SPEC_MEMO_CAP:
+                self._spec_memo.clear()
+        else:
+            # The parsing must not just move to whoever asks the pod
+            # next (``TaskInfo``, store:rebuild_objects).
+            pod._req_cache = feat.req_res
+            pod._init_req_cache = feat.init_res
+        try:
+            pod._mirror_feat = feat
+        except Exception:
+            pass
+        return feat
+
+    # holds: _lock
+    def _encode(self, pod: Pod) -> _PodFeat:
+        if self.between is not None:
+            self.between.specs_encoded += 1
         req = pod.resource_request()
         init_req = pod.init_resource_request()
 
@@ -455,12 +560,10 @@ class StoreMirror:
             return slots, vals
 
         sel = [self._intern_queried(kv) for kv in pod.node_selector.items()]
-        tol = []
-        for t in pod.tolerations:
-            # A toleration row gates taints; intern every (key,value,effect)
-            # combination it covers that exists in the taint dict lazily at
-            # cycle time instead — here we record the toleration spec items.
-            tol.append(t)
+        # A toleration row gates taints; which taints it covers is found
+        # lazily at cycle time (the taint dict may grow): here we record
+        # the toleration spec items.
+        tol = list(pod.tolerations)
         ports = [self.ports.intern(p) for p in pod.host_ports]
         aff_alts = [
             [self._intern_queried(kv) for kv in alt.items()]
@@ -476,16 +579,16 @@ class StoreMirror:
             self._intern_term(t, pod.namespace) for t in pod.anti_affinity
         ]
         ip_soft: List[Tuple[int, float]] = []
-        for term, w in getattr(pod, "preferred_affinity", []):
+        for term, w in pod.preferred_affinity:
             ip_soft.append((self._intern_term(term, pod.namespace), float(w)))
-        for term, w in getattr(pod, "preferred_anti_affinity", []):
+        for term, w in pod.preferred_anti_affinity:
             ip_soft.append((self._intern_term(term, pod.namespace), -float(w)))
-        for key, w in getattr(pod, "topology_spread", []):
+        for key, w in pod.topology_spread:
             ip_soft.append((self._intern_job_term(pod.job_id(), key), -float(w)))
 
         req_pair = res_csr(req)
         init_pair = res_csr(init_req)
-        feat = _PodFeat(
+        return _PodFeat(
             req=req_pair,
             init_req=init_pair,
             sel=sel,
@@ -498,12 +601,11 @@ class StoreMirror:
             ip_soft=ip_soft,
             has_ip=bool(ip_req_aff or ip_req_anti or ip_soft),
             priority=pod.priority if pod.priority is not None else 1,
-            create=pod.creation_timestamp,
             best_effort=init_req.is_empty(),
             # NOTE: the pod's own labels/namespace are deliberately NOT part
-            # of the key — they only influence inter-pod term membership
+            # of the profile — they only influence inter-pod term membership
             # (t_matches), which the fast path refines per cycle.
-            key=(
+            prof=self.profiles.intern((
                 tuple(zip(*req_pair)),
                 tuple(zip(*init_pair)),
                 tuple(sorted(sel)),
@@ -517,13 +619,11 @@ class StoreMirror:
                 tuple(sorted(ip_req_aff)),
                 tuple(sorted(ip_req_anti)),
                 tuple(sorted(ip_soft)),
-            ),
+            )),
+            profiles=self.profiles,
+            req_res=req,
+            init_res=init_req,
         )
-        try:
-            pod._mirror_feat = feat
-        except Exception:
-            pass
-        return feat
 
     # holds: _lock
     def _intern_queried(self, kv: Tuple[str, str]) -> int:
@@ -659,12 +759,16 @@ class StoreMirror:
         if row is not None and self.p_uid[row] == pod.uid:
             self.mark_pod_dirty(row)
             self.pod_obj_gen += 1
+            was = self.p_pod[row]
             self.p_pod[row] = pod
-            if self.p_feat[row] is feat:
-                # Same spec blob (bind/evict copy-on-write carries it over):
+            if self.p_feat[row] is feat and _same_frame(was, pod):
+                # Same spec blob (bind/evict copy-on-write carries it over,
+                # and a fresh object of equal spec is given it by ``_feat``):
                 # update dynamic state only.  The job link is re-derived —
                 # the podgroup controller back-annotates bare pods with a
                 # group name after the fact (pg_controller_handler.go:72-105).
+                # The record holds the priority; the creation time it
+                # does not.
                 old = int(self.p_status[row])
                 if old != status:
                     if st is not None:
@@ -681,6 +785,7 @@ class StoreMirror:
                 self.p_status[row] = status
                 self.p_node[row] = node_row
                 self.p_node_name[row] = pod.node_name or None
+                self.p_create[row] = pod.creation_timestamp
                 jid = pod.job_id()
                 self.p_job[row] = job_row_of(jid) if jid else -1
                 if st is not None:
@@ -735,7 +840,7 @@ class StoreMirror:
             if st is not None:
                 st.mark("journey")
         self.p_prio[row] = feat.priority
-        self.p_create[row] = feat.create
+        self.p_create[row] = pod.creation_timestamp
         self.p_alive[row] = True
         self.p_be[row] = feat.best_effort
         self.p_has_ip[row] = feat.has_ip
@@ -745,7 +850,7 @@ class StoreMirror:
                                    SYSTEM_NODE_CRITICAL)
             or pod.namespace == SYSTEM_NAMESPACE
         )
-        self.p_prof[row] = self.profiles.intern(feat.key)
+        self.p_prof[row] = feat.prof
 
         self.c_req.append(*feat.req)
         self.c_init_req.append(*feat.init_req)
@@ -1230,7 +1335,7 @@ class StoreMirror:
         fresh.__init__()
         # Dictionaries and node/job tables carry over untouched.
         for attr in ("scalar_slots", "labels", "taints", "ports", "terms",
-                     "term_info", "topo_keys", "profiles",
+                     "term_info", "topo_keys", "profiles", "_spec_memo",
                      "_terms_by_pair", "_terms_by_job", "_terms_all",
                      "n_name", "n_row", "n_ready",
                      "n_alive", "n_maxtasks", "c_n_alloc", "c_n_labels",

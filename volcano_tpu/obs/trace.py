@@ -487,12 +487,14 @@ class BetweenAccount:
     """What a store did between two cycles, by itself: its event
     handlers' calls by kind (exact), their seconds by kind and phase
     (from one timed call in ``SAMPLE_STRIDE``), the collector's passes
-    (exact, from ``_Collector``) and the pod table's compactions
-    (exact).  Lifetime counters; ``snapshot()`` copies them at a
-    cycle's start and ``block()`` turns the difference to the previous
-    sealed record's snapshot into ``CycleRecord.between``.
+    (exact, from ``_Collector``), the pod table's compactions (exact)
+    and the pod specs the mirror encoded (exact).  Lifetime counters;
+    ``snapshot()`` copies them at a cycle's start and ``block()`` turns
+    the difference to the previous sealed record's snapshot into
+    ``CycleRecord.between``.
 
-    Who writes what: ``counts`` the handlers, under the STORE's lock;
+    Who writes what: ``counts`` the handlers and ``specs_encoded`` the
+    mirror, under the STORE's lock;
     ``_samples`` / ``_sums`` / ``_open`` a timed call, under ``_lock``;
     the collector's numbers the hook, of which one runs at a time in a
     process.  The hook takes no lock at all: a pass interrupts whatever
@@ -517,6 +519,10 @@ class BetweenAccount:
         self._full_base = list(self._full)
         self._gc_longest_ns = 0
         self._compact = [0, 0]  # compactions, ns
+        # Pod specs the mirror encoded (``StoreMirror._encode``), under
+        # the store's lock: an add or update whose spec it had met is
+        # not one.
+        self.specs_encoded = 0
         self._cycles_open = 0
         self._lock = threading.Lock()
         self._base = self.snapshot(0)
@@ -602,6 +608,7 @@ class BetweenAccount:
             "sums": [dict(v) for v in self._sums],
             "gc": [list(g) for g in self._gc],
             "compact": list(self._compact),
+            "specs_encoded": self.specs_encoded,
         }
 
     def block(self, snap: dict, spans: list, seal_ns: int) -> dict:
@@ -654,6 +661,7 @@ class BetweenAccount:
                               for name, ns in phases.items()})
             held_s += _s((whole - phases.get("lock_wait", 0))
                          * SAMPLE_STRIDE)
+        out["specs_encoded"] = snap["specs_encoded"] - base["specs_encoded"]
         if not timing:
             return out
         out["lock_held_s"] = round(held_s, 9)
