@@ -526,15 +526,17 @@ def test_the_specs_encoded_are_counted_exactly_and_once(specs):
         store.add_pod(_sized(f"a{i}", 1 + i % specs))
     block = _seal(store).between
     assert block["events"]["Pod/add"]["n"] == 1000
-    assert block["specs_encoded"] == specs
+    assert block["specs_encoded"] == block["spec_rows"] == specs
     for i in range(1000):
         store.add_pod(_sized(f"b{i}", 1 + i % specs))
     store.update_pod(_sized("b0", 1))           # met: an update is none
     assert _seal(store).between["specs_encoded"] == 0
     store.add_pod(_sized("c0", 1000))
     store.update_pod(_sized("c0", 1001))        # not met: an update is one
-    assert _seal(store).between["specs_encoded"] == 2
-    assert _seal(store).between["specs_encoded"] == 0
+    block = _seal(store).between
+    assert (block["specs_encoded"], block["spec_rows"]) == (2, specs + 2)
+    block = _seal(store).between                # a level: it stays
+    assert (block["specs_encoded"], block["spec_rows"]) == (0, specs + 2)
 
 
 def test_the_specs_met_before_a_compaction_are_met_after_it():
@@ -552,6 +554,42 @@ def test_the_specs_met_before_a_compaction_are_met_after_it():
     block = _seal(store).between
     assert block["compactions"] == 1 and block["specs_encoded"] == 0
     assert store._between.specs_encoded == 7    # a lifetime count
+    assert block["spec_rows"] == 7
+
+
+@pytest.mark.parametrize("own", [0, 40, 400])
+def test_spec_rows_is_the_spec_tables_level_and_falls_at_a_compaction(own):
+    """``spec_rows`` is the rows of the mirror's spec table as the
+    cycle's snapshot finds them.  ``events.Pod/add.n`` less
+    ``specs_encoded`` is the adds that wrote no ragged row: the columns
+    the readers gather from grew by ``specs_encoded`` rows and no more.
+    Pods that each brought a spec of their own take its row along when
+    a compaction finds them gone."""
+    store = _store()
+    m = store.mirror
+    store.add_pod_group(PodGroup(name="pg"))
+    ragged = (m.c_req, m.c_init_req, m.c_sel, m.c_ports, m.c_ip_aff,
+              m.c_ip_anti, m.c_ip_soft)
+    loners = [_sized(f"l{i}", 100 + i) for i in range(own)]
+    crowd = [_sized(f"p{i}", 1 + i % 3) for i in range(4300 - own)]
+    for pod in loners + crowd:
+        store.add_pod(pod)
+    block = _seal(store).between
+    assert block["events"]["Pod/add"]["n"] == 4300
+    assert block["specs_encoded"] == block["spec_rows"] == own + 3
+    assert {col._n for col in ragged} == {own + 3}
+    for i in range(500):
+        store.add_pod(_sized(f"q{i}", 1 + i % 3))
+    block = _seal(store).between
+    assert block["events"]["Pod/add"]["n"] - block["specs_encoded"] == 500
+    assert block["spec_rows"] == own + 3
+    assert {col._n for col in ragged} == {own + 3}
+    for pod in loners + crowd[:2500]:
+        store.delete_pod(pod)
+    block = _seal(store).between
+    assert block["compactions"] == 1
+    assert block["spec_rows"] == len(m.s_feat) == 3
+    assert {col._n for col in ragged} == {3}
 
 
 # ------------------------------------------- (5) one hook, weak references
@@ -598,8 +636,9 @@ def test_with_tracing_off_the_counts_stay_and_nothing_is_timed(
         "Pod/delete": {"n": 130}}
     # The one spec of the 130 pods was encoded once: counted, not timed.
     assert rec.between["specs_encoded"] == 1
+    assert rec.between["spec_rows"] == 1
     assert set(rec.between) == {"t0_ns", "t1_ns", "stride", "events",
-                                "specs_encoded"}
+                                "specs_encoded", "spec_rows"}
     assert rec.spans == [] and not store.tracer._events
     assert [name for name, _ in log.seen] == ["vc:cycle"]  # no vc:gc2
 

@@ -8,6 +8,7 @@ import pytest
 
 from volcano_tpu.api import (
     GROUP_NAME_ANNOTATION,
+    AffinityTerm,
     Node,
     Pod,
     PodGroup,
@@ -15,6 +16,7 @@ from volcano_tpu.api import (
     Toleration,
 )
 from volcano_tpu.cache import ClusterStore
+from volcano_tpu.cache.mirror import HOSTNAME_KEY, JOB_SELECTOR
 from volcano_tpu.scheduler import Scheduler
 from volcano_tpu.synth import synthetic_cluster
 
@@ -184,10 +186,8 @@ def test_job_uid_rank_extends_its_uid_array_and_stays_the_full_sort():
 _ROW_COLUMNS = ("p_status", "p_node", "p_node_name", "p_job", "p_prio",
                 "p_create", "p_alive", "p_be", "p_has_ip", "p_has_tol",
                 "p_critical", "p_prof")
-_P_COLUMNS = _ROW_COLUMNS + ("p_aff_lo", "p_aff_hi", "p_pref_lo", "p_pref_hi")
 _ROW_CSR = ("c_req", "c_init_req", "c_sel", "c_ports", "c_ip_aff",
             "c_ip_anti", "c_ip_soft")
-_CSR_COLUMNS = _ROW_CSR + ("c_aff_alt", "c_pref")
 _INTERNERS = ("profiles", "terms", "labels", "ports", "scalar_slots",
               "topo_keys")
 _RECORD = ("req", "init_req", "sel", "ports", "aff_alts", "pref",
@@ -196,9 +196,11 @@ _RECORD = ("req", "init_req", "sel", "ports", "aff_alts", "pref",
 
 
 def _csr_row(col, r):
-    lo, hi = int(col.off[r]), int(col.off[r + 1])
-    vals = col.val[lo:hi].tolist() if col.has_val else None
-    return col.idx[lo:hi].tolist(), vals
+    """Row ``r`` as the column's readers are given it: a pod's row of
+    the seven columns asked by pod row, a side table's own."""
+    got = col.gather(np.array([r]))
+    assert col.lens(np.array([r])).tolist() == [len(got[0])]
+    return got[1].tolist(), got[2].tolist() if col.has_val else None
 
 
 def _tols(tols):
@@ -213,10 +215,9 @@ def _row_view(m, r):
         [getattr(m, name)[r] for name in _ROW_COLUMNS],
         [_csr_row(getattr(m, name), r) for name in _ROW_CSR],
         [_csr_row(m.c_aff_alt, a)
-         for a in range(m.p_aff_lo[r], m.p_aff_hi[r])],
+         for a in range(*(int(e[0]) for e in m.aff_ranges(np.array([r]))))],
         [(_csr_row(m.c_pref, a), m.pref_w[a])
-         for a in range(m.p_pref_lo[r], m.p_pref_hi[r])],
-        _tols(m._pod_tols[r]),
+         for a in range(*(int(e[0]) for e in m.pref_ranges(np.array([r]))))],
         [getattr(feat, name) for name in _RECORD] + [_tols(feat.tol)],
         (feat.req_res.milli_cpu, feat.req_res.memory,
          dict(feat.req_res.scalars or {}), feat.init_res.milli_cpu,
@@ -242,17 +243,18 @@ def _mirrors_agree(a, b, same_rows):
     n = len(a.p_uid)
     assert a.p_uid == b.p_uid and a.p_key == b.p_key
     assert (a.n_dead, a.compact_gen) == (b.n_dead, b.compact_gen)
-    for name in _P_COLUMNS:
+    for name in _ROW_COLUMNS:
         np.testing.assert_array_equal(getattr(a, name)[:n],
                                       getattr(b, name)[:n], err_msg=name)
-    for name in _CSR_COLUMNS:
+    # The spec tables differ (a row a spec against a row a pod); what a
+    # reader gathers for the whole pod table does not, dtype included.
+    rows = np.arange(n)
+    for name in _ROW_CSR:
         ca, cb = getattr(a, name), getattr(b, name)
-        assert (ca._n, ca._len) == (cb._n, cb._len), name
-        np.testing.assert_array_equal(ca.off[:ca._n + 1], cb.off[:cb._n + 1])
-        np.testing.assert_array_equal(ca.idx[:ca._len], cb.idx[:cb._len])
-        if ca.has_val:
-            np.testing.assert_array_equal(ca.val[:ca._len], cb.val[:cb._len])
-    assert a.pref_w == b.pref_w
+        np.testing.assert_array_equal(ca.lens(rows), cb.lens(rows))
+        for ga, gb in zip(ca.gather(rows), cb.gather(rows), strict=True):
+            assert ga.dtype == gb.dtype, name
+            np.testing.assert_array_equal(ga, gb, err_msg=name)
     assert a.term_members == b.term_members
     assert a._pods_by_pair == b._pods_by_pair
 
@@ -289,7 +291,6 @@ def _mutated(i, held):
 
 
 def _term(i):
-    from volcano_tpu.api import AffinityTerm
     return AffinityTerm(match_labels={"app": f"g{i // 4 % 3}"},
                         topology_key="zone")
 
@@ -411,3 +412,320 @@ def test_a_spec_encoded_once_leaves_the_mirror_it_left_encoded_per_pod(case):
         row = memo.mirror.p_row["u4299"]
         assert memo.mirror.p_prio[row] == 1
         assert memo.mirror.p_create[row] == 4300.0
+
+
+# ------------------------------------- one row a spec, asked by pod row
+
+_SPEC_KINDS = {
+    "plain": lambda v: dict(
+        containers=[{"cpu": str(1 + v), "memory": "1Gi"}]),
+    "selector-tolerations-ports": lambda v: dict(
+        containers=[{"cpu": "1"}],
+        node_selector={"zone": f"z{v % 2}", "disk": "ssd"},
+        tolerations=[Toleration(key="gpu", operator="Exists",
+                                effect="NoSchedule"),
+                     Toleration(key="team", operator="Equal",
+                                value=f"t{v}")],
+        host_ports=[8080 + v, 9090]),
+    "node-affinity": lambda v: dict(
+        containers=[{"cpu": "2", "memory": "1Gi"}],
+        required_node_affinity=[{"zone": "z0"},
+                                {"zone": "z1", "rack": f"r{v}"}][:1 + v % 2],
+        preferred_node_affinity=[({"rack": f"r{v}"}, 3),
+                                 ({"zone": "z1"}, 1 + v)]),
+    "inter-pod": lambda v: dict(
+        labels={"app": f"a{v}"}, containers=[{"cpu": "1", "memory": "2Gi"}],
+        affinity=[AffinityTerm({"app": f"a{v}"}, "zone")],
+        anti_affinity=[AffinityTerm({"app": "x"}, HOSTNAME_KEY,
+                                    namespaces=["other", "default"])],
+        preferred_affinity=[(AffinityTerm({"tier": "db"}, "zone"), 5)],
+        preferred_anti_affinity=[(AffinityTerm({"app": f"a{v}"}, "rack"),
+                                  2 + v)]),
+    "topology-spread": lambda v: dict(
+        containers=[{"cpu": "1"}],
+        topology_spread=[("zone", 10), ("rack", 1 + v)]),
+    "best-effort": lambda v: dict(containers=[], priority=v),
+    "init-containers": lambda v: dict(
+        containers=[{"cpu": "1", "example.com/gpu": 1}],
+        init_containers=[{"cpu": str(2 + v), "memory": "4Gi"},
+                         {"cpu": "1", "example.com/nic": 2}]),
+}
+
+
+def _kind_pod(kind, i, **over):
+    """Pod ``i`` of a case's stream: two in three of the case's kind in
+    one of four variants, the others plain, dealt to five jobs."""
+    spec = dict(name=f"p{i}", uid=f"u{i}", creation_timestamp=float(i + 1),
+                annotations={GROUP_NAME_ANNOTATION: f"pg{i % 5}"})
+    spec.update((_SPEC_KINDS[kind] if i % 3
+                 else _SPEC_KINDS["plain"])(i // 7 % 4))
+    spec.update(over)
+    return Pod(**spec)
+
+
+def _plain_encoding(m, pod):
+    """What a mirror that encodes every pod by itself holds of ``pod``
+    (never met by a store), in ``m``'s interned indices."""
+    def res(r):
+        slots = [0] * bool(r.milli_cpu) + [1] * bool(r.memory)
+        vals = [v for v in (r.milli_cpu, r.memory) if v]
+        for name, quant in (r.scalars or {}).items():
+            if quant:
+                slots.append(2 + m.scalar_slots.index[name])
+                vals.append(quant)
+        return slots, vals
+
+    def pairs(d):
+        return [m.labels.index[kv] for kv in d.items()]
+
+    def term(t):
+        ns = tuple(sorted(t.namespaces)) if t.namespaces else (pod.namespace,)
+        return m.terms.index[
+            (tuple(sorted(t.match_labels.items())), t.topology_key, ns)]
+
+    soft = ([(term(t), float(w)) for t, w in pod.preferred_affinity]
+            + [(term(t), -float(w)) for t, w in pod.preferred_anti_affinity]
+            + [(m.terms.index[(((JOB_SELECTOR, pod.job_id()),), key, None)],
+                -float(w)) for key, w in pod.topology_spread])
+    return {
+        "c_req": res(pod.resource_request()),
+        "c_init_req": res(pod.init_resource_request()),
+        "c_sel": (pairs(pod.node_selector), None),
+        "c_ports": ([m.ports.index[p] for p in pod.host_ports], None),
+        "c_ip_aff": ([term(t) for t in pod.affinity], None),
+        "c_ip_anti": ([term(t) for t in pod.anti_affinity], None),
+        "c_ip_soft": ([e for e, _ in soft], [w for _, w in soft]),
+        "aff": [pairs(alt) for alt in pod.required_node_affinity],
+        "pref": [(pairs(sel), float(w))
+                 for sel, w in pod.preferred_node_affinity],
+        "tol": _tols(pod.tolerations),
+    }
+
+
+def _readers_get_the_plain_encoding(m, want_of_row, rng):
+    """Every ragged column, gathered for the whole table and for rows
+    drawn with repeats, dead rows among them, is the plain encoding's
+    rows laid end to end: indices, values, order and dtype."""
+    n = len(m.p_uid)
+    assert set(want_of_row) == set(range(n))
+    want = {r: _plain_encoding(m, pod) for r, pod in want_of_row.items()}
+    drawn = rng.integers(0, n, 300)
+    for rows in (np.arange(n), drawn, drawn[:1], drawn[:0]):
+        for name in _ROW_CSR:
+            col = getattr(m, name)
+            per_row = [want[r][name] for r in rows.tolist()]
+            lens = [len(idx) for idx, _ in per_row]
+            got = col.gather(rows)
+            assert [a.dtype for a in got] == (
+                [np.int64, np.int32] + [np.float32] * col.has_val), name
+            np.testing.assert_array_equal(col.lens(rows), lens)
+            assert col.lens(rows).dtype == np.int64
+            np.testing.assert_array_equal(
+                got[0], np.repeat(np.arange(len(rows)), lens))
+            assert got[1].tolist() == [i for idx, _ in per_row for i in idx]
+            if col.has_val:
+                np.testing.assert_array_equal(
+                    got[2], np.array([v for _, vals in per_row for v in vals],
+                                     np.float32))
+    for r, lo, hi, plo, phi in zip(drawn.tolist(), *m.aff_ranges(drawn),
+                                   *m.pref_ranges(drawn)):
+        assert [_csr_row(m.c_aff_alt, a)[0]
+                for a in range(lo, hi)] == want[r]["aff"]
+        assert [(_csr_row(m.c_pref, a)[0], m.pref_w[a])
+                for a in range(plo, phi)] == want[r]["pref"]
+        assert _tols(m.p_feat[r].tol) == want[r]["tol"]
+        assert m.p_has_tol[r] == bool(want[r]["tol"])
+    alive = np.flatnonzero(m.p_alive[:n])
+    assert len(m.s_feat) >= len({id(m.p_feat[r]) for r in alive.tolist()})
+    assert [f.row for f in m.s_feat] == list(range(len(m.s_feat)))
+
+
+@pytest.mark.parametrize("kind", sorted(_SPEC_KINDS))
+def test_readers_asking_by_pod_row_get_what_a_row_a_pod_would_hold(kind):
+    """A spec's ragged features are one row of the spec table (ISSUE 47)
+    and the readers' ``gather(rows)`` / ``lens(rows)``, the ``aff`` /
+    ``pref`` ranges and the tolerations answer by pod row as a table
+    with one row a pod would: after the adds (the memo overflowing on
+    the way), after spec-changing updates, after a compaction, and for
+    a ``Pod`` object that comes back when its spec's row is gone."""
+    store = ClusterStore()
+    m = store.mirror
+    m.SPEC_MEMO_CAP = 3
+    for z in range(2):
+        store.add_node(Node(name=f"n{z}", labels={"zone": f"z{z}"},
+                            allocatable={"cpu": "64", "memory": "64Gi"}))
+    for g in range(5):
+        store.add_pod_group(PodGroup(name=f"pg{g}", min_member=1))
+    rng = np.random.default_rng(47)
+    want_of_row = {}
+
+    def put(pod, event):
+        event(pod)
+        want_of_row[m.p_row[pod.uid]] = copy.deepcopy(pod)
+
+    # Three pods of a spec nobody else has, whose objects are kept.
+    held = [_kind_pod(kind, 1, name=f"h{i}", uid=f"h{i}",
+                      containers=[{"cpu": "77m"}]) for i in range(3)]
+    for pod in held:
+        put(pod, store.add_pod)
+    for i in range(4300):
+        put(_kind_pod(kind, i), store.add_pod)
+    assert store._between.specs_encoded > 8     # the memo overflowed
+    assert len(m.s_feat) == store._between.specs_encoded
+    _readers_get_the_plain_encoding(m, want_of_row, rng)
+
+    for i in rng.choice(4300, 60, replace=False).tolist():
+        # Another spec under the same uid: the row dies, a new one is born.
+        dead0 = m.n_dead
+        put(_kind_pod(kind, i, **_SPEC_KINDS[kind](5)), store.update_pod)
+        assert m.n_dead == dead0 + 1
+    _readers_get_the_plain_encoding(m, want_of_row, rng)
+
+    gen = m.compact_gen
+    for pod in held:
+        store.delete_pod(pod)
+    for i in range(2300):
+        store.delete_pod(store.pods[f"u{i}"])
+    assert m.compact_gen == gen + 1
+    # Rows were renumbered; the deletes after it left tombstones, which
+    # keep their key.  A pod's later row is the later entry.
+    by_key = {f"default/{pod.name}": pod for pod in want_of_row.values()}
+    want_of_row = {row: by_key[key] for row, key in enumerate(m.p_key)}
+    _readers_get_the_plain_encoding(m, want_of_row, rng)
+    # The table holds the specs with a live row and no other; the memo
+    # likewise; the record the kept objects carry has no row any more.
+    live = np.flatnonzero(m.p_alive[:len(m.p_uid)])
+    assert ({id(f) for f in m.s_feat}
+            >= {id(m.p_feat[r]) for r in live.tolist()})
+    assert len(m.s_feat) <= len({id(m.p_feat[r])
+                                 for r in range(len(m.p_uid))})
+    assert all(f.row >= 0 and m.s_feat[f.row] is f
+               for f in m._spec_memo.values())
+    feat = held[0]._mirror_feat
+    assert feat.row == -1 and all(f is not feat for f in m.s_feat)
+
+    encoded = store._between.specs_encoded
+    put(held[0], store.add_pod)             # the same object, re-added
+    assert held[0]._mirror_feat is feat and m.s_feat[feat.row] is feat
+    assert store._between.specs_encoded == encoded + 1   # a row was written
+    put(held[1], store.add_pod)             # the record has its row again
+    assert store._between.specs_encoded == encoded + 1
+    for i in range(4300, 4500):
+        put(_kind_pod(kind, i), store.add_pod)
+    assert store._between.spec_rows == len(m.s_feat)
+    _readers_get_the_plain_encoding(m, want_of_row, rng)
+
+
+def _own_spec_gang(g, size=8):
+    """A gang whose pods carry a required anti-affinity term on the
+    gang's own label, as ``affinity-10k``'s constrained gangs do: a spec
+    nobody else has."""
+    labels = {"gang": f"g{g}"}
+    term = AffinityTerm(dict(labels), HOSTNAME_KEY)
+    return [Pod(name=f"g{g}-{i}", uid=f"g{g}-{i}", labels=labels,
+                containers=[{"cpu": "1", "memory": "1Gi"}],
+                anti_affinity=[term],
+                annotations={GROUP_NAME_ANNOTATION: "pg"})
+            for i in range(size)]
+
+
+def test_the_spec_table_is_bounded_by_the_specs_with_a_live_row():
+    """Rounds of gangs that each bring a spec of their own and leave
+    with their pods (``affinity-10k``'s 2,500 a round): after every
+    compaction the table holds one row a spec that still has a live
+    pod, and round after round it reaches the same size."""
+    store = ClusterStore()
+    m = store.mirror
+    store.add_pod_group(PodGroup(name="pg", min_member=1))
+    residents = [Pod(name=f"r{i}", uid=f"r{i}",
+                     containers=[{"cpu": str(1 + i % 3)}],
+                     annotations={GROUP_NAME_ANNOTATION: "pg"})
+                 for i in range(900)]
+    for pod in residents:
+        store.add_pod(pod)
+    assert len(m.s_feat) == 3
+    peaks, g = [], 0
+    for _round in range(6):
+        batch = []
+        for _ in range(600):
+            batch.extend(_own_spec_gang(g))
+            g += 1
+        for pod in batch:
+            store.add_pod(pod)
+        peaks.append(len(m.s_feat))
+        gen = m.compact_gen
+        for pod in batch:
+            store.delete_pod(pod)
+            if m.compact_gen != gen:
+                gen = m.compact_gen
+                live = np.flatnonzero(m.p_alive[:len(m.p_uid)]).tolist()
+                assert len(m.s_feat) == len({id(m.p_feat[r]) for r in live})
+                assert store._between.spec_rows == len(m.s_feat)
+                assert len(m._spec_memo) <= len(m.s_feat)
+                np.testing.assert_array_equal(
+                    m.p_spec[live], [m.p_feat[r].row for r in live])
+        assert gen > 0
+    # The residents' 3 + a round's 600 + at most the round before's,
+    # whose tombstones wait for the next compaction; the later rounds
+    # reach no higher than the earlier ones.
+    assert max(peaks) <= 3 + 2 * 600
+    assert max(peaks[3:]) <= max(peaks[:3])
+    assert store._between.specs_encoded == 3 + 6 * 600
+
+
+def test_one_record_shared_by_rows_of_two_jobs_binds_as_the_object_path():
+    """Fast cycle against object session (``test_fastpath``'s
+    comparison) where one record is the spec of rows of different jobs,
+    inter-pod terms (``has_aff``) among them: the same binds."""
+    conf = """
+actions: "enqueue, allocate, backfill"
+tiers:
+- plugins:
+  - name: priority
+  - name: gang
+- plugins:
+  - name: drf
+  - name: predicates
+  - name: proportion
+  - name: nodeorder
+  - name: binpack
+"""
+
+    def run(fast):
+        store = ClusterStore()
+        for i in range(6):
+            store.add_node(Node(
+                name=f"n{i}", labels={"zone": f"z{i % 2}"},
+                allocatable={"cpu": "8", "memory": "16Gi"}))
+        term = AffinityTerm({"role": "db"}, HOSTNAME_KEY)
+        for j in range(3):
+            store.add_pod_group(PodGroup(name=f"j{j}", min_member=2))
+            for i in range(2):
+                store.add_pod(Pod(
+                    name=f"db{j}-{i}", uid=f"db{j}-{i}",
+                    creation_timestamp=float(1 + 10 * j + i),
+                    labels={"role": "db"}, anti_affinity=[term],
+                    containers=[{"cpu": "2", "memory": "1Gi"}],
+                    annotations={GROUP_NAME_ANNOTATION: f"j{j}"}))
+                store.add_pod(Pod(
+                    name=f"web{j}-{i}", uid=f"web{j}-{i}",
+                    creation_timestamp=float(5 + 10 * j + i),
+                    containers=[{"cpu": "3", "memory": "2Gi"}],
+                    node_selector={"zone": "z1"},
+                    annotations={GROUP_NAME_ANNOTATION: f"j{j}"}))
+        m = store.mirror
+        assert len(m.s_feat) == 2 and store._between.specs_encoded == 2
+        a, b = m.p_row["db0-0"], m.p_row["db2-1"]
+        assert m.p_feat[a] is m.p_feat[b] and m.p_job[a] != m.p_job[b]
+        assert m.p_has_ip[a] and m.p_spec[a] == m.p_spec[b]
+        os.environ["VOLCANO_TPU_FASTPATH"] = "1" if fast else "0"
+        try:
+            Scheduler(store, conf_str=conf).run_once()
+        finally:
+            os.environ.pop("VOLCANO_TPU_FASTPATH", None)
+        return dict(store.binder.binds)
+
+    slow, fast = run(False), run(True)
+    assert fast == slow and len(fast) == 12
+    assert len({fast[f"default/db{j}-{i}"]
+                for j in range(3) for i in range(2)}) == 6

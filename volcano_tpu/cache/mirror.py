@@ -17,7 +17,9 @@ Design:
   rows never go stale.  A spec is encoded once, by the first pod that
   brings it: the feature blob is shared by every pod of equal spec and
   cached on the ``Pod`` object, so the copy-on-write pod replacement done
-  by ``bind``/``evict`` reuses it.
+  by ``bind``/``evict`` reuses it.  Its ragged features are one row of
+  the **spec table**, written when the spec is encoded; a pod row holds
+  its spec's row (``p_spec``) and readers ask the columns by pod row.
 - **Dynamic per-pod state is three scalars** (status i8-equivalent, node
   row, job row) updated in place.
 - **Everything aggregate is derived per cycle by vectorized reductions**
@@ -78,7 +80,8 @@ class CSRColumn:
 
     Rows are appended once and never mutated; ``gather`` materializes the
     concatenated segments of a row subset plus the local row index of every
-    element (for vectorized scatters).
+    element (for vectorized scatters).  ``keep`` is a compaction's: the
+    column is cut to a subset of its rows, renumbered in that order.
     """
 
     __slots__ = ("idx", "val", "off", "_n", "_len", "has_val")
@@ -113,7 +116,7 @@ class CSRColumn:
 
     def gather(self, rows: np.ndarray):
         """-> (elem_row_local, indices[, values]) for the given rows."""
-        lens = self.lens(rows)
+        lens = CSRColumn.lens(self, rows)
         total = int(lens.sum())
         elem_row = np.repeat(np.arange(len(rows)), lens)
         if total == 0:
@@ -130,6 +133,47 @@ class CSRColumn:
         if self.val is not None:
             return elem_row, self.idx[pos], self.val[pos]
         return elem_row, self.idx[pos]
+
+    def keep(self, rows: np.ndarray) -> None:
+        """Cut the column to ``rows`` (its own), which become rows
+        0, 1, ... in that order."""
+        lens = CSRColumn.lens(self, rows)
+        got = CSRColumn.gather(self, rows)
+        self.idx = got[1]
+        if self.val is not None:
+            self.val = got[2]
+        self.off = np.concatenate(([0], np.cumsum(lens))).astype(np.int64)
+        self._n = len(rows)
+        self._len = len(self.idx)
+
+    def keep_ranges(self, lo: np.ndarray, hi: np.ndarray):
+        """Cut the column to its rows [lo[i], hi[i]), range after range;
+        -> (the rows kept, the ranges' new lo, new hi)."""
+        count = (hi - lo).astype(np.int64)
+        ends = np.cumsum(count)
+        starts = ends - count
+        kept = np.arange(int(count.sum())) + np.repeat(lo - starts, count)
+        self.keep(kept)
+        return kept, starts.astype(I), ends.astype(I)
+
+
+class SpecColumn(CSRColumn):
+    """A ragged column of the mirror's spec table, one row a distinct
+    pod spec, that readers ask by POD row: ``lens`` and ``gather`` look
+    the rows' specs up in ``p_spec`` first and answer as a column with
+    one row a pod would, element for element."""
+
+    __slots__ = ("mirror",)
+
+    def __init__(self, mirror: "StoreMirror", has_val: bool = False):
+        super().__init__(has_val)
+        self.mirror = mirror
+
+    def lens(self, rows: np.ndarray) -> np.ndarray:
+        return CSRColumn.lens(self, self.mirror.p_spec[rows])
+
+    def gather(self, rows: np.ndarray):
+        return CSRColumn.gather(self, self.mirror.p_spec[rows])
 
 
 class Interner:
@@ -163,7 +207,8 @@ def _grow(a: np.ndarray, n: int) -> np.ndarray:
 class _PodFeat:
     """A pod spec's encoded features: one record per distinct spec,
     shared by every row of that spec and cached on the ``Pod`` objects
-    (``StoreMirror._feat``).  Nobody writes to one after it is made."""
+    (``StoreMirror._feat``).  Nobody writes to one after it is made,
+    but for the mirror that moves its ``row``."""
 
     req: Tuple[list, list]  # (slot idxs, values)
     init_req: Tuple[list, list]
@@ -187,6 +232,9 @@ class _PodFeat:
     # ``init_resource_request()`` with (readers clone, api/info.py).
     req_res: Resource
     init_res: Resource
+    # The spec's row in the mirror's spec table (``_spec_add``); -1 once
+    # a compaction found no live pod of the spec and dropped the row.
+    row: int = field(default=-1, compare=False)
 
 
 def _same_frame(was: Pod, pod: Pod) -> bool:
@@ -330,24 +378,36 @@ class StoreMirror:
         # 40k pod objects per session.
         self.p_critical = np.zeros(cap, bool)
         self.p_prof = np.zeros(cap, I)  # task profile id (self.profiles)
-        self.c_req = CSRColumn(has_val=True)
-        self.c_init_req = CSRColumn(has_val=True)
-        self.c_sel = CSRColumn()
-        self.c_tol = CSRColumn()
-        self.c_ports = CSRColumn()
-        # Node-affinity alternatives: rows in a side table, pods reference a
-        # contiguous [aff_lo, aff_hi) range of it.
+        # The row's spec in the spec table below: all an add writes of
+        # its spec's ragged features.
+        self.p_spec = np.zeros(cap, I)
+        self.n_dead = 0
+
+        # ------------------------------------------------------ spec table
+        # One row a distinct spec (``_PodFeat.row``), written by
+        # ``_spec_add`` when the spec is encoded and by no add after
+        # it; a compaction keeps the specs with a live pod.  The seven
+        # ``SpecColumn``s answer by pod row.  Tolerations are the
+        # record's own (``p_feat[row].tol``, matched lazily per cycle:
+        # the taint dictionary may grow after the pod was added).
+        self.s_feat: List[_PodFeat] = []
+        self.c_req = SpecColumn(self, has_val=True)
+        self.c_init_req = SpecColumn(self, has_val=True)
+        self.c_sel = SpecColumn(self)
+        self.c_ports = SpecColumn(self)
+        self.c_ip_aff = SpecColumn(self)
+        self.c_ip_anti = SpecColumn(self)
+        self.c_ip_soft = SpecColumn(self, has_val=True)
+        # Node-affinity alternatives and preferred terms: rows in two
+        # side tables, a spec references a contiguous [lo, hi) range of
+        # each (``aff_ranges`` / ``pref_ranges`` answer by pod row).
         self.c_aff_alt = CSRColumn()  # one row per alternative
-        self.p_aff_lo = np.zeros(cap, I)
-        self.p_aff_hi = np.zeros(cap, I)
         self.c_pref = CSRColumn()  # one row per preferred term
         self.pref_w: List[float] = []
-        self.p_pref_lo = np.zeros(cap, I)
-        self.p_pref_hi = np.zeros(cap, I)
-        self.c_ip_aff = CSRColumn()
-        self.c_ip_anti = CSRColumn()
-        self.c_ip_soft = CSRColumn(has_val=True)
-        self.n_dead = 0
+        self.s_aff_lo = np.zeros(64, I)
+        self.s_aff_hi = np.zeros(64, I)
+        self.s_pref_lo = np.zeros(64, I)
+        self.s_pref_hi = np.zeros(64, I)
 
         # ------------------------------------------------------ node table
         self.n_name: List[Optional[str]] = []
@@ -412,9 +472,6 @@ class StoreMirror:
         # Compaction-carried so codes stay stable for the store's life.
         self._fabric_vals: Dict[tuple, int] = {}
         self._fabric_blocks: Dict[tuple, int] = {}
-        # Toleration specs per pod row (matched lazily per cycle, because
-        # the taint dictionary may grow after the pod was added).
-        self._pod_tols: List[list] = []
         # Pods bound to nodes the mirror has not seen yet: name -> uids.
         self._orphans: Dict[str, List[str]] = {}
         # Epoch bumps force full fallback-path consumers to resync if needed.
@@ -505,6 +562,12 @@ class StoreMirror:
 
     # ================================================================ pods
 
+    # The per-row numpy columns, all of one length: an add tests the
+    # first and grows them together, a compaction gathers each.
+    _ROW_COLUMNS = ("p_status", "p_node", "p_node_name", "p_job", "p_prio",
+                    "p_create", "p_alive", "p_be", "p_has_ip", "p_has_tol",
+                    "p_critical", "p_prof", "p_spec")
+
     # The most specs ``_feat`` remembers; one more and it forgets them
     # all (a gang with an inter-pod term of its own is a spec of its own,
     # 2,500 a round at ``affinity-10k``, and is never asked for again).
@@ -514,10 +577,15 @@ class StoreMirror:
     def _feat(self, pod: Pod) -> _PodFeat:
         """``pod``'s encoded spec: the record its object carries (a
         copy-on-write copy's), else the one an equal spec was given
-        before, else a new one, which is the only case that parses,
-        interns or counts (``BetweenAccount.specs_encoded``)."""
+        before, else a new one, which is the only case that parses or
+        interns.  The record that is handed out has a row in the spec
+        table: a new one's is written with it, and one that outlived
+        its row (a compaction found no live pod of the spec, and the
+        object comes back) is given the next."""
         feat = getattr(pod, "_mirror_feat", None)
         if feat is not None and feat.profiles is self.profiles:
+            if feat.row < 0:
+                self._spec_add(feat)
             return feat
         key = _spec_key(pod)
         feat = self._spec_memo.get(key)
@@ -539,8 +607,6 @@ class StoreMirror:
 
     # holds: _lock
     def _encode(self, pod: Pod) -> _PodFeat:
-        if self.between is not None:
-            self.between.specs_encoded += 1
         req = pod.resource_request()
         init_req = pod.init_resource_request()
 
@@ -588,7 +654,7 @@ class StoreMirror:
 
         req_pair = res_csr(req)
         init_pair = res_csr(init_req)
-        return _PodFeat(
+        feat = _PodFeat(
             req=req_pair,
             init_req=init_pair,
             sel=sel,
@@ -624,6 +690,52 @@ class StoreMirror:
             req_res=req,
             init_res=init_req,
         )
+        self._spec_add(feat)
+        return feat
+
+    # holds: _lock
+    def _spec_add(self, feat: _PodFeat) -> None:
+        """Write ``feat``'s ragged features as the spec table's next
+        row: the one place that appends to the columns readers gather
+        from, once a spec and counted (``BetweenAccount.specs_encoded``;
+        ``spec_rows`` is the table's size)."""
+        row = feat.row = len(self.s_feat)
+        self.s_feat.append(feat)
+        self.c_req.append(*feat.req)
+        self.c_init_req.append(*feat.init_req)
+        self.c_sel.append(feat.sel)
+        self.c_ports.append(feat.ports)
+        self.c_ip_aff.append(feat.ip_req_aff)
+        self.c_ip_anti.append(feat.ip_req_anti)
+        self.c_ip_soft.append([e for e, _ in feat.ip_soft],
+                              [w for _, w in feat.ip_soft])
+        if row >= len(self.s_aff_lo):
+            for name in ("s_aff_lo", "s_aff_hi", "s_pref_lo", "s_pref_hi"):
+                setattr(self, name, _grow(getattr(self, name), row + 1))
+        self.s_aff_lo[row] = self.c_aff_alt._n
+        for alt in feat.aff_alts:
+            self.c_aff_alt.append(alt)
+        self.s_aff_hi[row] = self.c_aff_alt._n
+        self.s_pref_lo[row] = self.c_pref._n
+        for sel_idx, w in feat.pref:
+            self.c_pref.append(sel_idx)
+            self.pref_w.append(w)
+        self.s_pref_hi[row] = self.c_pref._n
+        if self.between is not None:
+            self.between.specs_encoded += 1
+            self.between.spec_rows = row + 1
+
+    def aff_ranges(self, rows: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """[lo, hi) into ``c_aff_alt`` of each pod row's required
+        node-affinity alternatives."""
+        spec = self.p_spec[rows]
+        return self.s_aff_lo[spec], self.s_aff_hi[spec]
+
+    def pref_ranges(self, rows: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """[lo, hi) into ``c_pref`` / ``pref_w`` of each pod row's
+        preferred node-affinity terms."""
+        spec = self.p_spec[rows]
+        return self.s_pref_lo[spec], self.s_pref_hi[spec]
 
     # holds: _lock
     def _intern_queried(self, kv: Tuple[str, str]) -> int:
@@ -800,23 +912,10 @@ class StoreMirror:
         self.p_pod.append(pod)
         self.p_feat.append(feat)
         self.p_row[pod.uid] = row
-        n = row + 1
-        self.p_status = _grow(self.p_status, n)
-        self.p_node = _grow(self.p_node, n)
-        self.p_job = _grow(self.p_job, n)
-        self.p_prio = _grow(self.p_prio, n)
-        self.p_create = _grow(self.p_create, n)
-        self.p_alive = _grow(self.p_alive, n)
-        self.p_be = _grow(self.p_be, n)
-        self.p_has_ip = _grow(self.p_has_ip, n)
-        self.p_has_tol = _grow(self.p_has_tol, n)
-        self.p_critical = _grow(self.p_critical, n)
-        self.p_prof = _grow(self.p_prof, n)
-        self.p_aff_lo = _grow(self.p_aff_lo, n)
-        self.p_aff_hi = _grow(self.p_aff_hi, n)
-        self.p_pref_lo = _grow(self.p_pref_lo, n)
-        self.p_pref_hi = _grow(self.p_pref_hi, n)
-        self.p_node_name = _grow(self.p_node_name, n)
+        if row >= len(self.p_status):
+            # The row columns are of one length and grow together.
+            for name in self._ROW_COLUMNS:
+                setattr(self, name, _grow(getattr(self, name), row + 1))
 
         if self.audit is not None:
             if st is not None:
@@ -851,31 +950,8 @@ class StoreMirror:
             or pod.namespace == SYSTEM_NAMESPACE
         )
         self.p_prof[row] = feat.prof
-
-        self.c_req.append(*feat.req)
-        self.c_init_req.append(*feat.init_req)
-        self.c_sel.append(feat.sel)
-        # Tolerations are matched lazily per cycle (taint dict may grow);
-        # store toleration list on the side.
-        self._pod_tols.append(feat.tol)
-        self.c_ports.append(feat.ports)
-        self.p_aff_lo[row] = self.c_aff_alt._n
-        for alt in feat.aff_alts:
-            self.c_aff_alt.append(alt)
-        self.p_aff_hi[row] = self.c_aff_alt._n
-        self.p_pref_lo[row] = self.c_pref._n
-        for sel_idx, w in feat.pref:
-            self.c_pref.append(sel_idx)
-            self.pref_w.append(w)
-        self.p_pref_hi[row] = self.c_pref._n
-        self.c_ip_aff.append(feat.ip_req_aff)
-        self.c_ip_anti.append(feat.ip_req_anti)
-        if feat.ip_soft:
-            si = [e for e, _ in feat.ip_soft]
-            sv = [w for _, w in feat.ip_soft]
-            self.c_ip_soft.append(si, sv)
-        else:
-            self.c_ip_soft.append([], [])
+        # The spec's ragged features are its row of the spec table.
+        self.p_spec[row] = feat.row
         # Inverted index + term membership via candidate lookup.
         for kv in pod.labels.items():
             self._pods_by_pair.setdefault(kv, []).append(row)
@@ -1335,7 +1411,7 @@ class StoreMirror:
         fresh.__init__()
         # Dictionaries and node/job tables carry over untouched.
         for attr in ("scalar_slots", "labels", "taints", "ports", "terms",
-                     "term_info", "topo_keys", "profiles", "_spec_memo",
+                     "term_info", "topo_keys", "profiles",
                      "_terms_by_pair", "_terms_by_job", "_terms_all",
                      "n_name", "n_row", "n_ready",
                      "n_alive", "n_maxtasks", "c_n_alloc", "c_n_labels",
@@ -1356,54 +1432,36 @@ class StoreMirror:
             fresh._node_csr_row = old._node_csr_row
         remap = np.full(total, -1, I)
         remap[live] = np.arange(len(live), dtype=I)
-        for r in live:
-            uid = old.p_uid[r]
-            fresh.p_uid.append(uid)
-            fresh.p_key.append(old.p_key[r])
-            fresh.p_pod.append(old.p_pod[r])
-            fresh.p_feat.append(old.p_feat[r])
-            fresh.p_row[uid] = len(fresh.p_uid) - 1
-        n = len(live)
-        for name in ("p_status", "p_node", "p_node_name", "p_job",
-                     "p_prio", "p_create", "p_alive", "p_be", "p_has_ip",
-                     "p_has_tol", "p_critical", "p_prof"):
-            arr = getattr(old, name)[:total][live]
-            setattr(fresh, name, arr.copy())
-        # CSR columns: re-append per live row (vectorized gather then bulk).
-        for col_name in ("c_req", "c_init_req", "c_sel", "c_ports",
-                         "c_ip_aff", "c_ip_anti", "c_ip_soft"):
-            oldc: CSRColumn = getattr(old, col_name)
-            newc = CSRColumn(has_val=oldc.has_val)
-            lens = oldc.lens(live)
-            g = oldc.gather(live)
-            newc.idx = g[1].astype(I).copy()
-            if oldc.has_val:
-                newc.val = g[2].astype(F).copy()
-            newc.off = np.concatenate(
-                ([0], np.cumsum(lens))
-            ).astype(np.int64)
-            newc._n = n
-            newc._len = int(lens.sum())
-            setattr(fresh, col_name, newc)
-        # Ragged side tables (aff alternatives / pref terms): rebuild.
-        fresh.p_aff_lo = np.zeros(max(n, 1), I)
-        fresh.p_aff_hi = np.zeros(max(n, 1), I)
-        fresh.p_pref_lo = np.zeros(max(n, 1), I)
-        fresh.p_pref_hi = np.zeros(max(n, 1), I)
-        fresh._pod_tols = []
-        for new_r, r in enumerate(live):
-            fresh.p_aff_lo[new_r] = fresh.c_aff_alt._n
-            for alt_row in range(old.p_aff_lo[r], old.p_aff_hi[r]):
-                _er, vals = old.c_aff_alt.gather(np.array([alt_row]))
-                fresh.c_aff_alt.append(vals)
-            fresh.p_aff_hi[new_r] = fresh.c_aff_alt._n
-            fresh.p_pref_lo[new_r] = fresh.c_pref._n
-            for p_row in range(old.p_pref_lo[r], old.p_pref_hi[r]):
-                _er, vals = old.c_pref.gather(np.array([p_row]))
-                fresh.c_pref.append(vals)
-                fresh.pref_w.append(old.pref_w[p_row])
-            fresh.p_pref_hi[new_r] = fresh.c_pref._n
-            fresh._pod_tols.append(old._pod_tols[r])
+        rows = live.tolist()
+        for name in ("p_uid", "p_key", "p_pod", "p_feat"):
+            was = getattr(old, name)
+            setattr(fresh, name, [was[r] for r in rows])
+        fresh.p_row = dict(zip(fresh.p_uid, range(len(rows))))
+        for name in self._ROW_COLUMNS:
+            setattr(fresh, name, getattr(old, name)[:total][live])
+        # The spec table keeps the specs with a live row, in the order
+        # they stood (a gang that brought a spec of its own and left
+        # takes it along); the columns' objects carry over, cut.
+        used = np.unique(fresh.p_spec)
+        to_new = np.full(len(old.s_feat), -1, I)
+        to_new[used] = np.arange(len(used), dtype=I)
+        fresh.p_spec = to_new[fresh.p_spec]
+        for name in ("c_req", "c_init_req", "c_sel", "c_ports",
+                     "c_ip_aff", "c_ip_anti", "c_ip_soft"):
+            col: SpecColumn = getattr(old, name)
+            col.keep(used)
+            setattr(fresh, name, col)
+        fresh.c_aff_alt, fresh.c_pref = old.c_aff_alt, old.c_pref
+        _kept, fresh.s_aff_lo, fresh.s_aff_hi = old.c_aff_alt.keep_ranges(
+            old.s_aff_lo[used], old.s_aff_hi[used])
+        kept, fresh.s_pref_lo, fresh.s_pref_hi = old.c_pref.keep_ranges(
+            old.s_pref_lo[used], old.s_pref_hi[used])
+        fresh.pref_w = [old.pref_w[r] for r in kept.tolist()]
+        for feat, row in zip(old.s_feat, to_new.tolist()):
+            feat.row = row
+        fresh.s_feat = [old.s_feat[r] for r in used.tolist()]
+        fresh._spec_memo = {key: feat for key, feat in old._spec_memo.items()
+                            if feat.row >= 0}
         fresh.term_members = [
             [int(remap[m]) for m in members if remap[m] >= 0]
             for members in old.term_members
@@ -1436,6 +1494,8 @@ class StoreMirror:
         self.audit = audit
         self.journey = journey
         self.between = between
+        if between is not None:
+            between.spec_rows = len(self.s_feat)
         self.mutation_seq = seq + 1
         self.compact_gen = gen + 1
         self._node_dirty_rows = dirty

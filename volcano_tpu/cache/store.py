@@ -808,10 +808,13 @@ class ClusterStore:
 
         The pod's spec is encoded by the first pod that brings it and
         by no other (``StoreMirror._feat``; the account's
-        ``specs_encoded`` counts those): as PR 46 left the tree an add
-        of a spec the mirror has met executes 2,442 bytecodes and parses
-        no quantity, the first add of a spec 2,908, where every add
-        executed 2,746 before."""
+        ``specs_encoded`` counts those), and its ragged features are
+        written then, as the spec's row of the mirror's spec table
+        (``_spec_add``; ``spec_rows`` is the table's size): as PR 47
+        left the tree an add of a spec the mirror has met executes
+        1,733 bytecodes, parses no quantity and appends to no ragged
+        column, the first add of a spec 2,718; PR 46's tree read 2,443
+        and 2,899 by the same count, and every add 2,746 before it."""
         bt = self._between
         st = (None if bt.counts[POD_ADD] % SAMPLE_STRIDE
               else bt.sample(POD_ADD))

@@ -2517,10 +2517,8 @@ class FastCycle:
         ok &= ~has_ip
         r_churn = np.zeros(len(task_rows), bool)
         if node_churn:
-            sensitive = (
-                m.p_has_tol[task_rows]
-                | (m.p_aff_lo[task_rows] < m.p_aff_hi[task_rows])
-            )
+            aff_lo, aff_hi = m.aff_ranges(task_rows)
+            sensitive = m.p_has_tol[task_rows] | (aff_lo < aff_hi)
             er, _li = m.c_sel.gather(task_rows)
             has_sel = np.zeros(len(task_rows), bool)
             has_sel[er] = True
@@ -3281,8 +3279,7 @@ class FastCycle:
             port_bits[:P] = _pack_bits(P, PW, er, pi)
 
         # Required node-affinity alternatives.
-        aff_lo = m.p_aff_lo[rows]
-        aff_hi = m.p_aff_hi[rows]
+        aff_lo, aff_hi = m.aff_ranges(rows)
         n_alts = (aff_hi - aff_lo).astype(np.int64)
         A = _pow2(max(1, int(n_alts.max()) if P else 1), 1)
         aff_bits = np.zeros((P, A, LW), np.uint32)
@@ -3301,8 +3298,7 @@ class FastCycle:
             aff_bits[task_of_alt, slot_of_alt] = flat
 
         # Preferred node affinity (normalized to [0,10] per task).
-        pref_lo = m.p_pref_lo[rows]
-        pref_hi = m.p_pref_hi[rows]
+        pref_lo, pref_hi = m.pref_ranges(rows)
         n_pref = (pref_hi - pref_lo).astype(np.int64)
         AP = _pow2(max(1, int(n_pref.max()) if P else 1), 1)
         pref_bits = np.zeros((P, AP, LW), np.uint32)

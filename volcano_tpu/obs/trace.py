@@ -487,14 +487,15 @@ class BetweenAccount:
     """What a store did between two cycles, by itself: its event
     handlers' calls by kind (exact), their seconds by kind and phase
     (from one timed call in ``SAMPLE_STRIDE``), the collector's passes
-    (exact, from ``_Collector``), the pod table's compactions (exact)
-    and the pod specs the mirror encoded (exact).  Lifetime counters;
+    (exact, from ``_Collector``), the pod table's compactions (exact),
+    the pod specs the mirror encoded (exact) and the rows of its spec
+    table (a level, not a count).  Lifetime counters;
     ``snapshot()`` copies them at a cycle's start and ``block()`` turns
     the difference to the previous sealed record's snapshot into
     ``CycleRecord.between``.
 
-    Who writes what: ``counts`` the handlers and ``specs_encoded`` the
-    mirror, under the STORE's lock;
+    Who writes what: ``counts`` the handlers, ``specs_encoded`` and
+    ``spec_rows`` the mirror, under the STORE's lock;
     ``_samples`` / ``_sums`` / ``_open`` a timed call, under ``_lock``;
     the collector's numbers the hook, of which one runs at a time in a
     process.  The hook takes no lock at all: a pass interrupts whatever
@@ -523,6 +524,10 @@ class BetweenAccount:
         # the store's lock: an add or update whose spec it had met is
         # not one.
         self.specs_encoded = 0
+        # The rows of the mirror's spec table as it stands: up by one
+        # with every spec counted above, down at a compaction to the
+        # specs that still have a live pod.
+        self.spec_rows = 0
         self._cycles_open = 0
         self._lock = threading.Lock()
         self._base = self.snapshot(0)
@@ -609,6 +614,7 @@ class BetweenAccount:
             "gc": [list(g) for g in self._gc],
             "compact": list(self._compact),
             "specs_encoded": self.specs_encoded,
+            "spec_rows": self.spec_rows,
         }
 
     def block(self, snap: dict, spans: list, seal_ns: int) -> dict:
@@ -662,6 +668,7 @@ class BetweenAccount:
             held_s += _s((whole - phases.get("lock_wait", 0))
                          * SAMPLE_STRIDE)
         out["specs_encoded"] = snap["specs_encoded"] - base["specs_encoded"]
+        out["spec_rows"] = snap["spec_rows"]
         if not timing:
             return out
         out["lock_held_s"] = round(held_s, 9)
