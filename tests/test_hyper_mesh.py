@@ -4,9 +4,9 @@ hostname domains outnumber everything else; 2,048 pods a round in gangs of
 8; the 5 / 5 / 10 mix) goes through ``benchmark/run.py``'s own ``run`` with
 the conf's ``mesh: 4`` and without, on the virtual CPU devices
 ``conftest.py`` forces: bind for bind the same node for every pod of every
-round, under both sides of the domain one-hot's gate and under a count
-budget the global reckoning would chunk.  Then the conf argument itself,
-the shape buckets on the mesh, and what the record says of a mesh solve."""
+round, and under a count budget the global reckoning would chunk.  Then
+the conf argument itself, the shape buckets on the mesh, and what the
+record says of a mesh solve."""
 
 import json
 import os
@@ -61,7 +61,7 @@ class Toy:
     object, each round's binds, the violations of the two affinity
     guarantees on them, and the timed store's flight records."""
 
-    def __init__(self, tmp, mesh, trace=False, env=None, dom_mm_mb=None):
+    def __init__(self, tmp, mesh, trace=False, env=None):
         tmp.mkdir()
         home = tmp / "benchmark"
         (home / "configs").mkdir(parents=True)
@@ -91,20 +91,11 @@ class Toy:
             mp.delenv("VOLCANO_TPU_MESH", raising=False)
             for k, v in (env or {}).items():
                 mp.setenv(k, v)
-            if dom_mm_mb is not None:
-                # the gate is read when ``_solve_wave`` is traced, so a
-                # program traced under another limit must not be found
-                mp.setattr(wave, "DOM_MM_MAX_MB", dom_mm_mb)
-                jax.clear_caches()
             mp.setattr(bench_run, "OUT_DIR", tmp / "out")
             mp.setattr(bench_run, "set_up", keep_driver)
             mp.setattr(loop, "run_window", _three_rounds)
             cell = cell_mod.load_cell("hyper-toy.burst", tmp / "BENCHMARK.json")
-            try:
-                self.result = bench_run.run(cell, SEED, 1.0, trace)
-            finally:
-                if dom_mm_mb is not None:
-                    jax.clear_caches()
+            self.result = bench_run.run(cell, SEED, 1.0, trace)
         driver = seen["driver"]
         self.records = driver.store.flight.recent()
         index = {n: i for i, n in enumerate(generate.node_names(cfg))}
@@ -172,14 +163,13 @@ def test_the_toy_binds_every_pod_to_the_same_node_with_mesh_4_as_without(
 
 def test_the_record_says_what_a_mesh_solve_held_and_placed(one_device, four_devices):
     """``solve`` gains ``mesh_shards``, a chip's share of the affinity
-    tensors, the side of the one-hot's gate and the bytes placed against
-    those found resident; ``device:shard`` stands inside ``device`` with its
-    counts.  None of it on one device."""
+    tensors and the bytes placed against those found resident;
+    ``device:shard`` stands inside ``device`` with its counts.  None of it
+    on one device."""
     for s in four_devices.solves():
         assert s["mesh_shards"] == 4 and s["aff_chunks"] == 1
-        assert s["aff_domains"] == 16 + NODES and s["aff_dom_mm"] == 1
-        whole = 2 * (s["aff_terms_padded"] + 1) * s["aff_domains"] * 4 \
-            + NODES * s["aff_domains"] * 4
+        assert s["aff_domains"] == 16 + NODES
+        whole = 2 * (s["aff_terms_padded"] + 1) * s["aff_domains"] * 4
         assert s["aff_device_bytes"] == whole
         assert s["aff_device_bytes_chip"] == -(-whole // 4)
         assert s["mesh_put_bytes"] > 0 and s["mesh_resident_bytes"] > 0
@@ -192,7 +182,7 @@ def test_the_record_says_what_a_mesh_solve_held_and_placed(one_device, four_devi
     assert set(shard.args) == {"arrays", "bytes", "cache_hits"}
     assert shard.args["bytes"] == cycle.solve["mesh_put_bytes"]
     assert by_name["device:dispatch"].args == {"mesh_shards": 4}
-    new = {"mesh_shards", "aff_device_bytes_chip", "aff_dom_mm",
+    new = {"mesh_shards", "aff_device_bytes_chip",
            "mesh_put_bytes", "mesh_resident_bytes"}
     for r in one_device.records:
         assert not new & set(r.solve or {})
@@ -216,20 +206,6 @@ def test_the_record_counts_the_count_plane_recomputes(one_device, four_devices):
         assert all((s["aff_count_reads"] > 0) == (s["aff_rows"] > 0) for s in probes)
     assert [s["aff_count_reads"] for s in one_device.solves()] \
         == [s["aff_count_reads"] for s in four_devices.solves()]
-
-
-@needs_4
-@pytest.mark.parametrize("mb, side", [(0, 0), (10**6, 1)])
-def test_both_sides_of_the_one_hots_gate_bind_alike(one_device, tmp_path, mb, side):
-    """The gather side (the gate shut) and the matmul side (wide open) of
-    the sharded ``has_aff`` program: the one-device run's binds."""
-    t = Toy(tmp_path / "gate", mesh=True, dom_mm_mb=mb)
-    _sound(t)
-    assert {s["aff_dom_mm"] for s in t.solves()} == {side}
-    _same_binds(one_device, t)
-    # ``count_plane``'s side and the one-hot's recompute as often
-    assert [s["aff_count_reads"] for s in t.solves()] \
-        == [s["aff_count_reads"] for s in one_device.solves()[-len(t.solves()):]]
 
 
 @needs_4

@@ -1,24 +1,22 @@
 """Exactness guards for the wave solver's domain machinery.
 
-Round-4 rewrote the per-attempt count lookup as an MXU matmul against a
-domain-membership one-hot and added wave-disjoint term detection that
-skips the global count write-back.  Both are claimed EXACT; these tests
-pin that claim:
+The ``has_aff`` solve reads its count window by ``count_plane`` (K row
+gathers from the window laid domain-major) and detects wave-disjoint
+terms to skip the global count write-back.  Both are claimed EXACT;
+these tests pin that claim:
 
-- ``count_plane`` (the other side of the one-hot's gate: K row gathers
-  from the window laid domain-major) gives the integers of the
-  element gather it replaced, kept here as the reference;
-- matmul path vs gather path produce identical placements
-  (``DOM_MM_MAX_MB`` forced to 0 switches to the gather side), with
-  the shortlists on and off, and the two overflow parities hold on
-  both sides, so all three callers of ``count_plane`` are pinned
-  against the one-hot and the object path;
+- ``count_plane`` gives the integers of the element gather it replaced,
+  kept here as the reference;
+- a solve with terms reaches ``count_plane`` from all three of its
+  callers, with the shortlists on and off, and places what the same
+  solve places with the element gather in its stead; the two overflow
+  parities hold the fast path to the object path;
 - multi-wave solves with terms SHARED across waves (disjoint detection
   off) still agree with the single-wave solve;
 - the sub-round filter's tightened gate changes nothing observable.
 
 jax caches compiled programs per (shape, static args), so each variant
-clears the jit caches after monkeypatching the module constants.
+clears the jit caches after monkeypatching a module attribute.
 """
 
 import jax
@@ -58,14 +56,6 @@ def _element_gather(cnt, node_dom, term_key):
     return jnp.where(nd_t >= 0, cv, 0)
 
 
-def _gate(monkeypatch, side):
-    """Trace ``_solve_wave`` on one side of ``dom_mm_on``'s gate; the
-    caller clears the jit caches again when it is done."""
-    if side == "rows":
-        monkeypatch.setattr(wave_mod, "DOM_MM_MAX_MB", 0)
-    jax.clear_caches()
-
-
 # K keys, R rows, E terms, D domains; what of the input is special.
 PLANE_CASES = {
     "one-key": dict(K=1, R=40, E=16, D=256),
@@ -78,6 +68,10 @@ PLANE_CASES = {
     "domains-not-a-multiple-of-128": dict(K=2, R=32, E=16, D=131),
     "rows-a-permutation-of-the-nodes": dict(K=2, R=64, E=16, D=96, perm=True),
     "hyper-50k-chip-shape-narrowed": dict(K=2, R=512, E=128, D=1579),
+    # affinity-10k's window at an eighth of its 10,016 domains
+    "affinity-10k-shape-narrowed": dict(K=2, R=512, E=128, D=1252),
+    # the one-pod probe cycle's window
+    "one-row": dict(K=2, R=1, E=16, D=128),
 }
 
 
@@ -85,7 +79,7 @@ PLANE_CASES = {
 def test_count_plane_reads_what_the_element_gather_read(case):
     """``count_plane`` against the element gather, bit for bit, on counts
     that are NOT zero outside a term's own key's domains (the row form
-    does not lean on that, the one-hot does), jitted and not."""
+    does not lean on that), jitted and not."""
     c = PLANE_CASES[case]
     K, R, E, D = c["K"], c["R"], c["E"], c["D"]
     rng = np.random.default_rng(sum(map(ord, case)))
@@ -117,15 +111,15 @@ def test_count_plane_reads_what_the_element_gather_read(case):
 
 
 @pytest.mark.parametrize("twophase", ["1", "0"])
-def test_dom_matmul_matches_gather_path(monkeypatch, twophase):
-    """cnt @ dom_oh must equal the row gathers bit-for-bit in every
-    consumed form (feasibility classification + soft score →
-    identical placements): through the shortlist attempt and the
-    conflict filter, and with the shortlists off through the full-N
-    planes that the fallback rescore reads."""
+def test_a_solve_with_terms_reads_its_counts_by_count_plane(
+        monkeypatch, twophase):
+    """A solve with terms goes through ``count_plane`` from all three of
+    its callers (the shortlist attempt, the conflict filter and, with
+    the shortlists off, the full-N planes that the fallback rescore
+    reads too) and places what the same solve places with the element
+    gather in ``count_plane``'s stead: every consumed form of the plane
+    (feasibility classification + soft score) is the same."""
     monkeypatch.setenv("VOLCANO_TPU_TWOPHASE", twophase)
-    base = solve(affinity_store(seed=7))
-    assert any(v for v in base.values())
     # count_plane is looked up when _solve_wave is traced: the rows it
     # was asked for say which of its callers the trace went through.
     asked = []
@@ -136,14 +130,15 @@ def test_dom_matmul_matches_gather_path(monkeypatch, twophase):
         return plane(cnt, node_dom, term_key)
 
     monkeypatch.setattr(wave_mod, "count_plane", spy)
-    _gate(monkeypatch, "one-hot")
-    assert solve(affinity_store(seed=7)) == base and not asked
-    _gate(monkeypatch, "rows")
+    jax.clear_caches()
     try:
-        gather = solve(affinity_store(seed=7))
+        rows = solve(affinity_store(seed=7))
+        monkeypatch.setattr(wave_mod, "count_plane", _element_gather)
+        jax.clear_caches()
+        elements = solve(affinity_store(seed=7))
     finally:
         jax.clear_caches()
-    assert base == gather
+    assert any(v for v in rows.values()) and rows == elements
     # every node's plane (the attempt's, and the fallback rescore's where
     # there are shortlists) and the conflict filter's W chosen rows
     N, W = min(asked), max(asked)
@@ -162,6 +157,37 @@ def test_the_record_counts_count_plane_recomputes(terms):
     assert (solve_counts["aff_terms"] > 0) == terms
     assert (solve_counts["aff_count_reads"] > 0) == terms
     assert solve_counts["aff_count_reads"] >= 0
+
+
+def test_the_record_reckons_the_count_pair_alone():
+    """``solve.aff_device_bytes`` on one device: the two ``[Ep + 1, D]``
+    int32 count tensors and nothing else, with no chip's share beside."""
+    store = affinity_store(seed=7)
+    Scheduler(store).run_once()
+    s = store.flight.recent()[-1].solve
+    assert s["aff_terms_padded"] >= s["aff_terms"] > 0 and s["aff_domains"] > 0
+    assert s["aff_device_bytes"] \
+        == 2 * (s["aff_terms_padded"] + 1) * s["aff_domains"] * 4
+    assert "aff_device_bytes_chip" not in s
+
+
+def test_no_name_selects_another_form_of_the_count_read():
+    """The domain one-hot and its size gate went with PR 48; neither the
+    kernel nor the cycle's account of it names them again, so the fork
+    cannot come back as a knob unnoticed."""
+    import ast
+    from pathlib import Path
+
+    gone = {"dom_mm_on", "DOM_MM_MAX_MB"}
+    pkg = Path(wave_mod.__file__).parents[1]
+    for rel, stays in (("ops/wave.py", "count_plane"),
+                       ("fastpath.py", "_count_affinity")):
+        names = set()
+        for node in ast.walk(ast.parse((pkg / rel).read_text())):
+            for field in ("id", "attr", "name", "asname"):
+                if isinstance(getattr(node, field, None), str):
+                    names.add(getattr(node, field))
+        assert stays in names and not gone & names, (rel, gone & names)
 
 
 def test_multiwave_shared_terms_match_single_wave(monkeypatch):
@@ -235,12 +261,10 @@ def test_forced_nondisjoint_write_back_roundtrip(monkeypatch):
     assert base == forced
 
 
-@pytest.mark.parametrize("side", ["one-hot", "rows"])
-def test_conflict_compaction_overflow_parity(monkeypatch, side):
+def test_conflict_compaction_overflow_parity(monkeypatch):
     """More than GCAP (256) anti-affinity givers in one wave force the
     full-scatter/full-gather fallback branches: placements must match
-    the object path exactly either way, on both sides of the one-hot's
-    gate."""
+    the object path exactly either way."""
     from volcano_tpu.api import AffinityTerm, Node, Pod, PodGroup
     from volcano_tpu.cache import ClusterStore
 
@@ -275,15 +299,11 @@ def test_conflict_compaction_overflow_parity(monkeypatch, side):
         return s
 
     res = {}
-    _gate(monkeypatch, side)
-    try:
-        for mode, env in (("fast", "1"), ("object", "0")):
-            monkeypatch.setenv("VOLCANO_TPU_FASTPATH", env)
-            store = build()
-            Scheduler(store).run_once()
-            res[mode] = placements(store)
-    finally:
-        jax.clear_caches()
+    for mode, env in (("fast", "1"), ("object", "0")):
+        monkeypatch.setenv("VOLCANO_TPU_FASTPATH", env)
+        store = build()
+        Scheduler(store).run_once()
+        res[mode] = placements(store)
     # Anti-affinity against a shared label: at most one pod per node,
     # 40 nodes -> exactly 40 placed, and the full PLACEMENTS agree.
     assert res["fast"] == res["object"]
@@ -292,13 +312,11 @@ def test_conflict_compaction_overflow_parity(monkeypatch, side):
     assert len(set(placed)) == len(placed)  # one per node
 
 
-@pytest.mark.parametrize("side", ["one-hot", "rows"])
-def test_count_update_overflow_parity(monkeypatch, side):
+def test_count_update_overflow_parity(monkeypatch):
     """More than GCAP (256) ACCEPTED matching tasks in one sub-round
     force the count-update full-scatter fallback (soft spread terms:
     every pod matches its job's term and places immediately on roomy
-    nodes).  Placements and scores must match the object path, on both
-    sides of the one-hot's gate."""
+    nodes).  Placements and scores must match the object path."""
     from volcano_tpu.api import GROUP_NAME_ANNOTATION, Node, Pod, PodGroup
     from volcano_tpu.cache import ClusterStore
 
@@ -328,14 +346,10 @@ def test_count_update_overflow_parity(monkeypatch, side):
         return s
 
     res = {}
-    _gate(monkeypatch, side)
-    try:
-        for mode, env in (("fast", "1"), ("object", "0")):
-            monkeypatch.setenv("VOLCANO_TPU_FASTPATH", env)
-            store = build()
-            Scheduler(store).run_once()
-            res[mode] = placements(store)
-    finally:
-        jax.clear_caches()
+    for mode, env in (("fast", "1"), ("object", "0")):
+        monkeypatch.setenv("VOLCANO_TPU_FASTPATH", env)
+        store = build()
+        Scheduler(store).run_once()
+        res[mode] = placements(store)
     assert all(v for v in res["fast"].values())
     assert res["fast"] == res["object"]

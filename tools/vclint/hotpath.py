@@ -227,7 +227,7 @@ HOT_REGISTRY: Dict[str, List[HotEntry]] = {
 # full-N node plane / full-P pod plane temporary) must appear in
 # ``CHUNK_BUDGET_REGISTRY`` — registration records that its peak
 # footprint is bounded by a reviewed chunk/budget mechanism (the
-# lax.map profile streams and DOM_MM_MAX_MB size gate in ops/wave.py,
+# lax.map profile streams and keyspace gate in ops/wave.py,
 # the devsnap delta-scatter budget, pow2-padded fixed planes in the
 # victim/rebalance kernels).  A NEW device fn declaring [N, *] planes
 # trips VCL204 until it routes through the chunk-budget machinery and
@@ -245,8 +245,7 @@ BUDGET_FILES = {
 CHUNK_BUDGET_REGISTRY: Dict[str, Set[str]] = {
     "volcano_tpu/ops/wave.py": {
         # Profile axes stream through lax.map in COARSE_CHUNK rows;
-        # the [N, D] domain one-hot sits behind the DOM_MM_MAX_MB
-        # size gate; conflict buffers behind the keyspace gate.
+        # conflict buffers sit behind the keyspace gate.
         "_solve_wave", "_coarse_shortlist", "_warm_shortlist",
         "_static_planes",
     },

@@ -1156,12 +1156,9 @@ class FastCycle:
         pending rows that carry a term, the active terms and their
         padded bucket, the topology domains, and the bytes the
         ``has_aff`` branch of ``_solve_wave`` holds for them, reckoned
-        from shapes: the two ``[Ep + 1, D]`` int32 count tensors and,
-        where it is on (``ops/wave.dom_mm_on``), the ``[N, D]`` float32
-        domain one-hot.  The largest solve of the cycle is kept; on a
-        mesh, a chip's share of it beside the whole."""
-        from .ops.wave import dom_mm_on
-
+        from shapes: the two ``[Ep + 1, D]`` int32 count tensors.  The
+        largest solve of the cycle is kept; on a mesh, a chip's share
+        of it beside the whole."""
         if getattr(self, "stats", None) is None:
             return  # a bare FastCycle outside run() (tests) records nothing
         m = self.m
@@ -1170,8 +1167,6 @@ class FastCycle:
         if Np % shards:
             shards = 1  # solve_wave's own rule: the global form
         nbytes = 2 * (Ep + 1) * D * 4
-        if dom_mm_on(D, Np):
-            nbytes += Np * D * 4
         sc = self._solve_counts()
         sc["aff_rows"] = max(sc["aff_rows"], int(
             np.count_nonzero(m.p_has_ip[task_rows])))
@@ -1180,9 +1175,9 @@ class FastCycle:
         sc["aff_domains"] = max(sc["aff_domains"], D)
         sc["aff_device_bytes"] = max(sc["aff_device_bytes"], nbytes)
         if shards > 1:
-            # On a mesh the count pair shards on the domain axis and the
-            # one-hot on the node axis: a chip's share (absent on one
-            # device, where aff_device_bytes is the chip's).
+            # On a mesh the count pair shards on the domain axis: a
+            # chip's share (absent on one device, where
+            # aff_device_bytes is the chip's).
             sc["aff_device_bytes_chip"] = max(
                 sc.get("aff_device_bytes_chip", 0), -(-nbytes // shards))
 
@@ -1301,10 +1296,8 @@ class FastCycle:
         shards = int(info.get("mesh_shards", 1) or 1)
         if shards > 1:
             self.stats["mesh_shards"] = shards
-            # Into the record's ``solve`` block too, with the side of the
-            # one-hot's gate the dispatched program took (``dom_mm_on``).
-            self._solve_counts().update(
-                mesh_shards=shards, aff_dom_mm=int(info.get("dom_mm", 0)))
+            # Into the record's ``solve`` block too.
+            self._solve_counts()["mesh_shards"] = shards
         terms = info.get("terms")
         if terms:
             # How the inter-pod term data crossed to this solve: its

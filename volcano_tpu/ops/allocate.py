@@ -149,7 +149,7 @@ class AllocResult(NamedTuple):
     # ``job_overskip``), for the cycle record's ``solve`` counts.
     overuse_gated: jnp.ndarray = None  # [] int32
     # ``has_aff`` wave solve only: how often the solve recomputed its
-    # count plane (``ops/wave.count_plane`` or the one-hot matmul).
+    # count plane (``ops/wave.count_plane``).
     aff_count_reads: jnp.ndarray = None  # [] int32
 
 

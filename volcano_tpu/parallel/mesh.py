@@ -226,8 +226,9 @@ def shard_wave_inputs(mesh: Mesh, solve_args: Sequence, pid, profiles,
       a placement here was fetched straight back; the jit replicates
       them (profile counts are tiny next to [*, N] and [E, D] state).
 
-    The kernel's count-window contraction (cnt @ dom_ohT over D) then
-    runs as partial products with an XLA-inserted reduce over ICI.
+    The kernel's count-window read (``ops/wave.count_plane``: row
+    gathers along the sharded D) then crosses chips by the collectives
+    XLA's partitioner inserts.
 
     ``plane_cache`` (with ``epoch``) keeps the epoch-stable node planes
     and ``aff.node_dom`` resident on the mesh across cycles: a hit skips
