@@ -27,6 +27,7 @@ from volcano_tpu.cache import ClusterStore, FakeBinder, FakeEvictor
 from volcano_tpu.metrics import metrics
 from volcano_tpu.oracle import oracle_preempt, oracle_reclaim
 from volcano_tpu.ops import victim as vk
+from volcano_tpu import whatif
 from volcano_tpu.scheduler import Scheduler
 from volcano_tpu.sim import ClusterSimulator
 
@@ -504,4 +505,93 @@ def test_evict_device_kill_switch(monkeypatch):
     preempt_before = {k: v for k, v in before.items()
                       if k[0][1] == "preempt"}
     assert preempt_after == preempt_before
+    store.close()
+
+
+# ------------------------------- the plan loop's spans and counts (PR 49)
+
+
+def _plan_records(store, conf, cycles=3):
+    """Cycle records (as dicts) of ``cycles`` cycles under ``conf``."""
+    sched = Scheduler(store, conf_str=conf)
+    sim = ClusterSimulator(store, grace_steps=2)
+    for _ in range(cycles):
+        sched.run_once()
+        sim.step()
+    return [r.to_dict(include_spans=True)
+            for r in store.flight.recent()[-cycles:]]
+
+
+@pytest.mark.parametrize("budget,outcome", [(None, "committed"),
+                                            (0, "rejected-budget")])
+def test_plan_phases_nest_and_counts_are_numbers(monkeypatch, budget, outcome):
+    """A cycle that plans has ``plan:victims`` / ``plan:scores`` /
+    ``plan:select`` as children of the action's plan span, and its
+    ``whatif`` block carries ``gangs_tried`` / ``committed`` /
+    ``rejected`` as numbers that agree with
+    ``volcano_whatif_plans_total``, and ``victims`` as the number the
+    evictor was handed."""
+    monkeypatch.setenv("VOLCANO_TPU_EVICT_DEVICE", "1")
+    before = {o: _whatif_count("preempt", o)
+              for o in ("committed", "rejected-budget", "rejected-no-gain")}
+    evictor = FakeEvictor()
+    store = ClusterStore(evictor=evictor, binder=FakeBinder())
+    ClusterSimulator.priority_tier_workload(store, workers=2,
+                                            serving_tasks=1)
+    if budget is not None:
+        for i in range(2):
+            store.pod_groups[f"default/batch{i}"].max_unavailable = budget
+    records = _plan_records(store, PREEMPT_CONF)
+    planned = [r for r in records if r["whatif"] is not None]
+    assert planned, [r["whatif"] for r in records]
+    tried = committed = rejected = victims = 0
+    for rec in planned:
+        block = rec["whatif"]
+        for key in whatif.WALK_COUNTS:
+            assert type(block[key]) is int, (key, block)
+        assert not any(key in prior for prior in block.get("prior", [])
+                       for key in whatif.WALK_COUNTS)
+        tried += block["gangs_tried"]
+        committed += block["committed"]
+        rejected += block["rejected"]
+        victims += block["victims"]
+        by_id = {s["span_id"]: s for s in rec["spans"]}
+        phases = [s for s in rec["spans"] if s["name"].startswith("plan:")]
+        # a walk in which every gang is gated (a wave still freeing room,
+        # a backoff) tries none: the block is there, with zeros
+        assert len(phases) >= block["gangs_tried"] >= 0
+        for s in phases:
+            assert s["cat"] == "whatif"
+            assert by_id[s["parent_id"]]["name"] == "preempt_plan", s
+        names = [s["name"] for s in phases]
+        assert names.count("plan:victims") == block["gangs_tried"]
+        assert names.count("plan:scores") <= names.count("plan:victims")
+        assert names.count("plan:select") <= names.count("plan:scores")
+    first = planned[0]["whatif"]
+    assert first["outcome"] == outcome and first["action"] == "preempt"
+    assert {s["name"] for s in planned[0]["spans"]} >= {
+        "plan:victims", "plan:scores", "plan:select"}
+    assert tried >= 1
+    assert committed == _whatif_count("preempt", "committed") \
+        - before["committed"]
+    assert rejected == sum(_whatif_count("preempt", o) - before[o]
+                           for o in ("rejected-budget", "rejected-no-gain"))
+    assert (committed if outcome == "committed" else rejected) >= 1
+    assert victims == len(evictor.evicts)
+    assert (victims >= 1) == (outcome == "committed")
+    store.close()
+
+
+def test_a_conf_without_eviction_actions_has_no_plan_span_or_block(monkeypatch):
+    monkeypatch.setenv("VOLCANO_TPU_EVICT_DEVICE", "1")
+    store = ClusterStore(evictor=FakeEvictor(), binder=FakeBinder())
+    ClusterSimulator.priority_tier_workload(store, workers=2,
+                                            serving_tasks=1)
+    conf = PREEMPT_CONF.replace('"enqueue, allocate, preempt"',
+                                '"enqueue, allocate"')
+    assert "preempt" not in conf.split("tiers")[0]
+    for rec in _plan_records(store, conf):
+        assert rec["whatif"] is None
+        assert not any(s["name"].startswith("plan:")
+                       or s["name"].endswith("_plan") for s in rec["spans"])
     store.close()

@@ -59,6 +59,13 @@ F = np.float32
 I = np.int32
 
 ACTIONS = ("preempt", "reclaim", "rebalance")
+# The numbers of a cycle record's ``whatif`` block, the cycle's own and
+# no one outcome's: calls of ``_plan_evict_gang`` (both actions), the
+# outcomes ``committed`` and ``rejected-*`` and the victims of the
+# committed plans, ``prior`` included (docs/tracing.md; a plan that was
+# rejected or voided took none: its size is in its ``whatif_solve``
+# span's ``args``).
+WALK_COUNTS = ("gangs_tried", "committed", "rejected", "victims")
 
 
 def _env_int(name: str, default: int) -> int:
@@ -518,14 +525,22 @@ def count_plan(cyc, action: str, outcome: str, **info) -> None:
     if action == "rebalance":
         metrics.rebalance_plans.inc(outcome=outcome)
         key = "rebalance"
-        d = {"outcome": outcome}
+        was = cyc.stats.get(key)
+        d = {"outcome": outcome, **info}
     else:
         key = "whatif"
-        d = {"action": action, "outcome": outcome}
-    d.update(info)
-    existing = cyc.stats.get(key)
-    if existing is not None:
-        d["prior"] = existing.pop("prior", []) + [existing]
+        # The cycle's numbers (``_plan_evict`` opens them) go on with
+        # its newest outcome; what is left of the block, if anything,
+        # is an earlier outcome.
+        was = cyc.stats.get(key) or {}
+        n = {k: was.pop(k, 0) for k in WALK_COUNTS}
+        n["committed"] += outcome == "committed"
+        n["rejected"] += outcome.startswith("rejected")
+        if outcome == "committed":
+            n["victims"] += info.get("victims", 0)
+        d = {"action": action, "outcome": outcome, **info, **n}
+    if was:
+        d["prior"] = was.pop("prior", []) + [was]
     cyc.stats[key] = d
 
 
@@ -700,6 +715,9 @@ def _plan_evict(cyc, action: str) -> Optional[WhatIfPlan]:
     # Highest-priority gang first (the point of preemption), then the
     # largest shortfall, then the lowest row for determinism.
     order = np.lexsort((cand, -needs, -prios))
+    # The block is there in every cycle whose action walks its starved
+    # gangs, whether or not a plan comes of it.
+    cyc.stats.setdefault("whatif", dict.fromkeys(WALK_COUNTS, 0))
     with cyc.tracer.span(f"{action}_plan", cat="whatif"):
         for r in cand[order]:
             jrow = int(r)
@@ -712,6 +730,7 @@ def _plan_evict(cyc, action: str) -> Optional[WhatIfPlan]:
                 # (victims terminating); re-planning now would double-
                 # evict for the same need.
                 continue
+            cyc.stats["whatif"]["gangs_tried"] += 1
             plan = _plan_evict_gang(cyc, action, jrow)
             if plan is not None:
                 return plan
@@ -720,6 +739,11 @@ def _plan_evict(cyc, action: str) -> Optional[WhatIfPlan]:
 
 # holds: _lock
 def _plan_evict_gang(cyc, action: str, jrow: int) -> Optional[WhatIfPlan]:
+    """One gang's plan, in three phases under the action's plan span:
+    ``plan:victims`` (the gang's profile table, the base victim set and
+    the host victim table, up to the kernel's arguments),
+    ``plan:scores`` (the jitted kernel and the wait for its planes) and
+    ``plan:select`` (the host greedy and what follows it)."""
     import jax
 
     from .fastpath import _pow2
@@ -727,102 +751,107 @@ def _plan_evict_gang(cyc, action: str, jrow: int) -> Optional[WhatIfPlan]:
 
     m = cyc.m
     store = cyc.store
+    span = cyc.tracer.span
     is_reclaim = action == "reclaim"
-    need = int(m.j_minav[jrow] - cyc.j_ready_base[jrow])
-    if need <= 0:
-        return None
-    gang_rows, prof_req = _gang_profile_table(cyc, jrow)
-    if prof_req is None:
-        return None
-    vict = _victim_base(cyc, jrow)
-    if not len(vict):
-        return None
-    V = len(vict)
-    Vp = _pow2(V)
-    Np = _pow2(max(cyc.Nn, 1))
-    Qp = _pow2(max(cyc.Qn, 1), 4)
-    v_ok = np.zeros(Vp, bool)
-    v_ok[:V] = True
-    v_jprio = np.zeros(Vp, I)
-    v_crank = np.zeros(Vp, I)
-    v_tie = np.arange(Vp, dtype=I)
-    v_queue = np.zeros(Vp, I)
-    v_node = np.zeros(Vp, I)
-    v_req = np.zeros((Vp, cyc.R), F)
-    vjobs = cyc.jobr[vict].astype(np.int64)
-    # A victim whose job has no known queue (q_of_job == -1: its queue
-    # was deleted) has no share to gate on — exclude it at the base
-    # level rather than letting the kernel's index clip alias it onto
-    # queue 0 (the oracle requires 0 <= q < Q the same way).
-    v_ok[:V] = cyc.q_of_job[vjobs] >= 0
-    v_jprio[:V] = m.j_prio[vjobs]
-    # Creation rank: larger = younger (evicted first among equals).
-    v_crank[:V] = np.argsort(
-        np.argsort(m.p_create[vict], kind="stable")).astype(I)
-    v_queue[:V] = cyc.q_of_job[vjobs]
-    v_node[:V] = m.p_node[:cyc.Pn][vict]
-    er, si, vv = m.c_req.gather(vict)
-    v_req[er, si] = vv
-    q_alloc_p = np.zeros((Qp, cyc.R), F)
-    q_des_p = np.full((Qp, cyc.R), 3.0e38, F)
-    q_alloc_p[:cyc.Qn] = cyc.q_alloc
-    q_des_p[:cyc.Qn] = cyc.q_deserved
-    q_rec = np.zeros(Qp, bool)
-    for name, qi in cyc.queue_index.items():
-        q = store.queues.get(name)
-        q_rec[qi] = bool(q is not None and q.reclaimable())
-    gang_prio = int(m.j_prio[jrow])
-    gang_queue = int(cyc.q_of_job[jrow])
-    planes = vk.victim_scores(
-        v_ok, v_jprio, v_crank, v_tie, v_queue, v_node, v_req,
-        np.int32(gang_prio), np.int32(gang_queue),
-        q_alloc_p, q_des_p, q_rec,
-        np.int32(vk.RECLAIM if is_reclaim else vk.PREEMPT),
-        np.zeros((Np, cyc.R), F),
-    )
-    eligible, order, evictable = jax.device_get(
-        (planes.eligible, planes.order, planes.evictable))
-    if not bool(eligible[:V].any()):
-        return None
-    groups = [m.j_uid[int(j)] for j in vjobs]
-    v_group = groups + [""] * (Vp - V)
-    budget_left = _budget_left(cyc, groups)
-    qa_sel = qd_sel = None
-    if is_reclaim:
-        qa_sel = cyc.q_alloc.astype(F)
-        qd_sel = cyc.q_deserved.astype(F)
-    idle_p = np.zeros((Np, cyc.R), F)
-    idle_p[:cyc.Nn] = cyc.n_idle.astype(F)
-    v_job_p = np.concatenate([vjobs, np.full(Vp - V, -1, np.int64)])
-    sel = vk.select_victims(
-        order, eligible, v_node, v_req, v_job_p,
-        v_group, v_queue, need, idle_p, evictable, prof_req,
-        cyc.eps, cyc.j_ready_base, m.j_minav, budget_left,
-        evict_cap(), q_alloc=qa_sel, q_deserved=qd_sel,
-    )
-    uid = m.j_uid[jrow]
-    if not sel.feasible:
-        if sel.budget_blocked:
-            count_plan(cyc, action, "rejected-budget",
-                       gang=uid, need=need)
-        # Cooldown either way: no wave can form until the cluster
-        # moves, so re-scoring every cycle is waste.
-        set_backoff(store, action, uid, cyc.REBALANCE_REJECT_BACKOFF)
-        return None
-    chosen = np.asarray(sel.chosen, np.int64)
-    victim_rows = vict[chosen]
-    victim_jobs = vjobs[chosen]
-    budgets: Dict[str, int] = {}
-    for j in victim_jobs.tolist():
-        g = m.j_uid[int(j)]
-        budgets[g] = budgets.get(g, 0) + 1
-    return WhatIfPlan(
-        action=action, gang_job=jrow, gang_uid=uid,
-        gang_rows=gang_rows, victim_rows=victim_rows,
-        victim_jobs=victim_jobs,
-        drain_nodes=np.zeros(0, np.int64), need=need,
-        frag_before=0.0, budgets=budgets, resolve_victims=False,
-    )
+    with span("plan:victims", cat="whatif"):
+        need = int(m.j_minav[jrow] - cyc.j_ready_base[jrow])
+        if need <= 0:
+            return None
+        gang_rows, prof_req = _gang_profile_table(cyc, jrow)
+        if prof_req is None:
+            return None
+        vict = _victim_base(cyc, jrow)
+        if not len(vict):
+            return None
+        V = len(vict)
+        Vp = _pow2(V)
+        Np = _pow2(max(cyc.Nn, 1))
+        Qp = _pow2(max(cyc.Qn, 1), 4)
+        v_ok = np.zeros(Vp, bool)
+        v_ok[:V] = True
+        v_jprio = np.zeros(Vp, I)
+        v_crank = np.zeros(Vp, I)
+        v_tie = np.arange(Vp, dtype=I)
+        v_queue = np.zeros(Vp, I)
+        v_node = np.zeros(Vp, I)
+        v_req = np.zeros((Vp, cyc.R), F)
+        vjobs = cyc.jobr[vict].astype(np.int64)
+        # A victim whose job has no known queue (q_of_job == -1: its
+        # queue was deleted) has no share to gate on — exclude it at
+        # the base level rather than letting the kernel's index clip
+        # alias it onto queue 0 (the oracle requires 0 <= q < Q the
+        # same way).
+        v_ok[:V] = cyc.q_of_job[vjobs] >= 0
+        v_jprio[:V] = m.j_prio[vjobs]
+        # Creation rank: larger = younger (evicted first among equals).
+        v_crank[:V] = np.argsort(
+            np.argsort(m.p_create[vict], kind="stable")).astype(I)
+        v_queue[:V] = cyc.q_of_job[vjobs]
+        v_node[:V] = m.p_node[:cyc.Pn][vict]
+        er, si, vv = m.c_req.gather(vict)
+        v_req[er, si] = vv
+        q_alloc_p = np.zeros((Qp, cyc.R), F)
+        q_des_p = np.full((Qp, cyc.R), 3.0e38, F)
+        q_alloc_p[:cyc.Qn] = cyc.q_alloc
+        q_des_p[:cyc.Qn] = cyc.q_deserved
+        q_rec = np.zeros(Qp, bool)
+        for name, qi in cyc.queue_index.items():
+            q = store.queues.get(name)
+            q_rec[qi] = bool(q is not None and q.reclaimable())
+        gang_prio = int(m.j_prio[jrow])
+        gang_queue = int(cyc.q_of_job[jrow])
+    with span("plan:scores", cat="whatif"):
+        planes = vk.victim_scores(
+            v_ok, v_jprio, v_crank, v_tie, v_queue, v_node, v_req,
+            np.int32(gang_prio), np.int32(gang_queue),
+            q_alloc_p, q_des_p, q_rec,
+            np.int32(vk.RECLAIM if is_reclaim else vk.PREEMPT),
+            np.zeros((Np, cyc.R), F),
+        )
+        eligible, order, evictable = jax.device_get(
+            (planes.eligible, planes.order, planes.evictable))
+    with span("plan:select", cat="whatif"):
+        if not bool(eligible[:V].any()):
+            return None
+        groups = [m.j_uid[int(j)] for j in vjobs]
+        v_group = groups + [""] * (Vp - V)
+        budget_left = _budget_left(cyc, groups)
+        qa_sel = qd_sel = None
+        if is_reclaim:
+            qa_sel = cyc.q_alloc.astype(F)
+            qd_sel = cyc.q_deserved.astype(F)
+        idle_p = np.zeros((Np, cyc.R), F)
+        idle_p[:cyc.Nn] = cyc.n_idle.astype(F)
+        v_job_p = np.concatenate([vjobs, np.full(Vp - V, -1, np.int64)])
+        sel = vk.select_victims(
+            order, eligible, v_node, v_req, v_job_p,
+            v_group, v_queue, need, idle_p, evictable, prof_req,
+            cyc.eps, cyc.j_ready_base, m.j_minav, budget_left,
+            evict_cap(), q_alloc=qa_sel, q_deserved=qd_sel,
+        )
+        uid = m.j_uid[jrow]
+        if not sel.feasible:
+            if sel.budget_blocked:
+                count_plan(cyc, action, "rejected-budget",
+                           gang=uid, need=need)
+            # Cooldown either way: no wave can form until the cluster
+            # moves, so re-scoring every cycle is waste.
+            set_backoff(store, action, uid, cyc.REBALANCE_REJECT_BACKOFF)
+            return None
+        chosen = np.asarray(sel.chosen, np.int64)
+        victim_rows = vict[chosen]
+        victim_jobs = vjobs[chosen]
+        budgets: Dict[str, int] = {}
+        for j in victim_jobs.tolist():
+            g = m.j_uid[int(j)]
+            budgets[g] = budgets.get(g, 0) + 1
+        return WhatIfPlan(
+            action=action, gang_job=jrow, gang_uid=uid,
+            gang_rows=gang_rows, victim_rows=victim_rows,
+            victim_jobs=victim_jobs,
+            drain_nodes=np.zeros(0, np.int64), need=need,
+            frag_before=0.0, budgets=budgets, resolve_victims=False,
+        )
 
 
 # holds: _lock
