@@ -559,3 +559,41 @@ def test_object_path_rebalance_action_is_noop(monkeypatch):
     sched.run_once()  # must not raise
     assert store.migrations is None
     store.close()
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_the_ledgers_one_pass_counts_what_disrupted_counts(seed):
+    """``disrupted_by_group`` is ``disrupted`` of every group, from one
+    prune and one pass: in-flight and restored-but-unbound entries count,
+    finished ones (the restored pod bound, or deleted again) and those of
+    a PodGroup that is gone are pruned, by either."""
+    import copy
+    from types import SimpleNamespace
+
+    from volcano_tpu.actions.rebalance import MigrationLedger
+
+    rng = np.random.RandomState(seed)
+    groups = [f"default/g{i}" for i in range(8)]
+    store = SimpleNamespace(
+        pod_groups={g: object() for g in groups[2:] if rng.rand() > 0.2},
+        pods={})          # g0 and g1 are gone, and some of the others
+    ledger = MigrationLedger()
+    states = ["in-flight", "restored-unbound", "bound", "deleted-again"]
+    made = {s: 0 for s in states}
+    for i in range(60):
+        uid, state = f"default/v{i}", states[rng.randint(len(states))]
+        ledger.register(uid, groups[rng.randint(len(groups))], "",
+                        action=("preempt", "reclaim", "rebalance")[i % 3])
+        made[state] += 1
+        if state != "in-flight":
+            ledger.entries[uid].restored_uid = f"{uid}-mig{i}"
+        if state in ("restored-unbound", "bound"):
+            store.pods[f"{uid}-mig{i}"] = SimpleNamespace(
+                node_name="n0" if state == "bound" else None)
+    assert all(made.values()) and len(store.pod_groups) < len(groups)
+    one_by_one = copy.deepcopy(ledger)
+    want = {g: one_by_one.disrupted(store, g) for g in groups}
+    assert ledger.disrupted_by_group(store) == {g: n for g, n in want.items() if n}
+    assert list(ledger.entries) == list(one_by_one.entries)
+    assert 0 < len(ledger.entries) < 60 and sum(want.values()) == len(ledger.entries)
+    assert all(want[g] == 0 for g in groups if g not in store.pod_groups)

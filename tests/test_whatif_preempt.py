@@ -10,9 +10,13 @@ VOLCANO_TPU_EVICT_DEVICE=0 for them); every device-lane test here opts
 in explicitly.
 """
 
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 
+from volcano_tpu.actions.rebalance import ledger_of, max_unavailable_of
 from volcano_tpu.api import (
     GROUP_NAME_ANNOTATION,
     Node,
@@ -30,6 +34,9 @@ from volcano_tpu.ops import victim as vk
 from volcano_tpu import whatif
 from volcano_tpu.scheduler import Scheduler
 from volcano_tpu.sim import ClusterSimulator
+
+sys.path.insert(0, str(Path(__file__).parent / "benchmark"))
+from test_benchmark_cell import toy_preempt  # noqa: E402,F401  (a fixture)
 
 PREEMPT_CONF = """
 actions: "enqueue, allocate, preempt"
@@ -142,6 +149,12 @@ def _random_wave(seed, mode):
 
     np.testing.assert_array_equal(eligible, ref.eligible,
                                   err_msg=f"seed {seed} eligibility")
+    # the host gate says no only where nothing is eligible; for preempt,
+    # which compares integers alone, it is the kernel's verdict
+    gate = vk.may_be_eligible(vk.queue_min_prio(v_ok, v_jprio, v_queue, Q),
+                              q_rec, mode, p_prio, p_queue)
+    assert gate or not eligible.any(), f"seed {seed} gate"
+    assert mode == vk.RECLAIM or gate == eligible.any(), f"seed {seed} gate"
     np.testing.assert_array_equal(order, ref.order,
                                   err_msg=f"seed {seed} order")
     np.testing.assert_allclose(q_share, ref.q_share, rtol=1e-6,
@@ -529,8 +542,9 @@ def test_plan_phases_nest_and_counts_are_numbers(monkeypatch, budget, outcome):
     ``plan:select`` as children of the action's plan span, and its
     ``whatif`` block carries ``gangs_tried`` / ``committed`` /
     ``rejected`` as numbers that agree with
-    ``volcano_whatif_plans_total``, and ``victims`` as the number the
-    evictor was handed."""
+    ``volcano_whatif_plans_total``, ``victims`` as the number the
+    evictor was handed, and ``tables_built`` / ``kernel_calls`` as the
+    tables and ``plan:scores`` spans the cycle has."""
     monkeypatch.setenv("VOLCANO_TPU_EVICT_DEVICE", "1")
     before = {o: _whatif_count("preempt", o)
               for o in ("committed", "rejected-budget", "rejected-no-gain")}
@@ -565,6 +579,10 @@ def test_plan_phases_nest_and_counts_are_numbers(monkeypatch, budget, outcome):
             assert by_id[s["parent_id"]]["name"] == "preempt_plan", s
         names = [s["name"] for s in phases]
         assert names.count("plan:victims") == block["gangs_tried"]
+        # past the host gate a try calls the kernel; a table serves the
+        # tries of one state of the mirror (one action here)
+        assert names.count("plan:scores") == block["kernel_calls"]
+        assert block["tables_built"] == min(1, block["gangs_tried"])
         assert names.count("plan:scores") <= names.count("plan:victims")
         assert names.count("plan:select") <= names.count("plan:scores")
     first = planned[0]["whatif"]
@@ -594,4 +612,322 @@ def test_a_conf_without_eviction_actions_has_no_plan_span_or_block(monkeypatch):
         assert rec["whatif"] is None
         assert not any(s["name"].startswith("plan:")
                        or s["name"].endswith("_plan") for s in rec["spans"])
+    store.close()
+
+
+# --------------- the victim table against the per-gang construction (PR 50)
+
+
+def _per_gang_try(cyc, action, jrow):
+    """What ``whatif._plan_evict_gang`` built for every gang before PR 50,
+    kept as the reference: the base rows with the gang masked out, the
+    kernel's columns filled for this gang alone, a uid string a row for
+    its group and ``MigrationLedger.disrupted`` asked once a group.  It
+    counts nothing and sets no backoff: ``(plan fields or None, would set
+    the backoff, would count rejected-budget)``."""
+    import jax
+
+    from volcano_tpu.api import TaskStatus
+    from volcano_tpu.fastpath import _pow2
+
+    m, store = cyc.m, cyc.store
+    F, I = np.float32, np.int32
+    is_reclaim = action == "reclaim"
+    need = int(m.j_minav[jrow] - cyc.j_ready_base[jrow])
+    if need <= 0:
+        return None, False, False
+    gang_rows, prof_req = whatif._gang_profile_table(cyc, jrow)
+    if prof_req is None:
+        return None, False, False
+    Pn = cyc.Pn
+    vict = np.flatnonzero(
+        cyc.resident[:Pn] & (m.p_status[:Pn] == int(TaskStatus.Running))
+        & ~m.p_critical[:Pn] & ~m.p_has_ip[:Pn] & (cyc.jobr >= 0)
+        & (cyc.jobr != jrow))
+    if len(vict):
+        vict = vict[m.c_req.lens(vict) > 0]
+    vict = vict.astype(np.int64)
+    if not len(vict):
+        return None, False, False
+    V = len(vict)
+    Vp, Np, Qp = _pow2(V), _pow2(max(cyc.Nn, 1)), _pow2(max(cyc.Qn, 1), 4)
+    v_ok = np.zeros(Vp, bool)
+    v_jprio, v_crank, v_queue, v_node = (np.zeros(Vp, I) for _ in range(4))
+    v_tie = np.arange(Vp, dtype=I)
+    v_req = np.zeros((Vp, cyc.R), F)
+    vjobs = cyc.jobr[vict].astype(np.int64)
+    v_ok[:V] = cyc.q_of_job[vjobs] >= 0
+    v_jprio[:V] = m.j_prio[vjobs]
+    v_crank[:V] = np.argsort(np.argsort(m.p_create[vict], kind="stable"))
+    v_queue[:V] = cyc.q_of_job[vjobs]
+    v_node[:V] = m.p_node[:Pn][vict]
+    er, si, vv = m.c_req.gather(vict)
+    v_req[er, si] = vv
+    q_alloc_p = np.zeros((Qp, cyc.R), F)
+    q_des_p = np.full((Qp, cyc.R), 3.0e38, F)
+    q_alloc_p[:cyc.Qn] = cyc.q_alloc
+    q_des_p[:cyc.Qn] = cyc.q_deserved
+    q_rec = np.zeros(Qp, bool)
+    for name, qi in cyc.queue_index.items():
+        q = store.queues.get(name)
+        q_rec[qi] = bool(q is not None and q.reclaimable())
+    planes = vk.victim_scores(
+        v_ok, v_jprio, v_crank, v_tie, v_queue, v_node, v_req,
+        np.int32(int(m.j_prio[jrow])), np.int32(int(cyc.q_of_job[jrow])),
+        q_alloc_p, q_des_p, q_rec,
+        np.int32(vk.RECLAIM if is_reclaim else vk.PREEMPT),
+        np.zeros((Np, cyc.R), F))
+    eligible, order, evictable = jax.device_get(
+        (planes.eligible, planes.order, planes.evictable))
+    if not bool(eligible[:V].any()):
+        return None, False, False
+    groups = [m.j_uid[int(j)] for j in vjobs]
+    ledger = store.migrations
+    budget_left = {}
+    for uid in set(groups):
+        row = m.j_row.get(uid, -1)
+        used = ledger.disrupted(store, uid) if ledger is not None else 0
+        budget_left[uid] = max_unavailable_of(
+            m.j_pg[row] if row >= 0 else None) - used
+    idle_p = np.zeros((Np, cyc.R), F)
+    idle_p[:cyc.Nn] = cyc.n_idle.astype(F)
+    sel = vk.select_victims(
+        order, eligible, v_node, v_req,
+        np.concatenate([vjobs, np.full(Vp - V, -1, np.int64)]),
+        groups + [""] * (Vp - V), v_queue, need, idle_p, evictable,
+        prof_req, cyc.eps, cyc.j_ready_base, m.j_minav, budget_left,
+        whatif.evict_cap(),
+        q_alloc=cyc.q_alloc.astype(F) if is_reclaim else None,
+        q_deserved=cyc.q_deserved.astype(F) if is_reclaim else None)
+    if not sel.feasible:
+        return None, True, sel.budget_blocked
+    chosen = np.asarray(sel.chosen, np.int64)
+    budgets = {}
+    for j in vjobs[chosen].tolist():
+        budgets[m.j_uid[j]] = budgets.get(m.j_uid[j], 0) + 1
+    return ({"gang_rows": gang_rows, "victim_rows": vict[chosen],
+             "victim_jobs": vjobs[chosen], "budgets": budgets,
+             "need": need}, False, False)
+
+
+@pytest.fixture
+def shadowed(monkeypatch):
+    """Every try of the plan loops runs the per-gang reference first and
+    is held to it: the same ``WhatIfPlan`` or the same ``None``, the same
+    backoff, the same ``rejected-budget``.  Yields the tries seen, as
+    ``(action, gang uid, victims or None)``."""
+    monkeypatch.setenv("VOLCANO_TPU_EVICT_DEVICE", "1")
+    real, seen = whatif._plan_evict_gang, []
+
+    def both(cyc, action, jrow):
+        uid = cyc.m.j_uid[jrow]
+        want, backs_off, rejects = _per_gang_try(cyc, action, jrow)
+        _, backoff = whatif._streak_maps(cyc.store)
+        assert not backoff.get((action, uid)), "a gang in backoff is not tried"
+        rejected = cyc.stats["whatif"]["rejected"]
+        got = real(cyc, action, jrow)
+        assert bool(backoff.get((action, uid))) == backs_off, (action, uid)
+        assert cyc.stats["whatif"]["rejected"] - rejected == rejects, uid
+        assert (got is None) == (want is None), (action, uid, got, want)
+        if got is not None:
+            assert (got.action, got.gang_job, got.gang_uid) == (action, jrow, uid)
+            assert got.need == want["need"] and got.budgets == want["budgets"]
+            assert list(got.budgets) == list(want["budgets"])
+            for key in ("gang_rows", "victim_rows", "victim_jobs"):
+                np.testing.assert_array_equal(getattr(got, key), want[key],
+                                              err_msg=f"{action} {uid} {key}")
+        seen.append((action, uid, None if got is None else len(got.victim_rows)))
+        return got
+
+    monkeypatch.setattr(whatif, "_plan_evict_gang", both)
+    return seen
+
+
+BOTH_CONF = PREEMPT_CONF.replace('"enqueue, allocate, preempt"',
+                                 '"enqueue, allocate, preempt, reclaim"')
+
+
+def _cluster(nodes=4, queues=()):
+    """``nodes`` nodes of 8 cpu, the classes ``high`` and ``low``, and the
+    queues named: ``(name, weight, reclaimable)``."""
+    store = ClusterStore(evictor=FakeEvictor(), binder=FakeBinder())
+    store.add_priority_class(PriorityClass(name="high", value=1000))
+    store.add_priority_class(PriorityClass(name="low", value=10))
+    for i in range(nodes):
+        store.add_node(Node(name=f"n{i}", allocatable={
+            "cpu": "8", "memory": "32Gi", "pods": 110}))
+    for name, weight, reclaimable in queues:
+        store.add_queue(Queue(name=name, weight=weight, reclaimable=reclaimable))
+    return store
+
+
+def _group(store, name, klass, on=(), pending=0, queue="default", **spec):
+    """A PodGroup of 4-cpu pods: one Running on each node of ``on`` and
+    ``pending`` more Pending."""
+    prio = store.priority_classes[klass].value
+    store.add_pod_group(PodGroup(name=name, queue=queue, priority_class=klass,
+                                 **{"min_member": 1, **spec}))
+    if on:
+        store.pod_groups[f"default/{name}"].status.phase = \
+            PodGroupPhase.Running.value
+    for i, node in enumerate(on):
+        store.add_pod(running_pod(f"{name}-r{i}", name, "4", node, prio=prio))
+    for i in range(pending):
+        store.add_pod(pending_pod(f"{name}-p{i}", name, "4", prio=prio))
+
+
+FULL = ("n0", "n0", "n1", "n1", "n2", "n2", "n3")  # and one slot on n3
+
+
+def _elastic_gang_with_running_pods_of_its_own():
+    store = _cluster()
+    _group(store, "lo", "low", on=FULL, max_unavailable=8)
+    _group(store, "hi", "high", on=("n3",), pending=2, min_member=3)
+    return store, PREEMPT_CONF, {("preempt", "default/hi", 2)}
+
+
+def _a_victim_job_whose_queue_was_deleted():
+    store = _cluster(queues=[("qx", 1, True)])
+    _group(store, "lo", "low", on=FULL[:5], max_unavailable=8)
+    _group(store, "gone", "low", on=("n2", "n3", "n3"), queue="qx",
+           max_unavailable=8)
+    store.delete_queue("qx")
+    _group(store, "hi", "high", pending=1)
+    return store, BOTH_CONF, {("preempt", "default/hi", 1)}
+
+
+def _ledger_entries_in_flight_use_up_the_budget():
+    store = _cluster()
+    _group(store, "lo", "low", on=FULL + ("n3",), max_unavailable=2)
+    _group(store, "hi", "high", pending=1)
+    for i in range(2):      # two of lo's are on their way out for another gang
+        ledger_of(store).register(f"default/lo-gone{i}", "default/lo", "",
+                                  action="reclaim", for_gang="default/other")
+    return store, PREEMPT_CONF, {("preempt", "default/hi", None)}
+
+
+def _reclaim_after_a_preempt_that_committed():
+    store = _cluster(6, queues=[("qa", 1, True), ("qb", 1, True),
+                                ("qc", 1, True)])
+    _group(store, "a", "low", on=FULL + ("n3",), queue="qa", max_unavailable=8)
+    _group(store, "b", "low", on=("n4", "n4", "n5", "n5"), queue="qb",
+           max_unavailable=8)
+    _group(store, "hi", "high", pending=1, queue="qb")
+    _group(store, "w", "low", pending=1, queue="qc")
+    return store, BOTH_CONF, {("preempt", "default/hi", 1),
+                              ("reclaim", "default/w", 1)}
+
+
+def _reclaim_after_a_preempt_that_found_nothing():
+    store = _cluster(queues=[("qa", 1, True), ("qb", 1, True)])
+    _group(store, "a", "low", on=FULL + ("n3",), queue="qa", max_unavailable=8)
+    _group(store, "w", "low", pending=1, queue="qb")
+    return store, BOTH_CONF, {("preempt", "default/w", None),
+                              ("reclaim", "default/w", 1)}
+
+
+EQUIVALENCE = {f.__name__[1:]: f for f in (
+    _elastic_gang_with_running_pods_of_its_own,
+    _a_victim_job_whose_queue_was_deleted,
+    _ledger_entries_in_flight_use_up_the_budget,
+    _reclaim_after_a_preempt_that_committed,
+    _reclaim_after_a_preempt_that_found_nothing)}
+# tables built in the first cycle that plans, where the case is about that
+TABLES = {"reclaim_after_a_preempt_that_committed": 2,
+          "reclaim_after_a_preempt_that_found_nothing": 1}
+
+
+@pytest.mark.parametrize("case", list(EQUIVALENCE) + ["the_toy_cell_that_evicts"])
+def test_the_table_path_plans_what_the_per_gang_construction_planned(
+        case, shadowed, request):
+    """Plan for plan (``shadowed``), for every gang the loops try."""
+    if case == "the_toy_cell_that_evicts":
+        from benchmark import run as bench_run
+        from benchmark.harness import cell as cell_mod
+
+        cell = cell_mod.load_cell(
+            "toypre.pre", request.getfixturevalue("toy_preempt"))
+        result = bench_run.run(cell, seed=2**31 + 50, seconds=0.5, trace=False)
+        assert result["correct"] is True, result
+        assert {a for a, _uid, _n in shadowed} == {"preempt", "reclaim"}
+        assert sum(n or 0 for _a, _uid, n in shadowed) >= 8
+        assert any(n is None for _a, _uid, n in shadowed)
+        return
+    store, conf, expected = EQUIVALENCE[case]()
+    records = _plan_records(store, conf, cycles=2)
+    assert expected <= set(shadowed), shadowed
+    first = [r["whatif"] for r in records if r["whatif"] is not None][0]
+    assert first["tables_built"] == TABLES.get(case, 1), first
+    if case == "ledger_entries_in_flight_use_up_the_budget":
+        assert first["outcome"] == "rejected-budget" and first["rejected"] == 1
+    store.close()
+
+
+# ------------------------------------------------ the host gate (PR 50)
+
+
+@pytest.mark.parametrize("reclaimable", [True, False],
+                         ids=["other-reclaimable", "other-not"])
+@pytest.mark.parametrize("lower", ["own-queue", "other-queue", "nowhere"])
+@pytest.mark.parametrize("action", ["preempt", "reclaim"])
+def test_the_gate_says_no_only_where_the_kernel_finds_no_victim(
+        monkeypatch, action, lower, reclaimable):
+    """A high gang waits in ``qa``; the cluster is full of ``qa``'s and
+    ``qb``'s Running pods, ``qb`` over its share, and a lower class runs
+    in the gang's queue, only in the other, or nowhere.  On the cycle's
+    own table the gate is the kernel's ``eligible.any()`` wherever it
+    says no, and it says no wherever integers alone can."""
+    monkeypatch.setenv("VOLCANO_TPU_EVICT_DEVICE", "1")
+    store = _cluster(queues=[("qa", 1, True), ("qb", 1, reclaimable)])
+    own, other = {"own-queue": ("low", "high"), "other-queue": ("high", "low"),
+                  "nowhere": ("high", "high")}[lower]
+    _group(store, "ra", own, on=FULL[:3], queue="qa", max_unavailable=8)
+    _group(store, "rb", other, on=FULL[3:] + ("n3",), queue="qb",
+           max_unavailable=8)
+    _group(store, "hi", "high", pending=1, queue="qa")
+    seen = []
+
+    def spy(cyc, act):
+        cyc._flush_aggr()
+        jrow = cyc.m.j_row["default/hi"]
+        prio = np.int32(cyc.m.j_prio[jrow])
+        queue = np.int32(cyc.q_of_job[jrow])
+        mode = np.int32(vk.RECLAIM if act == "reclaim" else vk.PREEMPT)
+        tbl = whatif.VictimTable(cyc)
+        planes = vk.victim_scores(
+            tbl.v_ok & (tbl.vjobs != jrow), tbl.v_jprio, tbl.v_crank,
+            tbl.v_tie, tbl.v_queue, tbl.v_node, tbl.v_req, prio, queue,
+            tbl.q_alloc, tbl.q_deserved, tbl.q_rec, mode, tbl.node_zero)
+        seen.append((act, vk.may_be_eligible(tbl.q_minprio, tbl.q_rec, mode,
+                                             prio, queue),
+                     bool(np.asarray(planes.eligible).any())))
+
+    monkeypatch.setattr(whatif, "run_evict_action", spy)
+    Scheduler(store, conf_str=PREEMPT_CONF.replace("preempt", action)).run_once()
+    (act, gate, eligible), = seen
+    assert act == action and (gate or not eligible)
+    if action == "preempt":
+        assert gate == eligible == (lower == "own-queue")
+    else:       # qb holds 20 cpu of the 32 and deserves 16
+        assert gate == eligible == reclaimable
+    store.close()
+
+
+def test_waiting_gangs_of_the_lowest_class_reach_no_kernel(monkeypatch):
+    """N gangs of class ``low`` wait on a cluster full of ``low``: preempt
+    tries each, builds one table for all of them and calls no kernel."""
+    monkeypatch.setenv("VOLCANO_TPU_EVICT_DEVICE", "1")
+    store = _cluster()
+    _group(store, "lo", "low", on=FULL + ("n3",), max_unavailable=8)
+    for i in range(3):
+        _group(store, f"w{i}", "low", pending=1)
+    calls = []
+    monkeypatch.setattr(vk, "victim_scores", lambda *a: calls.append(a))
+    rec, = _plan_records(store, PREEMPT_CONF, cycles=1)
+    assert rec["whatif"] == dict.fromkeys(whatif.WALK_COUNTS, 0) | {
+        "gangs_tried": 3, "tables_built": 1}
+    assert [s["name"] for s in rec["spans"]
+            if s["name"].startswith("plan:")] == ["plan:victims"] * 3
+    assert not calls and not store.evictor.evicts
+    assert not whatif._streak_maps(store)[1], "a gated try sets no backoff"
     store.close()

@@ -140,12 +140,18 @@ HOT_REGISTRY: Dict[str, List[HotEntry]] = {
         HotEntry("commit_plan"),
         HotEntry("_plan_evict"),
         HotEntry("_plan_evict_gang"),
+        # The victim table a plan try reads (ISSUE 50): built once per
+        # state of the mirror, from host arrays alone.
+        HotEntry("VictimTable.__init__"),
         HotEntry("run_evict_action"),
     ],
     "volcano_tpu/ops/victim.py": [
-        # The jitted victim-selection kernel (a VCL201 taint source)
-        # and the host-only greedy selection over its fetched planes.
+        # The jitted victim-selection kernel (a VCL201 taint source),
+        # the host gate asked before it and the host-only greedy
+        # selection over its fetched planes.
         HotEntry("victim_scores"),
+        HotEntry("queue_min_prio"),
+        HotEntry("may_be_eligible"),
         HotEntry("select_victims"),
         HotEntry("fit_counts"),
         HotEntry("queue_shares"),

@@ -27,11 +27,14 @@ single cycle:
   resolves a PodGroup's ceiling (``PodGroup.max_unavailable``, else the
   ``VOLCANO_TPU_REBALANCE_MAX_UNAVAIL`` default); the ledger's
   ``disrupted`` count (victims whose restored pod is not yet bound)
-  is charged against it both at plan time and at commit re-check.
+  is charged against it both at plan time and at commit re-check
+  (``BudgetsLeft``: what is left of every group's, from one pass of the
+  ledger).
 """
 
 from __future__ import annotations
 
+import collections
 import copy
 import logging
 import os
@@ -207,6 +210,14 @@ class MigrationLedger:
         return sum(1 for e in self.entries.values()
                    if e.group_uid == group_uid)
 
+    def disrupted_by_group(self, store) -> Dict[str, int]:
+        """``disrupted`` of every group that has an entry, in one
+        ``prune`` and one pass of the ledger; a group without an entry
+        is not in it (its count is 0)."""
+        self.prune(store)
+        return dict(collections.Counter(
+            e.group_uid for e in self.entries.values()))
+
     def active(self, store, action: Optional[str] = None) -> bool:
         """True while any migration is incomplete — the rebalance
         planner runs one migration wave at a time.  ``action`` filters
@@ -228,6 +239,23 @@ class MigrationLedger:
         self.prune(store)
         return any(e.for_gang == gang_uid and e.restored_uid is None
                    for e in self.entries.values())
+
+
+class BudgetsLeft:
+    """Remaining per-PodGroup disruption budget by mirror job row, after
+    waves already in flight across EVERY action sharing the ledger: the
+    ledger is counted once, a group's ceiling read when asked.  ``get``
+    is all of a mapping that ``ops.victim.select_victims`` calls."""
+
+    def __init__(self, store, mirror):
+        ledger = store.migrations
+        self._m = mirror
+        self._used = ({} if ledger is None
+                      else ledger.disrupted_by_group(store))
+
+    def get(self, jrow, default=0):
+        return (max_unavailable_of(self._m.j_pg[jrow])
+                - self._used.get(self._m.j_uid[jrow], 0))
 
 
 def ledger_of(store) -> MigrationLedger:

@@ -818,6 +818,7 @@ class FastCycle:
                 sp.args = self._proportion()
         self.new_conditions: Dict[int, PodGroupCondition] = {}
         self._evictor = None
+        self._victim_table = None  # whatif.VictimTable, one mirror state's
         # Async bind batches commit collects; dispatched at cycle end so
         # the dispatcher thread's drain (binder RPCs, Scheduled events)
         # does not contend the GIL with commit/close — in the reference

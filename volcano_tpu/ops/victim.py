@@ -19,6 +19,10 @@ allocate jit before anything is evicted.
   priority; reclaim gates to OTHER queues that are ``Reclaimable`` and
   currently over their deserved share.  Critical (conformance-exempt)
   pods are excluded on both paths.
+- ``queue_min_prio`` / ``may_be_eligible`` — the host gate a planner
+  asks before it calls the kernel at all: whether ``eligible`` can be
+  non-empty for a preemptor, from one reduction of the victim rows by
+  queue.  Exact (integers and booleans); the share test is not in it.
 - ``select_victims`` — the deterministic host-side greedy over the
   fetched planes: victims taken in kernel order, each charged against
   its PodGroup's remaining disruption budget and its job's gang floor
@@ -37,7 +41,8 @@ require exact agreement (tests/test_whatif_preempt.py).
 
 from __future__ import annotations
 
-from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import (Dict, Hashable, List, Mapping, NamedTuple, Optional,
+                    Sequence)
 
 import jax
 import jax.numpy as jnp
@@ -129,6 +134,36 @@ def victim_scores(v_ok, v_jprio, v_crank, v_tie, v_queue, v_node, v_req,
                         evictable=evictable, q_share=q_share)
 
 
+# ``queue_min_prio`` of a queue without a valid victim row.
+NO_ROW = np.iinfo(np.int64).max
+
+
+def queue_min_prio(v_ok: np.ndarray, v_jprio: np.ndarray,
+                   v_queue: np.ndarray, n_queues: int) -> np.ndarray:
+    """[Q] i64 least job priority over each queue's ``v_ok`` rows,
+    ``NO_ROW`` where it has none: all that ``may_be_eligible`` reads of
+    the victim rows, one reduction for every preemptor."""
+    out = np.full(n_queues, NO_ROW)
+    ok = np.asarray(v_ok, bool)
+    np.minimum.at(out, v_queue[ok], v_jprio[ok])
+    return out
+
+
+def may_be_eligible(q_minprio: np.ndarray, q_reclaimable: np.ndarray,
+                    mode: int, p_prio: int, p_queue: int) -> bool:
+    """Host gate before ``victim_scores``, in integer and boolean
+    compares alone: False only where the kernel's ``eligible`` is empty
+    for this preemptor.  Preempt needs a valid row of its queue at
+    strictly lower job priority; reclaim a valid row in ANOTHER queue
+    that is Reclaimable.  Whether that queue is over its share (a float32
+    division) stays the kernel's to say: ``queue_shares`` is no part of
+    the verdict."""
+    if mode == PREEMPT:
+        return bool(q_minprio[p_queue] < p_prio)
+    return bool(np.delete((q_minprio < NO_ROW) & q_reclaimable,
+                          p_queue).any())
+
+
 def fit_counts(plane: np.ndarray, prof_req: np.ndarray,
                eps: np.ndarray) -> np.ndarray:
     """[N] whole gang tasks each node row of ``plane`` can host: per
@@ -166,7 +201,7 @@ def select_victims(
     v_node: np.ndarray,
     v_req: np.ndarray,
     v_job: np.ndarray,
-    v_group: Sequence[str],
+    v_group: Sequence[Hashable],
     v_queue: np.ndarray,
     need: int,
     idle: np.ndarray,
@@ -175,7 +210,7 @@ def select_victims(
     eps: np.ndarray,
     j_ready: np.ndarray,
     j_minav: np.ndarray,
-    budget_left: Dict[str, int],
+    budget_left: Mapping[Hashable, int],
     cap: int,
     q_alloc: Optional[np.ndarray] = None,
     q_deserved: Optional[np.ndarray] = None,
@@ -195,6 +230,12 @@ def select_victims(
     fit never improved are pruned (their slot never completed — the
     eviction would free nothing the gang can use).  Mutates none of its
     inputs.
+
+    ``v_group`` names each victim's PodGroup by any hashable key (a uid,
+    or the job row itself: the planner passes ``v_job``).  Of
+    ``budget_left`` only ``get(group, 0)`` is called, and only where a
+    victim comes to the budget test, so a mapping that works a group's
+    budget out when asked pays for the groups the walk meets.
     """
     order = np.asarray(order, np.int64)
     eligible = np.asarray(eligible, bool)
@@ -204,32 +245,31 @@ def select_victims(
     idle = np.asarray(idle, F)
     ev = np.asarray(evictable, F)
 
-    touched = np.unique(v_node[eligible]) if eligible.any() else \
-        np.zeros(0, np.int64)
-    fit0: Dict[int, int] = {}
-    gain_ok: Dict[int, bool] = {}
-    if len(touched):
-        base = fit_counts(idle[touched], prof_req, eps)
-        drained = fit_counts(idle[touched] + ev[touched], prof_req, eps)
-        for i, n in enumerate(touched.tolist()):
-            fit0[n] = int(base[i])
-            gain_ok[n] = bool(drained[i] > base[i])
+    # Ineligible rows are sorted to the tail of ``order``: the walk is
+    # over the eligible prefix, and only that much becomes a list.
+    ranked = order[:int(np.count_nonzero(eligible))].tolist()
+    touched = np.unique(v_node[eligible])
+    base = fit_counts(idle[touched], prof_req, eps)
+    drained = fit_counts(idle[touched] + ev[touched], prof_req, eps)
+    fit0: Dict[int, int] = dict(zip(touched.tolist(), base.tolist()))
+    gain_ok = set(touched[drained > base].tolist())
 
-    def walk(budgets: Dict[str, int]):
+    def walk(left):
+        """One greedy pass; ``left(group)`` is the group's budget before
+        it, ``spent`` what the pass has charged."""
         freed: Dict[int, np.ndarray] = {}
         cur_fit: Dict[int, int] = {}
         occupancy: Dict[int, int] = {}
+        spent: Dict[Hashable, int] = {}
         qa = None if q_alloc is None else np.array(q_alloc, F)
         chosen: List[int] = []
         gain = 0
         skipped_budget = False
-        for idx in order.tolist():
-            if not eligible[idx]:
-                break  # ineligible rows are sorted to the tail
+        for idx in ranked:
             if gain >= need or len(chosen) >= cap:
                 break
             n = int(v_node[idx])
-            if not gain_ok.get(n, False):
+            if n not in gain_ok:
                 continue
             j = int(v_job[idx])
             cnt = occupancy.get(j)
@@ -239,7 +279,7 @@ def select_victims(
             if not (minav <= cnt - 1 or minav == 1):
                 continue  # gang tier: job would drop below minAvailable
             g = v_group[idx]
-            if budgets.get(g, 0) < 1:
+            if left(g) - spent.get(g, 0) < 1:
                 skipped_budget = True
                 continue
             if qa is not None:
@@ -258,7 +298,7 @@ def select_victims(
                     continue  # queue would drop below deserved
                 qa[q] = qa[q] - v_req[idx]
             occupancy[j] = cnt - 1
-            budgets[g] = budgets.get(g, 0) - 1
+            spent[g] = spent.get(g, 0) + 1
             f = freed.get(n)
             if f is None:
                 f = freed[n] = np.zeros(v_req.shape[1], F)
@@ -276,7 +316,7 @@ def select_victims(
             chosen = [i for i in chosen if int(v_node[i]) not in dead]
         return chosen, gain, skipped_budget
 
-    chosen, gain, skipped = walk(dict(budget_left))
+    chosen, gain, skipped = walk(lambda g: budget_left.get(g, 0))
     if gain >= need:
         return VictimSelection(chosen=chosen, feasible=True,
                                budget_blocked=False, gain=gain)
@@ -285,8 +325,7 @@ def select_victims(
         # Label the outcome honestly: budgets blocked the plan only if
         # the same greedy with unlimited budgets (same cap, same gang
         # floors, same queue slack) would have covered the need.
-        inf = {g: 1 << 30 for g in set(v_group)}
-        _, ugain, _ = walk(inf)
+        _, ugain, _ = walk(lambda g: 1 << 30)
         blocked = ugain >= need
     return VictimSelection(chosen=[], feasible=False,
                            budget_blocked=blocked, gain=gain)

@@ -64,8 +64,9 @@ ACTIONS = ("preempt", "reclaim", "rebalance")
 # outcomes ``committed`` and ``rejected-*`` and the victims of the
 # committed plans, ``prior`` included (docs/tracing.md; a plan that was
 # rejected or voided took none: its size is in its ``whatif_solve``
-# span's ``args``).
-WALK_COUNTS = ("gangs_tried", "committed", "rejected", "victims")
+# span's ``args``), victim tables built, tries that ran ``victim_scores``.
+WALK_COUNTS = ("gangs_tried", "committed", "rejected", "victims",
+               "tables_built", "kernel_calls")
 
 
 def _env_int(name: str, default: int) -> int:
@@ -641,44 +642,69 @@ def _gang_profile_table(cyc, jrow: int):
     return gang_rows, prof_req
 
 
-def _victim_base(cyc, gang_jrow: int) -> np.ndarray:
-    """Mirror rows eligible as wave victims BEFORE tier gating: Running
+class VictimTable:
+    """What a plan try reads of the Running residents, the same for
+    every gang: the base victim rows BEFORE tier gating (Running
     residents with requests, not critical (conformance), without
-    required inter-pod terms (their drain patches resident-derived
-    counts conservatively), never the starved gang itself."""
-    from .api import TaskStatus
+    required inter-pod terms — their drain patches resident-derived
+    counts conservatively — of a known job; a gang's own among them,
+    which its try masks in ``v_ok``), ``victim_scores``'s padded columns
+    and queue planes over them and ``may_be_eligible``'s reduction.  It
+    serves one ``(cycle, m.mutation_seq)``: ``commit_plan`` stamps the
+    counter when it evicts, so ``reclaim`` after a ``preempt`` that
+    committed builds its own."""
 
-    m = cyc.m
-    Pn = cyc.Pn
-    st_running = int(TaskStatus.Running)
-    vict = np.flatnonzero(
-        cyc.resident[:Pn]
-        & (m.p_status[:Pn] == st_running)
-        & ~m.p_critical[:Pn]
-        & ~m.p_has_ip[:Pn]
-        & (cyc.jobr >= 0)
-        & (cyc.jobr != gang_jrow)
-    )
-    if len(vict):
-        vict = vict[m.c_req.lens(vict) > 0]
-    return vict.astype(np.int64)
+    # holds: _lock
+    def __init__(self, cyc):
+        from .api import TaskStatus
+        from .fastpath import _pow2
+        from .ops.victim import queue_min_prio
 
+        m, Pn = cyc.m, cyc.Pn
+        self.seq = m.mutation_seq
+        vict = np.flatnonzero(
+            cyc.resident[:Pn]
+            & (m.p_status[:Pn] == int(TaskStatus.Running))
+            & ~m.p_critical[:Pn] & ~m.p_has_ip[:Pn] & (cyc.jobr >= 0))
+        self.vict = vict = vict[m.c_req.lens(vict) > 0].astype(np.int64)
+        V, Qp = len(vict), _pow2(max(cyc.Qn, 1), 4)
+        Vp = _pow2(V)
 
-def _budget_left(cyc, groups) -> Dict[str, int]:
-    """Remaining per-PodGroup disruption budget after waves already in
-    flight, across EVERY action sharing the ledger."""
-    from .actions.rebalance import max_unavailable_of
+        def padded(values, dtype, fill=0):
+            out = np.full(Vp, fill, dtype)
+            out[:V] = values
+            return out
 
-    m = cyc.m
-    ledger = cyc.store.migrations
-    out: Dict[str, int] = {}
-    for uid in set(groups):
-        row = m.j_row.get(uid, -1)
-        pg = m.j_pg[row] if row >= 0 else None
-        used = (ledger.disrupted(cyc.store, uid)
-                if ledger is not None else 0)
-        out[uid] = max_unavailable_of(pg) - used
-    return out
+        vjobs = cyc.jobr[vict].astype(np.int64)
+        vq = cyc.q_of_job[vjobs]
+        self.vjobs = padded(vjobs, np.int64, -1)
+        # A victim whose job has no known queue (q_of_job == -1: its
+        # queue was deleted) has no share to gate on — excluded at the
+        # base level rather than letting the kernel's index clip alias
+        # it onto queue 0 (the oracle requires 0 <= q < Q the same way).
+        self.v_ok = padded(vq >= 0, bool)
+        self.v_jprio = padded(m.j_prio[vjobs], I)
+        # Creation rank: larger = younger (evicted first among equals).
+        crank = np.empty(V, I)
+        crank[np.argsort(m.p_create[vict], kind="stable")] = np.arange(V)
+        self.v_crank = padded(crank, I)
+        self.v_tie = np.arange(Vp, dtype=I)
+        self.v_queue = padded(vq, I)
+        self.v_node = padded(m.p_node[:Pn][vict], I)
+        self.v_req = np.zeros((Vp, cyc.R), F)
+        er, si, vv = m.c_req.gather(vict)
+        self.v_req[er, si] = vv
+        self.q_alloc = np.zeros((Qp, cyc.R), F)
+        self.q_alloc[:cyc.Qn] = cyc.q_alloc
+        self.q_deserved = np.full((Qp, cyc.R), 3.0e38, F)
+        self.q_deserved[:cyc.Qn] = cyc.q_deserved
+        self.q_rec = np.zeros(Qp, bool)
+        for name, qi in cyc.queue_index.items():
+            q = cyc.store.queues.get(name)
+            self.q_rec[qi] = bool(q is not None and q.reclaimable())
+        self.node_zero = np.zeros((_pow2(max(cyc.Nn, 1)), cyc.R), F)
+        self.q_minprio = queue_min_prio(
+            self.v_ok, self.v_jprio, self.v_queue, Qp)
 
 
 # holds: _lock
@@ -740,94 +766,59 @@ def _plan_evict(cyc, action: str) -> Optional[WhatIfPlan]:
 # holds: _lock
 def _plan_evict_gang(cyc, action: str, jrow: int) -> Optional[WhatIfPlan]:
     """One gang's plan, in three phases under the action's plan span:
-    ``plan:victims`` (the gang's profile table, the base victim set and
-    the host victim table, up to the kernel's arguments),
-    ``plan:scores`` (the jitted kernel and the wait for its planes) and
-    ``plan:select`` (the host greedy and what follows it)."""
+    ``plan:victims`` (the victim table fetched or built, the host gate
+    and, past it, the gang's profile table), ``plan:scores`` (the kernel
+    and the wait for its planes) and ``plan:select`` (where a victim is
+    eligible: the host greedy and what follows it)."""
     import jax
 
-    from .fastpath import _pow2
+    from .actions.rebalance import BudgetsLeft
     from .ops import victim as vk
 
-    m = cyc.m
-    store = cyc.store
-    span = cyc.tracer.span
-    is_reclaim = action == "reclaim"
+    m, span = cyc.m, cyc.tracer.span
+    mode = np.int32(vk.RECLAIM if action == "reclaim" else vk.PREEMPT)
     with span("plan:victims", cat="whatif"):
         need = int(m.j_minav[jrow] - cyc.j_ready_base[jrow])
         if need <= 0:
             return None
+        tbl = cyc._victim_table
+        if tbl is None or tbl.seq != m.mutation_seq:
+            tbl = cyc._victim_table = VictimTable(cyc)
+            cyc.stats["whatif"]["tables_built"] += 1
+        gang_prio = np.int32(m.j_prio[jrow])
+        gang_queue = np.int32(cyc.q_of_job[jrow])
+        if not vk.may_be_eligible(tbl.q_minprio, tbl.q_rec, mode,
+                                  gang_prio, gang_queue):
+            return None
         gang_rows, prof_req = _gang_profile_table(cyc, jrow)
         if prof_req is None:
             return None
-        vict = _victim_base(cyc, jrow)
-        if not len(vict):
-            return None
-        V = len(vict)
-        Vp = _pow2(V)
-        Np = _pow2(max(cyc.Nn, 1))
-        Qp = _pow2(max(cyc.Qn, 1), 4)
-        v_ok = np.zeros(Vp, bool)
-        v_ok[:V] = True
-        v_jprio = np.zeros(Vp, I)
-        v_crank = np.zeros(Vp, I)
-        v_tie = np.arange(Vp, dtype=I)
-        v_queue = np.zeros(Vp, I)
-        v_node = np.zeros(Vp, I)
-        v_req = np.zeros((Vp, cyc.R), F)
-        vjobs = cyc.jobr[vict].astype(np.int64)
-        # A victim whose job has no known queue (q_of_job == -1: its
-        # queue was deleted) has no share to gate on — exclude it at
-        # the base level rather than letting the kernel's index clip
-        # alias it onto queue 0 (the oracle requires 0 <= q < Q the
-        # same way).
-        v_ok[:V] = cyc.q_of_job[vjobs] >= 0
-        v_jprio[:V] = m.j_prio[vjobs]
-        # Creation rank: larger = younger (evicted first among equals).
-        v_crank[:V] = np.argsort(
-            np.argsort(m.p_create[vict], kind="stable")).astype(I)
-        v_queue[:V] = cyc.q_of_job[vjobs]
-        v_node[:V] = m.p_node[:cyc.Pn][vict]
-        er, si, vv = m.c_req.gather(vict)
-        v_req[er, si] = vv
-        q_alloc_p = np.zeros((Qp, cyc.R), F)
-        q_des_p = np.full((Qp, cyc.R), 3.0e38, F)
-        q_alloc_p[:cyc.Qn] = cyc.q_alloc
-        q_des_p[:cyc.Qn] = cyc.q_deserved
-        q_rec = np.zeros(Qp, bool)
-        for name, qi in cyc.queue_index.items():
-            q = store.queues.get(name)
-            q_rec[qi] = bool(q is not None and q.reclaimable())
-        gang_prio = int(m.j_prio[jrow])
-        gang_queue = int(cyc.q_of_job[jrow])
     with span("plan:scores", cat="whatif"):
+        cyc.stats["whatif"]["kernel_calls"] += 1
+        # Never the starved gang itself: its own rows leave ``v_ok``
+        # (ineligible either way: its own priority, its own queue).
         planes = vk.victim_scores(
-            v_ok, v_jprio, v_crank, v_tie, v_queue, v_node, v_req,
-            np.int32(gang_prio), np.int32(gang_queue),
-            q_alloc_p, q_des_p, q_rec,
-            np.int32(vk.RECLAIM if is_reclaim else vk.PREEMPT),
-            np.zeros((Np, cyc.R), F),
+            tbl.v_ok & (tbl.vjobs != jrow), tbl.v_jprio, tbl.v_crank,
+            tbl.v_tie, tbl.v_queue, tbl.v_node, tbl.v_req,
+            gang_prio, gang_queue, tbl.q_alloc, tbl.q_deserved, tbl.q_rec,
+            mode, tbl.node_zero,
         )
         eligible, order, evictable = jax.device_get(
             (planes.eligible, planes.order, planes.evictable))
     with span("plan:select", cat="whatif"):
-        if not bool(eligible[:V].any()):
+        if not bool(eligible.any()):
             return None
-        groups = [m.j_uid[int(j)] for j in vjobs]
-        v_group = groups + [""] * (Vp - V)
-        budget_left = _budget_left(cyc, groups)
-        qa_sel = qd_sel = None
-        if is_reclaim:
-            qa_sel = cyc.q_alloc.astype(F)
-            qd_sel = cyc.q_deserved.astype(F)
-        idle_p = np.zeros((Np, cyc.R), F)
-        idle_p[:cyc.Nn] = cyc.n_idle.astype(F)
-        v_job_p = np.concatenate([vjobs, np.full(Vp - V, -1, np.int64)])
+        qa, qd = ((cyc.q_alloc, cyc.q_deserved) if action == "reclaim"
+                  else (None, None))
+        idle_p = tbl.node_zero.copy()
+        idle_p[:cyc.Nn] = cyc.n_idle
+        # A victim's group is its job row; pad rows are never reached.
         sel = vk.select_victims(
-            order, eligible, v_node, v_req, v_job_p,
-            v_group, v_queue, need, idle_p, evictable, prof_req,
-            cyc.eps, cyc.j_ready_base, m.j_minav, budget_left,
-            evict_cap(), q_alloc=qa_sel, q_deserved=qd_sel,
+            order, eligible, tbl.v_node, tbl.v_req, tbl.vjobs,
+            tbl.vjobs, tbl.v_queue, need, idle_p, evictable, prof_req,
+            cyc.eps, cyc.j_ready_base, m.j_minav,
+            BudgetsLeft(cyc.store, m), evict_cap(),
+            q_alloc=qa, q_deserved=qd,
         )
         uid = m.j_uid[jrow]
         if not sel.feasible:
@@ -836,18 +827,18 @@ def _plan_evict_gang(cyc, action: str, jrow: int) -> Optional[WhatIfPlan]:
                            gang=uid, need=need)
             # Cooldown either way: no wave can form until the cluster
             # moves, so re-scoring every cycle is waste.
-            set_backoff(store, action, uid, cyc.REBALANCE_REJECT_BACKOFF)
+            set_backoff(cyc.store, action, uid,
+                        cyc.REBALANCE_REJECT_BACKOFF)
             return None
         chosen = np.asarray(sel.chosen, np.int64)
-        victim_rows = vict[chosen]
-        victim_jobs = vjobs[chosen]
+        victim_jobs = tbl.vjobs[chosen]
         budgets: Dict[str, int] = {}
         for j in victim_jobs.tolist():
-            g = m.j_uid[int(j)]
+            g = m.j_uid[j]
             budgets[g] = budgets.get(g, 0) + 1
         return WhatIfPlan(
             action=action, gang_job=jrow, gang_uid=uid,
-            gang_rows=gang_rows, victim_rows=victim_rows,
+            gang_rows=gang_rows, victim_rows=tbl.vict[chosen],
             victim_jobs=victim_jobs,
             drain_nodes=np.zeros(0, np.int64), need=need,
             frag_before=0.0, budgets=budgets, resolve_victims=False,
