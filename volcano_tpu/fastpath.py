@@ -1146,6 +1146,7 @@ class FastCycle:
                 "aff_prof_entries": 0, "aff_cnt0_entries": 0,
                 "aff_host_dense_bytes": 0,
                 "shortlist_fb_affinity": 0, "shortlist_fb_exhausted": 0,
+                "shortlist_rows": 0, "shortlist_keys": 0,
                 "aff_count_reads": 0,
             }
         return sc
@@ -1299,6 +1300,13 @@ class FastCycle:
             self.stats["mesh_shards"] = shards
             # Into the record's ``solve`` block too.
             self._solve_counts()["mesh_shards"] = shards
+        # Profile rows the coarse shortlist served and the distinct
+        # scoring keys it ranked for them (ops/wave.shortlist_keys): the
+        # cycle's largest solve is kept.
+        sc = self._solve_counts()
+        if info.get("shortlist_rows", 0) >= sc["shortlist_rows"]:
+            sc["shortlist_rows"] = int(info["shortlist_rows"])
+            sc["shortlist_keys"] = int(info["shortlist_keys"])
         terms = info.get("terms")
         if terms:
             # How the inter-pod term data crossed to this solve: its

@@ -22,14 +22,19 @@ fallback and the ``VOLCANO_TPU_DEVINCR`` kill switch):
    rebuilds them wholesale.
 
 2. **Warm-started shortlists** — the coarse pass retains per-block
-   (score, global node id) candidate lists ([U, B, klb], the
-   ``_topk_nodes`` two-stage structure at block granularity); on the
+   (score, global node id) candidate lists per key row ([K, B, klb], the
+   ``_topk_nodes`` two-stage structure at block granularity; ``K`` the
+   distinct scoring keys of the profile table, ``ops.wave
+   .shortlist_keys``: 8 B a candidate, so 32 keys x 16 blocks x 819 are
+   3.4 MB at 10,000 nodes where a list per profile row, 4,096 of them
+   under inter-pod terms, was 429 MB); on the
    next solve only blocks containing a dirty node row re-rank
    (``_warm_shortlist``), and the winners merge exactly like the full
    pass.  The caller proves the dirty superset via ``begin_solve``;
    any invalidation that can't be proven (cache key drift, dirty
    overflow, affinity-count content change — the cnt0 token rides the
-   warm key) re-ranks fully, and the fine phase's full-N fallback still
+   warm key — or another key set: the keys' digest rides it too)
+   re-ranks fully, and the fine phase's full-N fallback still
    guarantees no binding is ever lost to pruning.
 
 3. **Null-delta fast cycles** — ``skip_token`` (written by the fast
@@ -95,7 +100,8 @@ class DeviceIncremental:
         self._static: Optional[Tuple] = None  # (ok [U,C], score [U,C])
         # --- warm shortlist candidates ------------------------------
         self._warm_key = None
-        self._cand: Optional[Tuple] = None  # (cand_s, cand_i, sl)
+        # (cand_s [K, B, klb], cand_i [K, B, klb], sl [U, sl_k])
+        self._cand: Optional[Tuple] = None
         # --- host info for the CURRENT solve (begin_solve) ----------
         self._pend_static = None
         self._pend_warm = None
@@ -270,17 +276,25 @@ class DeviceIncremental:
         return self._static
 
     def shortlist(self, nodes, prof, extra_prof, score_prof, cls, aff,
-                  weights, eps, scalar_slot, sl_k: int, chunk: int,
+                  weights, eps, scalar_slot, key_rows, key_of,
+                  key_tok: str, sl_k: int, chunk: int,
                   features: tuple, cnt0_any: bool, cls_identity: bool,
                   mesh_shards: int, stat):
-        """The solve's [U, sl_k] shortlists: warm-started when the warm
+        """The solve's [U, sl_k] shortlists, ranked per key row
+        (``ops.wave.shortlist_keys``: ``key_rows``, ``key_of`` and the
+        digest of both, ``key_tok``): warm-started when the warm
         key held and the dirty-block fraction is low, full re-rank
         (seeding fresh candidates) otherwise.  Bit-identical to
-        ``_coarse_shortlist`` either way."""
+        ``_coarse_shortlist`` either way.  The candidates kept are the
+        key rows' ([K, B, klb]); ``key_tok`` rides the warm key, so a
+        warm pass never patches candidates ranked for another key set,
+        and the shortlist a null delta hands back is the one expanded
+        to these rows."""
         from . import wave as _w
 
         N = int(nodes.idle.shape[0])
-        U = int(prof.req.shape[0])
+        U = int(key_of.shape[0])
+        K = int(key_rows.shape[0])
         n_sh = max(1, int(mesh_shards))
         B = max(warm_blocks(), n_sh)
         # Scale-tier growth: bound rows per block so the per-dirty-node
@@ -297,7 +311,7 @@ class DeviceIncremental:
         B = max(B, 1)
         nlb = N // B
         klb = min(sl_k, nlb)
-        meta = (self._place_tok, U, N, B, klb, int(sl_k),
+        meta = (self._place_tok, U, K, key_tok, N, B, klb, int(sl_k),
                 tuple(features), bool(cnt0_any), bool(cls_identity),
                 n_sh, stat is not None)
         key = ((self._pend_warm, meta)
@@ -330,8 +344,8 @@ class DeviceIncremental:
                 cand_s, cand_i, _sl = self._cand
                 sl, cand_s, cand_i = _w._warm_shortlist(
                     nodes, prof, extra_prof, score_prof, cls, aff,
-                    weights, eps, scalar_slot, stat_ok, stat_sc,
-                    self._place(db), cand_s, cand_i,
+                    weights, eps, scalar_slot, key_rows, key_of,
+                    stat_ok, stat_sc, self._place(db), cand_s, cand_i,
                     sl_k=int(sl_k), klb=klb, nlb=nlb, chunk=chunk,
                     features=tuple(features), cnt0_any=bool(cnt0_any),
                     cls_identity=bool(cls_identity),
@@ -345,7 +359,8 @@ class DeviceIncremental:
         # Full re-rank — also seeds the candidates for the next solve.
         sl, cand_s, cand_i = _w._coarse_shortlist(
             nodes, prof, extra_prof, score_prof, cls, aff, weights,
-            eps, scalar_slot, sl_k=int(sl_k), chunk=chunk,
+            eps, scalar_slot, key_rows, key_of, sl_k=int(sl_k),
+            chunk=chunk,
             features=tuple(features), cnt0_any=bool(cnt0_any),
             cls_identity=bool(cls_identity), mesh_shards=n_sh,
             n_blocks=B, with_cand=True, static_ext=stat is not None,

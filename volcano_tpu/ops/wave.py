@@ -589,6 +589,21 @@ def _topk_nodes(scores, k: int, n_shards: int = 1,
     return _merge_block_cands(loc_s, gid, k, n_shards)
 
 
+def _key_cols(key_rows, prof: SolveProfiles, extra_prof, score_prof,
+              *stat):
+    """What a shortlist pass reads of a profile row, at the key rows
+    ([K, ...] each): the thirteen profile columns, the custom plugins'
+    verdict and score rows ([K, 1] fillers where a solve has none) and
+    the rows of the static planes a caller hands in."""
+    K = key_rows.shape[0]
+    return tuple(a[key_rows] for a in prof) + (
+        jnp.ones((K, 1), bool) if extra_prof is None
+        else extra_prof[key_rows],
+        jnp.zeros((K, 1), jnp.float32) if score_prof is None
+        else score_prof[key_rows],
+    ) + tuple(a[key_rows] for a in stat)
+
+
 @partial(jax.jit, static_argnames=("sl_k", "chunk", "features",
                                    "cnt0_any", "cls_identity",
                                    "mesh_shards", "n_blocks",
@@ -597,6 +612,7 @@ def _topk_nodes(scores, k: int, n_shards: int = 1,
 def _coarse_shortlist(nodes: SolveNodes, prof: SolveProfiles, extra_prof,
                       score_prof, cls: NodeClasses, aff: AffinityArgs,
                       weights: ScoreWeights, eps, scalar_slot,
+                      key_rows, key_of,
                       sl_k: int, chunk: int, features: tuple,
                       cnt0_any: bool, cls_identity: bool,
                       mesh_shards: int = 1, n_blocks: int = 1,
@@ -604,12 +620,18 @@ def _coarse_shortlist(nodes: SolveNodes, prof: SolveProfiles, extra_prof,
                       stat_ok=None, stat_score=None, hier_pin: int = 0):
     """Phase 1 + shortlist selection of the two-phase solve.
 
-    Evaluates the wave-0-attempt-1 live mask + score for every profile
-    row over all N nodes ONCE (class-compacted statics, initial dynamic
-    state) and keeps each profile's top-``sl_k`` candidates, returned as
-    ``[U, sl_k]`` int32 node ids sorted ASCENDING — in-shortlist
-    rankings then break score ties by node index exactly like the full
-    path's top_k.  The masks are evaluated at solve-start state, which
+    Evaluates the wave-0-attempt-1 live mask + score for every KEY row
+    over all N nodes ONCE (class-compacted statics, initial dynamic
+    state) and keeps each key's top-``sl_k`` candidates.  A key row is
+    one of the profile rows that differ in what this pass reads of them
+    (``shortlist_keys``): ``key_rows`` [K] names one profile row per
+    key, ``key_of`` [U] each profile row's key.  Rows are evaluated
+    independently, so a row's ranking is its key's, and the result is
+    ``sl_keys[key_of]``: the ``[U, sl_k]`` int32 node ids, each row
+    sorted ASCENDING, that ranking every profile row would give, bit
+    for bit — in-shortlist rankings then break score ties by node index
+    exactly like the full path's top_k.  The masks are evaluated at
+    solve-start state, which
     within a solve only loses capacity/ports/pod slots and only gains
     affinity counts — so a node pruned here stays infeasible for every
     non-required-affinity feature, and required-affinity drift is what
@@ -622,28 +644,29 @@ def _coarse_shortlist(nodes: SolveNodes, prof: SolveProfiles, extra_prof,
     shortlist on attempt 1 and resolves through the fallback rescore,
     reaching the identical no-node outcome).
 
-    Profiles stream through ``lax.map`` in ``chunk`` rows so the
+    Key rows stream through ``lax.map`` in ``chunk`` rows so the
     [chunk, N, R] fit broadcast — the pass's only [*, N, R] tensor —
-    bounds device memory at hyperscale profile counts.
+    bounds device memory at hyperscale key counts.
 
     ``mesh_shards`` > 1 (the node axis is sharded over that many mesh
     devices) makes the candidate selection shard-local: each chip ranks
-    only its own node slice and the per-profile winners reduce across
+    only its own node slice and the per-key winners reduce across
     chips as (score, global node id) pairs (``_topk_nodes``) — the
     shortlist membership is bit-identical to the single-device pass.
 
     ``with_cand`` (the device-incremental lane, ISSUE 9) restructures
     the selection into per-block top-k + winner merge over ``n_blocks``
     ascending-id node blocks and ALSO returns the per-block candidate
-    lists ``(cand_s [U, B, klb], cand_i [U, B, klb])`` — the warm-start
-    state ``_warm_shortlist`` patches on later solves.  The selected
+    lists ``(cand_s [K, B, klb], cand_i [K, B, klb])``, per key row —
+    the warm-start state ``_warm_shortlist`` patches on later solves.
+    The selected
     SET is identical to the direct top-k (a global top-k element is a
     top-k element of its own block, and candidate positions order by
     (block, local rank) — ascending node id within any score class, the
     ``_topk_nodes`` argument), and the returned shortlist sorts
     ascending, so the array is bit-identical either way.  ``static_ext``
     takes the (profile x class) static planes as PARAMS (``stat_ok`` /
-    ``stat_score`` [U, C], chunk rows threaded through the profile
+    ``stat_score`` [U, C], their key rows threaded through the key
     stream) instead of evaluating ``_class_static`` in-kernel.
     """
     (has_ports, has_aff, has_taints, has_future, _has_overuse,
@@ -651,7 +674,8 @@ def _coarse_shortlist(nodes: SolveNodes, prof: SolveProfiles, extra_prof,
     f32 = jnp.float32
     bf = jnp.bfloat16
     N = nodes.idle.shape[0]
-    U = prof.req.shape[0]
+    K = key_rows.shape[0]
+    chunk = min(chunk, K)
     if cls_identity:
         cls = _identity_classes(nodes)
     # Initial dynamic node state, shared by every chunk.
@@ -734,31 +758,25 @@ def _coarse_shortlist(nodes: SolveNodes, prof: SolveProfiles, extra_prof,
         idx = _topk_nodes(masked, sl_k, mesh_shards, hier_pin)
         return jnp.sort(idx, axis=1).astype(jnp.int32)
 
-    ones_u = jnp.ones((U, 1), bool)
-    zeros_u = jnp.zeros((U, 1), f32)
-    cols = (
-        prof.req, prof.init_req, prof.ports, prof.sel_bits,
-        prof.aff_bits, prof.aff_terms, prof.tol_bits, prof.pref_bits,
-        prof.pref_w, prof.t_req_aff, prof.t_req_anti, prof.t_matches,
-        prof.t_soft,
-        extra_prof if has_extra else ones_u,
-        score_prof if has_extra_score else zeros_u,
+    cols = _key_cols(
+        key_rows, prof,
+        extra_prof if has_extra else None,
+        score_prof if has_extra_score else None,
+        *((stat_ok, stat_score) if static_ext else ()),
     )
-    if static_ext:
-        cols = cols + (stat_ok, stat_score)
-    if chunk >= U:
-        return body(cols)
-    resh = tuple(
-        a.reshape(U // chunk, chunk, *a.shape[1:]) for a in cols
-    )
-    out = jax.lax.map(body, resh)
+    if chunk >= K:
+        out = body(cols)
+    else:
+        out = jax.lax.map(body, tuple(
+            a.reshape(K // chunk, chunk, *a.shape[1:]) for a in cols
+        ))
     if with_cand:
         sl, cand_s, cand_i = out
         klb = cand_s.shape[-1]
-        return (sl.reshape(U, sl_k),
-                cand_s.reshape(U, n_blocks, klb),
-                cand_i.reshape(U, n_blocks, klb))
-    return out.reshape(U, sl_k)
+        return (sl.reshape(K, sl_k)[key_of],
+                cand_s.reshape(K, n_blocks, klb),
+                cand_i.reshape(K, n_blocks, klb))
+    return out.reshape(K, sl_k)[key_of]
 
 
 @partial(jax.jit, static_argnames=("sl_k", "klb", "nlb", "chunk",
@@ -768,6 +786,7 @@ def _coarse_shortlist(nodes: SolveNodes, prof: SolveProfiles, extra_prof,
 def _warm_shortlist(nodes: SolveNodes, prof: SolveProfiles, extra_prof,
                     score_prof, cls: NodeClasses, aff: AffinityArgs,
                     weights: ScoreWeights, eps, scalar_slot,
+                    key_rows, key_of,
                     stat_ok, stat_score, db_rows, cand_s, cand_i,
                     sl_k: int, klb: int, nlb: int, chunk: int,
                     features: tuple, cnt0_any: bool, cls_identity: bool,
@@ -779,24 +798,28 @@ def _warm_shortlist(nodes: SolveNodes, prof: SolveProfiles, extra_prof,
     ``db_rows`` is the [ndb] list of dirty block ids (padded with
     duplicates of the first — the scatter rewrites identical values, so
     padding is idempotent); ``cand_s``/``cand_i`` are the previous
-    solve's per-block candidates ([U, B, klb], produced by
+    solve's per-block candidates, per key row ([K, B, klb], produced by
     ``_coarse_shortlist`` with ``with_cand`` or by an earlier warm
-    pass).  The caller (``ops/devincr.DeviceIncremental``) proves every
-    node OUTSIDE the dirty blocks has byte-identical solve inputs to the
-    previous solve, so its retained candidates equal what a fresh
+    pass over the same ``key_rows``).  The caller
+    (``ops/devincr.DeviceIncremental``) proves every node OUTSIDE the
+    dirty blocks has byte-identical solve inputs to the previous solve,
+    and that the key rows are the previous solve's, so a key row's
+    retained candidates equal what a fresh
     ranking would produce and the merged shortlist is bit-identical to
     a full ``_coarse_shortlist`` over today's state.  Same formulas as
     the coarse body, evaluated on the gathered dirty-block node rows
-    ([U, ndb*nlb] instead of [U, N]).
+    ([K, ndb*nlb] instead of [K, N]).
 
-    Returns ``(shortlists [U, sl_k], cand_s, cand_i)`` — the updated
-    candidates are the next solve's warm state."""
+    Returns ``(shortlists [U, sl_k], cand_s, cand_i)``: the key rows'
+    shortlists at ``key_of``, and the updated candidates, the next
+    solve's warm state."""
     (has_ports, has_aff, has_taints, has_future, _has_overuse,
      _has_extra, _has_extra_score) = features
     f32 = jnp.float32
     bf = jnp.bfloat16
     N = nodes.idle.shape[0]
-    U = prof.req.shape[0]
+    K = key_rows.shape[0]
+    chunk = min(chunk, K)
     if cls_identity:
         cls = _identity_classes(nodes)
     ndb = db_rows.shape[0]
@@ -835,7 +858,7 @@ def _warm_shortlist(nodes: SolveNodes, prof: SolveProfiles, extra_prof,
          pref_bits, pref_w, t_req_aff, t_req_anti, t_matches,
          t_soft) = rowset[:13]
         if static_ext:
-            ok_c, score_c = rowset[13], rowset[14]
+            ok_c, score_c = rowset[15], rowset[16]
         else:
             ok_c, score_c = _class_static(
                 cls, sel_bits, aff_bits, aff_terms, tol_bits, pref_bits,
@@ -869,23 +892,21 @@ def _warm_shortlist(nodes: SolveNodes, prof: SolveProfiles, extra_prof,
         gid = loc_i.astype(jnp.int32) + db_rows[None, :, None] * nlb
         return loc_s, gid
 
-    cols = (
-        prof.req, prof.init_req, prof.ports, prof.sel_bits,
-        prof.aff_bits, prof.aff_terms, prof.tol_bits, prof.pref_bits,
-        prof.pref_w, prof.t_req_aff, prof.t_req_anti, prof.t_matches,
-        prof.t_soft,
+    # The coarse pass's columns (a warm solve has no custom-plugin rows:
+    # the two fillers keep the layout one).
+    cols = _key_cols(
+        key_rows, prof, None, None,
+        *((stat_ok, stat_score) if static_ext else ()),
     )
-    if static_ext:
-        cols = cols + (stat_ok, stat_score)
-    if chunk >= U:
+    if chunk >= K:
         s_new, i_new = body(cols)
     else:
         resh = tuple(
-            a.reshape(U // chunk, chunk, *a.shape[1:]) for a in cols
+            a.reshape(K // chunk, chunk, *a.shape[1:]) for a in cols
         )
         s_new, i_new = jax.lax.map(body, resh)
-        s_new = s_new.reshape(U, ndb, klb)
-        i_new = i_new.reshape(U, ndb, klb)
+        s_new = s_new.reshape(K, ndb, klb)
+        i_new = i_new.reshape(K, ndb, klb)
     # Patch the dirty blocks' candidates (duplicate padded block ids
     # rewrite identical values — idempotent) and merge winners exactly
     # like the coarse pass's with_cand tail: block->shard->global under
@@ -894,7 +915,7 @@ def _warm_shortlist(nodes: SolveNodes, prof: SolveProfiles, extra_prof,
     cand_i = cand_i.at[:, db_rows].set(i_new)
     idx = _merge_block_cands(cand_s, cand_i, sl_k, mesh_shards)
     sl = jnp.sort(idx, axis=1).astype(jnp.int32)
-    return sl, cand_s, cand_i
+    return sl[key_of], cand_s, cand_i
 
 
 @partial(jax.jit, static_argnames=("wave", "n_waves", "ew", "features",
@@ -2492,6 +2513,63 @@ def _pad_profiles_rows(sp: SparseProfiles, marks=None) -> SparseProfiles:
     return _grow_profiles(sp, settled_pow2(marks, "U", U, floor=64) - U)
 
 
+def shortlist_keys(sp: SparseProfiles, extra_prof, score_prof,
+                   own_terms: bool, marks=None):
+    """The distinct rows of the profile table in what the shortlist
+    passes read of a row, as ``(key_rows [K], key_of [U], n_keys,
+    token)``.
+
+    Two rows share a key only if every value ``_coarse_shortlist``'s
+    body reads of them is bytewise equal: the nine per-profile fields
+    (the static planes' rows are a function of them), the custom
+    plugins' ``extra_prof`` / ``score_prof`` rows where a solve has them
+    (None: it has not), and, where the body reads the four term tables
+    (``own_terms``: ``has_aff and cnt0_any``), their rows: a row with no
+    term entry has all-zero table rows and may share, a row with one
+    keeps a key of its own (the entries decide; no dense table is
+    compared).  In a burst round onto an empty cluster that is the
+    cluster's few pod shapes and the all-zero padding row, whatever the
+    count of constrained gangs (each a profile row for its own terms).
+
+    ``key_rows`` holds each key's first profile row, in the keys' byte
+    order (a function of the key set alone), padded with its first entry
+    to the settled bucket of the count (``marks``, axis ``"K"``; never
+    past ``U``: a table whose rows all differ is ranked row by row);
+    ``key_of`` is each profile row's place in it.  ``token`` is a digest
+    of the key table and of ``key_of``: two solves with equal tokens
+    rank the same keys and hand them to the same rows."""
+    import hashlib
+
+    U = sp.terms.shape[0]
+    cols = [_np(a) for a in sp[:9]]
+    cols += [a for a in (extra_prof, score_prof) if a is not None]
+    if own_terms:
+        own = np.zeros(U, np.int32)
+        own[sp.terms.rows] = sp.terms.rows + 1
+        cols.append(own)
+    table = np.concatenate(
+        [np.ascontiguousarray(a).view(np.uint8).reshape(U, -1)
+         for a in cols if a.size], axis=1)
+    # ``np.unique`` of the byte rows by hand (it spends three quarters
+    # of its time comparing void scalars): a stable sort, so a key's
+    # first row leads its run.
+    order = np.argsort(table.view(
+        np.dtype((np.void, table.shape[1]))).ravel(), kind="stable")
+    srt = table[order]
+    leads = np.concatenate([[True], (srt[1:] != srt[:-1]).any(axis=1)])
+    first = order[leads]
+    key_of = np.empty(U, np.int32)
+    key_of[order] = np.cumsum(leads) - 1
+    n_keys = len(first)
+    K = min(settled_pow2(marks, "K", n_keys, floor=16), U)
+    key_rows = np.concatenate(
+        [first, np.full(K - n_keys, first[0])]).astype(np.int32)
+    h = hashlib.blake2b(digest_size=16)
+    h.update(table[first].tobytes())
+    h.update(key_of.tobytes())
+    return key_rows, key_of, n_keys, h.hexdigest()
+
+
 def profile_term_entries(rows, cols, flags, soft, shape) -> ProfileTermEntries:
     """Entries from (profile, term) cells named in any order and any
     number of times: a cell's flags are ORed and its soft weights summed
@@ -3037,6 +3115,12 @@ def solve_wave(
     dv = devincr
     if dv is not None and (not two_phase or features[5] or features[6]):
         dv = None
+    # One ranking per distinct scoring key, not one per profile row.
+    if two_phase:
+        key_rows, key_of, n_keys, key_tok = shortlist_keys(
+            sp, extra_prof if features[5] else None,
+            score_prof if features[6] else None,
+            own_terms=features[1] and cnt0_any, marks=shape_marks)
     # Exact f32 matmuls are load-bearing: the one-hot matmuls carry node
     # indices, resource sums, and 0/1 predicate counts that are compared
     # with == / <=; the TPU default (bf16 MXU passes) rounds node ids above
@@ -3055,7 +3139,8 @@ def solve_wave(
                 )
                 sl = dv.shortlist(
                     nodes, profiles, extra_prof, score_prof, cls_arg,
-                    aff, weights, eps, scalar_slot,
+                    aff, weights, eps, scalar_slot, key_rows, key_of,
+                    key_tok,
                     sl_k=sl_k, chunk=chunk, features=features,
                     cnt0_any=bool(cnt0_any), cls_identity=cls_identity,
                     mesh_shards=n_sh, stat=stat,
@@ -3063,7 +3148,7 @@ def solve_wave(
             else:
                 sl = _coarse_shortlist(
                     nodes, profiles, extra_prof, score_prof, cls_arg,
-                    aff, weights, eps, scalar_slot,
+                    aff, weights, eps, scalar_slot, key_rows, key_of,
                     sl_k=sl_k, chunk=chunk,
                     features=features, cnt0_any=bool(cnt0_any),
                     cls_identity=cls_identity, mesh_shards=n_sh,
@@ -3100,6 +3185,10 @@ def solve_wave(
         "coarse_s": t_coarse,
         "fine_s": t_fine,
         "shortlist": (U_rows, sl_k) if two_phase else None,
+        # Profile rows the shortlist was handed to, and the distinct
+        # scoring keys that were ranked for them (before bucket padding).
+        "shortlist_rows": U_rows if two_phase else 0,
+        "shortlist_keys": n_keys if two_phase else 0,
         "n_nodes": N_in,
         "compacted_classes": two_phase and not cls_identity,
         "mesh_shards": n_sh,
