@@ -62,6 +62,11 @@ class Cell:
             "waiting_pods": int(round(backlog * float(t.get("waiting_fraction", 0.0)))),
             "resident_class": t.get("resident_class"),
             "batch_class": t.get("batch_class"),
+            # Where the user enters: "pods" (the store's event API) or
+            # "jobs" (admission and the controllers; max_pumps bounds each
+            # wait on them).
+            "entry": t.get("entry", "pods"),
+            "max_pumps": int(t.get("max_pumps", 4)),
         }
 
 
@@ -92,6 +97,19 @@ def load_cell(workload: str, benchmark_file: Path = ROOT / "BENCHMARK.json") -> 
         if _applies(entry, workload):
             spec = _read(home / "layer_metrics" / f"{entry['name']}.json")
             per_layer.append({**spec, **entry})
+    entry = traffic.get("entry", "pods")
+    if entry not in ("pods", "jobs"):
+        raise SystemExit(f"traffic/{w['traffic']}.json: entry is {entry!r}; "
+                         "the harness has 'pods' and 'jobs'")
+    if entry == "jobs":
+        if any(float(x) > 0 for x in config.get("affinity_mix", {}).values()):
+            raise SystemExit(f"cell {workload}: entry: jobs with a "
+                             "configuration that has an affinity_mix: a "
+                             "Job's TaskSpec carries no inter-pod terms")
+        if not traffic.get("pods_run"):
+            raise SystemExit(f"cell {workload}: entry: jobs needs pods_run: "
+                             "a Job reads Running only of pods that are "
+                             "reported Running")
     for name in checks.names(config):
         if not checks.path_of(home, name).is_file():
             raise SystemExit(f"{configs[w['config']]['file']}: guarantees."

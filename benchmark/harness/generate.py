@@ -25,6 +25,7 @@ import numpy as np
 
 GI = 1 << 30
 NAMESPACE = "default"
+TASK_NAME = "worker"        # the one task of a gang that enters as a Job
 
 
 @dataclass
@@ -63,6 +64,10 @@ class Plan:
     def keys(self) -> List[str]:
         return [f"{NAMESPACE}/{n}" for n in self.names]
 
+    def job_keys(self) -> List[str]:
+        """Each gang's key as a Job (``entry: jobs``), in the gangs' order."""
+        return [f"{NAMESPACE}/{g}" for g in self.gang_names]
+
 
 def node_names(config) -> List[str]:
     return [f"node-{i:06d}" for i in range(int(config["nodes"]["count"]))]
@@ -88,10 +93,14 @@ def queue_names(config) -> List[str]:
 
 
 class Generator:
-    """Batches of gangs for one run, all drawn from ``seed``."""
+    """Batches of gangs for one run, all drawn from ``seed``.  Under
+    ``entry: jobs`` a gang's pods bear the names the job controller will
+    give them (``<job>-worker-<i>``): the plan states them, and the store is
+    held to the plan."""
 
-    def __init__(self, config: dict, seed: int):
+    def __init__(self, config: dict, seed: int, entry: str = "pods"):
         self.config = config
+        self._pod_of = "{}-" + TASK_NAME + "-{}" if entry == "jobs" else "{}-{}"
         self.rng = np.random.default_rng(seed)
         pods = config["pods"]
         self.combos = [(int(c), int(m)) for c in pods["cpu_choices"]
@@ -157,7 +166,7 @@ class Generator:
             gang_cpu.append(c)
             gang_mem.append(m)
             for k in range(size):
-                names.append(f"{gname}-{k}")
+                names.append(self._pod_of.format(gname, k))
                 cpu.append(c * 1000)
                 mem.append(m * GI)
                 gang.append(g)
@@ -168,6 +177,11 @@ class Generator:
                     kinds, may_wait=may_wait)
         if classes:
             self._shape_by_class(plan, classes)
+        elif "min_member" in self.config["gang"]:
+            # An elastic gang without classes: the configuration's own floor.
+            floor = np.minimum(plan.gang_min_member,
+                               int(self.config["gang"]["min_member"]))
+            plan.gang_size, plan.gang_min_member = plan.gang_min_member, floor
         return plan
 
     def _draw_class(self) -> str:
@@ -321,4 +335,42 @@ def to_objects(plan: Plan, stamps, priority_values=None):
                             creation_timestamp=float(next(stamps)), **extra))
         start += size
         gangs.append((pg, pods))
+    return gangs
+
+
+def to_jobs(plan: Plan, stamps, block: Optional[dict] = None):
+    """The plan as Jobs (``entry: jobs``): one list of gangs, each ``(job,
+    [pod keys])``.  A gang is one ``Job`` of one task, ``worker``, whose
+    replicas are the gang's pods and whose container is the gang's cpu and
+    memory; ``min_available`` is the gang's ``min_member``; ``block`` is the
+    configuration's ``job`` block (``plugins``, ``policies``, ``max_retry``,
+    as ``examples/job.yaml`` has them), copied onto every Job.  The pods are
+    the controller's to make; their keys are the plan's."""
+    from volcano_tpu.controllers import Job, LifecyclePolicy, TaskSpec
+
+    block = block or {}
+    keys = plan.keys()
+    gangs = []
+    start = 0
+    sizes = plan.sizes()
+    classes = plan.gang_priority
+    for g, gname in enumerate(plan.gang_names):
+        size = int(sizes[g])
+        task = TaskSpec(name=TASK_NAME, replicas=size, containers=[
+            {"cpu": str(plan.gang_cpu[g]), "memory": f"{plan.gang_mem_gi[g]}Gi"}])
+        job = Job(name=gname, namespace=NAMESPACE, uid=f"bench-{gname}",
+                  min_available=int(plan.gang_min_member[g]), tasks=[task],
+                  queue=plan.gang_queue[g],
+                  priority_class=classes[g] if classes else "",
+                  plugins={name: list(args)
+                           for name, args in block.get("plugins", {}).items()},
+                  policies=[LifecyclePolicy(event=p.get("event", ""),
+                                            action=p["action"],
+                                            exit_code=p.get("exit_code"))
+                            for p in block.get("policies", [])],
+                  creation_timestamp=float(next(stamps)))
+        if "max_retry" in block:
+            job.max_retry = int(block["max_retry"])
+        gangs.append((job, keys[start:start + size]))
+        start += size
     return gangs

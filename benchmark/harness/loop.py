@@ -18,6 +18,20 @@ for the victim afterwards is whatever the program put into ``store.pods`` by
 then under the victim's namespace and name (its ``MigrationLedger`` restores
 the pod as a new Pending record), or nothing.  A cell in which nothing is
 evicted and no pod is said to run pays one comparison a cycle for all this.
+
+``entry: jobs`` (the traffic's key; absent or ``pods``: all of the above, as
+it was).  The same closed round with the user one layer further out: a gang
+is a ``Job`` handed to ``AdmittedStore.add_batch_job``, and
+``ControllerManager`` stands between the client and the store
+(``harness/jobs.py``).  *submit* is the admissions, each stamped (that stamp
+is the submit time of the gang's pods in every end-to-end metric), and one
+pump; *schedule* the cycles, with a pump after each cycle's hand-over and
+kubelet's side (the first cycle admits the PodGroups, which have no pods
+yet; the pump creates the pods; the second binds); *reconcile*, a phase of
+its own inside the round, the kubelet's Running reports and pumps until
+every Job of the batch reads Running; *complete* the Jobs' own end (pods
+Succeeded, a pump, ``delete_batch_job``, a pump, the terminations ended).
+Each wait on the controllers is at most the traffic's ``max_pumps`` pumps.
 """
 
 from __future__ import annotations
@@ -35,7 +49,8 @@ import numpy as np
 from . import generate
 from .binder import RecordingBinder
 from .evictor import RecordingEvictor
-from .validate import RoundEvents
+from .jobs import JobEntry
+from .validate import JobEvents, RoundEvents
 
 POLL_S = 0.01      # how often a wait asks whether the hand-over is done
 
@@ -51,10 +66,13 @@ class Round:
     """One round as observed.  Times are ``perf_counter_ns`` readings."""
 
     plan: generate.Plan
-    submit_ns: np.ndarray               # each pod's own add_pod call
+    submit_ns: np.ndarray               # each pod's own add_pod call (under
+    #                                     entry: jobs its gang's add_batch_job)
     t_start: int
     t_submitted: int                    # the backlog stands
     t_scheduled: int                    # every bind seen (or cycles used up)
+    t_reconciled: int                   # t_scheduled; under entry: jobs, every
+    #                                     Job Running (or its pumps used up)
     t_completed: int                    # completions done
     t_end: int                          # settled
     cycles: int                         # of the schedule phase
@@ -68,22 +86,38 @@ class Round:
     waits_timed_out: int = 0            # waits that reached bind_wait_s
     lanes: Dict[str, float] = field(default_factory=dict)
     records: List[dict] = field(default_factory=list)   # traced run: per cycle
+    # Under ``entry: jobs`` alone; else ``jobs`` is None and nothing reads them.
+    jobs: Optional[JobEvents] = None
+    t_admitted: int = 0                 # every add_batch_job call made
+    pumps: Dict[str, int] = field(default_factory=dict)      # by phase
+    pump_s: Dict[str, float] = field(default_factory=dict)   # their seconds
+    pumps_used_up: List[str] = field(default_factory=list)   # phases out of them
 
     def events(self) -> RoundEvents:
         ordered = self.settle_cycles or self.evictions or self.terminations
         return RoundEvents(self.plan, self.arrivals, self.deleted,
                            self.evictions, self.terminations,
-                           self.t_completed if ordered else None)
+                           self.t_completed if ordered else None, self.jobs)
 
     def spans(self) -> Dict[str, float]:
-        return {
+        """Seconds by phase.  Under ``entry: jobs`` also ``admit`` (the
+        ``add_batch_job`` calls, part of ``submit``), ``reconcile`` (between
+        ``schedule`` and ``complete``) and ``pump`` (every pump of the
+        round, whatever phase it lies in)."""
+        out = {
             "submit": (self.t_submitted - self.t_start) / 1e9,
             "schedule": (self.t_scheduled - self.t_submitted) / 1e9,
-            "complete": (self.t_completed - self.t_scheduled) / 1e9,
+            "complete": (self.t_completed - self.t_reconciled) / 1e9,
             "settle": (self.t_end - self.t_completed) / 1e9,
             "round": (self.t_end - self.t_start) / 1e9,
             "run_once": self.run_once_s,
         }
+        if self.jobs is not None:
+            out.update(
+                admit=(self.t_admitted - self.t_start) / 1e9,
+                reconcile=(self.t_reconciled - self.t_scheduled) / 1e9,
+                pump=sum(self.pump_s.values()))
+        return out
 
 
 class Driver:
@@ -95,7 +129,8 @@ class Driver:
                  read_lanes: bool = False, bind_wait_s: float = 10.0,
                  annotate: Optional[Callable] = None,
                  termination_cycles: int = 0, settle_cycles: int = 0,
-                 pods_run: bool = False):
+                 pods_run: bool = False, entry: str = "pods",
+                 max_pumps: int = 4):
         from volcano_tpu.api import GROUP_NAME_ANNOTATION
         from volcano_tpu.cache import ClusterStore
 
@@ -106,8 +141,12 @@ class Driver:
         self.store = ClusterStore(binder=self.binder, evictor=self.evictor)
         # Async bind dispatch, as in production (chip_smoke.py, bench.py).
         self.store.async_bind = True
+        # The controllers watch the store from before its first event.
+        self.jobs = JobEntry(self.store, max_pumps) if entry == "jobs" else None
+        add_queue = self.store.add_queue if self.jobs is None \
+            else self.jobs.admitted.add_queue
         for queue in generate.to_queues(config):
-            self.store.add_queue(queue)
+            add_queue(queue)
         for pc in generate.to_priority_classes(config):
             self.store.add_priority_class(pc)
         self.priority_values = {c["name"]: int(c["value"])
@@ -156,7 +195,12 @@ class Driver:
         that may wait: one cycle), delete the oldest ``complete_pods`` pods,
         then run the traffic's ``settle_cycles``.  The gangs' objects are
         built before the round's clock starts."""
-        gangs = generate.to_objects(plan, self.stamps, self.priority_values)
+        jobs = self.jobs
+        if jobs is None:
+            gangs = generate.to_objects(plan, self.stamps, self.priority_values)
+        else:
+            gangs = generate.to_jobs(plan, self.stamps, self.config.get("job"))
+            jobs.begin_round()
         store = self.store
         n = plan.n_pods
         submit_ns = np.empty(n, dtype=np.int64)
@@ -169,14 +213,20 @@ class Driver:
         own_keys = None
 
         t_start = now()
+        t_admitted = 0
         with self.annotate("bench:submit"):
-            i = 0
-            for pg, pods in gangs:
-                store.add_pod_group(pg)
-                for pod in pods:
-                    submit_ns[i] = now()
-                    store.add_pod(pod)
-                    i += 1
+            if jobs is not None:
+                jobs.submit(gangs, submit_ns, now)
+                t_admitted = now()
+                jobs.pump("submit")
+            else:
+                i = 0
+                for pg, pods in gangs:
+                    store.add_pod_group(pg)
+                    for pod in pods:
+                        submit_ns[i] = now()
+                        store.add_pod(pod)
+                        i += 1
         t_submitted = now()
 
         cycles = 0
@@ -185,7 +235,11 @@ class Driver:
             while cycles < self.max_cycles:
                 run_once_s += self._cycle()
                 cycles += 1
-                if lacks:
+                if jobs is not None and not jobs.created:
+                    # no pod of the batch is made yet: no bind to wait for
+                    self._await_hand_over()
+                    done = False
+                elif lacks:
                     done = self._await(want)
                 else:                   # a plan that may wait: one cycle
                     self._await_hand_over()
@@ -197,7 +251,16 @@ class Driver:
                 if done:
                     break
                 self._kubelet()
+                if jobs is not None:
+                    jobs.pump("schedule")
         t_scheduled = now()
+
+        t_reconciled, not_running = t_scheduled, ()
+        if jobs is not None:
+            with self.annotate("bench:reconcile"):
+                self._kubelet()
+                not_running = jobs.reconcile(gangs)
+            t_reconciled = now()
 
         self.fifo.extend(gangs)
         if self._gangs is not None:
@@ -205,8 +268,13 @@ class Driver:
         deleted: List[str] = []
         with self.annotate("bench:complete"):
             left = int(complete_pods)
+            finishing = []              # entry: jobs, the Jobs that finish
             while left > 0 and self.fifo:
                 pg, pods = self.fifo.popleft()
+                left -= len(pods)
+                if jobs is not None:
+                    finishing.append((pg, pods))
+                    continue
                 if self._terminating or self._gangs is not None:
                     self._complete_with_victims(pg, pods)
                 else:
@@ -214,7 +282,9 @@ class Driver:
                         store.delete_pod(pod)
                 store.delete_pod_group(pg.uid)
                 deleted.extend(f"{p.namespace}/{p.name}" for p in pods)
-                left -= len(pods)
+            if finishing:
+                jobs.complete(finishing)
+                deleted.extend(key for _job, keys in finishing for key in keys)
         t_completed = now()
 
         settled = 0
@@ -237,13 +307,19 @@ class Driver:
             arrivals = self.binder.arrivals[self._seen:]
             self._seen = len(self.binder.arrivals)
         rec = Round(plan, submit_ns, t_start, t_submitted, t_scheduled,
-                    t_completed, t_end, cycles, run_once_s, arrivals, deleted,
+                    t_reconciled, t_completed, t_end, cycles, run_once_s,
+                    arrivals, deleted,
                     settle_cycles=settled,
                     evictions=[(t, key) for t, key, _pod
                                in self.evictor.evictions[evictions0:]],
                     terminations=self.terminations[terminations0:],
                     wait_s=self._wait_s,
                     waits_timed_out=self._waits_timed_out)
+        if jobs is not None:
+            rec.jobs = JobEvents(jobs.created, not_running, jobs.left_behind())
+            rec.t_admitted = t_admitted
+            rec.pumps, rec.pump_s = jobs.pumps, jobs.pump_s
+            rec.pumps_used_up = jobs.used_up
         if self.read_lanes:
             rec.lanes, rec.records = self._records(cycles + settled)
         self.rounds.append(rec)
@@ -315,6 +391,9 @@ class Driver:
         ev = self.evictor
         if ev.count == self._ended:
             return
+        if self.jobs is not None:
+            raise RuntimeError("an eviction under entry: jobs: the kubelet's "
+                               "side holds no Job's victim yet")
         self._mixed = True
         seen = ev.count
         # An eviction is of the cycle after which it was first seen here.
@@ -395,10 +474,12 @@ class Driver:
             arrivals = self.binder.arrivals[self._ran:]
             self._ran = len(self.binder.arrivals)
         pods = self.store.pods
+        # the record's uid: the client's own, or under jobs the controller's
+        uid_of = self.jobs.uid_of.get if self.jobs is not None \
+            else lambda key: f"bench-{key.split('/', 1)[1]}"
         for _t, keys, hosts in arrivals:
             for key, host in zip(keys, hosts):
-                uid = self._restored_uid.get(key) \
-                    or f"bench-{key.split('/', 1)[1]}"
+                uid = self._restored_uid.get(key) or uid_of(key)
                 pod = pods.get(uid)
                 if pod is None or pod.deleting or pod.phase != PodPhase.Pending:
                     continue
@@ -412,8 +493,8 @@ class Driver:
         """From the program's flight recorder, for the round's ``cycles``
         cycles: seconds per lane summed over them (a cycle off the fast path
         or with an error is named under ``_off_fast_path``), and of each
-        cycle its spans and its ``solve``, ``whatif`` and ``between``
-        blocks as plain data."""
+        cycle its spans, its ``solve``, ``whatif`` and ``between`` blocks
+        and its ``path`` as plain data."""
         lanes: Dict[str, float] = {}
         records: List[dict] = []
         for rec in self.store.flight.recent()[-cycles:]:
@@ -425,7 +506,7 @@ class Driver:
                 "spans": [(s.name, s.dur_ns, s.span_id, s.parent_id)
                           for s in rec.spans],
                 "solve": rec.solve, "whatif": rec.whatif,
-                "between": rec.between})
+                "between": rec.between, "path": rec.path})
         return lanes, records
 
 

@@ -3,7 +3,8 @@ that names one of these readers and its arguments; a reader that finds
 nothing to read returns None and the harness leaves the metric out.
 
     span     a span of the benchmark's loop (submit, schedule, complete,
-             round, run_once), per round; ``per: "pod"`` divides by the
+             settle, round, run_once; under ``entry: jobs`` also admit,
+             pump, reconcile), per round; ``per: "pod"`` divides by the
              round's pods; ``minus_all_lanes_except: [...]`` subtracts every
              flight-recorder lane but the listed (nested) ones
     lane     the sum of the named flight-recorder lanes over a round's cycles
@@ -47,7 +48,9 @@ def read_span(args: dict, obs: Observed) -> Optional[float]:
     keep = args.get("minus_all_lanes_except")
     values = []
     for r in obs.rounds:
-        v = r.spans()[args["span"]]
+        v = r.spans().get(args["span"])
+        if v is None:           # a span of another entry's rounds
+            return None
         if keep is not None:
             if not r.lanes:
                 return None

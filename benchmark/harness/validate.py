@@ -24,7 +24,16 @@ Checked, at full size, for every round of a run (set-up rounds too):
   ``min_member`` is 1 (*gang_broken*, the gang plugin's floor), and every
   eviction has its termination ended by the round's end (*never_terminated*);
 - after the run, the keys the ledger holds alive are the keys the store
-  holds (*lost*: the ledger's alone, *ghost*: the store's alone).
+  holds (*lost*: the ledger's alone, *ghost*: the store's alone);
+- in a round whose gangs entered as Jobs (``RoundEvents.jobs``), and only
+  there: the pod records the job controller made for the batch are the
+  plan's, each once and none more under a Job of the batch
+  (*pods_not_as_planned*: per Job, the pods missing or the pods more,
+  whichever is more, and every second copy); a Job of which at least
+  ``min_available`` pods were bound, and so reported Running, reads Running
+  once the round has reconciled (*jobs_not_running*); a Job that completed
+  and was deleted has left no pod, PodGroup or Job record in the store when
+  its round ends (*jobs_left_behind*).
 
 Pods that may wait and victims waiting for room again are counted and
 printed with no limit.
@@ -41,8 +50,18 @@ import numpy as np
 LIMITED = ("unknown", "double", "unbound", "oversubscribed", "split",
            "evicted_unknown", "evicted_twice", "never_terminated",
            "gang_broken", "lost", "ghost")
+OF_JOBS = ("pods_not_as_planned", "jobs_not_running", "jobs_left_behind")
 # alive[key] = [cpu, mem, node or -1, terminating, lives, plan, index in plan]
 NODE, TERMINATING, LIVES, PLAN, INDEX = 2, 3, 4, 5, 6
+
+
+@dataclass
+class JobEvents:
+    """What a round whose gangs entered as Jobs saw of them, as plain data."""
+
+    created: Sequence = ()             # [(pod key, its owner's job key)] made
+    not_running: Sequence[str] = ()    # job keys not Running once reconciled
+    left_behind: Sequence[str] = ()    # completed, deleted, and a record left
 
 
 @dataclass
@@ -57,6 +76,7 @@ class RoundEvents:
     # When the client's deletions were done; given where something of the
     # round is stamped after it or was evicted, so that order matters.
     t_deleted: Optional[int] = None
+    jobs: Optional[JobEvents] = None   # given under ``entry: jobs`` alone
 
 
 @dataclass
@@ -81,6 +101,7 @@ class Verdict:
     waiting: int = 0                   # of them, not bound at the run's end
     waiting_victims: int = 0           # terminated, not bound again at its end
     worst_fill: float = 0.0            # highest used / allocatable seen
+    of_jobs: Dict[str, int] = field(default_factory=dict)  # entry: jobs alone
     extra: Dict[str, int] = field(default_factory=dict)   # a config's checks
     examples: List[str] = field(default_factory=list)
 
@@ -100,7 +121,8 @@ class Verdict:
 
     def compared(self) -> Dict[str, int]:
         """Every count that has a limit (0), by name."""
-        return {**{name: getattr(self, name) for name in LIMITED}, **self.extra}
+        return {**{name: getattr(self, name) for name in LIMITED},
+                **self.of_jobs, **self.extra}
 
     def lines(self) -> List[str]:
         out = [f"validate: {self.rounds} rounds, {self.submitted} pods "
@@ -176,6 +198,8 @@ class Ledger:
             v.example(f"{len(self.terminating)} evictions not ended by the "
                       f"round's end, e.g. {next(iter(self.terminating))}")
             self.terminating.clear()       # counted once
+        if ev.jobs is not None:
+            self._hold_jobs(plan, keys, ev.jobs, bound_in_gang)
         # The client's deletions free what the ledger says the pods held.
         self._delete(deleted)
 
@@ -220,6 +244,40 @@ class Ledger:
                 self.used[pod[NODE]] -= (pod[0], pod[1], 1)
             if remember:
                 self._gone[key] = pod
+
+    def _hold_jobs(self, plan, keys, jobs: JobEvents, bound_in_gang) -> None:
+        """The three counts of a round whose gangs entered as Jobs."""
+        v = self.verdict
+        if not v.of_jobs:
+            v.of_jobs.update(dict.fromkeys(OF_JOBS, 0))
+        counts = v.of_jobs
+        owners = plan.job_keys()
+        planned: Dict[str, set] = {owner: set() for owner in owners}
+        for key, g in zip(keys, plan.gang.tolist()):
+            planned[owners[g]].add(key)
+        held: Dict[str, Counter] = {owner: Counter() for owner in owners}
+        for key, owner in jobs.created:
+            if owner in held:
+                held[owner][key] += 1
+        for owner, want in planned.items():
+            got = held[owner]
+            off = sum(n - 1 for n in got.values() if n > 1) \
+                + max(len(want - got.keys()), len(got.keys() - want))
+            if off:
+                counts["pods_not_as_planned"] += off
+                v.example(f"job {owner}: the plan's pods {sorted(want - got.keys())[:3]} "
+                          f"are not in the store, {sorted(got.keys() - want)[:3]} "
+                          "are and are no pod of the plan")
+        floor = dict(zip(owners, (bound_in_gang >= plan.gang_min_member).tolist()))
+        for owner in jobs.not_running:
+            if floor.get(owner):
+                counts["jobs_not_running"] += 1
+                v.example(f"job {owner} has min_available pods bound and "
+                          "reported Running and does not read Running")
+        counts["jobs_left_behind"] += len(jobs.left_behind)
+        if jobs.left_behind:
+            v.example(f"{len(jobs.left_behind)} completed jobs left a record "
+                      f"in the store, e.g. {jobs.left_behind[0]}")
 
     def _over_example(self, i: int) -> None:
         self.verdict.example(

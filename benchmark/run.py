@@ -140,6 +140,29 @@ def end_to_end(counted, setup_s: float, window_end_ns: int) -> dict:
     }
 
 
+def jobs_line(counted, all_rounds, max_pumps: int) -> str:
+    """Under ``entry: jobs``: the pumps of a round by phase and their own
+    seconds, and every phase that used up its pumps, with the longest pump
+    of that phase (a run on a program that cannot keep up ends, loudly)."""
+    phases = list(counted[0].pumps)
+    line = ("window: gangs enter as Jobs; pumps a round by phase (median) "
+            + ", ".join(f"{p} {statistics.median(r.pumps[p] for r in counted)}"
+                        for p in phases)
+            + "; the pumps' own seconds (median) "
+            + ", ".join(f"{p} {statistics.median(r.pump_s[p] for r in counted):.3f}"
+                        for p in phases))
+    out = {}
+    for r in all_rounds:
+        for p in r.pumps_used_up:
+            n, worst = out.get(p, (0, 0.0))
+            out[p] = (n + 1, max(worst, r.pump_s[p]))
+    if not out:
+        return line + f"; no phase of the run used up its {max_pumps} pumps"
+    return line + f"; OUT OF PUMPS (max_pumps {max_pumps}): " + ", ".join(
+        f"{p} in {n} rounds, its pumps took up to {worst:.3f} s a round"
+        for p, (n, worst) in out.items())
+
+
 def device_dict(info: dict, profile: dict, on_cpu: bool) -> dict:
     import jax
 
@@ -169,14 +192,15 @@ def set_up(cell, seed: int, trace: bool, make_scheduler=None,
     sizes = cell.sizes()
     if bind_wait_s is not None:
         sizes["bind_wait_s"] = bind_wait_s
-    gen = generate.Generator(cell.config, seed)
+    gen = generate.Generator(cell.config, seed, entry=sizes["entry"])
     driver = loop.Driver(cell.config, max_cycles=sizes["max_cycles"],
                          make_scheduler=make_scheduler or loop.default_scheduler,
                          read_lanes=trace, bind_wait_s=sizes["bind_wait_s"],
                          annotate=jax.profiler.TraceAnnotation if trace else None,
                          termination_cycles=sizes["termination_cycles"],
                          settle_cycles=sizes["settle_cycles"],
-                         pods_run=sizes["pods_run"])
+                         pods_run=sizes["pods_run"], entry=sizes["entry"],
+                         max_pumps=sizes["max_pumps"])
     if sizes["resident_pods"]:
         driver.round(gen.plan(sizes["resident_pods"], "resident",
                               klass=sizes["resident_class"]), 0)
@@ -300,7 +324,10 @@ def run(cell, seed: int, seconds: float, trace: bool, make_scheduler=None,
         f"{statistics.median(own):.3f}, max {own[-1]:.3f} s; by phase (median "
         "s) " + ", ".join(
             f"{k} {statistics.median(r.spans()[k] for r in counted):.3f}"
-            for k in ("submit", "schedule", "complete")))
+            for k in ("submit", "admit", "pump", "schedule", "reconcile",
+                      "complete") if k in counted[0].spans()))
+    if sizes["entry"] == "jobs":
+        say(jobs_line(counted, all_rounds, sizes["max_pumps"]))
     say(f"window: {sum(len(r.evictions) for r in counted)} evictions seen, "
         f"{sum(len(r.terminations) for r in counted)} terminations ended; "
         f"cycles after the completions per round "

@@ -3,8 +3,9 @@ made row by row on a copy of the real benchmark in every run of the suite: a
 traffic mix and a plain cell under it, a second plain cell on four chips, a
 configuration that holds evictions (``preempt-10k`` at ``PERF.md`` section
 7 (3)'s shape) with its traffic, its check and its cell, a per-layer metric
-of that cell alone and one of every cell.  New files and appended entries,
-nothing else; the contract and the configuration tests' loading assertions
+of that cell alone and one of every cell, a traffic mix whose gangs enter as
+Jobs (``entry: jobs``) and a plain cell under it.  New files and appended
+entries, nothing else; the contract and the configuration tests' loading assertions
 then pass on the copy, and the cells the benchmark has load as they did.
 Files are loaded; no scheduler runs.
 
@@ -32,7 +33,9 @@ NEW_CELLS = {
     "affinity-10k.churn-added": ("affinity-10k", "churn-added", 1),  # (1) plain
     "hyper-50k.churn-added": ("hyper-50k", "churn-added", 4),   # (2) four chips
     EVICT: ("preempt-10k-added", "evict-added", 1),         # (3) holds evictions
+    "binpack-1k.asjobs-added": ("binpack-1k", "asjobs-added", 1),   # (6) as Jobs
 }
+JOBS = "binpack-1k.asjobs-added"
 # (4) of the evicting cell alone, (5) of every cell
 NEW_METRICS = {
     "preempt_plan_ms_added": ("what-if engine", "span_self",
@@ -77,10 +80,15 @@ def add_to(root):
         "termination_cycles": 0, "pods_run": True, "resident_class": "low",
         "batch_class": "high"}
     churn = json.loads((home / "traffic" / "churn.json").read_text())
+    burst = json.loads((home / "traffic" / "burst.json").read_text())
+    # (6) burst with the user one layer further out: admission, the controllers
+    asjobs = dict(burst, name="asjobs-added", entry="jobs", pods_run=True,
+                  max_cycles=6, max_pumps=8)
     new = {home / "configs" / "preempt-10k-added.json": json.dumps(cfg),
            home / "traffic" / "evict-added.json": json.dumps(traffic),
            home / "traffic" / "churn-added.json":
                json.dumps(dict(churn, name="churn-added")),
+           home / "traffic" / "asjobs-added.json": json.dumps(asjobs),
            home / "reference" / "preempt_added_ref.py": STUB_REF}
     bench = json.loads((root / "BENCHMARK.json").read_text())
     bench["configs"].append({
@@ -168,6 +176,30 @@ def test_the_benchmarks_cells_load_as_they_did(grown, name):
     added = [m for m in now.per_layer if m not in was.per_layer]
     assert [m for m in now.per_layer if m in was.per_layer] == was.per_layer
     assert [m["name"] for m in added] == [ALL_CELLS_METRIC]
+
+
+def test_the_cell_whose_gangs_enter_as_jobs_is_plain_and_says_so(grown):
+    """``entry`` and ``max_pumps`` are the traffic's; every cell that was
+    there enters as pods, as it did."""
+    c = cell_mod.load_cell(JOBS, grown)
+    sizes = c.sizes()
+    assert (sizes["entry"], sizes["max_pumps"], sizes["pods_run"],
+            sizes["max_cycles"]) == ("jobs", 8, True, 6)
+    assert not contract.holds_evictions(c)
+    was = cell_mod.load_cell("binpack-1k.burst", grown).sizes()
+    assert (was["entry"], was["max_pumps"], was["pods_run"]) == ("pods", 4, False)
+    assert {k: v for k, v in sizes.items()
+            if k not in ("entry", "max_pumps", "pods_run", "max_cycles")} \
+        == {k: v for k, v in was.items()
+            if k not in ("entry", "max_pumps", "pods_run", "max_cycles")}
+    for name in contract.CELLS:
+        assert cell_mod.load_cell(name, grown).sizes()["entry"] == "pods", name
+    # the same draw as the burst's, under the names the controller will give
+    plan = generate.Generator(c.config, 2**31 + 52, entry=sizes["entry"]) \
+        .plan(sizes["batch_pods"], "w0000")
+    assert plan.names[0] == "w0000-pg-000000-worker-0"
+    assert len(generate.to_jobs(plan, iter(range(1, 10**6)),
+                                c.config.get("job"))) == len(plan.gang_names)
 
 
 def test_the_evicting_cells_bursts_go_to_the_queues_its_class_names(grown):

@@ -4,11 +4,16 @@ test into a temporary directory, run ``run.py``'s whole path on the CPU
 timed path broken underneath comes out ``correct: false``.  A second toy
 fills its nodes with a low priority class and sends bursts of a high one:
 the round holds evictions, the kubelet's side ends them, and the same path
-with the eviction path broken comes out ``correct: false``."""
+with the eviction path broken comes out ``correct: false``.  A third toy's
+gangs enter as Jobs of ``examples/job.yaml``'s shape (``entry: jobs``):
+admission and the controllers stand between the client and the store, a round
+is two cycles and three pumps to Running, and each count of that entry goes
+to 1 when the toy is broken the matching way."""
 
 import json
 import os
 import shutil
+import time
 
 import pytest
 
@@ -31,6 +36,19 @@ def check(events, nodes, config):
         for _t, _keys, hosts in ev.arrivals for host in hosts)}
 '''
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
+
+
+def _bench_file(tmp_path, monkeypatch, bench):
+    """The toy's ``BENCHMARK.json``, written; the run's trace and compile
+    cache go under ``tmp_path``."""
+    path = tmp_path / "BENCHMARK.json"
+    path.write_text(json.dumps(bench))
+    # main() sets this when it is unset; keep the test's process as it was.
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                       os.environ.get("JAX_COMPILATION_CACHE_DIR",
+                                      str(tmp_path / "xla")))
+    monkeypatch.setattr(bench_run, "OUT_DIR", tmp_path / "out")
+    return path
 
 
 @pytest.fixture
@@ -71,7 +89,13 @@ def toy(tmp_path, monkeypatch):
         "name": "commit_self_ms", "unit": "ms",
         "layer": "fast cycle host lanes", "moves": "backlog_to_bind_ms",
         "reader": "span_self", "args": {"name": "commit", "scale": 1e3}}))
+    # a span only a round that enters as Jobs has: nothing to read here
+    (home / "layer_metrics" / "pump_ms.json").write_text(json.dumps({
+        "name": "pump_ms", "unit": "ms", "layer": "admission and controllers",
+        "moves": "backlog_to_bind_ms", "reader": "span",
+        "args": {"span": "pump", "scale": 1e3}}))
     for name, unit, source, layer in (
+            ("pump_ms", "ms", "host_clock", "admission and controllers"),
             ("schedule_ms", "ms", "host_clock", "cycle driver"),
             ("solve_rows", "rows", "program_counter", "solve"),
             ("commit_self_ms", "ms", "program_span", "fast cycle host lanes")):
@@ -79,14 +103,7 @@ def toy(tmp_path, monkeypatch):
             "name": name, "unit": unit, "better": "lower", "source": source,
             "layer": layer, "moves": "backlog_to_bind_ms",
             "workloads": ["toy.drip"]})
-    path = tmp_path / "BENCHMARK.json"
-    path.write_text(json.dumps(real))
-    # main() sets this when it is unset; keep the test's process as it was.
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
-                       os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                                      str(tmp_path / "xla")))
-    monkeypatch.setattr(bench_run, "OUT_DIR", tmp_path / "out")
-    return path
+    return _bench_file(tmp_path, monkeypatch, real)
 
 
 CONF_PREEMPT = """actions: "enqueue, allocate, preempt, reclaim, backfill"
@@ -176,13 +193,61 @@ def toy_preempt(request, tmp_path, monkeypatch):
             "name": name, "unit": unit, "better": "lower", "source": source,
             "layer": "what-if engine", "moves": "backlog_to_bind_ms",
             "workloads": ["toypre.pre"]})
-    path = tmp_path / "BENCHMARK.json"
-    path.write_text(json.dumps(real))
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
-                       os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                                      str(tmp_path / "xla")))
-    monkeypatch.setattr(bench_run, "OUT_DIR", tmp_path / "out")
-    return path
+    return _bench_file(tmp_path, monkeypatch, real)
+
+
+JOB_BLOCK = {"plugins": {"ssh": [], "env": [], "svc": []},
+             "policies": [{"event": "PodEvicted", "action": "RestartJob"}],
+             "max_retry": 5}
+
+
+@pytest.fixture
+def toy_jobs(request, tmp_path, monkeypatch):
+    """A cell whose gangs enter as Jobs, by files and entries alone: 48
+    nodes, Jobs of ``examples/job.yaml``'s shape (6 replicas of 1 cpu / 1 Gi,
+    ``minAvailable`` 3, plugins ``ssh``, ``env``, ``svc``, ``PodEvicted`` ->
+    ``RestartJob``, ``maxRetry`` 5), four of them a burst, and three
+    per-layer metrics that read the new spans with the ``span`` reader as it
+    is.  A test may give the traffic's ``max_pumps`` (``request.param``)."""
+    max_pumps = getattr(request, "param", None)
+    real = json.loads((ROOT / "BENCHMARK.json").read_text())
+    home = tmp_path / "benchmark"
+    shutil.copytree(ROOT / "benchmark" / "layer_metrics", home / "layer_metrics")
+    for part in ("configs", "traffic"):
+        (home / part).mkdir()
+    config = json.loads((ROOT / "benchmark" / "configs" / "binpack-1k.json").read_text())
+    config.update(name="toyjob", backlog_pods=48, job=JOB_BLOCK)
+    config["nodes"].update(count=48, cpu=8, memory_gi=32, pods=16, zones=0)
+    config["pods"] = {"cpu_choices": [1], "mem_gi_choices": [1]}
+    config["gang"] = {"size": 6, "min_member": 3}
+    config["probe"] = {"probes": 4, "before_drain": 1, "keep_pods": 24}
+    (home / "configs" / "toyjob.json").write_text(json.dumps(config))
+    traffic = {"name": "asjobs", "entry": "jobs", "resident_fraction": 0.0,
+               "batch_fraction": 0.5, "warmup_rounds": 1, "max_cycles": 6,
+               "pods_run": True, "profile_seconds": 0.2}
+    if max_pumps is not None:
+        traffic["max_pumps"] = max_pumps
+    (home / "traffic" / "asjobs.json").write_text(json.dumps(traffic))
+    real["configs"] = [{"name": "toyjob", "source": "a test",
+                        "file": "benchmark/configs/toyjob.json", "reduced": [],
+                        "why": "toy"}]
+    real["workloads"] = [{"name": "toyjob.asjobs", "config": "toyjob",
+                          "traffic": "asjobs", "chips": 1, "why": "toy"}]
+    for name, span, per in (("admit_us_per_pod", "admit", "pod"),
+                            ("pump_ms", "pump", None),
+                            ("reconcile_ms", "reconcile", None)):
+        args = {"span": span, "scale": 1e6 if per else 1e3}
+        if per:
+            args["per"] = per
+        unit = "us/pod" if per else "ms"
+        (home / "layer_metrics" / f"{name}.json").write_text(json.dumps({
+            "name": name, "unit": unit, "layer": "admission and controllers",
+            "moves": "bind_rate", "reader": "span", "args": args}))
+        real["per_layer"].append({
+            "name": name, "unit": unit, "better": "lower",
+            "source": "host_clock", "layer": "admission and controllers",
+            "moves": "bind_rate", "workloads": ["toyjob.asjobs"]})
+    return _bench_file(tmp_path, monkeypatch, real)
 
 
 def _keep_driver(monkeypatch):
@@ -226,6 +291,7 @@ def test_toy_cell_runs_end_to_end(toy, capsys, trace):
             < result["metrics"]["commit_lane_ms"]["value"]
         assert "host_lanes_ms" in names and "commit_lane_ms" in names
         assert "device_busy_ms_per_round" not in names  # nothing to read
+        assert "pump_ms" not in names           # no pump in a round of pods
         assert "bind_rate" not in names
     else:
         assert names == {"bind_rate", "backlog_to_bind_ms",
@@ -314,6 +380,116 @@ def test_toy_preempt_cell_holds_evictions(toy_preempt, monkeypatch, capsys, trac
     else:
         assert set(result["metrics"]) == {"bind_rate", "backlog_to_bind_ms",
                                           "submit_to_bind_p95_ms", "setup_s"}
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_toy_jobs_cell_takes_a_job_from_the_users_call_to_running(
+        toy_jobs, monkeypatch, capsys, trace):
+    """Submit -> Running through admission and the controllers: the round
+    is two cycles (``enqueue`` admits PodGroups that have no pods yet, then
+    the pods bind) and three pumps (PodGroup, pods, Job Running)."""
+    from volcano_tpu.controllers import JobPhase
+    from volcano_tpu.webhooks import AdmittedStore
+
+    calls = {}                  # job key -> when its add_batch_job was entered
+    admit = AdmittedStore.add_batch_job
+
+    def stamped(self, job):
+        calls[job.key] = time.perf_counter_ns()
+        admit(self, job)
+
+    monkeypatch.setattr(AdmittedStore, "add_batch_job", stamped)
+    seen = _keep_driver(monkeypatch)
+    cell = cell_mod.load_cell("toyjob.asjobs", toy_jobs)
+    result = bench_run.run(cell, seed=2**31 + 52, seconds=0.5, trace=bool(trace))
+    out = capsys.readouterr().out
+    driver = seen["driver"]
+    assert result["correct"] is True and result["failed"] == 0, out[-3000:]
+    for name in ("pods_not_as_planned", "jobs_not_running", "jobs_left_behind",
+                 "lost", "ghost", "unbound", "split", "unknown", "probe_misses"):
+        assert result["compared"][name] == {"value": 0, "limit": 0}, name
+    assert "validate: jobs_left_behind = 0 (limit 0)" in out
+    assert "no phase of the run used up its 4 pumps" in out
+    counted = [r for r in driver.rounds if r.plan.tag.startswith("w0")]
+    bursts = [r for r in driver.rounds if r.plan.n_pods == 24]
+    assert counted and len(bursts) >= len(counted) + 2     # warm-up, the fill
+    assert result["attempted"] == 24 * len(counted)
+    for r in bursts:
+        # the plan states what the controller will call the pods
+        assert r.plan.names[:2] == [f"{r.plan.tag}-pg-000000-worker-0",
+                                    f"{r.plan.tag}-pg-000000-worker-1"]
+        assert sorted(k for k, _owner in r.jobs.created) == sorted(r.plan.keys())
+        assert {owner for _k, owner in r.jobs.created} == set(r.plan.job_keys())
+        assert r.plan.gang_min_member.tolist() == [3] * 4
+        assert r.plan.sizes().tolist() == [6] * 4
+        # two cycles, three pumps to Running; the Jobs' own end takes two more
+        assert r.cycles == 2 and r.waits_timed_out == 0
+        assert r.pumps == {"submit": 1, "schedule": 1, "reconcile": 1,
+                           "complete": 2 if r.deleted else 0}, r.pumps
+        assert not r.pumps_used_up
+        assert r.jobs.not_running == [] and r.jobs.left_behind == []
+        # the submit stamp is the user's call, a gang: taken just before its
+        # own add_batch_job is entered, after the one before it was
+        stamps = r.submit_ns.reshape(4, 6)
+        entered = [calls[key] for key in r.plan.job_keys()]
+        assert (stamps == stamps[:, :1]).all()
+        assert all(a <= b for a, b in zip(stamps[:, 0].tolist(), entered))
+        assert all(a < b for a, b in zip(entered, stamps[1:, 0].tolist()))
+        assert r.t_start <= stamps[0, 0] and entered[-1] < r.t_admitted \
+            <= r.t_submitted <= r.t_scheduled <= r.t_reconciled <= r.t_completed
+        spans = r.spans()
+        assert 0 < spans["admit"] < spans["submit"] and spans["reconcile"] > 0
+        assert spans["pump"] == pytest.approx(sum(r.pump_s.values()))
+        assert spans["round"] == pytest.approx(sum(
+            spans[k] for k in ("submit", "schedule", "reconcile", "complete",
+                               "settle")))
+    # what stands in the store is what the ledger holds alive, as Jobs do
+    store = driver.store
+    alive = [job for job, _keys in driver.fifo]
+    assert sorted(store.batch_jobs) == sorted(job.key for job in alive)
+    assert sorted(store.pod_groups) == sorted(job.key for job in alive)
+    assert {j.status.state.phase for j in store.batch_jobs.values()} \
+        == {JobPhase.Running.value}
+    job = alive[-1]
+    assert (job.min_available, job.max_retry, sorted(job.plugins)) \
+        == (1, 5, ["env", "ssh", "svc"])                # a probe's lone pod
+    assert [(p.event, p.action) for p in job.policies] \
+        == [("PodEvicted", "RestartJob")]
+    pod = next(reversed(store.pods.values()))
+    assert pod.owner_job == job.key and pod.task_name == "worker"
+    assert pod.env["VC_PROCESS_COUNT"] == "1" and "VK_TASK_INDEX" in pod.env
+    assert any("gangs enter as Jobs" in ln for ln in out.splitlines())
+    if trace:
+        metrics = result["metrics"]
+        assert {"admit_us_per_pod", "pump_ms", "reconcile_ms"} <= set(metrics)
+        assert metrics["pump_ms"]["value"] > metrics["reconcile_ms"]["value"] > 0
+        # the first cycle of a round meets PodGroups that have no pods, and
+        # the fast cycle takes them
+        assert all([rec["path"] for rec in r.records] == ["fast", "fast"]
+                   for r in bursts)
+        assert "traced: 0 cycles left the fast path or failed" in out
+    else:
+        assert set(result["metrics"]) == {"bind_rate", "backlog_to_bind_ms",
+                                          "submit_to_bind_p95_ms", "setup_s"}
+
+
+def test_a_jobs_cell_with_an_affinity_mix_or_no_running_pods_does_not_load(toy_jobs):
+    home = toy_jobs.parent / "benchmark"
+    config = json.loads((home / "configs" / "toyjob.json").read_text())
+    traffic = json.loads((home / "traffic" / "asjobs.json").read_text())
+    (home / "configs" / "toyjob.json").write_text(json.dumps(
+        dict(config, affinity_mix={"affinity": 0.05})))
+    with pytest.raises(SystemExit, match="no inter-pod terms"):
+        cell_mod.load_cell("toyjob.asjobs", toy_jobs)
+    (home / "configs" / "toyjob.json").write_text(json.dumps(config))
+    (home / "traffic" / "asjobs.json").write_text(json.dumps(
+        dict(traffic, pods_run=False)))
+    with pytest.raises(SystemExit, match="needs pods_run"):
+        cell_mod.load_cell("toyjob.asjobs", toy_jobs)
+    (home / "traffic" / "asjobs.json").write_text(json.dumps(
+        dict(traffic, entry="service")))
+    with pytest.raises(SystemExit, match="'pods' and 'jobs'"):
+        cell_mod.load_cell("toyjob.asjobs", toy_jobs)
 
 
 class _Idle:
@@ -445,6 +621,64 @@ def _broken_deletes_behind_the_client(store, conf):
     return _DeletesBehindTheClient(loop.default_scheduler(store, conf), store)
 
 
+# ---- the Job's path, broken (on the toy whose gangs enter as Jobs) ----------
+
+
+def _broken_renames_a_pod(store, conf):
+    """The controller's first pod of the window bears another name."""
+    add_pod, done = store.add_pod, []
+
+    def add(pod):
+        if not done and pod.owner_job.startswith("default/w0000"):
+            done.append(pod)
+            pod.name += "x"
+        add_pod(pod)
+
+    store.add_pod = add
+    return loop.default_scheduler(store, conf)
+
+
+def _broken_withholds_running(store, conf):
+    """The Running reports of one Job's pods never reach the store."""
+    update_pod = store.update_pod
+
+    def update(pod):
+        if pod.phase == "Running" and pod.owner_job == "default/w0000-pg-000001":
+            return
+        update_pod(pod)
+
+    store.update_pod = update
+    return loop.default_scheduler(store, conf)
+
+
+def _broken_skips_a_delete(store, conf):
+    """The first ``delete_batch_job`` is swallowed: the Job is never cleaned up."""
+    delete, done = store.delete_batch_job, []
+
+    def delete_batch_job(key):
+        if not done:
+            return done.append(key)
+        delete(key)
+
+    store.delete_batch_job = delete_batch_job
+    return loop.default_scheduler(store, conf)
+
+
+def _broken_slow_controller(store, conf):
+    """A job controller that handles three requests a pump: with
+    ``max_pumps: 1`` it is never pumped to the end."""
+    from volcano_tpu.controllers import JobController
+
+    patch = pytest.MonkeyPatch()
+    process_all = JobController.process_all
+    patch.setattr(JobController, "process_all",
+                  lambda self, max_iters=3: process_all(self, 3))
+    _broken_slow_controller.undo = patch.undo
+    return loop.default_scheduler(store, conf)
+
+
+JOBS_PATH = {_broken_renames_a_pod, _broken_withholds_running,
+             _broken_skips_a_delete, _broken_slow_controller}
 EVICTION_PATH = {_broken_kubelet, _broken_evicts_the_batch,
                  _broken_double_of_a_restored_key,
                  _broken_deletes_behind_the_client}
@@ -457,11 +691,17 @@ EVICTION_PATH = {_broken_kubelet, _broken_evicts_the_batch,
     (_broken_kubelet, ["validate: never_terminated =", "validate: unbound ="]),
     (_broken_evicts_the_batch, ["validate: victim_of_the_top_class ="]),
     (_broken_double_of_a_restored_key, ["validate: double ="]),
-    (_broken_deletes_behind_the_client, ["validate: lost ="])])
+    (_broken_deletes_behind_the_client, ["validate: lost ="]),
+    (_broken_renames_a_pod, ["validate: pods_not_as_planned = 1 "]),
+    (_broken_withholds_running, ["validate: jobs_not_running = 1 "]),
+    (_broken_skips_a_delete, ["validate: jobs_left_behind = 1 "])])
 def test_broken_timed_path_is_not_correct(request, capsys, broken, symptoms):
     if broken in EVICTION_PATH:
         path = request.getfixturevalue("toy_preempt")
         cell, wait = cell_mod.load_cell("toypre.pre", path), None
+    elif broken in JOBS_PATH:
+        path = request.getfixturevalue("toy_jobs")
+        cell, wait = cell_mod.load_cell("toyjob.asjobs", path), None
     else:
         path = request.getfixturevalue("toy")
         # keep the idle scheduler's rounds short
@@ -483,6 +723,29 @@ def test_broken_timed_path_is_not_correct(request, capsys, broken, symptoms):
         assert "validate: oversubscribed = 0" in out
     if broken in EVICTION_PATH:   # and no cycle's wait was a time-out
         assert "0 waits of the run reached bind_wait_s" in out
+    if broken is _broken_withholds_running:     # its wait ended, by pumps
+        assert "OUT OF PUMPS (max_pumps 4): reconcile in 1 rounds" in out
+
+
+@pytest.mark.parametrize("toy_jobs", [1], indirect=True)
+def test_a_program_too_slow_to_reconcile_ends_with_its_last_line(toy_jobs, capsys):
+    """No pump has a time limit, so every wait on the controllers is counted
+    in pumps: a controller that cannot keep up leaves pods failed, and the
+    run goes on to its result line and says which phase ran out."""
+    cell = cell_mod.load_cell("toyjob.asjobs", toy_jobs)
+    assert cell.sizes()["max_pumps"] == 1
+    try:
+        result = bench_run.run(cell, seed=7, seconds=0.5, trace=False,
+                               make_scheduler=_broken_slow_controller)
+    finally:
+        _broken_slow_controller.undo()
+    out = capsys.readouterr().out
+    assert set(result) == RESULT_KEYS
+    assert result["correct"] is False and result["failed"] > 0
+    assert result["attempted"] > 0
+    assert result["compared"]["unbound"]["value"] > 0
+    line = [ln for ln in out.splitlines() if "OUT OF PUMPS (max_pumps 1)" in ln]
+    assert line and "its pumps took up to" in line[0], out[-2000:]
 
 
 def test_no_accelerator_and_no_cpu_named_fails(toy, monkeypatch):

@@ -144,10 +144,14 @@ def test_metrics(bench=BENCH, root=ROOT):
 def holds_evictions(c):
     """A cell is *plain* when its configuration has no tiers and no queue
     that may be reclaimed from and its traffic none of the keys of the
-    kubelet's side and the tiers; else it is an eviction cell."""
+    kubelet's side and the tiers; else it is an eviction cell.  Under
+    ``entry: jobs`` ``pods_run`` is no such key: a Job reads Running only of
+    pods that run, and the loader demands it."""
+    jobs = c.traffic.get("entry") == "jobs"
     return ("priority_classes" in c.config
             or "reclaimable" in c.config.get("queues", {})
-            or any(key in c.traffic for key in EVICTION_KEYS))
+            or any(key in c.traffic for key in EVICTION_KEYS
+                   if not (jobs and key == "pods_run")))
 
 
 def eviction_clause(c):
@@ -192,10 +196,13 @@ def test_every_cells_files_resolve(name, bench_file=ROOT / "BENCHMARK.json"):
                                   "record", "span_self")
     if holds_evictions(c):
         return eviction_clause(c)
-    # none of the seven holds an eviction, a tier or a pod that may wait
+    # none of the seven holds an eviction, a tier or a pod that may wait;
+    # pods run in a plain cell only where its gangs enter as Jobs
     assert (sizes["termination_cycles"], sizes["settle_cycles"],
             sizes["pods_run"], sizes["waiting_pods"], sizes["resident_class"],
-            sizes["batch_class"]) == (0, 0, False, 0, None, None)
+            sizes["batch_class"]) \
+        == (0, 0, sizes["entry"] == "jobs", 0, None, None)
+    assert sizes["entry"] in ("pods", "jobs") and sizes["max_pumps"] >= 1
     assert "priority_classes" not in c.config
     assert "reclaimable" not in c.config.get("queues", {})
     # demand stays under capacity, by the file's own arithmetic
@@ -207,14 +214,17 @@ def test_every_cells_files_resolve(name, bench_file=ROOT / "BENCHMARK.json"):
 
 
 def test_the_harness_takes_only_what_it_may_from_the_program():
-    """bench.py, synth.py, oracle.py and the object session are not imported;
-    no knob of the program is set."""
+    """bench.py, synth.py, oracle.py, the object session and ``Service`` are
+    not imported; no knob of the program is set; the harness itself watches
+    nothing (the controllers it builds under ``entry: jobs`` do, as in a
+    deployment)."""
     banned = re.compile(r"^\s*(from|import)\s+(bench|volcano_tpu\.(synth|oracle|"
-                        r"session|framework|actions))\b", re.M)
+                        r"session|framework|actions|service|sim))\b", re.M)
     for f in (ROOT / "benchmark").rglob("*.py"):
         text = f.read_text()
         assert not banned.search(text), f
         assert "VOLCANO_TPU_" not in text, f
+        assert not re.search(r"\.watch\(", text), f
     ref = (ROOT / "benchmark" / "reference" / "score_ref.py").read_text()
     assert "volcano_tpu" not in ref.split('"""', 2)[2]
     val = (ROOT / "benchmark" / "harness" / "validate.py").read_text()
@@ -238,7 +248,15 @@ def test_the_harness_takes_only_what_it_may_from_the_program():
         ("volcano_tpu.api", "PriorityClass"), ("volcano_tpu.api", "PodPhase"),
         ("volcano_tpu.api", "GROUP_NAME_ANNOTATION"),
         ("volcano_tpu.api", "AffinityTerm"), ("volcano_tpu.api", "Pod"),
-        ("volcano_tpu.api", "PodGroup")}
+        ("volcano_tpu.api", "PodGroup"),
+        # entry: jobs (harness/jobs.py, generate.to_jobs): these and no more
+        ("volcano_tpu.controllers", "ControllerManager"),
+        ("volcano_tpu.controllers", "Job"),
+        ("volcano_tpu.controllers", "TaskSpec"),
+        ("volcano_tpu.controllers", "LifecyclePolicy"),
+        ("volcano_tpu.controllers", "JobPhase"),
+        ("volcano_tpu.webhooks", "AdmittedStore")}
+    assert not [name for _mod, name in taken if name.startswith("_")]
     readme = (ROOT / "benchmark" / "README.md").read_text()
     for _mod, name in taken:
         assert f"`{name}`" in readme or f"`volcano_tpu.{name}`" in readme, name
@@ -248,6 +266,19 @@ def test_the_harness_takes_only_what_it_may_from_the_program():
                      "async_bind", "add_pod_group", "add_pod", "update_pod",
                      "delete_pod", "delete_pod_group", "flush_binds", "pods",
                      "flight", "close"}, calls
-    for name in calls - {"async_bind", "close"}:
+    # entry: jobs: what harness/jobs.py reads and calls, on the store (the
+    # kubelet's side and the records a Job leaves) and on the two objects
+    # that stand for the controller plane
+    jobs_py = (ROOT / "benchmark" / "harness" / "jobs.py").read_text()
+    body = jobs_py.split('"""', 2)[2]
+    on_store = set(re.findall(r"\bstore\.(\w+)\b", body))
+    assert on_store == {"pods", "batch_jobs", "pod_groups", "update_pod",
+                        "delete_pod"}, on_store
+    assert set(re.findall(r"\badmitted\.(\w+)\b", body + loop_py)) \
+        == {"add_batch_job", "delete_batch_job", "add_queue"}
+    assert set(re.findall(r"\bmanager\.(\w+)\b", body)) == {"process"}
+    for name in (calls | on_store | {"add_batch_job", "delete_batch_job",
+                                     "process"}) - {"async_bind", "close"}:
         assert f"`{name}`" in readme or f"`store.{name}" in readme \
-            or f"`ClusterStore.{name}" in readme, name
+            or f"`ClusterStore.{name}" in readme \
+            or f".{name}`" in readme or f".{name}()`" in readme, name

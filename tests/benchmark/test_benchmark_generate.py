@@ -218,3 +218,71 @@ def test_a_class_that_names_its_queues_is_dealt_there_and_only_there():
 def test_a_class_that_names_a_queue_the_configuration_lacks_raises(names):
     with pytest.raises(ValueError, match="names queues"):
         generate.Generator(_with_queues(names), BIG_SEED)
+
+
+# ---- ISSUE 52: a gang that enters as a Job ----------------------------------
+
+JOBS = {**CONFIG, "gang": {"size": 6, "min_member": 3},
+        "queues": {"count": 2, "weights": [1, 3]},
+        "job": {"plugins": {"ssh": [], "env": [], "svc": ["--disable-network-policy"]},
+                "policies": [{"event": "PodEvicted", "action": "RestartJob"},
+                             {"exit_code": 3, "action": "AbortJob"}],
+                "max_retry": 5}}
+
+
+def test_a_gangs_own_floor_makes_it_elastic_without_a_class():
+    plan = generate.Generator(JOBS, BIG_SEED).plan(20, "x")
+    assert plan.sizes().tolist() == [6, 6, 6, 2]
+    assert plan.gang_min_member.tolist() == [3, 3, 3, 2]
+    assert plan.gang_priority == [] and plan.gang_max_unavailable == []
+    gangs = generate.to_objects(plan, itertools.count(1))
+    assert [(pg.min_member, len(pods)) for pg, pods in gangs] \
+        == [(3, 6), (3, 6), (3, 6), (2, 2)]
+
+
+def test_under_jobs_the_plan_is_the_same_draw_with_the_controllers_names():
+    pods = generate.Generator(JOBS, BIG_SEED).plan(20, "x")
+    jobs = generate.Generator(JOBS, BIG_SEED, entry="jobs").plan(20, "x")
+    assert pods.names[:2] == ["x-pg-000000-0", "x-pg-000000-1"]
+    assert jobs.names[:2] == ["x-pg-000000-worker-0", "x-pg-000000-worker-1"]
+    assert jobs.names[-1] == "x-pg-000003-worker-1"
+    assert jobs.job_keys() == ["default/x-pg-00000%d" % g for g in range(4)]
+    for name in ("cpu_milli", "mem_bytes", "gang", "gang_min_member"):
+        assert np.array_equal(getattr(pods, name), getattr(jobs, name)), name
+    assert (pods.gang_names, pods.gang_queue, pods.gang_cpu, pods.gang_mem_gi) \
+        == (jobs.gang_names, jobs.gang_queue, jobs.gang_cpu, jobs.gang_mem_gi)
+
+
+def test_a_gang_becomes_one_job_of_one_task_with_the_configurations_block():
+    from volcano_tpu.controllers import JobController
+
+    plan = generate.Generator(JOBS, BIG_SEED, entry="jobs").plan(20, "x")
+    gangs = generate.to_jobs(plan, itertools.count(1), JOBS["job"])
+    assert [keys for _job, keys in gangs] \
+        == [plan.keys()[0:6], plan.keys()[6:12], plan.keys()[12:18],
+            plan.keys()[18:20]]
+    stamps = [job.creation_timestamp for job, _keys in gangs]
+    assert stamps == sorted(stamps) == [1.0, 2.0, 3.0, 4.0]
+    for g, (job, keys) in enumerate(gangs):
+        assert (job.key, job.queue, job.priority_class) \
+            == (plan.job_keys()[g], plan.gang_queue[g], "")
+        assert job.min_available == int(plan.gang_min_member[g])
+        (task,) = job.tasks
+        assert (task.name, task.replicas) == ("worker", len(keys))
+        assert task.containers == [{"cpu": str(plan.gang_cpu[g]),
+                                    "memory": f"{plan.gang_mem_gi[g]}Gi"}]
+        assert job.plugins == JOBS["job"]["plugins"]
+        assert job.plugins is not JOBS["job"]["plugins"]
+        assert [(p.event, p.exit_code, p.action) for p in job.policies] \
+            == [("PodEvicted", None, "RestartJob"), ("", 3, "AbortJob")]
+        assert job.max_retry == 5
+        # the names the plan states are the ones the controller will give
+        assert [f"default/{JobController._pod_name(None, job, task, i)}"
+                for i in range(task.replicas)] == keys
+    # no block: none of it
+    (bare, _keys) = generate.to_jobs(plan, itertools.count(1))[0]
+    assert (bare.plugins, bare.policies, bare.max_retry) == ({}, [], 3)
+    # a class on the gang is the Job's
+    tiers = generate.Generator(TIERS, BIG_SEED, entry="jobs").plan(8, "t", klass="high")
+    assert {job.priority_class for job, _k in generate.to_jobs(
+        tiers, itertools.count(1))} == {"high"}

@@ -248,3 +248,69 @@ def test_every_count_is_printed_beside_its_limit():
     assert v.failed == 8 + 3 and set(v.compared()) \
         == set(validate.LIMITED) | {"of_the_configs_own"}
     assert any("may wait" in ln and "(no limit)" in ln for ln in lines)
+
+
+# ---- ISSUE 52: a round whose gangs entered as Jobs --------------------------
+
+JOBS = {**CONFIG, "gang": {"size": 2, "min_member": 1}}
+
+
+def _job_round(created=None, not_running=(), left_behind=(), bound=None):
+    plan = generate.Generator(JOBS, seed=1, entry="jobs").plan(4, "j0")
+    keys, owners = plan.keys(), plan.job_keys()
+    if created is None:
+        created = [(k, owners[g]) for k, g in zip(keys, plan.gang.tolist())]
+    bound = keys if bound is None else bound
+    ev = validate.RoundEvents(
+        plan, [(1, list(bound), [NODES[i % 2] for i in range(len(bound))])],
+        jobs=validate.JobEvents(created, list(not_running), list(left_behind)))
+    return plan, validate.check(NODES, ALLOC, [ev])
+
+
+def test_a_sound_round_of_jobs_passes_and_prints_its_three_counts():
+    plan, v = _job_round()
+    assert plan.names[0] == "j0-pg-000000-worker-0"
+    assert v.ok and v.of_jobs == dict.fromkeys(validate.OF_JOBS, 0)
+    assert list(v.compared())[len(validate.LIMITED):] == list(validate.OF_JOBS)
+    for name in validate.OF_JOBS:
+        assert f"validate: {name} = 0 (limit 0)" in v.lines()
+
+
+def test_a_round_of_pods_has_none_of_the_jobs_counts():
+    v = _check(_plan(), [])
+    assert v.of_jobs == {} and not set(validate.OF_JOBS) & set(v.compared())
+
+
+@pytest.mark.parametrize("change,count", [
+    (lambda c: c[1:], 1),                                   # one missing
+    (lambda c: c + [c[0]], 1),                              # one twice
+    (lambda c: c + [("default/j0-pg-000000-worker-9", c[0][1])], 1),   # one more
+    (lambda c: [("default/j0-pg-000000-workerx-0", c[0][1])] + c[1:], 1),  # renamed
+    (lambda c: c[2:], 2),                                   # a Job's pods, all
+    (lambda c: c + [("default/a-stranger-0", "default/another-job")], 0),
+    (lambda c: [(k, "default/j0-pg-000001") for k, _o in c], 4)],  # wrong owner
+    ids=["missing", "twice", "more", "renamed", "a-whole-job",
+         "another-jobs-pod", "under-the-wrong-owner"])
+def test_pods_not_as_planned_counts_per_job_the_pods_that_are_off(change, count):
+    plan, _ = _job_round()
+    created = [(k, o) for k, o in zip(
+        plan.keys(), [plan.job_keys()[g] for g in plan.gang.tolist()])]
+    _plan_, v = _job_round(created=change(created))
+    assert v.of_jobs["pods_not_as_planned"] == count
+    assert v.ok == (count == 0) and v.failed == count
+
+
+def test_jobs_not_running_counts_a_job_whose_floor_of_pods_was_bound():
+    plan, v = _job_round(not_running=["default/j0-pg-000001"])
+    assert v.of_jobs["jobs_not_running"] == 1 and v.failed == 1
+    # a Job of which nothing was bound is not held to Running: unbound holds it
+    _p, v = _job_round(not_running=["default/j0-pg-000001"], bound=plan.keys()[:2])
+    assert v.of_jobs["jobs_not_running"] == 0 and v.unbound == 2
+    # min_available 1 of 2: one pod bound is the floor
+    _p, v = _job_round(not_running=["default/j0-pg-000001"], bound=plan.keys()[:3])
+    assert v.of_jobs["jobs_not_running"] == 1 and v.unbound == 1 and v.split == 0
+
+
+def test_jobs_left_behind_counts_the_jobs_the_driver_names():
+    _p, v = _job_round(left_behind=["default/j0-pg-000000", "default/old-job"])
+    assert v.of_jobs["jobs_left_behind"] == 2 and not v.ok and v.failed == 2
