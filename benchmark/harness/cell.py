@@ -115,9 +115,38 @@ def load_cell(workload: str, benchmark_file: Path = ROOT / "BENCHMARK.json") -> 
             raise SystemExit(f"{configs[w['config']]['file']}: guarantees."
                              f"checks names {name!r}, and there is no "
                              f"{checks.path_of(home, name)}")
-    return Cell(
+    cell = Cell(
         home=home,
         name=workload, chips=int(w["chips"]), config_name=w["config"],
         config=config, traffic_name=w["traffic"], traffic=traffic,
         end_to_end=[e for e in bench["end_to_end"] if _applies(e, workload)],
         per_layer=per_layer)
+    if "fill_pods" in config.get("probe", {}):
+        _hold_the_probes_fill(cell)
+    return cell
+
+
+def _hold_the_probes_fill(cell: Cell) -> None:
+    """A configuration whose probe brings a fill of its own (``probe.
+    fill_pods``): the window's pods, the batch-sized fill, that fill and the
+    probes together, each at the largest size, stay under the cluster's cpu,
+    memory and pod slots, or the cell does not load."""
+    sizes, nodes, probe = cell.sizes(), cell.config["nodes"], cell.config["probe"]
+    own = int(probe["fill_pods"])
+    if own < 1:
+        raise SystemExit(f"cell {cell.name}: probe.fill_pods is {own}; a fill "
+                         "is at least one pod, and no fill is no key")
+    pods = sizes["resident_pods"] + 2 * sizes["batch_pods"] + own \
+        + int(probe["probes"])
+    pod = cell.config["pods"]
+    room = {"cpu": (max(pod["cpu_choices"]), int(nodes["cpu"])),
+            "memory_gi": (max(pod["mem_gi_choices"]), int(nodes["memory_gi"])),
+            "pods": (1, int(nodes["pods"]))}
+    for what, (each, a_node) in room.items():
+        if pods * each >= int(nodes["count"]) * a_node:
+            raise SystemExit(
+                f"cell {cell.name}: probe.fill_pods {own}: the window's "
+                f"{sizes['resident_pods'] + sizes['batch_pods']} pods, the "
+                f"fill's {sizes['batch_pods']}, the probe's own {own} and its "
+                f"{probe['probes']} probes are {pods * each} {what} of the "
+                f"cluster's {int(nodes['count']) * a_node}")

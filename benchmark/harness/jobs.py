@@ -69,7 +69,10 @@ class JobEntry:
 
     def _note_created(self) -> None:
         """The records added to ``store.pods`` since the last look: its
-        tail, back to the first record already known."""
+        tail, back to the first record already known.  A record that names
+        no owner entered as a pod (the probe's own fill): it is known from
+        now on, so that the next look stops at it, and was made under no
+        Job."""
         uid_of = self.uid_of
         fresh = []
         for pod in reversed(self.store.pods.values()):
@@ -79,7 +82,8 @@ class JobEntry:
             fresh.append((key, pod))
         for key, pod in reversed(fresh):
             uid_of[key] = pod.uid
-            self.created.append((key, pod.owner_job))
+            if pod.owner_job:
+                self.created.append((key, pod.owner_job))
 
     def _pump_until(self, phase: str, keys: List[str], reads: str) -> List[str]:
         """Pumps, at most ``max_pumps``, until every Job of ``keys`` reads

@@ -272,8 +272,8 @@ class Driver:
             while left > 0 and self.fifo:
                 pg, pods = self.fifo.popleft()
                 left -= len(pods)
-                if jobs is not None:
-                    finishing.append((pg, pods))
+                if jobs is not None and isinstance(pods[0], str):
+                    finishing.append((pg, pods))    # a Job, its pods' keys
                     continue
                 if self._terminating or self._gangs is not None:
                     self._complete_with_victims(pg, pods)
@@ -322,6 +322,41 @@ class Driver:
             rec.pumps_used_up = jobs.used_up
         if self.read_lanes:
             rec.lanes, rec.records = self._records(cycles + settled)
+        self.rounds.append(rec)
+        return rec
+
+    def place(self, plan: generate.Plan, hosts: List[str]) -> Round:
+        """A round of pods that arrive bound: the client names each pod's
+        node in its ``add_pod`` (and, where the traffic says ``pods_run``,
+        reports it Running there), as a scheduler finds the pods of a
+        cluster it takes over.  They enter as pods whatever the traffic's
+        ``entry`` and belong to no Job; no cycle, pump or completion is the
+        round's, and the binder sees nothing of it: its one arrival is the
+        client's own placement, which ``validate`` holds like a bind.  The
+        probe's own fill (``probe.fill_pods``) and nothing of a window."""
+        from volcano_tpu.api import PodPhase
+
+        gangs = generate.to_objects(plan, self.stamps, self.priority_values)
+        store, now = self.store, time.perf_counter_ns
+        submit_ns = np.empty(plan.n_pods, dtype=np.int64)
+        t_start = now()
+        i = 0
+        for pg, pods in gangs:
+            store.add_pod_group(pg)
+            for pod in pods:
+                pod.node_name = hosts[i]
+                if self.pods_run:
+                    pod.phase = PodPhase.Running
+                submit_ns[i] = now()
+                store.add_pod(pod)
+                i += 1
+        t = now()
+        self.fifo.extend(gangs)
+        if self._gangs is not None:
+            self._gangs.update((pg.name, (pg, pods)) for pg, pods in gangs)
+        rec = Round(plan, submit_ns, t_start, t, t, t, t, t, cycles=0,
+                    run_once_s=0.0, arrivals=[(t, plan.keys(), list(hosts))],
+                    deleted=[])
         self.rounds.append(rec)
         return rec
 

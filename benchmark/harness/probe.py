@@ -13,6 +13,20 @@ configuration):
 1. one more batch of the cell's own size is submitted and scheduled and not
    completed, so the cluster stands as the timed solve leaves it (a burst
    cell: the whole backlog bound; a churn cell: the residents and a batch);
+   where the configuration's ``probe`` block says ``fill_pods``, that many
+   more in the configuration's gang shape and pod size, which the client
+   places itself (``Driver.place``: they arrive bound, as pods whatever the
+   traffic's ``entry``), one at a time on the node that holds the fewest
+   pods, lowest index among ties, by the ledger of the run so far, and
+   which are not completed either.  The control can miss only where no
+   node stands nearly empty: a 1 cpu / 1 Gi pod on a 64 cpu / 256 Gi node
+   scores 20 - 30 k / 256 as the node's k-th pod, the emptiest node wins,
+   and bfloat16 tells a node's 1st, 2nd, 3rd and 4th pod apart and scores
+   its 4th and 5th alike (19.5).  A cell whose batch is small against its
+   cluster gets to 3 and 4 pods a node by this fill alone, and not through
+   the scheduler: the wave solver gives a burst's k-th pod the k-th best
+   node scaled by what the best still holds, so a burst of one pod size
+   lands 64 a node and leaves the other nodes empty (PERF.md section 2);
 2. ``before_drain`` one-pod gangs are submitted, one per cycle, on that
    state;
 3. the client lets the oldest jobs finish until ``keep_pods`` pods remain
@@ -29,13 +43,14 @@ the program chose must be the one ``reference/score_ref.py`` names.
 
 from __future__ import annotations
 
+import heapq
 from dataclasses import dataclass, field
-from typing import List, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
 from ..reference import score_ref
-from . import validate
+from . import generate, validate
 
 
 @dataclass
@@ -46,6 +61,8 @@ class ProbeVerdict:
     smallest_gap: float = float("inf")  # between the two best distinct scores
     most_tied: int = 0                  # nodes within TIE of the best, at most
     missed: List[bool] = field(default_factory=list)   # probe by probe
+    # at the first probe: pods a node holds -> nodes that hold so many
+    nodes_by_pods: Dict[int, int] = field(default_factory=dict)
     examples: List[str] = field(default_factory=list)
 
     @property
@@ -69,8 +86,11 @@ def drive(driver, gen, batch_pods: int, par: dict) -> int:
     """Go on with the timed ``driver`` after the window: the fill, the
     one-pod gangs before the drain, the drain, the rest of them (``par`` is
     the configuration's ``probe`` block; where the traffic names the
-    batches' priority class, ``gen`` deals it to these too).  Returns the
-    index in ``driver.rounds`` of the first probe round."""
+    batches' priority class, ``gen`` deals it to these too).  With
+    ``fill_pods`` the fill is two rounds: the batch-sized one through the
+    cell's own entry and the scheduler, then that many pods more which the
+    client places.  Returns the index in ``driver.rounds`` of the first
+    probe round."""
     probes, before = int(par["probes"]), int(par.get("before_drain", 0))
     keep = int(par.get("keep_pods", 0))
 
@@ -80,11 +100,48 @@ def drive(driver, gen, batch_pods: int, par: dict) -> int:
 
     fill = gen.plan(batch_pods, "probefill")
     driver.round(fill, 0 if before else drain(fill))
+    if par.get("fill_pods"):
+        # After the batch-sized fill: as Jobs, that one is the controllers'
+        # to make, and a job controller that walks every pod of the store
+        # for each (PERF.md section 7 (5)) walks the fewer the earlier.
+        own = gen.plan(int(par["fill_pods"]), "probefill-own")
+        driver.place(own, _deal(driver, own))
     first = len(driver.rounds)
     for k in range(probes):
         plan = gen.plan(1, f"probe{k:03d}", gang_size=1)
         driver.round(plan, drain(plan) if k + 1 == before else 0)
     return first
+
+
+def _deal(driver, plan) -> List[str]:
+    """The node of each pod of ``plan``, dealt one at a time to the node
+    that holds the fewest pods and has room for it, lowest index among
+    ties, on the cluster as the ledger of the driver's rounds so far leaves
+    it: the nodes end level to within one pod, and those that hold one more
+    are the first of them."""
+    names = generate.node_names(driver.config)
+    ledger = validate.Ledger(names, generate.node_alloc(driver.config))
+    for r in driver.rounds:
+        ledger.apply(r.events())
+    alloc, used = ledger.alloc, ledger.used
+    heap = [(held, i) for i, held in enumerate(used[:, 2].tolist())]
+    heapq.heapify(heap)
+    hosts = []
+    for cpu, mem in zip(plan.cpu_milli.tolist(), plan.mem_bytes.tolist()):
+        full = []
+        while True:
+            if not heap:
+                raise RuntimeError(f"the probe's own fill: no node has room "
+                                   f"for pod {len(hosts)} of {plan.n_pods}")
+            held, i = heapq.heappop(heap)
+            if np.all(used[i] + (cpu, mem, 1) <= alloc[i]):
+                break
+            full.append((held, i))
+        used[i] += (cpu, mem, 1)
+        hosts.append(names[i])
+        for entry in full + [(held + 1, i)]:
+            heapq.heappush(heap, entry)
+    return hosts
 
 
 def check(node_names: Sequence[str], alloc: np.ndarray,
@@ -101,6 +158,9 @@ def check(node_names: Sequence[str], alloc: np.ndarray,
     ledger = validate.Ledger(node_names, alloc)
     out = ProbeVerdict()
     for i, ev in enumerate(events):
+        if i == first_probe:
+            held, n = np.unique(ledger.used[:, 2], return_counts=True)
+            out.nodes_by_pods = dict(zip(held.tolist(), n.tolist()))
         if i >= first_probe:
             _compare(ledger, ev, control_dtype, out)
         ledger.apply(ev)

@@ -115,7 +115,11 @@ def end_to_end(counted, setup_s: float, window_end_ns: int) -> dict:
         seen = t_bind >= 0
         bound += int(seen.sum())
         if seen.all():
-            backlog_ms.append((int(t_bind.max()) - r.t_submitted) / 1e6)
+            # The backlog stands once the user has made the last call: the
+            # last add_pod; under entry: jobs the last add_batch_job, and
+            # not the controllers' first pump after it.
+            stands = r.t_submitted if r.jobs is None else r.t_admitted
+            backlog_ms.append((int(t_bind.max()) - stands) / 1e6)
         # A pod not bound within its round misses any limit: its wait is
         # taken to the end of the window.
         t_bind[~seen] = window_end_ns
@@ -303,7 +307,15 @@ def run(cell, seed: int, seconds: float, trace: bool, make_scheduler=None,
             f"{ctl.misses} of {ctl.probes} choices differ, worst shortfall "
             f"{ctl.worst_shortfall:.6f} ("
             + "".join("x" if m else "." for m in ctl.missed) + ")")
-    say(f"after the window: the probe's fill and {probe_verdict.probes} "
+    fill = "the probe's fill"
+    if cell.config["probe"].get("fill_pods"):
+        own = all_rounds[first_probe - 1]
+        fill += (f" (and {own.plan.n_pods} pods of its own, placed by the "
+                 f"client as pods: {own.spans()['round']:.3f} s; at the first "
+                 "probe "
+                 + ", ".join(f"{n} nodes hold {k}" for k, n
+                             in probe_verdict.nodes_by_pods.items()) + ")")
+    say(f"after the window: {fill} and {probe_verdict.probes} "
         f"one-pod cycles {t_check - t_probe:.3f} s, validation and reference "
         f"{time.perf_counter() - t_check:.3f} s, of which the configuration's "
         f"own checks {checks.names(cell.config)} {own_s:.3f} s (none of it in "

@@ -4,10 +4,16 @@ traffic mix and a plain cell under it, a second plain cell on four chips, a
 configuration that holds evictions (``preempt-10k`` at ``PERF.md`` section
 7 (3)'s shape) with its traffic, its check and its cell, a per-layer metric
 of that cell alone and one of every cell, a traffic mix whose gangs enter as
-Jobs (``entry: jobs``) and a plain cell under it.  New files and appended
-entries, nothing else; the contract and the configuration tests' loading assertions
-then pass on the copy, and the cells the benchmark has load as they did.
-Files are loaded; no scheduler runs.
+Jobs (``entry: jobs``) with a plain cell under it and, in the shape the next
+``model_config`` PR brings (``service-gang``, BASELINE configs[0]), a
+configuration with ``gang.min_member``, a ``job`` block, ``probe.fill_pods``
+and a check of its own, its cell under that traffic and ten per-layer metrics
+of that cell alone, one of them a nested key of ``between``.  New files and
+appended entries, nothing else; the contract, the configuration tests'
+loading assertions and every test of this directory that goes over the real
+benchmark's cells, configurations or metrics then pass on the copy, and the
+cells the benchmark has load as they did.  Files are loaded; no scheduler
+runs.
 
 Every name added here ends in ``-added`` or ``_added``, so that the real
 ``preempt-10k`` (or any other cell, mix or metric) can come in beside them:
@@ -21,11 +27,16 @@ import pytest
 
 import test_benchmark_affinity_config as affinity_test
 import test_benchmark_contract as contract
+import test_benchmark_cycle_lanes as lanes_test
 import test_benchmark_drf_config as drf_test
+import test_benchmark_generate as generate_test
 import test_benchmark_hyper_config as hyper_test
+import test_benchmark_preempt_config as preempt_test
+import test_benchmark_whatif_counters as whatif_test
 from benchmark.harness import cell as cell_mod
 from benchmark.harness import generate
-from test_benchmark_cell import CONF_PREEMPT, toy_preempt  # noqa: F401
+from test_benchmark_cell import (CONF_PREEMPT, JOB_BLOCK,  # noqa: F401
+                                 toy_preempt)
 
 ROOT = cell_mod.ROOT
 EVICT = "preempt-10k-added.evict-added"
@@ -34,8 +45,12 @@ NEW_CELLS = {
     "hyper-50k.churn-added": ("hyper-50k", "churn-added", 4),   # (2) four chips
     EVICT: ("preempt-10k-added", "evict-added", 1),         # (3) holds evictions
     "binpack-1k.asjobs-added": ("binpack-1k", "asjobs-added", 1),   # (6) as Jobs
+    "service-gang-added.asjobs-added":                  # and a Job's own config
+        ("service-gang-added", "asjobs-added", 1),
 }
 JOBS = "binpack-1k.asjobs-added"
+SERVICE_GANG = "service-gang-added.asjobs-added"
+AS_JOBS = (JOBS, SERVICE_GANG)
 # (4) of the evicting cell alone, (5) of every cell
 NEW_METRICS = {
     "preempt_plan_ms_added": ("what-if engine", "span_self",
@@ -44,12 +59,33 @@ NEW_METRICS = {
                              {"lanes": ["encode"], "scale": 1e3}, None),
 }
 ALL_CELLS_METRIC = "encode_lane_ms_added"
+# (6) ten of the cell that enters as Jobs alone: its spans, and the store's
+# own account of the time between two cycles by a dotted key
+for _name, _reader, _args in (
+        ("admit_us_per_pod_added", "span", {"span": "admit", "per": "pod", "scale": 1e6}),
+        ("pump_ms_added", "span", {"span": "pump", "scale": 1e3}),
+        ("reconcile_ms_added", "span", {"span": "reconcile", "scale": 1e3}),
+        ("submit_ms_added", "span", {"span": "submit", "scale": 1e3}),
+        ("complete_ms_added", "span", {"span": "complete", "scale": 1e3}),
+        ("notify_self_ms_added", "span_self", {"name": "commit:notify", "scale": 1e3}),
+        ("pod_updates_added", "record", {"key": "between.events.Pod/update.n"}),
+        ("pod_adds_added", "record", {"key": "between.events.Pod/add.n"}),
+        ("job_spec_rows_added", "record", {"key": "between.spec_rows", "reduce": "max"}),
+        ("lock_held_ms_added", "record", {"key": "between.lock_held_s", "scale": 1e3})):
+    NEW_METRICS[_name] = ("admission and controllers", _reader, _args, [SERVICE_GANG])
 STUB_REF = '''"""A stand-in: the victims' reference comes with the cell."""
 
 
 def check(events, nodes, config):
     return {"victims_unjudged": 0}
 '''
+JOB_STUB_REF = '''"""A stand-in: the replay of a Job's lifecycle comes with the cell."""
+
+
+def check(events, nodes, config):
+    return {"pods_no_job_desires": 0}
+'''
+FILL_PODS = 32_000
 
 
 def add_to(root):
@@ -84,25 +120,44 @@ def add_to(root):
     # (6) burst with the user one layer further out: admission, the controllers
     asjobs = dict(burst, name="asjobs-added", entry="jobs", pods_run=True,
                   max_cycles=6, max_pumps=8)
+    # ... and the configuration of a Job: examples/job.yaml on north-10k's nodes
+    north = json.loads((home / "configs" / "north-10k.json").read_text())
+    jobs_cfg = dict(
+        north, name="service-gang-added", source="BASELINE.json configs[0]: "
+        "example/job.yaml 3-replica gang PodGroup, through admission and the "
+        "controllers", pods={"cpu_choices": [1], "mem_gi_choices": [1]},
+        gang={"size": 6, "min_member": 3}, queues={"count": 1}, job=JOB_BLOCK,
+        backlog_pods=100_002,
+        probe={"probes": 12, "fill_pods": FILL_PODS},
+        guarantees=dict(north["guarantees"], checks=["job_added"]),
+        capacity_arithmetic="at most 100,002 pods of a round + the probe's "
+        "100,002 + its own fill of 32,000 + 12 probe pods, x 1 cpu = 232,016 of "
+        "10,000 x 64 = 640,000 cpu; x 1 Gi of 2,560,000 Gi; of 2,560,000 pod slots")
     new = {home / "configs" / "preempt-10k-added.json": json.dumps(cfg),
+           home / "configs" / "service-gang-added.json": json.dumps(jobs_cfg),
+           home / "reference" / "job_added_ref.py": JOB_STUB_REF,
            home / "traffic" / "evict-added.json": json.dumps(traffic),
            home / "traffic" / "churn-added.json":
                json.dumps(dict(churn, name="churn-added")),
            home / "traffic" / "asjobs-added.json": json.dumps(asjobs),
            home / "reference" / "preempt_added_ref.py": STUB_REF}
     bench = json.loads((root / "BENCHMARK.json").read_text())
-    bench["configs"].append({
-        "name": cfg["name"], "source": cfg["source"], "reduced": cfg["reduced"],
-        "file": "benchmark/configs/preempt-10k-added.json", "why": "a full cluster"})
+    for held, why in ((cfg, "a full cluster"), (jobs_cfg, "a Job, as the user sends it")):
+        bench["configs"].append({
+            "name": held["name"], "source": held["source"], "why": why,
+            "reduced": held["reduced"],
+            "file": f"benchmark/configs/{held['name']}.json"})
     for name, (config, mix, chips) in NEW_CELLS.items():
         bench["workloads"].append({"name": name, "config": config,
                                    "traffic": mix, "chips": chips, "why": name})
     for name, (layer, reader, args, cells) in NEW_METRICS.items():
-        on_file = {"name": name, "unit": "ms", "layer": layer,
+        unit = "us/pod" if args.get("per") else "ms" if "scale" in args else "count"
+        on_file = {"name": name, "unit": unit, "layer": layer,
                    "moves": "backlog_to_bind_ms", "reader": reader, "args": args}
         new[home / "layer_metrics" / f"{name}.json"] = json.dumps(on_file)
-        entry = {"name": name, "unit": "ms", "better": "lower", "layer": layer,
-                 "source": "program_span", "moves": "backlog_to_bind_ms"}
+        entry = {"name": name, "unit": unit, "better": "lower", "layer": layer,
+                 "source": {"span": "host_clock", "record": "program_counter"}
+                 .get(reader, "program_span"), "moves": "backlog_to_bind_ms"}
         bench["per_layer"].append(dict(entry, workloads=cells) if cells else entry)
     for path, text in new.items():
         assert not path.exists(), path
@@ -192,14 +247,91 @@ def test_the_cell_whose_gangs_enter_as_jobs_is_plain_and_says_so(grown):
             if k not in ("entry", "max_pumps", "pods_run", "max_cycles")} \
         == {k: v for k, v in was.items()
             if k not in ("entry", "max_pumps", "pods_run", "max_cycles")}
-    for name in contract.CELLS:
-        assert cell_mod.load_cell(name, grown).sizes()["entry"] == "pods", name
+    # every cell says how it enters: as pods unless its traffic says jobs,
+    # and one that does states what a Jobs cell must
+    as_jobs = []
+    for name in contract.CELLS + list(NEW_CELLS):
+        cell = cell_mod.load_cell(name, grown)
+        entry = cell.sizes()["entry"]
+        if cell.traffic.get("entry") != "jobs":
+            assert entry == "pods", name
+            continue
+        as_jobs.append(name)
+        assert entry == "jobs" and cell.sizes()["pods_run"], name
+        assert cell.sizes()["max_cycles"] >= 2, name
+        assert not any(float(x) > 0
+                       for x in cell.config.get("affinity_mix", {}).values()), name
+        assert not contract.holds_evictions(cell), name
+    # the two added here are held so; a real cell may be, and none is yet
+    assert [name for name in as_jobs if name in NEW_CELLS] == list(AS_JOBS)
     # the same draw as the burst's, under the names the controller will give
     plan = generate.Generator(c.config, 2**31 + 52, entry=sizes["entry"]) \
         .plan(sizes["batch_pods"], "w0000")
     assert plan.names[0] == "w0000-pg-000000-worker-0"
     assert len(generate.to_jobs(plan, iter(range(1, 10**6)),
                                 c.config.get("job"))) == len(plan.gang_names)
+
+
+def test_the_configuration_of_a_job_states_its_gang_its_block_and_its_fill(grown):
+    """``service-gang``'s shape by files and entries: an elastic gang without
+    a class, ``examples/job.yaml``'s block, a check of its own and a probe
+    that brings a fill of its own, which the file's arithmetic counts."""
+    c = cell_mod.load_cell(SERVICE_GANG, grown)
+    contract.test_every_cells_files_resolve(SERVICE_GANG, grown)
+    cfg, sizes = c.config, c.sizes()
+    assert (sizes["entry"], sizes["pods_run"], sizes["batch_pods"]) \
+        == ("jobs", True, 100_002)
+    assert cfg["gang"] == {"size": 6, "min_member": 3} and cfg["job"] == JOB_BLOCK
+    assert cfg["probe"] == {"probes": 12, "fill_pods": FILL_PODS}
+    assert f"{FILL_PODS:,}" in cfg["capacity_arithmetic"]
+    assert cfg["guarantees"]["checks"] == ["job_added"]
+    assert (c.home / "reference" / "job_added_ref.py").is_file()
+    assert "priority_classes" not in cfg and not contract.holds_evictions(c)
+    plan = generate.Generator(cfg, 2**31 + 54, entry="jobs").plan(600, "w0000")
+    assert plan.sizes().tolist() == [6] * 100
+    assert plan.gang_min_member.tolist() == [3] * 100
+    job, keys = generate.to_jobs(plan, iter(range(1, 10**4)), cfg["job"])[0]
+    assert (job.min_available, job.max_retry, sorted(job.plugins), len(keys)) \
+        == (3, 5, ["env", "ssh", "svc"], 6)
+    # ten metrics of this cell alone, one a nested key of ``between``
+    own = [m for m in c.per_layer if m.get("workloads") == [SERVICE_GANG]]
+    assert len(own) == 10
+    assert {"reader": "record", "args": {"key": "between.events.Pod/update.n"}}.items() \
+        <= next(m for m in own if m["name"] == "pod_updates_added").items()
+    assert not [m for m in cell_mod.load_cell(JOBS, grown).per_layer if m in own]
+
+
+OVER_THE_REAL_BENCHMARK = (
+    [(whatif_test.test_the_counter_is_an_appended_entry_and_a_file_of_this_cell, m)
+     for m in whatif_test.TWO]
+    + [(preempt_test.test_the_metric_reports_on_this_cell_and_no_other, m)
+       for m in preempt_test.NINE]
+    + [(preempt_test.test_the_seven_cells_load_as_they_did, n)
+       for n in sorted(contract.SEVEN)]
+    + [(lanes_test.test_the_file_resolves_against_its_entry, n)
+       for n in sorted(lanes_test.LANE_ALL) + sorted(lanes_test.PROFILE)]
+    + [(lanes_test.test_they_are_the_last_eight_entries_and_nothing_else_moved,),
+       (hyper_test.test_the_file_states_its_deployment,)]
+    + [(generate_test.test_the_seven_cells_plans_are_the_parents_byte_for_byte, n)
+       for n in sorted(generate_test.PARENT_DIGEST)])
+
+
+@pytest.mark.parametrize(
+    "test", OVER_THE_REAL_BENCHMARK,
+    ids=lambda t: "-".join([t[0].__module__[15:], t[0].__name__[5:30], *t[1:]]))
+def test_what_goes_over_the_real_benchmark_holds_of_the_grown_one(
+        grown, monkeypatch, test):
+    """The tests of this directory that go over ``BENCHMARK.json``'s cells,
+    configurations or metrics by a loop or by name, read here with the grown
+    file in the real one's place: a cell that enters as Jobs, its
+    configuration and its ten metrics fail none of them."""
+    bench = json.loads(grown.read_text())
+    load = cell_mod.load_cell
+    monkeypatch.setattr(cell_mod, "load_cell", lambda name, benchmark_file=grown:
+                        load(name, benchmark_file))
+    for module in (contract, lanes_test, hyper_test):
+        monkeypatch.setattr(module, "BENCH", bench)
+    test[0](*test[1:])
 
 
 def test_the_evicting_cells_bursts_go_to_the_queues_its_class_names(grown):

@@ -8,12 +8,17 @@ with the eviction path broken comes out ``correct: false``.  A third toy's
 gangs enter as Jobs of ``examples/job.yaml``'s shape (``entry: jobs``):
 admission and the controllers stand between the client and the store, a round
 is two cycles and three pumps to Running, and each count of that entry goes
-to 1 when the toy is broken the matching way."""
+to 1 when the toy is broken the matching way; with ``probe.fill_pods`` its
+probe brings a fill of its own, which the client places as pods among the
+Jobs, and a fill that is counted under a Job, placed past a node's room or
+deleted behind the client comes out ``correct: false``."""
 
 import json
 import os
 import shutil
+import statistics
 import time
+from collections import deque
 
 import pytest
 
@@ -208,8 +213,10 @@ def toy_jobs(request, tmp_path, monkeypatch):
     ``minAvailable`` 3, plugins ``ssh``, ``env``, ``svc``, ``PodEvicted`` ->
     ``RestartJob``, ``maxRetry`` 5), four of them a burst, and three
     per-layer metrics that read the new spans with the ``span`` reader as it
-    is.  A test may give the traffic's ``max_pumps`` (``request.param``)."""
-    max_pumps = getattr(request, "param", None)
+    is.  A test may give the traffic's ``max_pumps`` (``request.param``) or,
+    as a dict, the configuration's ``probe`` block."""
+    param = getattr(request, "param", None)
+    max_pumps = None if isinstance(param, dict) else param
     real = json.loads((ROOT / "BENCHMARK.json").read_text())
     home = tmp_path / "benchmark"
     shutil.copytree(ROOT / "benchmark" / "layer_metrics", home / "layer_metrics")
@@ -221,6 +228,8 @@ def toy_jobs(request, tmp_path, monkeypatch):
     config["pods"] = {"cpu_choices": [1], "mem_gi_choices": [1]}
     config["gang"] = {"size": 6, "min_member": 3}
     config["probe"] = {"probes": 4, "before_drain": 1, "keep_pods": 24}
+    if isinstance(param, dict):
+        config["probe"] = param
     (home / "configs" / "toyjob.json").write_text(json.dumps(config))
     traffic = {"name": "asjobs", "entry": "jobs", "resident_fraction": 0.0,
                "batch_fraction": 0.5, "warmup_rounds": 1, "max_cycles": 6,
@@ -247,6 +256,16 @@ def toy_jobs(request, tmp_path, monkeypatch):
             "name": name, "unit": unit, "better": "lower",
             "source": "host_clock", "layer": "admission and controllers",
             "moves": "bind_rate", "workloads": ["toyjob.asjobs"]})
+    # and the store's own count of what the kubelet's reports cost it, a
+    # nested key of the record's ``between`` block, by the ``record`` reader
+    (home / "layer_metrics" / "pod_updates.json").write_text(json.dumps({
+        "name": "pod_updates", "unit": "count", "layer": "store + mirror",
+        "moves": "bind_rate", "reader": "record",
+        "args": {"key": "between.events.Pod/update.n"}}))
+    real["per_layer"].append({
+        "name": "pod_updates", "unit": "count", "better": "lower",
+        "source": "program_counter", "layer": "store + mirror",
+        "moves": "bind_rate", "workloads": ["toyjob.asjobs"]})
     return _bench_file(tmp_path, monkeypatch, real)
 
 
@@ -310,6 +329,28 @@ def test_toy_cell_runs_end_to_end(toy, capsys, trace):
             "gang_broken", "lost", "ghost", "probe_misses"} \
         <= set(result["compared"])
     assert any("0 evictions seen" in ln for ln in lines)
+
+
+def test_under_pods_the_probes_rounds_and_the_backlogs_clock_are_as_they_were(
+        toy, monkeypatch, capsys):
+    """No ``fill_pods`` and ``entry: pods``: after the window the fill and
+    the probes, no round more; the backlog's clock starts at the round's
+    last ``add_pod``, which is where its submit ends."""
+    seen = _keep_driver(monkeypatch)
+    cell = cell_mod.load_cell("toy.drip", toy)
+    result = bench_run.run(cell, seed=2**31 + 54, seconds=0.5, trace=False)
+    capsys.readouterr()
+    rounds = seen["driver"].rounds
+    tags = [r.plan.tag for r in rounds]
+    assert tags[tags.index("probefill"):] == ["probefill"] + [
+        f"probe{k:03d}" for k in range(cell.config["probe"]["probes"])]
+    assert all(r.jobs is None and r.t_admitted == 0 and not r.pumps for r in rounds)
+    counted = [r for r in rounds if r.plan.tag.startswith("w0")]
+    waits = [(max(t for t, _k, _h in r.arrivals) - r.t_submitted) / 1e6
+             for r in counted]
+    assert result["metrics"]["backlog_to_bind_ms"]["value"] \
+        == pytest.approx(statistics.median(waits))
+    assert all(r.submit_ns[-1] <= r.t_submitted for r in counted)
 
 
 @pytest.mark.parametrize("trace,toy_preempt", [(0, 0), (1, 1)],
@@ -463,6 +504,9 @@ def test_toy_jobs_cell_takes_a_job_from_the_users_call_to_running(
         metrics = result["metrics"]
         assert {"admit_us_per_pod", "pump_ms", "reconcile_ms"} <= set(metrics)
         assert metrics["pump_ms"]["value"] > metrics["reconcile_ms"]["value"] > 0
+        # a round's cycles see the last round's 24 Succeeded reports and the
+        # Running reports made between its own two
+        assert metrics["pod_updates"]["value"] >= 24
         # the first cycle of a round meets PodGroups that have no pods, and
         # the fast cycle takes them
         assert all([rec["path"] for rec in r.records] == ["fast", "fast"]
@@ -471,6 +515,15 @@ def test_toy_jobs_cell_takes_a_job_from_the_users_call_to_running(
     else:
         assert set(result["metrics"]) == {"bind_rate", "backlog_to_bind_ms",
                                           "submit_to_bind_p95_ms", "setup_s"}
+        # the backlog stands when the user has made the last call: the
+        # controllers' first pump lies inside backlog_to_bind_ms, not before
+        waits = [(max(t for t, _k, _h in r.arrivals) - r.t_admitted) / 1e6
+                 for r in counted]
+        assert result["metrics"]["backlog_to_bind_ms"]["value"] \
+            == pytest.approx(statistics.median(waits))
+        for wait, r in zip(waits, counted):
+            old = wait - (r.t_submitted - r.t_admitted) / 1e6   # PR 52's clock
+            assert wait - old >= r.pump_s["submit"] * 1e3 > 0
 
 
 def test_a_jobs_cell_with_an_affinity_mix_or_no_running_pods_does_not_load(toy_jobs):
@@ -664,17 +717,54 @@ def _broken_skips_a_delete(store, conf):
     return loop.default_scheduler(store, conf)
 
 
-def _broken_slow_controller(store, conf):
-    """A job controller that handles three requests a pump: with
-    ``max_pumps: 1`` it is never pumped to the end."""
+class _HoldsOnce(deque):
+    """A work queue that holds an equal request once, as the reference's
+    does: a request equal to one that waits is not added again."""
+
+    def __init__(self):
+        super().__init__()
+        self.waiting, self.folded = set(), 0
+
+    @staticmethod
+    def _key(r):
+        return (r.namespace, r.job_name, r.task_name, r.event, r.exit_code,
+                r.action, r.job_version)
+
+    def append(self, req):
+        if self._key(req) in self.waiting:
+            self.folded += 1
+        else:
+            self.waiting.add(self._key(req))
+            super().append(req)
+
+    def popleft(self):
+        req = super().popleft()
+        self.waiting.discard(self._key(req))
+        return req
+
+
+def _broken_slow_controller(store, conf, folds=False):
+    """A job controller that handles one request a pump: with
+    ``max_pumps: 1`` it is never pumped to the end, whatever its queue
+    (``folds``: ``JobController.queue`` planted to hold an equal request
+    once; the controller is built by now and its queue is still empty)."""
     from volcano_tpu.controllers import JobController
 
     patch = pytest.MonkeyPatch()
     process_all = JobController.process_all
     patch.setattr(JobController, "process_all",
-                  lambda self, max_iters=3: process_all(self, 3))
+                  lambda self, max_iters=1: process_all(self, 1))
+    if folds:
+        queues = {}
+        patch.setattr(JobController, "queue", property(
+            lambda self: queues.setdefault(id(self), _HoldsOnce()),
+            lambda self, value: None), raising=False)
     _broken_slow_controller.undo = patch.undo
     return loop.default_scheduler(store, conf)
+
+
+def _broken_slow_controller_that_folds(store, conf):
+    return _broken_slow_controller(store, conf, folds=True)
 
 
 JOBS_PATH = {_broken_renames_a_pod, _broken_withholds_running,
@@ -727,18 +817,29 @@ def test_broken_timed_path_is_not_correct(request, capsys, broken, symptoms):
         assert "OUT OF PUMPS (max_pumps 4): reconcile in 1 rounds" in out
 
 
+@pytest.mark.parametrize("slow", [_broken_slow_controller,
+                                  _broken_slow_controller_that_folds],
+                         ids=["the_programs_queue", "a_queue_that_holds_once"])
 @pytest.mark.parametrize("toy_jobs", [1], indirect=True)
-def test_a_program_too_slow_to_reconcile_ends_with_its_last_line(toy_jobs, capsys):
+def test_a_program_too_slow_to_reconcile_ends_with_its_last_line(
+        toy_jobs, monkeypatch, capsys, slow):
     """No pump has a time limit, so every wait on the controllers is counted
     in pumps: a controller that cannot keep up leaves pods failed, and the
-    run goes on to its result line and says which phase ran out."""
+    run goes on to its result line and says which phase ran out.  Slow is
+    one request a pump, so that the guard holds whether the program's queue
+    keeps every request or holds an equal one once."""
+    seen = _keep_driver(monkeypatch)
     cell = cell_mod.load_cell("toyjob.asjobs", toy_jobs)
     assert cell.sizes()["max_pumps"] == 1
     try:
         result = bench_run.run(cell, seed=7, seconds=0.5, trace=False,
-                               make_scheduler=_broken_slow_controller)
+                               make_scheduler=slow)
+        queue = seen["driver"].jobs.manager.job_controller.queue
     finally:
         _broken_slow_controller.undo()
+    # the plant took: equal requests were folded there, and only there
+    assert getattr(queue, "folded", 0) > 0 \
+        if slow is _broken_slow_controller_that_folds else type(queue) is deque
     out = capsys.readouterr().out
     assert set(result) == RESULT_KEYS
     assert result["correct"] is False and result["failed"] > 0
@@ -746,6 +847,171 @@ def test_a_program_too_slow_to_reconcile_ends_with_its_last_line(toy_jobs, capsy
     assert result["compared"]["unbound"]["value"] > 0
     line = [ln for ln in out.splitlines() if "OUT OF PUMPS (max_pumps 1)" in ln]
     assert line and "its pumps took up to" in line[0], out[-2000:]
+
+
+# ---- the probe's own fill (``probe.fill_pods``), on the same toy -------------
+
+
+FILL = {"probes": 4, "fill_pods": 48}
+FILL_AND_DRAIN = dict(FILL, before_drain=1, keep_pods=24)
+
+
+@pytest.mark.parametrize("toy_jobs", [FILL, FILL_AND_DRAIN], indirect=True,
+                         ids=["no_drain", "a_drain_through_it"])
+def test_toy_jobs_probe_brings_a_fill_of_its_own(toy_jobs, monkeypatch, capsys):
+    """``probe.fill_pods``: after the batch-sized fill, which enters as Jobs
+    like the window's batches, one round of that many pods in the
+    configuration's gang shape which the client places itself, as pods, on
+    the emptiest nodes; they run and stay, the ledger holds them like any
+    round's pods and no count of a Jobs cell sees them.  A drain that
+    reaches them completes Jobs as Jobs and their gangs as pods."""
+    from volcano_tpu.api import PodPhase
+
+    seen = _keep_driver(monkeypatch)
+    cell = cell_mod.load_cell("toyjob.asjobs", toy_jobs)
+    drains = "keep_pods" in cell.config["probe"]
+    result = bench_run.run(cell, seed=2**31 + 54, seconds=0.5, trace=False)
+    out = capsys.readouterr().out
+    driver = seen["driver"]
+    assert result["correct"] is True and result["failed"] == 0, out[-3000:]
+    assert {name for name, c in result["compared"].items() if c["value"]} \
+        == {"fullest_node"}
+    assert {"pods_not_as_planned", "jobs_not_running", "jobs_left_behind",
+            "lost", "ghost", "unbound", "oversubscribed", "probe_misses"} \
+        <= set(result["compared"])
+    tags = [r.plan.tag for r in driver.rounds]
+    after = tags[tags.index("probefill"):]
+    assert after == ["probefill", "probefill-own"] + [f"probe{k:03d}" for k in range(4)]
+    fill, own, first = driver.rounds[-6:-3]
+    # the batch-sized fill is the controllers' to make, the probe's own is not
+    assert fill.plan.n_pods == 24 and fill.jobs is not None and fill.pumps["submit"] == 1
+    assert len(fill.jobs.created) == 24 and not fill.deleted
+    assert own.plan.n_pods == 48 and own.plan.sizes().tolist() == [6] * 8
+    assert own.plan.gang_min_member.tolist() == [3] * 8
+    assert own.jobs is None and own.pumps == {} and own.t_admitted == 0
+    assert own.cycles == 0 and not own.deleted and own.spans()["schedule"] == 0
+    # no bind of it reached the binder: its arrival is the client's placement,
+    # one pod at a time on the node that held the fewest (8 cpu a node: the
+    # window's and the fill's Jobs stand 8 a node on the first three)
+    (_t, keys, hosts), = own.arrivals
+    assert keys == own.plan.keys()
+    assert not set(keys) & {k for _t, ks, _h in driver.binder.arrivals for k in ks}
+    assert hosts == [f"node-{i:06d}" for i in list(range(3, 48)) + [3, 4, 5]]
+    # the next pump learns its records and counts none as made under a Job
+    assert sorted(k for k, _owner in first.jobs.created) == first.plan.keys()
+    assert set(own.plan.keys()) <= set(driver.jobs.uid_of)
+    store = driver.store
+    left = [p for p in store.pods.values() if p.name.startswith("probefill-own")]
+    if drains:
+        # keep_pods 24: the four Jobs of the fill finish as Jobs, then five
+        # gangs of the probe's own as pods, in the first probe's round
+        assert sorted(first.deleted) == sorted(fill.plan.keys()
+                                               + own.plan.keys()[:30])
+        assert len(left) == 18 and first.pumps["complete"] == 2
+        assert not [k for k in store.pod_groups if "probefill-own-pg-000004" in k]
+    else:
+        assert len(left) == 48
+    placed = dict(zip(keys, hosts))
+    for pod in left:
+        assert (pod.owner_job, pod.uid, pod.phase, pod.node_name) \
+            == ("", f"bench-{pod.name}", PodPhase.Running,
+                placed[f"default/{pod.name}"])
+    assert sorted(store.batch_jobs) == sorted(
+        job.key for job, pods in driver.fifo if isinstance(pods[0], str))
+    line = [ln for ln in out.splitlines() if ln.startswith("after the window")]
+    assert "48 pods of its own, placed by the client as pods" in line[0]
+    assert "at the first probe 42 nodes hold 1, 3 nodes hold 2, 3 nodes hold 8" \
+        in line[0]
+
+
+def _fill_counted_under_a_job(store, conf):
+    """The harness's own book wrong: the probe's own fill recorded as a
+    round of Jobs, so its pods are owed under Jobs nobody made."""
+    from benchmark.harness.validate import JobEvents
+
+    patch = pytest.MonkeyPatch()
+    place = loop.Driver.place
+
+    def as_jobs(self, plan, hosts):
+        rec = place(self, plan, hosts)
+        rec.jobs = JobEvents((), (), ())
+        return rec
+
+    patch.setattr(loop.Driver, "place", as_jobs)
+    _fill_counted_under_a_job.undo = patch.undo
+    return loop.default_scheduler(store, conf)
+
+
+def _fill_dealt_to_one_node(store, conf):
+    """The harness's own dealing wrong: every pod of the probe's own fill
+    placed on the first node, which the fill's Jobs have filled."""
+    from benchmark.harness import probe
+
+    patch = pytest.MonkeyPatch()
+    patch.setattr(probe, "_deal",
+                  lambda driver, plan: ["node-000000"] * plan.n_pods)
+    _fill_dealt_to_one_node.undo = patch.undo
+    return loop.default_scheduler(store, conf)
+
+
+class _DeletesOfTheFill:
+    """A scheduler that once deletes a running pod of the probe's own fill."""
+
+    def __init__(self, real, store):
+        self.real, self.store, self.done = real, store, False
+
+    def run_once(self):
+        self.real.run_once()
+        own = [] if self.done else [
+            p for p in self.store.pods.values() if p.phase == "Running"
+            and p.name.startswith("probefill-own")]
+        if own:
+            self.store.delete_pod(own[-1])
+            self.done = True
+
+
+def _fill_deleted_behind_the_client(store, conf):
+    return _DeletesOfTheFill(loop.default_scheduler(store, conf), store)
+
+
+@pytest.mark.parametrize("toy_jobs", [FILL], indirect=True, ids=["fill_pods"])
+@pytest.mark.parametrize("broken,symptom", [
+    (_fill_counted_under_a_job, "pods_not_as_planned"),
+    (_fill_dealt_to_one_node, "oversubscribed"),
+    (_fill_deleted_behind_the_client, "lost")])
+def test_a_fill_of_the_probes_own_that_goes_wrong_is_not_correct(
+        toy_jobs, capsys, broken, symptom):
+    cell = cell_mod.load_cell("toyjob.asjobs", toy_jobs)
+    try:
+        result = bench_run.run(cell, seed=7, seconds=0.5, trace=False,
+                               make_scheduler=broken)
+    finally:
+        getattr(broken, "undo", lambda: None)()
+    out = capsys.readouterr().out
+    assert result["correct"] is False and result["failed"] > 0
+    assert result["compared"][symptom]["value"] >= 1, out[-3000:]
+    assert f"validate: {symptom} = {result['compared'][symptom]['value']} " in out
+    # the window and the batch-sized fill were sound: the fill alone is caught
+    assert result["compared"]["unbound"]["value"] == 0
+
+
+def test_a_fill_past_the_clusters_room_does_not_load(toy_jobs):
+    """Window, fill, the probe's own fill and the probes stay under the
+    cluster's cpu, memory and pod slots, by the files' own numbers."""
+    home = toy_jobs.parent / "benchmark"
+    config = json.loads((home / "configs" / "toyjob.json").read_text())
+
+    def load(**probe):
+        (home / "configs" / "toyjob.json").write_text(json.dumps(
+            dict(config, probe=dict(config["probe"], **probe))))
+        return cell_mod.load_cell("toyjob.asjobs", toy_jobs)
+
+    # 48 nodes of 8 cpu: 24 + 24 + 331 + 4 = 383 of 384
+    assert load(fill_pods=331).config["probe"]["fill_pods"] == 331
+    with pytest.raises(SystemExit, match="fill_pods 332.*384 cpu of the cluster's 384"):
+        load(fill_pods=332)
+    with pytest.raises(SystemExit, match="at least one pod"):
+        load(fill_pods=0)
 
 
 def test_no_accelerator_and_no_cpu_named_fails(toy, monkeypatch):

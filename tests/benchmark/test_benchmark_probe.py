@@ -3,7 +3,11 @@ single pending pod equals the plain float64 reference's over every node,
 with both configurations' shapes (here at a size a test run can hold); a
 record with one probe pod on the second-best node fails; and the control
 (the reference in bfloat16, the precision below the float32 the
-configurations state, in the program's place) misses.
+configurations state, in the program's place) misses.  Where every pod is
+1 cpu / 1 Gi on a 64 cpu / 256 Gi node the control can miss only on a cluster
+that stands at 3 or 4 pods a node (the reckoning is a test here), which is
+what ``probe.fill_pods`` is for: that many pods which the client places on
+the emptiest nodes before the first one-pod gang.
 """
 
 import copy
@@ -139,3 +143,97 @@ def test_reference_score_is_the_sum_of_the_three_plugins():
             + 10 * (1 - abs(cf - mf)))
     got = score_ref.scores(alloc, used, (4000, 8 * 2**30))[0]
     assert got == pytest.approx(want, abs=1e-12)
+
+
+# ---- the probe's own fill (``probe.fill_pods``) ------------------------------
+
+GI = 2**30
+
+
+@pytest.mark.parametrize("more_first", [True, False],
+                         ids=["one_more_on_the_first", "one_more_on_the_last"])
+@pytest.mark.parametrize("k,least", [(0, 0), (1, 0), (2, 0), (3, 10), (4, 0)])
+def test_the_control_is_blind_until_every_node_holds_three_pods(k, least, more_first):
+    """Why a cell of 1 cpu / 1 Gi pods needs the fill (ISSUE 54): binpack and
+    least-requested cancel, balanced is 10 - 30 (k + 1) / 256 for a node's
+    (k + 1)-th pod, so the emptiest node wins and bfloat16 names it too
+    until a node's 4th and 5th pod both score 19.5.  10,000 nodes, half of
+    them at k pods and half at k + 1, 12 one-pod choices one after the
+    other, the ledger following the reference: the control misses none at
+    0/1, 1/2 and 2/3 pods a node and at least 10 of 12 at 3/4; at 4/5 it is
+    blind again (19.5 against 19.25), so a fill is sized to leave 3 and 4."""
+    n = 10_000
+    alloc = np.tile(np.array([[64_000, 256 * GI, 256]], dtype=np.int64), (n, 1))
+    held = np.full(n, k, dtype=np.int64)
+    held[:n // 2] += more_first
+    held[n // 2:] += not more_first
+    used = np.stack([held * 1000, held * GI, held], axis=1)
+    req = (1000, GI)
+    misses = 0
+    for _ in range(12):
+        want = score_ref.choose(alloc, used, req)
+        misses += score_ref.choose(alloc, used, req, ml_dtypes.bfloat16) != want
+        assert used[want, 2] == k            # the reference takes an emptiest node
+        used[want] += (1000, GI, 1)
+    assert misses >= least and (least or misses == 0), misses
+
+
+SMALL = copy.deepcopy(CONFIGS["north-10k"])
+SMALL.update(pods={"cpu_choices": [1], "mem_gi_choices": [1]},
+             gang={"size": 6, "min_member": 3})
+OWN = 190       # 64 nodes: 3 a node, and two that hold one more than they would
+
+
+def _record_with(par, config=SMALL, batch=60, seed=SEEDS[0]):
+    gen = generate.Generator(config, seed)
+    driver = loop.Driver(config)
+    try:
+        driver.round(gen.plan(batch, "w0000"), batch)
+        first = probe.drive(driver, gen, batch, par)
+        return driver.rounds, first, driver.live_keys()
+    finally:
+        driver.close()
+
+
+def test_the_probes_own_fill_is_placed_level_and_the_ledger_holds_it():
+    """Under ``entry: pods``: the batch-sized fill goes through the scheduler
+    (60 pods of 1 cpu land on one node), then ``fill_pods`` pods which the
+    client places on the emptiest nodes: no cycle, no completion, the ledger
+    holds them, every other node ends at 3 or 4 pods, the program's 12
+    choices are the reference's and the control misses nearly all."""
+    rounds, first, live = _record_with({"probes": 12, "fill_pods": OWN})
+    assert [r.plan.tag for r in rounds] == ["w0000", "probefill", "probefill-own"] \
+        + [f"probe{k:03d}" for k in range(12)]
+    own = rounds[first - 1]
+    assert (own.plan.n_pods, own.cycles, own.deleted) == (OWN, 0, [])
+    assert own.plan.sizes().tolist()[:-1] == [6] * 31 and own.plan.gang_min_member[0] == 3
+    (_t, keys, hosts), = own.arrivals
+    assert keys == own.plan.keys() and set(keys) <= set(live)
+    # the scheduler packed the fill's 60 pods onto node 0; the others take
+    # the probe's own in turn, and the first of them one more
+    assert hosts == [f"node-{i:06d}" for i in list(range(1, 64)) * 3 + [1]]
+    events = [r.events() for r in rounds]
+    guarantees, verdict = _check(SMALL, events, first, live_keys=live)
+    assert guarantees.ok and guarantees.submitted == 60 + 60 + OWN + 12
+    assert (guarantees.lost, guarantees.ghost, guarantees.oversubscribed) == (0, 0, 0)
+    assert verdict.nodes_by_pods == {3: 62, 4: 1, 60: 1}
+    assert verdict.probes == 12 and verdict.misses == 0, verdict.examples
+    _, control = _check(SMALL, events, first, control_dtype=ml_dtypes.bfloat16)
+    assert control.misses >= 10
+
+
+def test_without_the_key_the_probe_makes_the_rounds_it_made():
+    rounds, first, _live = _record_with({"probes": 3, "before_drain": 1,
+                                         "keep_pods": 30})
+    assert [r.plan.tag for r in rounds] == ["w0000", "probefill", "probe000",
+                                            "probe001", "probe002"]
+    assert first == 2 and all(r.cycles >= 1 for r in rounds)
+    # the drain, where it was: after the first one-pod gang
+    assert [len(r.deleted) for r in rounds] == [60, 0, 36, 0, 0]
+
+
+def test_a_fill_that_finds_no_room_says_so():
+    tiny = copy.deepcopy(SMALL)
+    tiny["nodes"]["count"] = 2
+    with pytest.raises(RuntimeError, match="no node has room for pod 68 of 70"):
+        _record_with({"probes": 1, "fill_pods": 70}, config=tiny)
